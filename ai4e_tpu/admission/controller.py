@@ -6,7 +6,7 @@ shape with fixed knobs (``submit_concurrency=64``, a hand-picked
 ``dispatcher_concurrency``, an unbounded gateway sync proxy). A static cap
 is wrong in both directions: too low and the device idles under headroom,
 too high and queueing delay eats every deadline the moment latency shifts
-(a checkpoint reload, a degraded tunnel, a noisy neighbor).
+(a checkpoint reload, a slow backend, a noisy neighbor).
 
 ``GradientLimiter`` is a latency-gradient AIMD limiter (the
 Netflix-concurrency-limits / TCP-Vegas family): it tracks the observed

@@ -57,8 +57,7 @@ VALIDATION_EVENT = "ai4e.subscription.validation"
 # speaks): event metadata rides headers, the task body rides the HTTP body
 # RAW. The structured JSON envelope decodes the body surrogateescape and
 # escapes it into a JSON string — for the image configs' ~100-200 kB binary
-# payloads that is megabytes/s of pure (de)escaping per hop, measured as the
-# r3 push-vs-queue 3x gap (bench_results/r3-tpu/landcover_push.json). Task
+# payloads that is megabytes/s of pure (de)escaping per hop. Task
 # events default to binary mode; the validation handshake and any external
 # publisher keep the structured envelope (the webhook accepts both).
 HDR_EVENT_ID = "X-AI4E-Event-Id"

@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import inspect
 import json
 import logging
 import signal
@@ -267,7 +268,7 @@ def build_worker(config: FrameworkConfig, models: dict):
     )
 
     rt = config.runtime
-    enable_compilation_cache(rt.compile_cache_dir)
+    cache_dir = enable_compilation_cache()
     # Multi-host slice: JAX_COORDINATOR_ADDRESS et al. initialise the DCN
     # plane (no-op single-process); the default mesh then spans every host.
     from .parallel import init_distributed
@@ -389,7 +390,7 @@ def build_worker(config: FrameworkConfig, models: dict):
             max_programs=rt.ladder_max_programs,
             period_s=rt.ladder_period_s, dwell_s=rt.ladder_dwell_s,
             persist_path=(rt.ladder_path or os.path.join(
-                rt.compile_cache_dir, "ladders.json")))
+                cache_dir, "ladders.json")))
         restored = ladders.restore()
         if restored:
             log.info("restored derived ladders for %s",
@@ -595,9 +596,49 @@ async def run_control_plane(config: FrameworkConfig, routes: dict) -> None:
         await runner.cleanup()
 
 
+def _claim_devices(rt) -> None:
+    """The worker role's device boundary: pin the platform the operator
+    named (``AI4E_RUNTIME_PLATFORM`` overrides an inherited
+    ``JAX_PLATFORMS``), bring the backend up, and refuse to serve from a
+    CPU nobody asked for. With no pin JAX takes the best backend it can
+    initialise, and on a host whose chip is missing or held by another
+    process that is the CPU after one libtpu warning — a worker that
+    carried on would answer requests at CPU speed under a TPU deployment's
+    name. A pinned platform that is absent fails inside ``jax.devices()``
+    with JAX's own "Unable to initialize backend"."""
+    import jax
+
+    from .parallel import init_distributed
+    from .runtime.registry import device_report
+    if rt.platform:
+        jax.config.update("jax_platforms", rt.platform)
+    # Before the first backend touch: jax.distributed cannot start after it.
+    init_distributed()
+    report = device_report()
+    if report["platform"] == "cpu" and rt.platform != "cpu":
+        raise SystemExit(
+            "worker: JAX found no accelerator and came back with "
+            f"{report['device_count']} x {report['device_kind']!r} on "
+            f"platform 'cpu' (jax_platforms={jax.config.jax_platforms!r}); "
+            "refusing to serve from it unasked. Set "
+            "AI4E_RUNTIME_PLATFORM=cpu for a CPU worker, or free the chip "
+            "(one process holds it at a time).")
+
+
+async def _close(resource) -> None:
+    """``close()`` is a coroutine on the HTTP-backed task manager/store
+    and a plain method on the in-memory ones."""
+    closer = getattr(resource, "close", None)
+    if closer is not None:
+        result = closer()
+        if inspect.isawaitable(result):
+            await result
+
+
 async def run_worker(config: FrameworkConfig, models: dict) -> None:
     from aiohttp import web
 
+    _claim_devices(config.runtime)
     worker, batcher, task_manager = build_worker(config, models)
 
     import jax
@@ -626,8 +667,17 @@ async def run_worker(config: FrameworkConfig, models: dict) -> None:
                                interval_s=config.observability
                                .vitals_interval)
         await vitals.start()
-    log.info("worker on %s:%s serving %s%s%s%s%s%s", config.service.host,
-             config.service.port, list(worker.runtime.models),
+    from .runtime.registry import device_report
+    device = device_report(worker.runtime.mesh)
+    log.info("worker on %s:%s, device %s (%s) x%d, mesh %s, serving "
+             "%s%s%s%s%s%s", config.service.host, config.service.port,
+             # Device posture: what JAX says this process holds — the line
+             # to read before believing any number the worker produces.
+             device["platform"], device["device_kind"],
+             device["device_count"],
+             ",".join(f"{axis}={n}" for axis, n in device["mesh"].items()
+                      if n > 1) or "dp=1",
+             list(worker.runtime.models),
              # Mesh posture (docs/mesh_serving.md): the declared serving
              # layout doubles as the orchestration cost-tier label.
              (", mesh %s ON (tier %s)" % (
@@ -659,10 +709,8 @@ async def run_worker(config: FrameworkConfig, models: dict) -> None:
             worker.runtime.shutdown_followers()
         if worker.service.reporter is not None:
             await worker.service.reporter.close()
-        if hasattr(task_manager, "close"):
-            await task_manager.close()
-        if hasattr(worker.store, "close"):
-            await worker.store.close()
+        await _close(task_manager)
+        await _close(worker.store)
         await runner.cleanup()
 
 
@@ -923,12 +971,6 @@ def main(argv=None) -> None:
         return
     config = FrameworkConfig.from_env()
     config.observability.apply()
-    if config.runtime.platform:
-        # Must be a config update, not an env var: the TPU plugin force-sets
-        # jax_platforms at import, so AI4E_RUNTIME_PLATFORM=cpu is how a
-        # CPU-only node (e.g. the control plane) opts out of device init.
-        import jax
-        jax.config.update("jax_platforms", config.runtime.platform)
 
     if args.component == "control-plane":
         if args.port is not None:

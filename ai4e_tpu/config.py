@@ -56,6 +56,22 @@ class ConfigError(ValueError):
     pass
 
 
+# Where the compile cache goes when nobody outside the program said: one
+# fixed, git-ignored directory at the checkout root — no pid, timestamp or
+# temp name, so every process of every run resolves the same path.
+_CHECKOUT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def compile_cache_dir() -> str:
+    """The persistent XLA compile cache directory: ``JAX_COMPILATION_
+    CACHE_DIR`` where the environment sets it (JAX reads that variable
+    itself — the program then sets no directory in code), else the fixed
+    in-checkout path. No JAX import: launch scripts resolve it too."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_COMPILE_CACHE
+
+
 def _parse(raw: str, typ, name: str):
     """Parse an env string per the declared field type."""
     origin = typing.get_origin(typ)
@@ -369,9 +385,8 @@ class RuntimeSection:
     batch_max_wait_ms: float = 5.0
     batch_max_pending: int = 256
     # In-flight device batches (MicroBatcher pipeline window). 2 = double
-    # buffering, right for a locally-attached chip; raise to ~6 when the
-    # host↔device link is long-fat (remote-attached TPU) so transfers of
-    # several batches overlap.
+    # buffering: one batch transfers while one executes. Not re-measured
+    # on a locally attached chip.
     batch_pipeline_depth: int = 2
     # Priority-class batching (batch-API stacks run at background priority):
     # fraction of batch_max_pending reserved for interactive admissions, and
@@ -394,9 +409,9 @@ class RuntimeSection:
     ladder_max_programs: int = 16        # compiled-programs budget per model
     ladder_period_s: float = 60.0        # re-derive cadence per model
     ladder_dwell_s: float = 120.0        # min seconds between ladder swaps
-    # Persisted derived-ladder file; unset = <compile_cache_dir>/ladders.json
-    # (beside the persistent compilation cache, so a restart AOT-warms the
-    # traffic-tuned ladder).
+    # Persisted derived-ladder file; unset = ladders.json in
+    # compile_cache_dir() (beside the persistent compilation cache, so a
+    # restart AOT-warms the traffic-tuned ladder).
     ladder_path: typing.Optional[str] = None
     buckets: typing.Tuple[int, ...] = (1, 8, 32, 64)
     # Continuous-batching decode engine (runtime/decode.py,
@@ -415,7 +430,6 @@ class RuntimeSection:
     # (prompt + generated tokens must fit under it).
     kv_slots: int = 8
     kv_max_len: int = 256
-    compile_cache_dir: str = "/tmp/ai4e_tpu_xla_cache"
     checkpoint_dir: typing.Optional[str] = None
     donate_batch: bool = False
     # mesh axes; 0 = infer from device count

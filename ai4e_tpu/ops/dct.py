@@ -1,10 +1,11 @@
 """DCT-truncation host↔device wire codec — JPEG-grade h2d compression whose
 decoder is two small matmuls (MXU work), not entropy decoding.
 
-The yuv420 wire (``ops/yuv.py``) halved h2d bytes and still left the chip
-~80% idle behind the link on the image configs (r3:
-``bench_results/r3-tpu/landcover_yuv.json`` — 170.8 req/s delivered vs 841
-device capability). The remaining compression JPEG gets comes from the DCT:
+The yuv420 wire (``ops/yuv.py``) halves h2d bytes. Whether the host→device
+link bounds the image configs on a locally attached chip has not been
+measured (ROADMAP.md Speed 8 decides this codec's fate on that number; it
+has never run on a chip). The remaining compression JPEG gets comes from
+the DCT:
 after an 8×8 block transform, camera imagery concentrates its energy in the
 low-frequency corner, and coarse quantization of the rest is visually
 lossless. JPEG spends that insight on Huffman coding — sequential, hostile

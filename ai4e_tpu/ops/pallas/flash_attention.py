@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .lowering import resolve_interpret, shard_over_batch
+
 NEG_INF = -1e30  # large-but-finite: avoids (-inf) - (-inf) NaNs in the kernel
 
 
@@ -360,22 +362,28 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int | None = None,
     ``block_q``/``block_k`` default per head_dim via :func:`default_blocks`
     (512/1024 at D≤128, shrinking for larger D to bound VMEM).
     ``interpret`` defaults to True off-TPU (CPU CI runs the pallas
-    interpreter; on device it compiles to Mosaic). ``mesh``/``batch_axes``
-    are accepted (and ignored) so ``attention_for`` can treat this as a
+    interpreter; on device it compiles to Mosaic). ``mesh``: the serving
+    mesh when the batch is sharded over its data axes — each device then
+    attends over its own examples (``lowering.shard_over_batch``);
+    ``batch_axes`` is accepted so ``attention_for`` can treat this as a
     drop-in strategy alongside ring/Ulysses.
     """
-    del mesh, batch_axes
-    b, h, s_q, d = q.shape
+    del batch_axes
+    _, h, s_q, d = q.shape
     s_k = k.shape[2]
     if causal and s_q != s_k:
         raise ValueError("causal flash attention expects S_q == S_k")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret("flash_attention", interpret)
     dq, dk = default_blocks(d)
     block_q = _dividing_block(s_q, block_q if block_q is not None else dq)
     block_k = _dividing_block(s_k, block_k if block_k is not None else dk)
 
-    out = _flash3(q.reshape(b * h, s_q, d), k.reshape(b * h, s_k, d),
-                  v.reshape(b * h, s_k, d), causal, block_q, block_k,
-                  interpret)
-    return out.reshape(b, h, s_q, d)
+    def call(qkv):
+        q, k, v = qkv
+        n = q.shape[0]  # this device's share of the batch
+        out = _flash3(q.reshape(n * h, s_q, d), k.reshape(n * h, s_k, d),
+                      v.reshape(n * h, s_k, d), causal, block_q, block_k,
+                      interpret)
+        return out.reshape(n, h, s_q, d)
+
+    return shard_over_batch(call, mesh, interpret)((q, k, v))

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .lowering import resolve_interpret, shard_over_batch
+
 
 def _normalize_kernel(img_ref, scale_ref, bias_ref, out_ref):
     # img_ref: (1, TH, W*C) uint8; out: (1, TH, W*C) float32
@@ -31,13 +33,15 @@ def _normalize_kernel(img_ref, scale_ref, bias_ref, out_ref):
 
 
 def normalize_image(images: jax.Array, mean=None, std=None,
-                    tile_h: int = 64, interpret: bool | None = None) -> jax.Array:
-    """(B, H, W, C) uint8 → (B, H, W, C) float32 in normalized range."""
+                    tile_h: int = 64, interpret: bool | None = None,
+                    mesh=None) -> jax.Array:
+    """(B, H, W, C) uint8 → (B, H, W, C) float32 in normalized range.
+    ``mesh``: the serving mesh when the batch is sharded over its data axes
+    (``lowering.shard_over_batch``)."""
     b, h, w, c = images.shape
     if images.dtype != jnp.uint8:
         raise ValueError(f"expected uint8 input, got {images.dtype}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret("normalize_image", interpret)
     # Largest divisor of H within the target keeps the grid exact for
     # non-multiple-of-64 sizes (224 → 56, 512 → 64) — but never below the
     # 8-sublane minimum Mosaic tiles f32 at: a prime-ish H would otherwise
@@ -55,21 +59,25 @@ def normalize_image(images: jax.Array, mean=None, std=None,
     scale_row = jnp.tile(1.0 / (255.0 * std), w)    # (W*C,)
     bias_row = jnp.tile(-mean / std, w)
 
-    flat = images.reshape(b, h, w * c)
-    out = pl.pallas_call(
-        _normalize_kernel,
-        out_shape=jax.ShapeDtypeStruct((b, h, w * c), jnp.float32),
-        grid=(b, h // tile_h),
-        in_specs=[
-            pl.BlockSpec((1, tile_h, w * c), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, w * c), lambda i, j: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, w * c), lambda i, j: (0, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, tile_h, w * c), lambda i, j: (i, j, 0),
-                               memory_space=pltpu.VMEM),
-        interpret=interpret,
-    )(flat, scale_row[None], bias_row[None])
+    def call(flat, scale, bias):
+        n = flat.shape[0]  # this device's share of the batch
+        return pl.pallas_call(
+            _normalize_kernel,
+            out_shape=jax.ShapeDtypeStruct((n, h, w * c), jnp.float32),
+            grid=(n, h // tile_h),
+            in_specs=[
+                pl.BlockSpec((1, tile_h, w * c), lambda i, j: (i, j, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, w * c), lambda i, j: (0, 0),
+                             memory_space=pltpu.VMEM),
+                pl.BlockSpec((1, w * c), lambda i, j: (0, 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_specs=pl.BlockSpec((1, tile_h, w * c), lambda i, j: (i, j, 0),
+                                   memory_space=pltpu.VMEM),
+            interpret=interpret,
+        )(flat, scale, bias)
+
+    out = shard_over_batch(call, mesh, interpret, replicated_args=2)(
+        images.reshape(b, h, w * c), scale_row[None], bias_row[None])
     return out.reshape(b, h, w, c)

@@ -30,13 +30,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .lowering import resolve_interpret, shard_over_batch
+
 
 def _argmax_kernel(logits_ref, out_ref, *, num_classes: int):
-    # logits_ref: (1, C, TH, W); out_ref: (1, TH, W) uint8
-    best = logits_ref[0, 0]
+    # logits_ref: (1, C, TH, W); out_ref: (1, TH, W) uint8. Compared in
+    # float32 whatever the logits dtype: a bfloat16 compare yields a mask in
+    # bf16's (16, 128) tiling, and Mosaic cannot relayout that i1 vector to
+    # select the int32 index plane ("Invalid relayout ... xi1"). The widening
+    # is exact, so the argmax is unchanged.
+    best = logits_ref[0, 0].astype(jnp.float32)
     idx = jnp.zeros(best.shape, jnp.int32)
     for c in range(1, num_classes):
-        cand = logits_ref[0, c]
+        cand = logits_ref[0, c].astype(jnp.float32)
         take = cand > best
         best = jnp.where(take, cand, best)
         idx = jnp.where(take, c, idx)
@@ -44,31 +50,37 @@ def _argmax_kernel(logits_ref, out_ref, *, num_classes: int):
 
 
 def segmentation_argmax(logits: jax.Array, tile_h: int = 64,
-                        interpret: bool | None = None) -> jax.Array:
+                        interpret: bool | None = None,
+                        mesh=None) -> jax.Array:
     """(B, H, W, C) float32/bfloat16 logits → (B, H, W) uint8 class map.
 
     ``interpret`` defaults to True off-TPU so the same code path runs in CPU
-    CI (pallas interpreter) and compiles to Mosaic on device.
+    CI (pallas interpreter) and compiles to Mosaic on device. ``mesh``: the
+    serving mesh when the batch is sharded over its data axes
+    (``lowering.shard_over_batch``).
     """
-    b, h, w, c = logits.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    _, h, w, c = logits.shape
+    interpret = resolve_interpret("segmentation_argmax", interpret)
     tile_h = min(tile_h, h)
     if h % tile_h:
         raise ValueError(f"H={h} not divisible by tile_h={tile_h}")
 
-    logits_cf = jnp.transpose(logits, (0, 3, 1, 2))  # (B, C, H, W)
-    return pl.pallas_call(
-        partial(_argmax_kernel, num_classes=c),
-        out_shape=jax.ShapeDtypeStruct((b, h, w), jnp.uint8),
-        grid=(b, h // tile_h),
-        in_specs=[pl.BlockSpec((1, c, tile_h, w),
-                               lambda i, j: (i, 0, j, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((1, tile_h, w), lambda i, j: (i, j, 0),
-                               memory_space=pltpu.VMEM),
-        interpret=interpret,
-    )(logits_cf)
+    def call(logits_cf):
+        n = logits_cf.shape[0]  # this device's share of the batch
+        return pl.pallas_call(
+            partial(_argmax_kernel, num_classes=c),
+            out_shape=jax.ShapeDtypeStruct((n, h, w), jnp.uint8),
+            grid=(n, h // tile_h),
+            in_specs=[pl.BlockSpec((1, c, tile_h, w),
+                                   lambda i, j: (i, 0, j, 0),
+                                   memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec((1, tile_h, w), lambda i, j: (i, j, 0),
+                                   memory_space=pltpu.VMEM),
+            interpret=interpret,
+        )(logits_cf)
+
+    return shard_over_batch(call, mesh, interpret)(
+        jnp.transpose(logits, (0, 3, 1, 2)))  # (B, C, H, W)
 
 
 def class_histogram(classmap: jax.Array, num_classes: int) -> jax.Array:
@@ -79,12 +91,12 @@ def class_histogram(classmap: jax.Array, num_classes: int) -> jax.Array:
 
 def fused_seg_postprocess(logits: jax.Array,
                           interpret: bool | None = None,
-                          with_classmap: bool = True) -> dict:
+                          with_classmap: bool = True, mesh=None) -> dict:
     """Full API postprocess: per-class counts, plus the uint8 class map when
     ``with_classmap``. Histogram-only APIs pass False so the map never leaves
     the device — the counts are B·C int32s, ~4000× less device→host traffic
     than the map (which itself is 16× less than the logits)."""
-    classmap = segmentation_argmax(logits, interpret=interpret)
+    classmap = segmentation_argmax(logits, interpret=interpret, mesh=mesh)
     counts = class_histogram(classmap, logits.shape[-1])
     if with_classmap:
         return {"classmap": classmap, "counts": counts}

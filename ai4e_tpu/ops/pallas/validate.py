@@ -6,8 +6,9 @@ proves they compile to Mosaic and fit VMEM on real hardware. This module is
 that proof: ``validate_kernels()`` runs each kernel with ``interpret=False``
 (on TPU) against a pure-XLA oracle and asserts its working set fits the
 per-core scoped-VMEM budget under double buffering. ``bench.py`` embeds the
-result in its JSON (``"pallas_tpu"``) whenever the bench lands on a TPU, so
-every driver bench run is also a kernel-validation artifact.
+result in its JSON (``"pallas_tpu"``) on the chip, and ``chip_smoke.py`` runs
+``python -m ai4e_tpu.ops.pallas.validate`` as its kernel phase — a failed
+kernel fails either run.
 
 VMEM accounting mirrors each kernel's BlockSpecs (pallas_guide.md: Mosaic
 double-buffers every in/out block; scratch is single-buffered).
@@ -111,3 +112,27 @@ def validate_kernels(interpret: bool = False) -> dict:
                             if isinstance(r, dict))
     results["interpret"] = interpret
     return results
+
+
+def main(argv=None) -> int:
+    """``python -m ai4e_tpu.ops.pallas.validate [--interpret]``: one JSON
+    line — the result plus the device it ran on — and exit 1 unless
+    ``all_ok``. Compiled (Mosaic) by default, which only a TPU can run;
+    ``--interpret`` is the CPU test mode."""
+    import argparse
+    import json
+
+    from ...runtime.registry import device_report, enable_compilation_cache
+
+    parser = argparse.ArgumentParser(prog="ai4e_tpu.ops.pallas.validate")
+    parser.add_argument("--interpret", action="store_true")
+    args = parser.parse_args(argv)
+    enable_compilation_cache()
+    result = validate_kernels(interpret=args.interpret)
+    result["device"] = device_report()
+    print(json.dumps(result), flush=True)
+    return 0 if result["all_ok"] else 1
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

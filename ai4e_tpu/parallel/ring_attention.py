@@ -28,20 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at the top level
-    from jax import shard_map
-except ImportError:  # 0.4.x keeps it under experimental
-    from jax.experimental.shard_map import shard_map
-
-if hasattr(jax.lax, "pcast"):
-    _pcast = jax.lax.pcast
-else:
-    # 0.4.x shard_map has no varying-axis type system — device-constant
-    # carries already unify with collective-produced values, so the cast
-    # is the identity there.
-    def _pcast(x, axes, to="varying"):
-        del axes, to
-        return x
+from jax import shard_map
 
 
 def reference_attention(q, k, v, causal: bool = False):
@@ -107,10 +94,10 @@ def _ring_attention_local(q, k, v, axis_name: str, causal: bool,
     # Mark device-constant initial carries as axis-varying so the scan carry
     # type matches its (collective-produced, varying) outputs.
     vary = vary_axes or (axis_name,)
-    m0 = _pcast(jnp.full((*q.shape[:3], 1), -jnp.inf, q.dtype), vary,
-                to="varying")
-    l0 = _pcast(jnp.zeros((*q.shape[:3], 1), q.dtype), vary,
-                to="varying")
+    m0 = jax.lax.pcast(jnp.full((*q.shape[:3], 1), -jnp.inf, q.dtype),
+                       vary, to="varying")
+    l0 = jax.lax.pcast(jnp.zeros((*q.shape[:3], 1), q.dtype), vary,
+                       to="varying")
     (o, m, l, _, _), _ = jax.lax.scan(
         step, (o0, m0, l0, k, v), jnp.arange(n))
     return o / jnp.maximum(l, 1e-30)

@@ -158,7 +158,7 @@ def init_distributed(coordinator: str | None = None,
     args are absent (typed-config-over-env, SURVEY.md §5 config system).
     """
     coordinator = coordinator or os.environ.get("JAX_COORDINATOR_ADDRESS")
-    if not coordinator:
+    if not coordinator or jax.distributed.is_initialized():
         return
     num_processes = num_processes or int(os.environ.get("JAX_NUM_PROCESSES", "1"))
     process_id = process_id if process_id is not None else int(

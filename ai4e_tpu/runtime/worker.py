@@ -31,7 +31,7 @@ from ..service.task_manager import TaskManagerBase
 from ..taskstore import TaskStatus
 from .batcher import BatcherSaturated, MicroBatcher
 from .mesh.redelivery import RowPoisoned, redeliver_poisoned
-from .registry import ModelRuntime, ServableModel
+from .registry import ModelRuntime, ServableModel, device_report
 
 log = logging.getLogger("ai4e_tpu.worker")
 
@@ -197,7 +197,10 @@ class InferenceWorker:
 
     async def _list_models(self, _request):
         """Model-registry introspection — what the reference delegates to its
-        container registry + values files, queryable live here."""
+        container registry + values files, queryable live here. ``device``
+        is what JAX says this process executes on (platform, device_kind,
+        device_count, mesh axes, jax/jaxlib/libtpu versions): a caller
+        reads it before believing any number the worker produced."""
         from aiohttp import web
         out = []
         # Mesh serving plane: the validated layout + live health, one per
@@ -218,6 +221,12 @@ class InferenceWorker:
                 "batch_buckets": list(s.batch_buckets),
                 "endpoints": self._served.get(name, {}),
             }
+            # How a batch lands on the devices: the number the input
+            # sharding spans and one device's slice of the largest bucket.
+            entry["batch_sharding"] = {
+                "devices": len(s._batch_sharding.device_set),
+                "largest_bucket_shard": list(s._batch_sharding.shard_shape(
+                    (s.max_bucket, *s.input_shape)))}
             if mesh_desc is not None:
                 entry["mesh"] = mesh_desc
             if s.stack_item_shape is not None:
@@ -229,7 +238,8 @@ class InferenceWorker:
                     s.stack_item_dtype if s.stack_item_dtype is not None
                     else s.input_dtype))
             out.append(entry)
-        return web.json_response({"models": out})
+        return web.json_response(
+            {"models": out, "device": device_report(self.runtime.mesh)})
 
     async def _reload_model(self, request):
         """POST {prefix}/models/{name}/reload — hot-swap the model's weights

@@ -26,6 +26,10 @@ def build_native_library(src_name: str, so_name: str,
     missing or older than the source; returns the .so path."""
     src = os.path.join(NATIVE_DIR, src_name)
     out = os.path.join(NATIVE_DIR, so_name)
+    # Said on every request, prebuilt or not: a process's log shows
+    # whether it ran on a native core (chip_smoke.py asserts its path
+    # loads none).
+    log.info("native library requested: %s", so_name)
     if (not force and os.path.exists(out)
             and os.path.getmtime(out) >= os.path.getmtime(src)):
         return out

@@ -100,6 +100,13 @@ class TestMultiProcess:
             np.save(payload, np.arange(16, dtype=np.float32))
             payload = payload.getvalue()
 
+            # The worker says which device it holds (the one it was asked
+            # for: AI4E_RUNTIME_PLATFORM=cpu).
+            device = http_json(f"{wk_base}/v1/echo/models")["device"]
+            assert device["platform"] == "cpu"
+            assert device["device_kind"] and device["device_count"] >= 1
+            assert device["mesh"]["dp"] == device["device_count"]
+
             # Sync across the gateway proxy → worker process.
             sync = http_json(f"{cp_base}/v1/echo/run", data=payload)
             assert sync["echo"][:3] == [0.0, 1.0, 2.0]

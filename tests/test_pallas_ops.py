@@ -259,3 +259,46 @@ class TestValidationHarness:
         # dim — never sequence length (the k-axis is a grid axis) — so even
         # the largest serving config (d=128) fits comfortably.
         assert flash_attention_vmem_bytes(128, 128, 128) <= VMEM_BUDGET_BYTES
+
+
+class TestShardOverBatch:
+    """``lowering.shard_over_batch`` — how a compiled kernel runs under a
+    batch sharded over the mesh's data axes (GSPMD cannot partition a Mosaic
+    call). The Mosaic side is pinned by the deviceless compile in
+    ``test_tpu_aot_compile.py``; here, on the virtual CPU mesh, the
+    plumbing: each device gets its slice of the batch and the replicated
+    arguments whole."""
+
+    @staticmethod
+    def _kernel(x, scale, bias):
+        return x * scale + bias
+
+    def test_per_shard_result_equals_the_unsharded_one(self):
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from ai4e_tpu.ops.pallas.lowering import shard_over_batch
+        from ai4e_tpu.parallel import make_mesh
+        mesh = make_mesh()
+        assert mesh.shape["dp"] == 8
+        x = jnp.arange(16 * 4, dtype=jnp.float32).reshape(16, 4)
+        scale, bias = jnp.full((1, 4), 2.0), jnp.full((1, 4), -1.0)
+        sharded = shard_over_batch(self._kernel, mesh, interpret=False,
+                                   replicated_args=2)
+        assert sharded is not self._kernel
+        got = jax.jit(sharded, in_shardings=(
+            NamedSharding(mesh, P(("dp", "fsdp"))), None, None))(
+                x, scale, bias)
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(self._kernel(x, scale, bias)))
+        assert len(got.sharding.device_set) == 8
+
+    def test_left_alone_where_nothing_needs_partitioning(self):
+        from ai4e_tpu.ops.pallas.lowering import shard_over_batch
+        from ai4e_tpu.parallel import MeshSpec, make_mesh
+        import jax
+        k = self._kernel
+        assert shard_over_batch(k, None, interpret=False) is k
+        assert shard_over_batch(k, make_mesh(), interpret=True) is k
+        one_shard = make_mesh(MeshSpec(dp=1, tp=8), devices=jax.devices())
+        assert shard_over_batch(k, one_shard, interpret=False) is k

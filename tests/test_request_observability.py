@@ -916,6 +916,32 @@ class TestDevicePhases:
         assert "execute" in phases2 and "compile" not in phases2
         assert all(v >= 0 for v in phases2.values())
 
+    def test_warmup_builds_the_program_the_phased_path_dispatches(self):
+        """Warmup goes through run_batch; serving with the hop ledger on
+        goes through run_batch_phases or the split-phase calls. All of
+        them must hit ONE jit dispatch-cache entry per bucket — when
+        warmup passed raw numpy and the phased path a sharded device
+        array, every bucket was traced and compiled a second time on its
+        first request (7-12 s each for the deployed UNet on a v5e), under
+        an ``execute`` label. The label now reads the cache itself."""
+        import numpy as np
+        from ai4e_tpu.runtime import ModelRuntime, ServableModel
+        runtime = ModelRuntime()
+        servable = runtime.register(ServableModel(
+            name="double", apply_fn=lambda params, batch: batch * 2.0,
+            params={}, input_shape=(4,),
+            preprocess=lambda body, ct: np.frombuffer(body, np.float32),
+            postprocess=lambda out: out, batch_buckets=(8,)))
+        runtime.warmup()
+        assert servable._compiled._cache_size() == 1
+        batch = np.ones((8, 4), np.float32)
+        _out, _p, phases = runtime.run_batch_phases("double", batch)
+        assert set(phases) == {"h2d", "execute", "d2h"}
+        resident, _w = runtime.h2d_resident("double", batch)
+        _out, label, _w = runtime.execute_resident("double", resident)
+        assert label == "execute"
+        assert servable._compiled._cache_size() == 1
+
 
 class TestWorkerFlushOnFailure:
     def test_execution_failure_still_flushes_buffered_events(self):

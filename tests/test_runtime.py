@@ -104,9 +104,8 @@ class TestMicroBatcher:
         run(main())
 
     def test_pipeline_depth_overlaps_batches(self):
-        """pipeline_depth N admits N batches in flight concurrently (the
-        remote-attached-TPU tuning knob: fill the long-fat link); results
-        still fan back correctly and depth < 1 is rejected."""
+        """pipeline_depth N admits N batches in flight concurrently;
+        results still fan back correctly and depth < 1 is rejected."""
         async def main():
             import threading
 

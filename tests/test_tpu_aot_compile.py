@@ -1,0 +1,150 @@
+"""Deviceless TPU compile pre-flight — slow, outside tier-1.
+
+``libtpu`` hands out a v5e topology description without a chip, and
+``jit(...).lower(...).compile()`` against it runs the real Mosaic/XLA:TPU
+compiler. Nothing executes, so this says nothing about numerics or time — it
+says whether a kernel or the deployed servable still *compiles* for the chip,
+which is the cheapest thing to know before spending chip budget on a change:
+
+    JAX_PLATFORMS=cpu python -m pytest tests/test_tpu_aot_compile.py -m slow
+
+Skipped where libtpu cannot describe the topology.
+"""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+pytestmark = pytest.mark.slow
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    from jax.experimental import topologies
+    try:
+        topology = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no libtpu / no such topology
+        pytest.skip(f"no deviceless v5e topology here: {exc}")
+    assert topology.devices[0].device_kind == "TPU v5 lite"
+    return list(topology.devices)
+
+
+@pytest.fixture(scope="module")
+def v5e_sharding(v5e_devices):
+    return jax.sharding.SingleDeviceSharding(v5e_devices[0])
+
+
+@pytest.fixture(scope="module")
+def v5e_host_mesh(v5e_devices):
+    """The four chips of one host as the worker lays them out by default:
+    all on ``dp``."""
+    from ai4e_tpu.parallel.sharding import make_mesh
+    return make_mesh(devices=v5e_devices)
+
+
+def _on(sharding, spec):
+    """``spec`` — a ``(shape, dtype)`` pair or a pytree of arrays — as
+    abstract arguments placed with ``sharding``."""
+    def struct(leaf):
+        shape, dtype = ((leaf.shape, leaf.dtype) if hasattr(leaf, "shape")
+                        else leaf)
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return jax.tree.map(struct, spec, is_leaf=lambda x: isinstance(x, tuple))
+
+
+def _compile(fn, *args):
+    """AOT-compile ``fn`` for the topology the arguments are placed on."""
+    return jax.jit(fn).lower(*args).compile()
+
+
+class TestServingKernelsCompileForV5e:
+    def test_normalize_image(self, v5e_sharding):
+        from ai4e_tpu.ops.pallas import normalize_image
+        _compile(lambda x: normalize_image(x, interpret=False),
+                 _on(v5e_sharding, ((16, 256, 256, 3), jnp.uint8)))
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_segmentation_argmax(self, v5e_sharding, dtype):
+        """Both dtypes the docstring promises: bfloat16 logits used to die
+        in Mosaic ("Invalid relayout ... xi1") until the kernel compared
+        in float32."""
+        from ai4e_tpu.ops.pallas import segmentation_argmax
+        _compile(lambda x: segmentation_argmax(x, interpret=False),
+                 _on(v5e_sharding, ((16, 256, 256, 4), dtype)))
+
+    @pytest.mark.parametrize("head_dim", [64, 128])
+    def test_flash_attention_forward_and_grad(self, v5e_sharding, head_dim):
+        from ai4e_tpu.ops.pallas import flash_attention
+        qkv = _on(v5e_sharding, ((1, 2, 4096, head_dim), jnp.bfloat16))
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, interpret=False).astype(
+                jnp.float32).sum()
+
+        _compile(lambda q, k, v: flash_attention(q, k, v, interpret=False),
+                 qkv, qkv, qkv)
+        _compile(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+
+
+def _deployed_landcover(mesh=None):
+    from ai4e_tpu.runtime import build_servable
+    with open(os.path.join(REPO, "deploy", "specs", "models.json")) as f:
+        spec = dict(next(m for m in json.load(f)["models"]
+                         if m["name"] == "landcover"))
+    family = spec.pop("family")
+    for key in ("checkpoint", "sync_path", "async_path"):
+        spec.pop(key)
+    servable = build_servable(family, mesh=mesh, **spec)
+    assert servable.input_shape == (256, 256, 3)
+    return servable
+
+
+def test_deployed_landcover_servable_compiles_at_bucket_16(
+        v5e_sharding, monkeypatch):
+    """The servable ``deploy/specs/models.json`` names, at its deployed
+    widths, through the same ``apply_fn`` the worker jits — with the
+    kernels lowered to Mosaic, as they are when the default backend is a
+    TPU."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    servable = _deployed_landcover()
+    compiled = _compile(
+        servable.apply_fn, _on(v5e_sharding, servable.params),
+        _on(v5e_sharding, ((16, *servable.input_shape),
+                           np.dtype(servable.input_dtype))))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+class TestFourChipHost:
+    """The batch sharded over a 2x2 host's four chips, as ``ModelRuntime``
+    shards it. GSPMD cannot partition a Mosaic kernel: without the
+    per-shard ``shard_map`` (``ops/pallas/lowering.shard_over_batch``) these
+    fail to lower with "Mosaic kernels cannot be automatically partitioned"
+    — what the deployed landcover worker died of on a four-chip v5e."""
+
+    def test_deployed_landcover_servable(self, v5e_host_mesh, monkeypatch):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        servable = _deployed_landcover(mesh=v5e_host_mesh)
+        batch = NamedSharding(v5e_host_mesh, P(("dp", "fsdp")))
+        compiled = _compile(
+            servable.apply_fn,
+            _on(NamedSharding(v5e_host_mesh, P()), servable.params),
+            _on(batch, ((16, *servable.input_shape),
+                        np.dtype(servable.input_dtype))))
+        assert "tpu_custom_call" in compiled.as_text()
+
+    def test_flash_attention(self, v5e_host_mesh):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from ai4e_tpu.ops.pallas import flash_attention
+        batch = NamedSharding(v5e_host_mesh, P(("dp", "fsdp")))
+        qkv = _on(batch, ((4, 2, 4096, 128), jnp.bfloat16))
+        _compile(lambda q, k, v: flash_attention(
+            q, k, v, interpret=False, mesh=v5e_host_mesh), qkv, qkv, qkv)
