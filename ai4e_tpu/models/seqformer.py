@@ -157,20 +157,26 @@ class _LMBlock(nn.Module):
         h = self.ln1(x)
         qkv = self.qkv(h).reshape(s, 3, self.heads, hd)
         q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # (S, H, hd)
-        oh = jax.nn.one_hot(position, length, dtype=k_cache.dtype)  # (S, L)
-        k_cache = (k_cache * (1.0 - oh)[:, None, :, None]
-                   + k_new[:, :, None, :] * oh[:, None, :, None])
-        v_cache = (v_cache * (1.0 - oh)[:, None, :, None]
-                   + v_new[:, :, None, :] * oh[:, None, :, None])
-        scores = jnp.einsum("shd,shld->shl", q, k_cache) / jnp.sqrt(hd)
-        valid = (jnp.arange(length)[None, :]
-                 <= position[:, None])  # keys at or before the new token
-        scores = jnp.where(valid[:, None, :], scores,
-                           jnp.asarray(-1e30, scores.dtype))
-        o = jnp.einsum("shl,shld->shd", jax.nn.softmax(scores, axis=-1),
-                       v_cache)
+        # Scopes name the device side for the trace's readers; they are
+        # metadata and change no program.
+        with jax.named_scope("cache_update"):
+            oh = jax.nn.one_hot(position, length,
+                                dtype=k_cache.dtype)  # (S, L)
+            k_cache = (k_cache * (1.0 - oh)[:, None, :, None]
+                       + k_new[:, :, None, :] * oh[:, None, :, None])
+            v_cache = (v_cache * (1.0 - oh)[:, None, :, None]
+                       + v_new[:, :, None, :] * oh[:, None, :, None])
+        with jax.named_scope("attention"):
+            scores = jnp.einsum("shd,shld->shl", q, k_cache) / jnp.sqrt(hd)
+            valid = (jnp.arange(length)[None, :]
+                     <= position[:, None])  # keys at or before the new token
+            scores = jnp.where(valid[:, None, :], scores,
+                               jnp.asarray(-1e30, scores.dtype))
+            o = jnp.einsum("shl,shld->shd",
+                           jax.nn.softmax(scores, axis=-1), v_cache)
         x = x + self.proj(o.reshape(s, self.dim))
-        x = x + self.mlp_down(nn.gelu(self.mlp_up(self.ln2(x))))
+        with jax.named_scope("mlp"):
+            x = x + self.mlp_down(nn.gelu(self.mlp_up(self.ln2(x))))
         return x, k_cache, v_cache
 
 
@@ -216,27 +222,35 @@ class SeqFormerLM(nn.Module):
 
     def prefill(self, tokens, length):
         b, p = tokens.shape
-        h = self.embed(tokens) + self.pos_emb[None, :p].astype(self.dtype)
+        with jax.named_scope("embedding"):
+            h = (self.embed(tokens)
+                 + self.pos_emb[None, :p].astype(self.dtype))
         mask = jnp.arange(p)[None, :] < length[:, None]
         ks, vs = [], []
         for blk in self.blocks:
             h, k, v = blk.prefill(h, mask)
             ks.append(k)
             vs.append(v)
-        last = jnp.take_along_axis(
-            h, (length - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-        next_token = jnp.argmax(self._logits(last), axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            last = jnp.take_along_axis(
+                h, (length - 1)[:, None, None].astype(jnp.int32),
+                axis=1)[:, 0]
+            next_token = jnp.argmax(self._logits(last),
+                                    axis=-1).astype(jnp.int32)
         return next_token, jnp.stack(ks), jnp.stack(vs)
 
     def decode_step(self, tokens, k_cache, v_cache, position):
-        h = (self.embed(tokens)
-             + self.pos_emb[position].astype(self.dtype))  # (S, D)
+        with jax.named_scope("embedding"):
+            h = (self.embed(tokens)
+                 + self.pos_emb[position].astype(self.dtype))  # (S, D)
         new_k, new_v = [], []
         for i, blk in enumerate(self.blocks):
             h, k, v = blk.step(h, k_cache[i], v_cache[i], position)
             new_k.append(k)
             new_v.append(v)
-        next_token = jnp.argmax(self._logits(h), axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            next_token = jnp.argmax(self._logits(h),
+                                    axis=-1).astype(jnp.int32)
         return next_token, jnp.stack(new_k), jnp.stack(new_v)
 
 

@@ -60,6 +60,15 @@ STAGE = "stage"              # pipeline stage boundary (r="name event" or
 CHUNK = "chunk"              # streaming first token (ms=TTFT; one stamp
                              # per request — a 512-token stream must not
                              # eat the event cap)
+QUEUED = "queued"            # decode engine took the request (enqueue)
+SLOT = "slot"                # KV-cache slot acquired (r="slot n tick k",
+                             # ms=queue wait since ``queued``)
+PREFILL = "prefill"          # prompt prefill + slot insert (r="bucket b",
+                             # ms=host seconds around the executor call)
+DECODED = "decoded"          # last token made (r="n tokens ticks a..b";
+                             # ``chunk`` → ``decoded`` is the decode span,
+                             # ticks a..b join the ai4e.decode.tick
+                             # annotations on the profiler's clock)
 ROLLOUT = "rollout"          # rollout transition (r="worker -> gen" /
                              # "canary weight N%" — the controller's
                              # evidence trail, docs/deployment.md)

@@ -34,6 +34,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..metrics import DEFAULT_REGISTRY, MetricsRegistry
+from ..observability.tracing import device_trace
 from ..rollout.drain import DrainingError, retire_pending
 from .ladder import EXPOSITION_BUCKETS, exposition_buckets
 from .registry import ModelRuntime
@@ -370,7 +371,8 @@ class MicroBatcher:
                 # as possible (cutting first would freeze the batch at
                 # whatever had arrived, then let it stale-wait).
                 await self._window.acquire()
-                batch, bucket = self._take_batch(model_name)
+                with device_trace("ai4e.batch.cut", model=model_name):
+                    batch, bucket = self._take_batch(model_name)
                 if not batch:
                     self._window.release()
                     continue

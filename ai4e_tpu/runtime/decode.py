@@ -50,13 +50,23 @@ import logging
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from functools import partial
 
 from ..admission.deadline import DeadlineExceeded, priority_name
 from ..metrics import DEFAULT_REGISTRY, MetricsRegistry
+from ..observability.tracing import device_trace
 from ..rollout.drain import DrainingError
 
 log = logging.getLogger("ai4e_tpu.decode")
+
+# One step's submit to the next step's submit, partitioned where the work
+# happens (docs/observability.md "Decode-tick decomposition"). ``yield`` is
+# the rest of the interval: reload check, sweep, the loop given to others.
+TICK_PHASES = ("prepare", "handoff", "dispatch", "device_wait", "return",
+               "bookkeeping", "admit", "yield")
+# Most phases are 50 us - 1 ms, the device's run tens of ms: the default
+# ladder starts at 1 ms and would put seven of the eight in its first bucket.
+_TICK_BUCKETS = (25e-6, 50e-6, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 0.01,
+                 0.025, 0.05, 0.1, 0.25, 1.0, float("inf"))
 
 
 class DecodeSaturated(RuntimeError):
@@ -123,6 +133,29 @@ class SlotPool:
                 f"!= {self.slots}")
 
 
+class _CallClock:
+    """``time.perf_counter`` of one backend call, read where each thing
+    happens: ``submit`` on the loop before the hop to the device thread,
+    ``entered``/``left`` as the first and last lines inside that thread,
+    ``resumed`` when the awaiting coroutine runs again. ``wait`` is what the
+    backend's ``phase_hook`` reported as blocked on the device (None from a
+    backend without the hook); ``ledger`` is the request the call serves,
+    for the hook's ``compile`` stamp."""
+
+    __slots__ = ("submit", "entered", "left", "resumed", "wait", "ledger")
+
+    def __init__(self, ledger=None):
+        self.wait = None
+        self.ledger = ledger
+
+    def run(self, fn, args):
+        self.entered = time.perf_counter()
+        try:
+            return fn(*args)
+        finally:
+            self.left = time.perf_counter()
+
+
 @dataclass
 class _Sequence:
     """One streaming request's decode state."""
@@ -140,6 +173,7 @@ class _Sequence:
     done: bool = False
     enqueued: float = field(default_factory=time.perf_counter)
     last_token_at: float = 0.0
+    first_tick: int = 0           # the tick that gave it its slot
 
 
 class DecodeEngine:
@@ -155,7 +189,13 @@ class DecodeEngine:
       invalidation);
     - ``prefill_into(slot, tokens) -> first generated token id``;
     - ``step(tokens, positions, active) -> next token id per slot``
-      (plain int lists — the backend owns array conversion).
+      (plain int lists — the backend owns array conversion);
+    - optionally ``phase_hook`` (attribute, None until the engine installs
+      ``hook(phase, seconds)``): called inside a backend call with
+      ``device_wait`` (seconds of ``step`` blocked on the device; the rest
+      of the in-thread time is ``dispatch``) and ``compile`` (a call that
+      grew a program's dispatch cache). Without it the whole in-thread time
+      of a step is booked as ``device_wait``.
 
     Backend methods may be sync (run on the engine's single device
     executor thread — the device is the serial resource, same discipline
@@ -198,7 +238,25 @@ class DecodeEngine:
             "Gap between consecutive tokens of one sequence")
         self._step_hist = self.metrics.histogram(
             "ai4e_decode_step_seconds",
-            "Device time per engine step, by phase (prefill/decode)")
+            "Host clock around the executor call of one engine step, by "
+            "phase (prefill/decode): thread hops, launch, the device's run "
+            "and the fetch of the ids")
+        self._tick_hist = self.metrics.histogram(
+            "ai4e_decode_tick_seconds",
+            "One step's submit to the next step's submit, partitioned by "
+            "phase (prepare/handoff/dispatch/device_wait/return/"
+            "bookkeeping/admit/yield)", buckets=_TICK_BUCKETS)
+        self._queue_wait = self.metrics.histogram(
+            "ai4e_decode_queue_wait_seconds",
+            "Engine enqueue to KV-cache slot acquired, per request")
+        self._step_active = self.metrics.histogram(
+            "ai4e_decode_step_active_slots",
+            "Active slots of each decode step (the batch size per step)",
+            buckets=(*range(1, backend.slots + 1), float("inf")))
+        self._kv_positions = self.metrics.counter(
+            "ai4e_decode_kv_positions_total",
+            "K/V positions per decode step: live (sum of position + 1 over "
+            "active slots) and attended (slots x max_len)")
         self._occupancy = self.metrics.gauge(
             "ai4e_decode_slot_occupancy",
             "Occupied KV-cache slots / total slots per model")
@@ -217,6 +275,15 @@ class DecodeEngine:
         self._expired_total = self.metrics.counter(
             "ai4e_admission_expired_total",
             "Requests dropped on deadline expiry, by hop/priority")
+        self._tick_no = 0
+        # Seconds booked since the previous step's submit, every phase but
+        # ``yield``; ``_last_submit`` is None after an idle wait or a tick
+        # without a step, so an idle engine is not a long tick.
+        self._phase = dict.fromkeys(TICK_PHASES[:-1], 0.0)
+        self._last_submit: float | None = None
+        self._inflight: _CallClock | None = None
+        if hasattr(backend, "phase_hook"):
+            backend.phase_hook = self._on_backend_phase
 
     # -- request side ------------------------------------------------------
 
@@ -260,6 +327,8 @@ class DecodeEngine:
                         priority=priority, deadline_at=deadline_at,
                         ledger=ledger)
         self._queue.append(seq)
+        if ledger is not None:
+            ledger.stamp("queued", "decode")
         self._pending_gauge.set(self.pending_count, model=self._model)
         self._wakeup.set()
         return await fut
@@ -341,6 +410,7 @@ class DecodeEngine:
     async def _run(self) -> None:
         while not self._stop:
             if not self._active and not self._queue:
+                self._last_submit = None
                 self._wakeup.clear()
                 try:
                     await asyncio.wait_for(self._wakeup.wait(), timeout=0.5)
@@ -352,6 +422,7 @@ class DecodeEngine:
                 await self._tick()
             except Exception:  # noqa: BLE001 — a backend crash fails the affected sequences below, never the loop
                 log.exception("decode tick failed; failing active sequences")
+                self._last_submit = None
                 for seq in list(self._active.values()):
                     self._retire(seq, "failed",
                                  error=RuntimeError("decode step failed"))
@@ -359,9 +430,12 @@ class DecodeEngine:
     async def _tick(self) -> None:
         """One scheduling iteration: reload check → expiry/cancel sweep →
         admission (prefill into free slots) → one decode step."""
+        self._tick_no += 1
         await self._check_reload()
         self._sweep()
+        t0 = time.perf_counter()
         await self._admit()
+        self._phase["admit"] += time.perf_counter() - t0
         await self._step()
 
     async def _check_reload(self) -> None:
@@ -389,20 +463,12 @@ class DecodeEngine:
                 # was about to hit the context bound anyway.
                 self._retire(seq, "completed")
                 continue
-            t0 = time.perf_counter()
-            try:
-                token = await self._call(self.backend.prefill_into,
-                                         seq.slot, list(history))
-            except Exception as exc:  # noqa: BLE001; ai4e: noqa[AIL005] — delivered to the sequence's waiter as its failure
-                self._retire(seq, "failed", error=exc)
-                continue
-            self._step_hist.observe(time.perf_counter() - t0,
-                                    phase="prefill", model=self._model)
-            if seq.done:
-                continue  # retired (cancel/expiry) while re-prefilling
+            token = await self._prefill(seq, history)
+            if token is None or seq.done:
+                continue  # failed, or retired (cancel/expiry) meanwhile
             seq.position = len(history)
             self._reprefills_total.inc(model=self._model)
-            self._note_token(seq, int(token))
+            self._note_token(seq, token)
 
     def _sweep(self) -> None:
         """Expiry + cancellation sweep, every iteration — single
@@ -454,49 +520,125 @@ class DecodeEngine:
                     self._retire(seq, "cancelled")
                 continue
             seq.slot = slot
+            seq.first_tick = self._tick_no
             self._active[slot] = seq
             self._occupancy.set(self.pool.busy_count / self.pool.slots,
                                 model=self._model)
-            t0 = time.perf_counter()
-            try:
-                token = await self._call(self.backend.prefill_into,
-                                         slot, list(seq.prompt))
-            except Exception as exc:  # noqa: BLE001; ai4e: noqa[AIL005] — delivered to the sequence's waiter as its failure
-                self._retire(seq, "failed", error=exc)
-                continue
-            self._step_hist.observe(time.perf_counter() - t0,
-                                    phase="prefill", model=self._model)
-            if seq.done:
-                continue  # re-check after the await: retired mid-prefill
+            wait = time.perf_counter() - seq.enqueued
+            self._queue_wait.observe(wait, model=self._model)
+            if seq.ledger is not None:
+                seq.ledger.stamp("slot", "decode", ms=wait * 1e3,
+                                 reason=f"slot {slot} tick {self._tick_no}")
+            token = await self._prefill(seq, seq.prompt)
+            if token is None or seq.done:
+                continue  # failed, or re-check after the await: retired
             seq.position = len(seq.prompt)
-            self._note_token(seq, int(token))
+            self._note_token(seq, token)
+
+    async def _prefill(self, seq: _Sequence, tokens) -> int | None:
+        """``tokens`` (the prompt or, after a reload, the history) through
+        the backend's prefill into the sequence's slot. Returns the first
+        generated token; a backend failure retires the sequence and
+        returns None."""
+        try:
+            token, clock = await self._call(
+                self.backend.prefill_into, seq.slot, list(tokens),
+                ledger=seq.ledger)
+        except Exception as exc:  # noqa: BLE001; ai4e: noqa[AIL005] — delivered to the sequence's waiter as its failure
+            self._retire(seq, "failed", error=exc)
+            return None
+        seconds = clock.resumed - clock.submit
+        self._step_hist.observe(seconds, phase="prefill", model=self._model)
+        if seq.ledger is not None:
+            bucket_for = getattr(self.backend, "bucket_for", None)
+            seq.ledger.stamp(
+                "prefill", "decode", t=time.time() - seconds,
+                ms=seconds * 1e3,
+                reason=(f"bucket {bucket_for(len(tokens))}" if bucket_for
+                        else f"{len(tokens)} tokens"))
+        return int(token)
 
     async def _step(self) -> None:
         """One decode step over the whole slot pool: every active
         sequence advances one token; inactive slots ride along masked."""
-        if not self._active:
-            return
+        entered = time.perf_counter()
         snapshot = [(slot, seq, seq.position)
                     for slot, seq in sorted(self._active.items())
                     if not seq.done]
         if not snapshot:
+            self._last_submit = None
             return
-        tokens = [0] * self.pool.slots
-        positions = [0] * self.pool.slots
-        active = [False] * self.pool.slots
-        for slot, seq, position in snapshot:
-            tokens[slot] = seq.tokens[-1]
-            positions[slot] = position
-            active[slot] = True
-        t0 = time.perf_counter()
-        out = await self._call(self.backend.step, tokens, positions, active)
-        self._step_hist.observe(time.perf_counter() - t0, phase="decode",
-                                model=self._model)
-        for slot, seq, position in snapshot:
-            if seq.done or seq.slot != slot:
-                continue  # re-check after the await: retired mid-step
-            seq.position = position + 1
-            self._note_token(seq, int(out[slot]))
+        with device_trace("ai4e.decode.tick", tick=self._tick_no,
+                          active=len(snapshot)):
+            with device_trace("ai4e.decode.prepare"):
+                tokens = [0] * self.pool.slots
+                positions = [0] * self.pool.slots
+                active = [False] * self.pool.slots
+                for slot, seq, position in snapshot:
+                    tokens[slot] = seq.tokens[-1]
+                    positions[slot] = position
+                    active[slot] = True
+            out, clock = await self._call(self.backend.step, tokens,
+                                          positions, active)
+            self._step_hist.observe(clock.resumed - clock.submit,
+                                    phase="decode", model=self._model)
+            self._close_tick(clock.submit, entered)
+            # This step's own phases open the next interval.
+            phase = self._phase
+            in_thread = clock.left - clock.entered
+            wait = in_thread if clock.wait is None else clock.wait
+            phase["handoff"] = clock.entered - clock.submit
+            phase["dispatch"] = in_thread - wait
+            phase["device_wait"] = wait
+            phase["return"] = clock.resumed - clock.left
+            with device_trace("ai4e.decode.bookkeeping"):
+                self._step_active.observe(len(snapshot), model=self._model)
+                self._kv_positions.inc(
+                    sum(position + 1 for _, _, position in snapshot),
+                    model=self._model, kind="live")
+                self._kv_positions.inc(
+                    self.pool.slots * self.backend.max_len,
+                    model=self._model, kind="attended")
+                for slot, seq, position in snapshot:
+                    if seq.done or seq.slot != slot:
+                        continue  # re-check after the await: retired mid-step
+                    seq.position = position + 1
+                    self._note_token(seq, int(out[slot]))
+            phase["bookkeeping"] = time.perf_counter() - clock.resumed
+
+    def _close_tick(self, submit: float, entered: float) -> None:
+        """A step was submitted at ``submit``: observe the interval since
+        the previous step's submit, one observation of each phase, and
+        start the next. After an idle wait there is no interval to close."""
+        phase = self._phase
+        phase["prepare"] = submit - entered
+        if self._last_submit is not None:
+            rest = submit - self._last_submit - sum(phase.values())
+            for name, seconds in phase.items():
+                self._tick_hist.observe(seconds, phase=name,
+                                        model=self._model)
+            self._tick_hist.observe(max(rest, 0.0), phase="yield",
+                                    model=self._model)
+        self._last_submit = submit
+        for name in phase:
+            phase[name] = 0.0
+
+    def _on_backend_phase(self, phase: str, seconds: float) -> None:
+        """The backend's ``phase_hook``: runs on the device thread, inside
+        the one backend call in flight."""
+        clock = self._inflight
+        if phase == "compile":
+            # The existing family the batcher feeds: a warm worker counts 0,
+            # and the series exists only once something compiled.
+            self.metrics.histogram(
+                "ai4e_device_phase_seconds",
+                "Device-boundary phase durations (h2d/compile/execute/d2h)"
+            ).observe(seconds, phase="compile", model=self._model)
+            if clock is not None and clock.ledger is not None:
+                clock.ledger.stamp("compile", "device",
+                                   t=time.time() - seconds, ms=seconds * 1e3)
+        elif phase == "device_wait" and clock is not None:
+            clock.wait = (clock.wait or 0.0) + seconds
 
     # -- bookkeeping (single-segment: no suspension points below) ---------
 
@@ -530,6 +672,11 @@ class DecodeEngine:
         if (len(seq.tokens) >= seq.max_new_tokens
                 or (eos is not None and token == eos)
                 or seq.position >= self.backend.max_len):
+            if seq.ledger is not None:
+                seq.ledger.stamp(
+                    "decoded", "decode",
+                    reason=f"{len(seq.tokens)} tokens ticks "
+                           f"{seq.first_tick}..{self._tick_no}")
             self._retire(seq, "completed")
 
     def _retire(self, seq: _Sequence, outcome: str, error=None) -> None:
@@ -559,15 +706,27 @@ class DecodeEngine:
             else:
                 seq.future.set_result(list(seq.tokens))
 
-    async def _call(self, fn, /, *args):
+    async def _call(self, fn, /, *args, ledger=None):
         """Invoke a backend method: async backends (race-test fakes)
         await inline; sync backends (the JAX runtime) run on the single
-        device executor thread — the device is the serial resource."""
+        device executor thread — the device is the serial resource.
+        Returns ``(result, clock)``: the one timer every caller reads."""
+        clock = _CallClock(ledger)
         if inspect.iscoroutinefunction(fn):
-            return await fn(*args)
+            clock.submit = clock.entered = time.perf_counter()
+            out = await fn(*args)
+            clock.left = clock.resumed = time.perf_counter()
+            return out, clock
         if self._executor is None:
             from concurrent.futures import ThreadPoolExecutor
             self._executor = ThreadPoolExecutor(
                 max_workers=1, thread_name_prefix="tpu-decode")
-        return await asyncio.get_running_loop().run_in_executor(
-            self._executor, partial(fn, *args))
+        self._inflight = clock   # what the backend's phase_hook books to
+        clock.submit = time.perf_counter()
+        try:
+            out = await asyncio.get_running_loop().run_in_executor(
+                self._executor, clock.run, fn, args)
+        finally:
+            self._inflight = None
+        clock.resumed = time.perf_counter()
+        return out, clock

@@ -41,6 +41,8 @@ from typing import Any
 
 import numpy as np
 
+from ..observability.tracing import device_trace
+
 log = logging.getLogger("ai4e_tpu.kvcache")
 
 
@@ -107,6 +109,11 @@ class PagedDecodeRuntime:
         self._v = None
         self._donate = donate
         self._programs = None
+        # ``hook(phase, seconds)``, installed by the DecodeEngine: told the
+        # seconds a step spent blocked on the device (``device_wait``) and
+        # the seconds of any call that had to build its program
+        # (``compile``). None (and during ``warm()``): nothing is reported.
+        self.phase_hook = None
 
     # -- cache lifecycle ---------------------------------------------------
 
@@ -161,14 +168,29 @@ class PagedDecodeRuntime:
             zero = (0, slot, 0, 0, 0)
             # Blocks arrive as (depth, 1, H, P, hd) — rank-matched to the
             # pool, so one dynamic_update_slice lands the whole prompt.
-            return (jax.lax.dynamic_update_slice(k, k_block, zero),
-                    jax.lax.dynamic_update_slice(v, v_block, zero))
+            with jax.named_scope("cache_insert"):
+                return (jax.lax.dynamic_update_slice(k, k_block, zero),
+                        jax.lax.dynamic_update_slice(v, v_block, zero))
 
         self._programs = {
             "prefill": jax.jit(prefill),
             "step": jax.jit(step, donate_argnums=donate_step),
             "insert": jax.jit(insert, donate_argnums=donate_insert),
         }
+
+    def _run(self, program: str, *args):
+        """Call one of the three programs. A call that grew the jit's
+        dispatch cache traced and compiled (or loaded from the persistent
+        cache) instead of dispatching what ``warm()`` had built: its
+        seconds go to the hook as ``compile`` — read off the cache itself,
+        as ``registry._execute_blocked`` does for the batch path."""
+        fn = self._programs[program]
+        before = fn._cache_size()
+        t0 = time.perf_counter()
+        out = fn(*args)
+        if fn._cache_size() > before and self.phase_hook is not None:
+            self.phase_hook("compile", time.perf_counter() - t0)
+        return out
 
     # -- engine backend surface -------------------------------------------
 
@@ -190,11 +212,14 @@ class PagedDecodeRuntime:
         bucket = self.bucket_for(n)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = tokens
-        token, k_block, v_block = self._programs["prefill"](
-            self.servable.params, padded, np.asarray([n], np.int32))
-        self._k, self._v = self._programs["insert"](
-            self._k, self._v, k_block, v_block, np.int32(slot))
-        return int(token[0])
+        with device_trace("ai4e.decode.prefill", bucket=bucket, slot=slot):
+            token, k_block, v_block = self._run(
+                "prefill", self.servable.params, padded,
+                np.asarray([n], np.int32))
+        with device_trace("ai4e.decode.insert", slot=slot):
+            self._k, self._v = self._run(
+                "insert", self._k, self._v, k_block, v_block, np.int32(slot))
+        return int(token[0])   # waits for the prefill program's run
 
     def step(self, tokens, positions, active) -> list[int]:
         """One decode step over the pool. ``active`` is advisory — the
@@ -202,10 +227,16 @@ class PagedDecodeRuntime:
         engine never reads."""
         self._ensure()
         del active
-        out, self._k, self._v = self._programs["step"](
-            self.servable.params, np.asarray(tokens, np.int32),
-            self._k, self._v, np.asarray(positions, np.int32))
-        return [int(t) for t in np.asarray(out)]
+        with device_trace("ai4e.decode.dispatch"):
+            out, self._k, self._v = self._run(
+                "step", self.servable.params, np.asarray(tokens, np.int32),
+                self._k, self._v, np.asarray(positions, np.int32))
+        t0 = time.perf_counter()
+        with device_trace("ai4e.decode.device_wait"):
+            ids = np.asarray(out)   # the device's run and the ids' d2h
+        if self.phase_hook is not None:
+            self.phase_hook("device_wait", time.perf_counter() - t0)
+        return [int(t) for t in ids]
 
     # -- weights -----------------------------------------------------------
 

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..observability.tracing import device_trace
 from .ladder import DEFAULT_BUCKETS
 # The one sanctioned device→host fetch (AIL014: every other transfer on
 # the serving path must carry an explicit placement).
@@ -401,14 +402,18 @@ class ModelRuntime:
             out, poisoned = self.run_batch_report(name, batch)
             return out, poisoned, {}
         phases: dict[str, float] = {}
+        rows = batch.shape[0]
         t0 = time.perf_counter()
-        device_batch = jax.device_put(batch, servable._batch_sharding)
-        jax.block_until_ready(device_batch)
+        with device_trace("ai4e.batch.h2d", model=name, rows=rows):
+            device_batch = jax.device_put(batch, servable._batch_sharding)
+            jax.block_until_ready(device_batch)
         phases["h2d"] = time.perf_counter() - t0
-        out, label, (t0, t1) = self._execute_blocked(name, device_batch)
+        with device_trace("ai4e.batch.execute", model=name, rows=rows):
+            out, label, (t0, t1) = self._execute_blocked(name, device_batch)
         phases[label] = t1 - t0
         t0 = time.perf_counter()
-        host = fetch_to_host(out)
+        with device_trace("ai4e.batch.d2h", model=name, rows=rows):
+            host = fetch_to_host(out)
         phases["d2h"] = time.perf_counter() - t0
         return host, frozenset(), phases
 

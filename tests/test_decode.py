@@ -305,7 +305,10 @@ class TestEngineScheduling:
             "ai4e_decode_ttft_seconds", "ai4e_decode_intertoken_seconds",
             "ai4e_decode_step_seconds", "ai4e_decode_slot_occupancy",
             "ai4e_decode_pending", "ai4e_decode_tokens_total",
-            "ai4e_decode_sequences_total", "ai4e_decode_reprefills_total"}
+            "ai4e_decode_sequences_total", "ai4e_decode_reprefills_total",
+            "ai4e_decode_tick_seconds", "ai4e_decode_queue_wait_seconds",
+            "ai4e_decode_step_active_slots",
+            "ai4e_decode_kv_positions_total"}
 
     def test_default_worker_has_no_decode_metrics(self):
         """Decode-engine-off identity (acceptance): nothing in the
