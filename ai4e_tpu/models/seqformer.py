@@ -147,10 +147,13 @@ class _LMBlock(nn.Module):
 
     def step(self, x, k_cache, v_cache, position):
         """One decode step over the slot pool. x: (S, D) — one new token
-        per slot; k_cache/v_cache: (S, H, L, hd); position: (S,) — the
-        cache index the new token's K/V lands at. Returns ``(y, k, v)``
-        with the caches updated via a one-hot scatter (SPMD-friendly: no
-        per-slot dynamic slices)."""
+        per slot; k_cache/v_cache: (S, H, L, hd), read and never
+        rewritten: positions ``< position`` hold the sequence so far;
+        position: (S,) — the cache index the new token belongs at. The
+        new token's own key and value enter the softmax as one more term
+        beside the cached ones, so attention needs no updated cache.
+        Returns ``(y, k_new, v_new)`` with k_new/v_new of shape
+        (S, H, hd) — the rows ``SeqFormerLM.decode_step`` stores."""
         s, _ = x.shape
         hd = self.dim // self.heads
         length = k_cache.shape[2]
@@ -159,25 +162,25 @@ class _LMBlock(nn.Module):
         q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # (S, H, hd)
         # Scopes name the device side for the trace's readers; they are
         # metadata and change no program.
-        with jax.named_scope("cache_update"):
-            oh = jax.nn.one_hot(position, length,
-                                dtype=k_cache.dtype)  # (S, L)
-            k_cache = (k_cache * (1.0 - oh)[:, None, :, None]
-                       + k_new[:, :, None, :] * oh[:, None, :, None])
-            v_cache = (v_cache * (1.0 - oh)[:, None, :, None]
-                       + v_new[:, :, None, :] * oh[:, None, :, None])
         with jax.named_scope("attention"):
             scores = jnp.einsum("shd,shld->shl", q, k_cache) / jnp.sqrt(hd)
             valid = (jnp.arange(length)[None, :]
-                     <= position[:, None])  # keys at or before the new token
+                     < position[:, None])  # keys before the new token
             scores = jnp.where(valid[:, None, :], scores,
                                jnp.asarray(-1e30, scores.dtype))
-            o = jnp.einsum("shl,shld->shd",
-                           jax.nn.softmax(scores, axis=-1), v_cache)
+            own = jnp.einsum("shd,shd->sh", q, k_new) / jnp.sqrt(hd)
+            # softmax over [cached keys, the new token's key], by hand:
+            # the new key is not in the cache yet.
+            top = jnp.maximum(scores.max(axis=-1), own)
+            w = jnp.exp(scores - top[..., None])
+            w_own = jnp.exp(own - top)
+            o = ((jnp.einsum("shl,shld->shd", w, v_cache)
+                  + w_own[..., None] * v_new)
+                 / (w.sum(axis=-1) + w_own)[..., None])
         x = x + self.proj(o.reshape(s, self.dim))
         with jax.named_scope("mlp"):
             x = x + self.mlp_down(nn.gelu(self.mlp_up(self.ln2(x))))
-        return x, k_cache, v_cache
+        return x, k_new, v_new
 
 
 class SeqFormerLM(nn.Module):
@@ -243,15 +246,31 @@ class SeqFormerLM(nn.Module):
         with jax.named_scope("embedding"):
             h = (self.embed(tokens)
                  + self.pos_emb[position].astype(self.dtype))  # (S, D)
-        new_k, new_v = [], []
+        k_rows, v_rows = [], []
         for i, blk in enumerate(self.blocks):
             h, k, v = blk.step(h, k_cache[i], v_cache[i], position)
-            new_k.append(k)
-            new_v.append(v)
+            k_rows.append(k)
+            v_rows.append(v)
         with jax.named_scope("head"):
             next_token = jnp.argmax(self._logits(h),
                                     axis=-1).astype(jnp.int32)
-        return next_token, jnp.stack(new_k), jnp.stack(new_v)
+        # One row per slot, all layers at once, written where the pool
+        # already lives. A Python loop of dynamic_update_slice on purpose:
+        # a scatter (``.at[].set``), a vmap or a fori_loop of the same
+        # writes makes XLA:TPU re-lay or copy the whole pool every step
+        # (CHANGES.md PR 25 has the compiled programs side by side). A
+        # position past the last row is clamped onto it, not dropped: the
+        # engine retires a sequence before it gets there.
+        with jax.named_scope("cache_update"):
+            k_rows = jnp.stack(k_rows)[:, :, :, None, :]  # (depth, S, H, 1, hd)
+            v_rows = jnp.stack(v_rows)[:, :, :, None, :]
+            for slot in range(tokens.shape[0]):
+                at = (0, slot, 0, position[slot], 0)
+                k_cache = jax.lax.dynamic_update_slice(
+                    k_cache, k_rows[:, slot:slot + 1], at)
+                v_cache = jax.lax.dynamic_update_slice(
+                    v_cache, v_rows[:, slot:slot + 1], at)
+        return next_token, k_cache, v_cache
 
 
 def create_seqformer_lm(rng=None, vocab_size: int = 512, max_len: int = 256,

@@ -25,11 +25,17 @@ discipline ``ModelRuntime.warmup`` applies to batch buckets):
   slot);
 - **step** — one decode step over the WHOLE pool: every slot advances
   one token (inactive slots ride along masked; their rows are garbage a
-  later prefill overwrites). One fixed shape → exactly one program.
+  later prefill overwrites). One fixed shape → exactly one program. The
+  layers read the pool as it came in and the new token's K/V are stored
+  afterwards as ONE row per slot (all layers at once), each a
+  ``dynamic_update_slice`` at ``(0, slot, 0, position[slot], 0)``: the
+  step produces nothing else of the pool's shape.
 
 Buffer donation: the step and insert programs consume the cache and
 return the updated one; on non-CPU backends the input buffer is donated
-so the pool exists on-device exactly once.
+and every write lands in it, so the pool exists on-device exactly once —
+also while the step runs (no copy to write into, no rewritten pool:
+``tests/test_tpu_aot_compile.py`` holds the compiled program to that).
 """
 
 from __future__ import annotations
@@ -136,6 +142,10 @@ class PagedDecodeRuntime:
         m = self.servable.model
         head_dim = m.dim // m.heads
         shape = (m.depth, self.slots, m.heads, self.max_len, head_dim)
+        # The old pool goes first: while it lives, building the new one
+        # holds three pool tensors on the device at once, which would be
+        # the allocator's peak of the whole worker.
+        self._k = self._v = None
         self._k = jnp.zeros(shape, jnp.float32)
         self._v = jnp.zeros(shape, jnp.float32)
 

@@ -21,6 +21,7 @@ Three layers:
 import asyncio
 import json
 import time
+from types import SimpleNamespace
 
 import pytest
 
@@ -170,6 +171,35 @@ class TestEngineScheduling:
             return out
 
         assert len(run(main())) == 3
+
+    def test_step_positions_stay_inside_the_cache(self):
+        """The step program clamps a position past the last row onto it
+        (``dynamic_update_slice``) and has no guard of its own: the
+        engine retires a sequence before it gets there, and an inactive
+        slot rides at position 0 of a row nobody reads."""
+        seen = []
+
+        class Recording(FakeBackend):
+            def step(self, tokens, positions, active):
+                seen.append((list(positions), list(active)))
+                return super().step(tokens, positions, active)
+
+        async def main():
+            engine = DecodeEngine(Recording(slots=3, max_len=6),
+                                  metrics=MetricsRegistry())
+            await engine.start()
+            out = await asyncio.gather(engine.submit([1, 2, 3, 4], 64),
+                                       engine.submit([5], 3))
+            await engine.stop()
+            return out
+
+        long, short = run(main())
+        assert (len(long), len(short)) == (3, 3)
+        assert seen
+        for positions, active in seen:
+            assert all(0 <= p < 6 for p in positions)
+            assert all(p == 0 for p, a in zip(positions, active) if not a)
+        assert max(max(p) for p, _ in seen) == 5   # the last row is used
 
     def test_late_joiner_streams_before_running_sequence_finishes(self):
         """THE acceptance property: a request arriving mid-decode of a
@@ -367,6 +397,25 @@ class TestPagedDecodeRuntime:
             history.append(t)
         assert got == oracle
 
+    def test_reset_cache_frees_the_old_pool_before_it_builds_the_new(
+            self, lm_runtime, monkeypatch):
+        """A third pool tensor never exists: on the chip it was the
+        allocator's peak (warm-up ends in a reset)."""
+        import jax.numpy as jnp
+        held = []
+        real_zeros = jnp.zeros
+
+        def zeros(*args, **kwargs):
+            held.append((lm_runtime._k is not None,
+                         lm_runtime._v is not None))
+            return real_zeros(*args, **kwargs)
+
+        lm_runtime._ensure()
+        monkeypatch.setattr(jnp, "zeros", zeros)
+        lm_runtime.reset_cache()
+        assert held == [(False, False), (True, False)]
+        assert lm_runtime._k.shape == lm_runtime._v.shape
+
     def test_reload_params_bumps_version_and_checks_tree(self, lm_runtime):
         import jax
         before = lm_runtime.params_version
@@ -388,6 +437,172 @@ class TestPagedDecodeRuntime:
         a, b = run(main())
         assert len(a) == 5 and len(b) == 4
         assert all(0 <= t < 64 for t in a + b)
+
+
+# The step's contract (PR 25): one K/V row per slot per layer, written in
+# place; nothing else of the pool is touched.
+
+_POOL = dict(depth=2, slots=5, heads=4, max_len=32, head_dim=8)
+_POOL_SHAPE = tuple(_POOL.values())   # (layers, slots, heads, max_len, hd)
+
+
+@pytest.fixture(scope="module")
+def tiny_lm():
+    import jax
+    from ai4e_tpu.models.seqformer import SeqFormerLM, create_seqformer_lm
+    model, params = create_seqformer_lm(
+        vocab_size=64, max_len=_POOL["max_len"],
+        dim=_POOL["heads"] * _POOL["head_dim"], depth=_POOL["depth"],
+        heads=_POOL["heads"])
+
+    def step(params, tokens, k, v, position):
+        return model.apply(params, tokens, k, v, position,
+                           method=SeqFormerLM.decode_step)
+
+    def prefill(tokens, length):
+        return model.apply(params, tokens, length,
+                           method=SeqFormerLM.prefill)
+
+    return SimpleNamespace(params=params, step=jax.jit(step),
+                           prefill=jax.jit(prefill))
+
+
+def _garbage_pool(rng):
+    import numpy as np
+    return (rng.standard_normal(_POOL_SHAPE).astype(np.float32),
+            rng.standard_normal(_POOL_SHAPE).astype(np.float32))
+
+
+def _bits(a):
+    import numpy as np
+    return np.ascontiguousarray(np.asarray(a)).view(np.uint32)
+
+
+def _step_over_histories(lm, positions, seed=0):
+    """Slot i holds a random history of ``positions[i]`` tokens (its K/V
+    from the prefill program) in a pool of garbage, then every slot takes
+    one step. Returns what the contract is stated over."""
+    import numpy as np
+    rng = np.random.default_rng(seed)
+    k0, v0 = _garbage_pool(rng)
+    length = _POOL["max_len"]
+    tokens, want_tok, want_k, want_v = [], [], [], []
+    for slot, p in enumerate(positions):
+        history = rng.integers(1, 64, size=p + 1)
+        padded = np.zeros((1, length), np.int32)
+        padded[0, :p + 1] = history
+        tok, k, v = lm.prefill(padded, np.asarray([p + 1], np.int32))
+        k, v = np.asarray(k), np.asarray(v)   # (depth, 1, H, L, hd)
+        k0[:, slot, :, :p] = k[:, 0, :, :p]
+        v0[:, slot, :, :p] = v[:, 0, :, :p]
+        tokens.append(int(history[-1]))
+        want_tok.append(int(tok[0]))
+        want_k.append(k[:, 0, :, p])
+        want_v.append(v[:, 0, :, p])
+    out, k1, v1 = lm.step(lm.params, np.asarray(tokens, np.int32), k0, v0,
+                          np.asarray(positions, np.int32))
+    return SimpleNamespace(
+        tokens=[int(t) for t in np.asarray(out)], want_tokens=want_tok,
+        before=(k0, v0), after=(np.asarray(k1), np.asarray(v1)),
+        want_rows=(want_k, want_v))
+
+
+class TestStepWritesOneRowInPlace:
+    @pytest.mark.parametrize("positions", [
+        (0, 3, 7, 16, 31),       # every slot somewhere else, first and last row
+        (5, 5, 5, 5, 5),         # every slot at the same row
+        (31, 31, 0, 0, 31),      # the last row, ``max_len - 1``
+        (1, 0, 30, 2, 0),
+    ], ids=["spread", "same", "last-row", "mixed"])
+    def test_exactly_one_row_per_slot_changes(self, tiny_lm, positions):
+        import numpy as np
+        got = _step_over_histories(tiny_lm, positions)
+        # The same token greedy re-prefill over the history gives.
+        assert got.tokens == got.want_tokens
+        for before, after, rows in zip(got.before, got.after,
+                                       got.want_rows):
+            written = np.zeros(before.shape, bool)
+            for slot, p in enumerate(positions):
+                written[:, slot, :, p, :] = True
+                np.testing.assert_allclose(after[:, slot, :, p], rows[slot],
+                                           rtol=1e-5, atol=1e-5)
+            changed = _bits(before) != _bits(after)
+            # Bit for bit: garbage the blend would have rounded (or
+            # poisoned, were it not finite) stays what it was.
+            assert not (changed & ~written).any()
+            assert changed[written].all()
+            assert int(changed.sum()) == (
+                _POOL["depth"] * _POOL["slots"] * _POOL["heads"]
+                * _POOL["head_dim"])
+
+    def test_position_past_the_cache_lands_on_the_last_row(self, tiny_lm):
+        """What ``dynamic_update_slice`` does with a start it cannot
+        honour: it clamps. No caller sends one (the engine test above);
+        the program carries no guard."""
+        import numpy as np
+        rng = np.random.default_rng(1)
+        k0, v0 = _garbage_pool(rng)
+        length = _POOL["max_len"]
+        positions = np.asarray([length, length + 7, 2, 2, 2], np.int32)
+        _, k1, v1 = tiny_lm.step(tiny_lm.params,
+                                 np.ones(_POOL["slots"], np.int32), k0, v0,
+                                 positions)
+        for before, after in ((k0, k1), (v0, v1)):
+            changed = _bits(before) != _bits(after)
+            rows = {(int(s), int(p))
+                    for _, s, _, p, _ in zip(*np.nonzero(changed))}
+            assert rows == {(0, length - 1), (1, length - 1),
+                            (2, 2), (3, 2), (4, 2)}
+
+    def test_inactive_slots_disturb_no_active_slot(self, tiny_lm):
+        """Inactive slots ride at token 0, position 0 over whatever their
+        rows hold — here NaN and inf, which the one-hot blend would have
+        spread over the slot's whole row."""
+        import numpy as np
+        rng = np.random.default_rng(2)
+        k0, v0 = _garbage_pool(rng)
+        tokens = np.asarray([9, 0, 17, 0, 0], np.int32)
+        positions = np.asarray([4, 0, 11, 0, 0], np.int32)
+        inactive = [1, 3, 4]
+        clean = tiny_lm.step(tiny_lm.params, tokens, k0, v0, positions)
+        k_bad, v_bad = k0.copy(), v0.copy()
+        k_bad[:, inactive] = np.nan
+        v_bad[:, inactive] = np.inf
+        dirty = tiny_lm.step(tiny_lm.params, tokens, k_bad, v_bad, positions)
+        active = [0, 2]
+        assert (np.asarray(clean[0])[active]
+                == np.asarray(dirty[0])[active]).all()
+        for a, b, bad in ((clean[1], dirty[1], k_bad),
+                          (clean[2], dirty[2], v_bad)):
+            a, b = np.asarray(a), np.asarray(b)
+            assert (_bits(a[:, active]) == _bits(b[:, active])).all()
+            # and the inactive slots' garbage is still theirs, but for row 0
+            assert (_bits(b[:, inactive, :, 1:])
+                    == _bits(bad[:, inactive, :, 1:])).all()
+
+    def test_lowered_step_makes_the_pool_only_by_row_writes(self, tiny_lm):
+        """Every operation of the lowered program whose result has the
+        pool's shape is a ``dynamic_update_slice``: no blend, no stack of
+        rewritten layers, no scatter, no copy to write into."""
+        import re
+        import jax
+        import jax.numpy as jnp
+        pool = jax.ShapeDtypeStruct(_POOL_SHAPE, jnp.float32)
+        ints = jax.ShapeDtypeStruct((_POOL["slots"],), jnp.int32)
+        text = tiny_lm.step.lower(
+            tiny_lm.params, ints, pool, pool, ints).as_text()
+        pool_type = "tensor<" + "x".join(map(str, _POOL_SHAPE)) + "xf32>"
+        makers = []
+        for line in text.splitlines():
+            m = re.match(r"\s*%\S+ = \"?([\w.]+)\"?", line)
+            if m is None:
+                continue
+            results = line.rsplit("->", 1)[-1] if "->" in line else (
+                line.rsplit(":", 1)[-1])
+            if pool_type in results:
+                makers.append(m.group(1))
+        assert makers == (["stablehlo.dynamic_update_slice"]
+                          * (2 * _POOL["slots"])), makers
 
 
 # -- worker serve_stream + SSE chunk flow ------------------------------------
