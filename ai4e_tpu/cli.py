@@ -255,6 +255,7 @@ def _restore_checkpoint(servable, checkpoint: str,
 def build_worker(config: FrameworkConfig, models: dict):
     """Assemble a worker process; returns (worker, batcher, task_manager)."""
     from .runtime import (
+        LM_FAMILIES,
         InferenceWorker,
         MicroBatcher,
         ModelRuntime,
@@ -339,13 +340,13 @@ def build_worker(config: FrameworkConfig, models: dict):
     lm_specs: list[dict] = []
     for spec in models.get("models", []):
         spec = dict(spec)
-        family = spec.pop("family")
-        if family == "seqformer-lm":
+        if spec["family"] in LM_FAMILIES:
             # Streaming decode servables ride the continuous-batching
             # engine, not the MicroBatcher — collected here, wired after
             # the worker exists (docs/streaming.md).
             lm_specs.append(spec)
             continue
+        family = spec.pop("family")
         sync_path = spec.pop("sync_path", None)
         async_path = spec.pop("async_path", None)
         cap = spec.pop("maximum_concurrent_requests", 64)
@@ -443,19 +444,19 @@ def build_worker(config: FrameworkConfig, models: dict):
     runtime.warmup()
 
     # Continuous-batching decode path (AI4E_RUNTIME_DECODE_ENABLE,
-    # docs/streaming.md): one engine per seqformer-lm spec, AOT-warmed
+    # docs/streaming.md): one engine per LM-family spec, AOT-warmed
     # (prefill buckets + the step program) so nothing compiles on the
     # serving path. Gated twice: the knob AND a spec — neither alone
     # constructs an engine, keeping the default worker byte-identical.
     # serve_stream registers each engine on worker.decode_engines (the
     # reload endpoint and run_worker's start/stop read it there).
     if lm_specs and not rt.decode_enable:
-        log.warning("models spec names %d seqformer-lm servable(s) but "
+        log.warning("models spec names %d LM servable(s) but "
                     "AI4E_RUNTIME_DECODE_ENABLE is off — not serving them",
                     len(lm_specs))
     elif lm_specs and jax.process_count() > 1:
         log.warning("streaming decode is single-host only (the engine "
-                    "loop owns the device); not serving %d seqformer-lm "
+                    "loop owns the device); not serving %d LM "
                     "servable(s)", len(lm_specs))
     elif lm_specs:
         from .runtime.decode import DecodeEngine

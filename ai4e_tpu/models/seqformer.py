@@ -183,6 +183,29 @@ class _LMBlock(nn.Module):
         return x, k_new, v_new
 
 
+def write_kv_rows(k_cache, v_cache, k_rows, v_rows, position):
+    """Store one decode step's K/V: ``k_rows``/``v_rows`` are per-layer lists
+    of (S, H, hd), the pool is (depth, S, H, L, hd), ``position`` (S,).
+
+    One row per slot, all layers at once, written where the pool already
+    lives. A Python loop of dynamic_update_slice on purpose: a scatter
+    (``.at[].set``), a vmap or a fori_loop of the same writes makes XLA:TPU
+    re-lay or copy the whole pool every step (CHANGES.md PR 25 has the
+    compiled programs side by side). A position past the last row is
+    clamped onto it, not dropped: the engine retires a sequence before it
+    gets there."""
+    with jax.named_scope("cache_update"):
+        k_rows = jnp.stack(k_rows)[:, :, :, None, :]  # (depth, S, H, 1, hd)
+        v_rows = jnp.stack(v_rows)[:, :, :, None, :]
+        for slot in range(position.shape[0]):
+            at = (0, slot, 0, position[slot], 0)
+            k_cache = jax.lax.dynamic_update_slice(
+                k_cache, k_rows[:, slot:slot + 1], at)
+            v_cache = jax.lax.dynamic_update_slice(
+                v_cache, v_rows[:, slot:slot + 1], at)
+    return k_cache, v_cache
+
+
 class SeqFormerLM(nn.Module):
     """Causal token LM over the SeqFormer block stack — the
     autoregressive serving shape (``runtime/decode.py``). Two entry
@@ -216,6 +239,11 @@ class SeqFormerLM(nn.Module):
         self.blocks = [_LMBlock(self.dim, self.heads, dtype=self.dtype,
                                 name=f"block{i}") for i in range(self.depth)]
         self.ln_f = nn.LayerNorm(name="ln_f")
+
+    @nn.nowrap
+    def cache_spec(self):
+        """``((layers, heads, head_dim), dtype)`` of the K/V pool."""
+        return (self.depth, self.heads, self.dim // self.heads), jnp.float32
 
     def _logits(self, h):
         # Tied embedding head: attend() reuses the embedding matrix, so
@@ -254,22 +282,8 @@ class SeqFormerLM(nn.Module):
         with jax.named_scope("head"):
             next_token = jnp.argmax(self._logits(h),
                                     axis=-1).astype(jnp.int32)
-        # One row per slot, all layers at once, written where the pool
-        # already lives. A Python loop of dynamic_update_slice on purpose:
-        # a scatter (``.at[].set``), a vmap or a fori_loop of the same
-        # writes makes XLA:TPU re-lay or copy the whole pool every step
-        # (CHANGES.md PR 25 has the compiled programs side by side). A
-        # position past the last row is clamped onto it, not dropped: the
-        # engine retires a sequence before it gets there.
-        with jax.named_scope("cache_update"):
-            k_rows = jnp.stack(k_rows)[:, :, :, None, :]  # (depth, S, H, 1, hd)
-            v_rows = jnp.stack(v_rows)[:, :, :, None, :]
-            for slot in range(tokens.shape[0]):
-                at = (0, slot, 0, position[slot], 0)
-                k_cache = jax.lax.dynamic_update_slice(
-                    k_cache, k_rows[:, slot:slot + 1], at)
-                v_cache = jax.lax.dynamic_update_slice(
-                    v_cache, v_rows[:, slot:slot + 1], at)
+        k_cache, v_cache = write_kv_rows(k_cache, v_cache, k_rows, v_rows,
+                                         position)
         return next_token, k_cache, v_cache
 
 

@@ -14,6 +14,7 @@ _EXPORTS = {
     "BatcherSaturated": ".batcher",
     "MicroBatcher": ".batcher",
     "FAMILIES": ".families",
+    "LM_FAMILIES": ".families",
     "build_servable": ".families",
     "crops_handoff": ".handoffs",
     "LadderManager": ".ladder",

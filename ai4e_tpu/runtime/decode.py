@@ -190,6 +190,9 @@ class DecodeEngine:
     - ``prefill_into(slot, tokens) -> first generated token id``;
     - ``step(tokens, positions, active) -> next token id per slot``
       (plain int lists — the backend owns array conversion);
+    - optionally ``step_report`` (attribute): figures of the step just
+      run, by name, from a model that reports on it — observed as
+      ``ai4e_decode_experts_touched`` / ``ai4e_decode_expert_peak_load``;
     - optionally ``phase_hook`` (attribute, None until the engine installs
       ``hook(phase, seconds)``): called inside a backend call with
       ``device_wait`` (seconds of ``step`` blocked on the device; the rest
@@ -253,6 +256,22 @@ class DecodeEngine:
             "ai4e_decode_step_active_slots",
             "Active slots of each decode step (the batch size per step)",
             buckets=(*range(1, backend.slots + 1), float("inf")))
+        # From a backend whose model reports on its step (``step_report``:
+        # the sparse-expert LM); a dense model's worker never observes them.
+        self._step_report = {
+            "experts_touched": self.metrics.histogram(
+                "ai4e_decode_experts_touched",
+                "Experts with at least one LIVE token, a MoE layer a decode "
+                "step (mean over the step's layers)",
+                buckets=(*(2 ** i for i in range(11)), float("inf"))),
+            "expert_peak_load": self.metrics.histogram(
+                "ai4e_decode_expert_peak_load",
+                "The fullest expert's live tokens over the mean load (live "
+                "slots x experts a token / experts), a MoE layer a decode "
+                "step: the straggler measure",
+                buckets=(1.0, 1.25, 1.5, 1.75, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0,
+                         float("inf"))),
+        }
         self._kv_positions = self.metrics.counter(
             "ai4e_decode_kv_positions_total",
             "K/V positions per decode step: live (sum of position + 1 over "
@@ -599,6 +618,9 @@ class DecodeEngine:
                 self._kv_positions.inc(
                     self.pool.slots * self.backend.max_len,
                     model=self._model, kind="attended")
+                for name, value in getattr(self.backend, "step_report",
+                                           {}).items():
+                    self._step_report[name].observe(value, model=self._model)
                 for slot, seq, position in snapshot:
                     if seq.done or seq.slot != slot:
                         continue  # re-check after the await: retired mid-step

@@ -594,6 +594,46 @@ FAMILIES = {
 }
 
 
+def build_seqformer_lm(name: str = "lm", vocab_size: int = 512,
+                       max_len: int = 256, dim: int = 64, depth: int = 2,
+                       heads: int = 4, eos_id: int | None = None, rng=None,
+                       **_):
+    """The GPT-2-shaped LM (``models/seqformer.py`` ``SeqFormerLM``):
+    LayerNorm, learned positions, GELU, tied head, float32."""
+    from ..models.seqformer import create_seqformer_lm
+    from .kvcache import LMServable
+    model, params = create_seqformer_lm(
+        rng=rng, vocab_size=vocab_size, max_len=max_len, dim=dim,
+        depth=depth, heads=heads)
+    return LMServable(name=name, model=model, params=params,
+                      vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
+
+
+def build_olmoe_lm(name: str = "lm", vocab_size: int = 512,
+                   max_len: int = 256, eos_id: int | None = None, rng=None,
+                   dtype: str = "bfloat16", **dims):
+    """The sparse-expert decoder (``models/olmoe.py`` ``OlmoeLM``): RoPE,
+    RMSNorm, q/k norms, top-K of E SwiGLU experts, untied head, bfloat16
+    weights and cache. ``dims``: ``dim``, ``depth``, ``heads``,
+    ``experts``, ``experts_per_token``, ``expert_dim``, ``rms_eps``,
+    ``rope_theta``; a key the family does not know is an error, not a
+    default."""
+    from ..models.olmoe import create_olmoe_lm
+    from .kvcache import LMServable
+    model, params = create_olmoe_lm(rng=rng, vocab_size=vocab_size,
+                                    dtype=dtype, **dims)
+    return LMServable(name=name, model=model, params=params,
+                      vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
+
+
+# LM families ride the decode engine (``runtime/decode.py``), never the
+# MicroBatcher: ``cli`` tells them from the batch families by this table.
+LM_FAMILIES = {
+    "seqformer-lm": build_seqformer_lm,
+    "olmoe": build_olmoe_lm,
+}
+
+
 def build_servable(family: str, **kwargs) -> ServableModel:
     if family not in FAMILIES:
         raise ValueError(

@@ -60,7 +60,14 @@ class LMServable:
     them)."""
 
     name: str
-    model: Any                   # models.seqformer.SeqFormerLM
+    # A flax module with the LM entry points, called by name:
+    # ``prefill(tokens (B, P), length (B,))`` → ids, K block, V block;
+    # ``decode_step(tokens (S,), k, v, position (S,))`` → ids (S,) then,
+    # optionally, more int32s the model's own ``step_report(extra,
+    # active)`` turns into per-step figures; k, v; and
+    # ``cache_spec()`` → ``((layers, heads, head_dim), dtype)`` of the
+    # pool. ``runtime/families.py`` (``LM_FAMILIES``) builds them.
+    model: Any
     params: Any
     vocab_size: int
     max_len: int
@@ -74,19 +81,14 @@ class LMServable:
     generation: int = 1
 
 
-def build_lm_servable(name: str = "lm", vocab_size: int = 512,
-                      max_len: int = 256, dim: int = 64, depth: int = 2,
-                      heads: int = 4, eos_id: int | None = None,
-                      rng=None, **_) -> LMServable:
-    """Build a SeqFormerLM servable for the streaming path (the ``**_``
-    sink mirrors the batch families: spec-driven callers may pass keys
-    this family ignores)."""
-    from ..models.seqformer import create_seqformer_lm
-    model, params = create_seqformer_lm(
-        rng=rng, vocab_size=vocab_size, max_len=max_len, dim=dim,
-        depth=depth, heads=heads)
-    return LMServable(name=name, model=model, params=params,
-                      vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
+def build_lm_servable(family: str = "seqformer-lm", **spec) -> LMServable:
+    """Build the servable of one LM family (``families.LM_FAMILIES``) from
+    a models-spec entry's keys."""
+    from .families import LM_FAMILIES
+    if family not in LM_FAMILIES:
+        raise ValueError(f"unknown LM family {family!r}; valid: "
+                         f"{sorted(LM_FAMILIES)}")
+    return LM_FAMILIES[family](**spec)
 
 
 class PagedDecodeRuntime:
@@ -120,6 +122,9 @@ class PagedDecodeRuntime:
         # the seconds of any call that had to build its program
         # (``compile``). None (and during ``warm()``): nothing is reported.
         self.phase_hook = None
+        # Figures of the last step from a model that reports on it (the
+        # engine observes each as ``ai4e_decode_<name>``); else empty.
+        self.step_report: dict[str, float] = {}
 
     # -- cache lifecycle ---------------------------------------------------
 
@@ -127,27 +132,30 @@ class PagedDecodeRuntime:
     def params_version(self) -> int:
         return self.servable.params_version
 
+    def cache_spec(self) -> tuple:
+        """``(shape, dtype)`` of each pool tensor: the model's layers, heads
+        and head size and its cache dtype, this runtime's slots and
+        length."""
+        (layers, heads, head_dim), dtype = self.servable.model.cache_spec()
+        return (layers, self.slots, heads, self.max_len, head_dim), dtype
+
     def cache_nbytes(self) -> int:
         """Resident bytes of the pooled cache (both tensors) — the
         number the memory math in docs/streaming.md bounds."""
-        m = self.servable.model
-        head_dim = m.dim // m.heads
-        return (2 * m.depth * self.slots * m.heads * self.max_len
-                * head_dim * np.dtype(np.float32).itemsize)
+        shape, dtype = self.cache_spec()
+        return 2 * int(np.prod(shape)) * np.dtype(dtype).itemsize
 
     def reset_cache(self) -> None:
         """Drop + reallocate the pooled cache (hot-reload invalidation:
         blocks computed under the old weights must never serve)."""
         import jax.numpy as jnp
-        m = self.servable.model
-        head_dim = m.dim // m.heads
-        shape = (m.depth, self.slots, m.heads, self.max_len, head_dim)
+        shape, dtype = self.cache_spec()
         # The old pool goes first: while it lives, building the new one
         # holds three pool tensors on the device at once, which would be
         # the allocator's peak of the whole worker.
         self._k = self._v = None
-        self._k = jnp.zeros(shape, jnp.float32)
-        self._v = jnp.zeros(shape, jnp.float32)
+        self._k = jnp.zeros(shape, dtype)
+        self._v = jnp.zeros(shape, dtype)
 
     def _ensure(self) -> None:
         if self._k is None:
@@ -157,7 +165,6 @@ class PagedDecodeRuntime:
 
     def _build_programs(self) -> None:
         import jax
-        from ..models.seqformer import SeqFormerLM
         model = self.servable.model
         if self._donate is None:
             # CPU XLA cannot donate (every run would warn); on device
@@ -167,12 +174,11 @@ class PagedDecodeRuntime:
         donate_insert = (0, 1) if self._donate else ()
 
         def prefill(params, tokens, length):
-            return model.apply(params, tokens, length,
-                               method=SeqFormerLM.prefill)
+            return model.apply(params, tokens, length, method="prefill")
 
         def step(params, tokens, k, v, position):
             return model.apply(params, tokens, k, v, position,
-                               method=SeqFormerLM.decode_step)
+                               method="decode_step")
 
         def insert(k, v, k_block, v_block, slot):
             zero = (0, slot, 0, 0, 0)
@@ -232,21 +238,25 @@ class PagedDecodeRuntime:
         return int(token[0])   # waits for the prefill program's run
 
     def step(self, tokens, positions, active) -> list[int]:
-        """One decode step over the pool. ``active`` is advisory — the
-        program computes every slot; inactive rows are garbage the
-        engine never reads."""
+        """One decode step over the pool. The program computes every slot;
+        inactive rows are garbage the engine never reads. ``active`` only
+        tells a model that reports on its step (``step_report``) which
+        slots to count."""
         self._ensure()
-        del active
         with device_trace("ai4e.decode.dispatch"):
             out, self._k, self._v = self._run(
                 "step", self.servable.params, np.asarray(tokens, np.int32),
                 self._k, self._v, np.asarray(positions, np.int32))
         t0 = time.perf_counter()
         with device_trace("ai4e.decode.device_wait"):
-            ids = np.asarray(out)   # the device's run and the ids' d2h
+            out = np.asarray(out)   # the device's run and the ids' d2h
         if self.phase_hook is not None:
             self.phase_hook("device_wait", time.perf_counter() - t0)
-        return [int(t) for t in ids]
+        if out.shape[0] > self.slots:
+            # What the model appended to its ids came with the same fetch.
+            self.step_report = self.servable.model.step_report(
+                out[self.slots:], active)
+        return [int(t) for t in out[:self.slots]]
 
     # -- weights -----------------------------------------------------------
 

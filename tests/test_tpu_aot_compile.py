@@ -197,3 +197,69 @@ def test_decode_step_at_the_benchmark_cell_writes_rows_in_place(v5e_sharding):
     assert memory.temp_size_in_bytes < 0.5e9, memory.temp_size_in_bytes
     # Both pool tensors are aliased input to output: the pool exists once.
     assert memory.alias_size_in_bytes >= 2 * 4 * np.prod(pool_shape)
+
+
+def test_olmoe_step_at_the_benchmark_cell_writes_rows_in_place(v5e_sharding):
+    """The same, for the ``olmoe.decode`` cell, read from
+    ``benchmark/configs/olmoe-1b-7b.json``: a bfloat16 pool of
+    ``[8,32,16,2048,128]`` is another layout (bf16 tiles, head dimension
+    128), and the step must still make it only by row writes — 2 x slots
+    ``dynamic-update-slice``, no pool-shaped copy or fusion — with the pool
+    aliased input to output. Then the whole worker's memory: weights + pool
+    + the largest program's temporaries (the top prefill bucket) stay under
+    the 15 GB line the configuration states. About 25 s."""
+    import re
+    from ai4e_tpu.models.olmoe import OlmoeLM, create_olmoe_lm
+    from ai4e_tpu.runtime.kvcache import LMServable, PagedDecodeRuntime
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "olmoe-1b-7b.json")) as f:
+        config = json.load(f)
+    spec = config["models"]["models"][0]
+    slots = int(config["worker_env"]["AI4E_RUNTIME_KV_SLOTS"])
+    dims = {key: spec[key] for key in (
+        "vocab_size", "dim", "depth", "heads", "experts",
+        "experts_per_token", "expert_dim", "rms_eps", "rope_theta")}
+    params = jax.eval_shape(lambda: create_olmoe_lm(**dims)[1])
+    runtime = PagedDecodeRuntime(
+        LMServable(name="lm", model=OlmoeLM(**dims), params=params,
+                   vocab_size=spec["vocab_size"], max_len=spec["max_len"]),
+        slots=slots, donate=True)
+    runtime._build_programs()
+    params = _on(v5e_sharding, params)
+    pool_shape, pool_dtype = runtime.cache_spec()
+    assert pool_shape == (8, 32, 16, 2048, 128)
+    assert pool_dtype == jnp.bfloat16
+    pool = _on(v5e_sharding, (pool_shape, pool_dtype))
+    ints = _on(v5e_sharding, ((slots,), jnp.int32))
+    step = runtime._programs["step"].lower(
+        params, ints, pool, pool, ints).compile()
+
+    pool_type = "bf16[" + ",".join(map(str, pool_shape)) + "]"
+    entry = re.search(r"ENTRY [^\n]*\{\n(.*?)\n\}", step.as_text(),
+                      re.S).group(1)
+    makers = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?\S+ = (\S+) ([\w\-]+)\(", line)
+        if m and m.group(1).startswith(pool_type):
+            makers.append(m.group(2))
+    assert sorted(set(makers)) == ["dynamic-update-slice", "parameter"], (
+        sorted(set(makers)))
+    assert makers.count("dynamic-update-slice") == 2 * slots
+    assert makers.count("parameter") == 2
+    memory = step.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.1e9, memory.temp_size_in_bytes
+    pool_bytes = 2 * 2 * int(np.prod(pool_shape))
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert runtime.cache_nbytes() == pool_bytes
+
+    top = runtime.prompt_buckets[-1]
+    assert top == spec["max_len"] == 2048
+    prefill = runtime._programs["prefill"].lower(
+        params, _on(v5e_sharding, ((1, top), jnp.int32)),
+        _on(v5e_sharding, ((1,), jnp.int32))).compile().memory_analysis()
+    resident = memory.argument_size_in_bytes      # weights + pool (+ ints)
+    assert 11.3e9 < resident < 11.5e9, resident
+    peak = resident + max(memory.temp_size_in_bytes,
+                          prefill.temp_size_in_bytes
+                          + prefill.output_size_in_bytes)
+    assert peak < 15e9, peak
