@@ -199,7 +199,8 @@ class _OlmoeLayer(nn.Module):
 
     def step(self, x, k_cache, v_cache, position):
         """One token per slot against the pool, as ``_LMBlock.step``: x
-        (S, D); k_cache/v_cache (S, H, L, hd), read and never rewritten; the
+        (S, D); k_cache/v_cache (S, H, L, hd), read and never rewritten (L
+        may be a prefix of the cache that holds every live position); the
         new token's own key and value enter the softmax as one more term.
         Returns ``(y, k_new, v_new, experts)`` with k_new/v_new (S, H, hd)
         and ``experts`` (S, K) the slot's chosen experts."""
@@ -275,12 +276,14 @@ class OlmoeLM(nn.Module):
             vs.append(v)
         return h, jnp.stack(ks), jnp.stack(vs)
 
-    def _step(self, tokens, k_cache, v_cache, position):
+    def _step(self, tokens, k_cache, v_cache, position, bound):
         with jax.named_scope("embedding"):
             h = self.embed[tokens]
         k_rows, v_rows, experts = [], [], []
         for i, layer in enumerate(self.layers):
-            h, k, v, e = layer.step(h, k_cache[i], v_cache[i], position)
+            # One static slice, layer and bound at once, as ``SeqFormerLM``.
+            h, k, v, e = layer.step(h, k_cache[i, :, :, :bound],
+                                    v_cache[i, :, :, :bound], position)
             k_rows.append(k)
             v_rows.append(v)
             experts.append(e)
@@ -294,9 +297,13 @@ class OlmoeLM(nn.Module):
             h, (length - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
         return jnp.argmax(self._logits(last), axis=-1).astype(jnp.int32), k, v
 
-    def decode_step(self, tokens, k_cache, v_cache, position):
+    def decode_step(self, tokens, k_cache, v_cache, position, bound=None):
+        """As ``SeqFormerLM.decode_step``, ``bound`` included: attention
+        reads the cached positions ``< bound`` (a Python int, static under
+        jit; default the whole cache), which must be ``>=`` the largest
+        position of a slot whose output is read."""
         h, k_cache, v_cache, experts = self._step(tokens, k_cache, v_cache,
-                                                  position)
+                                                  position, bound)
         ids = jnp.argmax(self._logits(h), axis=-1).astype(jnp.int32)
         return (jnp.concatenate([ids, experts.astype(jnp.int32).reshape(-1)]),
                 k_cache, v_cache)
@@ -307,9 +314,9 @@ class OlmoeLM(nn.Module):
         h, k, v = self._prefill(tokens, length)
         return self._logits(h), k, v
 
-    def decode_logits(self, tokens, k_cache, v_cache, position):
+    def decode_logits(self, tokens, k_cache, v_cache, position, bound=None):
         h, k_cache, v_cache, _ = self._step(tokens, k_cache, v_cache,
-                                            position)
+                                            position, bound)
         return self._logits(h), k_cache, v_cache
 
     @nn.nowrap

@@ -152,8 +152,10 @@ class _LMBlock(nn.Module):
         position: (S,) — the cache index the new token belongs at. The
         new token's own key and value enter the softmax as one more term
         beside the cached ones, so attention needs no updated cache.
-        Returns ``(y, k_new, v_new)`` with k_new/v_new of shape
-        (S, H, hd) — the rows ``SeqFormerLM.decode_step`` stores."""
+        L may be a prefix of the cache that holds every live slot's
+        position (``SeqFormerLM.decode_step``'s ``bound``). Returns
+        ``(y, k_new, v_new)`` with k_new/v_new of shape (S, H, hd) — the
+        rows ``SeqFormerLM.decode_step`` stores."""
         s, _ = x.shape
         hd = self.dim // self.heads
         length = k_cache.shape[2]
@@ -216,9 +218,14 @@ class SeqFormerLM(nn.Module):
       block, inserted into a slot of the pooled cache by the decode
       runtime (``runtime/kvcache.py``);
     - ``decode_step(tokens (S,), k (depth, S, H, L, hd), v, position
-      (S,))`` → ``(next-token ids (S,), k, v)`` — ONE token for every
-      slot in the pool per call, inactive slots riding along masked
-      (their cache rows are garbage a later prefill overwrites).
+      (S,), bound=None)`` → ``(next-token ids (S,), k, v)`` — ONE token
+      for every slot in the pool per call, inactive slots riding along
+      masked (their cache rows are garbage a later prefill overwrites).
+      ``bound`` (a Python int, static under jit; default L) cuts every
+      layer's attention to the cached positions ``< bound``: the same
+      result, to the order of a float32 sum, for any bound ``>=`` the
+      largest position of a slot whose output is read. The row writes
+      take the whole pool either way.
 
     Greedy decoding is computed on-device (argmax over the tied-embedding
     logits) so each step ships S int32s back to the host, not S×V logits.
@@ -270,21 +277,38 @@ class SeqFormerLM(nn.Module):
                                     axis=-1).astype(jnp.int32)
         return next_token, jnp.stack(ks), jnp.stack(vs)
 
-    def decode_step(self, tokens, k_cache, v_cache, position):
+    def _step(self, tokens, k_cache, v_cache, position, bound):
         with jax.named_scope("embedding"):
             h = (self.embed(tokens)
                  + self.pos_emb[position].astype(self.dtype))  # (S, D)
         k_rows, v_rows = [], []
         for i, blk in enumerate(self.blocks):
-            h, k, v = blk.step(h, k_cache[i], v_cache[i], position)
+            # One static slice a layer and tensor, layer and bound at once:
+            # XLA:TPU fuses it into the attention's reads. A cut of the
+            # layer's view (``k_cache[i][:, :, :bound]``) costs a copy of
+            # every layer's K and V (tests/test_tpu_aot_compile.py).
+            h, k, v = blk.step(h, k_cache[i, :, :, :bound],
+                               v_cache[i, :, :, :bound], position)
             k_rows.append(k)
             v_rows.append(v)
+        k_cache, v_cache = write_kv_rows(k_cache, v_cache, k_rows, v_rows,
+                                         position)
+        return h, k_cache, v_cache
+
+    def decode_step(self, tokens, k_cache, v_cache, position, bound=None):
+        h, k_cache, v_cache = self._step(tokens, k_cache, v_cache, position,
+                                         bound)
         with jax.named_scope("head"):
             next_token = jnp.argmax(self._logits(h),
                                     axis=-1).astype(jnp.int32)
-        k_cache, v_cache = write_kv_rows(k_cache, v_cache, k_rows, v_rows,
-                                         position)
         return next_token, k_cache, v_cache
+
+    def decode_logits(self, tokens, k_cache, v_cache, position, bound=None):
+        """``decode_step`` with the logits in place of their argmax: for
+        tests only, the serving program ships ids."""
+        h, k_cache, v_cache = self._step(tokens, k_cache, v_cache, position,
+                                         bound)
+        return self._logits(h), k_cache, v_cache
 
 
 def create_seqformer_lm(rng=None, vocab_size: int = 512, max_len: int = 256,

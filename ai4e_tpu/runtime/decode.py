@@ -193,6 +193,13 @@ class DecodeEngine:
     - optionally ``step_report`` (attribute): figures of the step just
       run, by name, from a model that reports on it — observed as
       ``ai4e_decode_experts_touched`` / ``ai4e_decode_expert_peak_load``;
+    - optionally ``step_bound`` (attribute): the positions of every slot
+      the step just run attended — counted as ``slots x step_bound``
+      attended K/V positions and observed as ``ai4e_decode_step_bound``;
+      a backend without it attends ``max_len``;
+    - optionally ``bound_for(longest)``: the bound a step whose largest
+      live position is ``longest`` will run — the ``bound=`` of the
+      ``ai4e.decode.tick`` region, which opens before the step;
     - optionally ``phase_hook`` (attribute, None until the engine installs
       ``hook(phase, seconds)``): called inside a backend call with
       ``device_wait`` (seconds of ``step`` blocked on the device; the rest
@@ -256,6 +263,12 @@ class DecodeEngine:
             "ai4e_decode_step_active_slots",
             "Active slots of each decode step (the batch size per step)",
             buckets=(*range(1, backend.slots + 1), float("inf")))
+        self._step_bound = self.metrics.histogram(
+            "ai4e_decode_step_bound",
+            "Positions of every slot each decode step attended (the rung of "
+            "the backend's step programs it ran; max_len without rungs)",
+            buckets=(*getattr(backend, "step_bounds", (backend.max_len,)),
+                     float("inf")))
         # From a backend whose model reports on its step (``step_report``:
         # the sparse-expert LM); a dense model's worker never observes them.
         self._step_report = {
@@ -275,7 +288,7 @@ class DecodeEngine:
         self._kv_positions = self.metrics.counter(
             "ai4e_decode_kv_positions_total",
             "K/V positions per decode step: live (sum of position + 1 over "
-            "active slots) and attended (slots x max_len)")
+            "active slots) and attended (slots x the step's bound)")
         self._occupancy = self.metrics.gauge(
             "ai4e_decode_slot_occupancy",
             "Occupied KV-cache slots / total slots per model")
@@ -587,8 +600,11 @@ class DecodeEngine:
         if not snapshot:
             self._last_submit = None
             return
-        with device_trace("ai4e.decode.tick", tick=self._tick_no,
-                          active=len(snapshot)):
+        bound_for = getattr(self.backend, "bound_for", None)
+        with device_trace(
+                "ai4e.decode.tick", tick=self._tick_no, active=len(snapshot),
+                bound=(bound_for(max(p for _, _, p in snapshot)) if bound_for
+                       else self.backend.max_len)):
             with device_trace("ai4e.decode.prepare"):
                 tokens = [0] * self.pool.slots
                 positions = [0] * self.pool.slots
@@ -612,11 +628,14 @@ class DecodeEngine:
             phase["return"] = clock.resumed - clock.left
             with device_trace("ai4e.decode.bookkeeping"):
                 self._step_active.observe(len(snapshot), model=self._model)
+                bound = getattr(self.backend, "step_bound",
+                                self.backend.max_len)
+                self._step_bound.observe(bound, model=self._model)
                 self._kv_positions.inc(
                     sum(position + 1 for _, _, position in snapshot),
                     model=self._model, kind="live")
                 self._kv_positions.inc(
-                    self.pool.slots * self.backend.max_len,
+                    self.pool.slots * bound,
                     model=self._model, kind="attended")
                 for name, value in getattr(self.backend, "step_report",
                                            {}).items():

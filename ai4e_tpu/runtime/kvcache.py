@@ -12,8 +12,8 @@ is a stale cached result). Slots are rows of that buffer; admission and
 release are pure bookkeeping in ``decode.SlotPool`` — the device never
 reallocates per request.
 
-Three compiled programs serve the whole path, none of which may compile
-on the serving path (``warm()`` executes every one — the AOT-warm
+Three kinds of compiled program serve the whole path, none of which may
+compile on the serving path (``warm()`` executes every one — the AOT-warm
 discipline ``ModelRuntime.warmup`` applies to batch buckets):
 
 - **prefill** — full causal attention over ONE padded prompt, per
@@ -25,11 +25,15 @@ discipline ``ModelRuntime.warmup`` applies to batch buckets):
   slot);
 - **step** — one decode step over the WHOLE pool: every slot advances
   one token (inactive slots ride along masked; their rows are garbage a
-  later prefill overwrites). One fixed shape → exactly one program. The
-  layers read the pool as it came in and the new token's K/V are stored
-  afterwards as ONE row per slot (all layers at once), each a
-  ``dynamic_update_slice`` at ``(0, slot, 0, position[slot], 0)``: the
-  step produces nothing else of the pool's shape.
+  later prefill overwrites). The layers read the pool as it came in and
+  the new token's K/V are stored afterwards as ONE row per slot (all
+  layers at once), each a ``dynamic_update_slice`` at
+  ``(0, slot, 0, position[slot], 0)``: the step produces nothing else of
+  the pool's shape. Attention reads only the first ``bound`` positions of
+  every slot: one program per rung of ``step_bounds`` (three quarters of
+  ``max_len`` and ``max_len``), and each step runs the smallest rung that holds its
+  longest LIVE sequence — the same pool, the same row writes, fewer dead
+  positions read.
 
 Buffer donation: the step and insert programs consume the cache and
 return the updated one; on non-CPU backends the input buffer is donated
@@ -62,9 +66,10 @@ class LMServable:
     name: str
     # A flax module with the LM entry points, called by name:
     # ``prefill(tokens (B, P), length (B,))`` → ids, K block, V block;
-    # ``decode_step(tokens (S,), k, v, position (S,))`` → ids (S,) then,
-    # optionally, more int32s the model's own ``step_report(extra,
-    # active)`` turns into per-step figures; k, v; and
+    # ``decode_step(tokens (S,), k, v, position (S,), bound)`` → ids (S,)
+    # then, optionally, more int32s the model's own ``step_report(extra,
+    # active)`` turns into per-step figures; k, v — attending cached
+    # positions ``< bound`` only (a Python int: one program a value); and
     # ``cache_spec()`` → ``((layers, heads, head_dim), dtype)`` of the
     # pool. ``runtime/families.py`` (``LM_FAMILIES``) builds them.
     model: Any
@@ -113,6 +118,16 @@ class PagedDecodeRuntime:
         # compiled program — no serving-path compile, ever.
         self.prompt_buckets = tuple(sorted(
             {min(int(b), self.max_len) for b in raw} | {self.max_len}))
+        # The attended lengths the step is compiled for: three quarters and
+        # all of the cache, on multiples of 128 (whole tiles of the pool's
+        # compiled layout). The top rung is always max_len, so every step
+        # has a program; a tiny cache has that one alone. Two, because a
+        # rung costs ~2 s of every worker start (trace, lower and load at
+        # GPT-2-medium's depth) and the long answers that rule a latency
+        # tail live above half the cache (PERF.md §6, PR 28).
+        self.step_bounds = tuple(sorted(
+            {min(-(-rung // 128) * 128, self.max_len)
+             for rung in (3 * self.max_len // 4, self.max_len)}))
         self._k = None
         self._v = None
         self._donate = donate
@@ -125,6 +140,9 @@ class PagedDecodeRuntime:
         # Figures of the last step from a model that reports on it (the
         # engine observes each as ``ai4e_decode_<name>``); else empty.
         self.step_report: dict[str, float] = {}
+        # Positions a slot the last step attended (the engine counts
+        # ``slots x step_bound`` as attended).
+        self.step_bound = self.max_len
 
     # -- cache lifecycle ---------------------------------------------------
 
@@ -176,8 +194,8 @@ class PagedDecodeRuntime:
         def prefill(params, tokens, length):
             return model.apply(params, tokens, length, method="prefill")
 
-        def step(params, tokens, k, v, position):
-            return model.apply(params, tokens, k, v, position,
+        def step(params, tokens, k, v, position, bound):
+            return model.apply(params, tokens, k, v, position, bound,
                                method="decode_step")
 
         def insert(k, v, k_block, v_block, slot):
@@ -190,12 +208,15 @@ class PagedDecodeRuntime:
 
         self._programs = {
             "prefill": jax.jit(prefill),
-            "step": jax.jit(step, donate_argnums=donate_step),
+            # ``bound`` is static: one entry of this jit's cache per rung,
+            # so ``_run`` sees a rung that was not warmed as a compile.
+            "step": jax.jit(step, donate_argnums=donate_step,
+                            static_argnums=(5,)),
             "insert": jax.jit(insert, donate_argnums=donate_insert),
         }
 
     def _run(self, program: str, *args):
-        """Call one of the three programs. A call that grew the jit's
+        """Call one of the programs. A call that grew the jit's
         dispatch cache traced and compiled (or loaded from the persistent
         cache) instead of dispatching what ``warm()`` had built: its
         seconds go to the hook as ``compile`` — read off the cache itself,
@@ -237,16 +258,33 @@ class PagedDecodeRuntime:
                 "insert", self._k, self._v, k_block, v_block, np.int32(slot))
         return int(token[0])   # waits for the prefill program's run
 
+    def bound_for(self, longest: int) -> int:
+        """The smallest rung of ``step_bounds`` that holds every key a step
+        reads whose largest LIVE position is ``longest``. A slot reads
+        cached positions ``< position`` (the new token's own key and value
+        are a separate term), so a rung ``>= longest`` is enough."""
+        for bound in self.step_bounds:
+            if bound >= longest:
+                return bound
+        return self.max_len
+
     def step(self, tokens, positions, active) -> list[int]:
         """One decode step over the pool. The program computes every slot;
-        inactive rows are garbage the engine never reads. ``active`` only
-        tells a model that reports on its step (``step_report``) which
-        slots to count."""
+        inactive rows are garbage the engine never reads. ``positions`` and
+        ``active`` choose the program: the one compiled for
+        ``bound_for`` the largest position among the ACTIVE slots (an
+        inactive slot's stale position does not count), which attends that
+        many positions of every slot and is otherwise the same step
+        (``step_bound`` says which it was). ``active`` also tells a model
+        that reports on its step (``step_report``) which slots to count."""
         self._ensure()
-        with device_trace("ai4e.decode.dispatch"):
+        self.step_bound = self.bound_for(max(
+            (p for p, live in zip(positions, active) if live), default=0))
+        with device_trace("ai4e.decode.dispatch", bound=self.step_bound):
             out, self._k, self._v = self._run(
                 "step", self.servable.params, np.asarray(tokens, np.int32),
-                self._k, self._v, np.asarray(positions, np.int32))
+                self._k, self._v, np.asarray(positions, np.int32),
+                self.step_bound)
         t0 = time.perf_counter()
         with device_trace("ai4e.decode.device_wait"):
             out = np.asarray(out)   # the device's run and the ids' d2h
@@ -282,17 +320,22 @@ class PagedDecodeRuntime:
 
     def warm(self) -> float:
         """Execute every program once — ``len(prompt_buckets)`` prefill +
-        insert pairs and the one step program — so nothing compiles on
-        the serving path, then reset the cache to a clean pool. Returns
-        wall seconds (exported by the worker boot like batch warmup)."""
+        insert pairs and the step program of every rung of ``step_bounds``
+        — so nothing compiles on the serving path, then reset the cache to
+        a clean pool. The programs do not depend on the weights: after
+        ``reload_params`` the same ones serve. Returns wall seconds
+        (exported by the worker boot like batch warmup)."""
         self._ensure()
         t0 = time.perf_counter()
         for bucket in self.prompt_buckets:
             n = min(bucket, self.max_len - 1)
             self.prefill_into(0, [1] * n)
-        self.step([0] * self.slots, [1] * self.slots, [True] * self.slots)
+        for bound in self.step_bounds:
+            self.step([0] * self.slots, [bound] * self.slots,
+                      [True] * self.slots)
         self.reset_cache()
         seconds = time.perf_counter() - t0
-        log.info("decode warmup %s: %d prompt buckets + step in %.1fs",
-                 self.name, len(self.prompt_buckets), seconds)
+        log.info("decode warmup %s: %d prompt buckets + %d step bounds in "
+                 "%.1fs", self.name, len(self.prompt_buckets),
+                 len(self.step_bounds), seconds)
         return seconds

@@ -337,7 +337,7 @@ class TestEngineScheduling:
             "ai4e_decode_pending", "ai4e_decode_tokens_total",
             "ai4e_decode_sequences_total", "ai4e_decode_reprefills_total",
             "ai4e_decode_tick_seconds", "ai4e_decode_queue_wait_seconds",
-            "ai4e_decode_step_active_slots",
+            "ai4e_decode_step_active_slots", "ai4e_decode_step_bound",
             "ai4e_decode_kv_positions_total",
             "ai4e_decode_experts_touched", "ai4e_decode_expert_peak_load"}
 

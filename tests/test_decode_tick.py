@@ -316,7 +316,7 @@ class TestNamedScopes:
             zeros = np.zeros(3, np.int32)
             return runtime._programs["step"].lower(
                 runtime.servable.params, zeros, runtime._k, runtime._v,
-                zeros).as_text(debug_info=debug_info)
+                zeros, runtime.max_len).as_text(debug_info=debug_info)
 
         scoped = tiny_runtime()
         with_scopes = decode_tokens(scoped)
@@ -371,9 +371,9 @@ class TestProfilerAnnotations:
         assert x == 2
 
     @pytest.mark.parametrize("name,stats", [
-        ("ai4e.decode.tick", ("tick", "active")),
+        ("ai4e.decode.tick", ("tick", "active", "bound")),
         ("ai4e.decode.prepare", ()),
-        ("ai4e.decode.dispatch", ()),
+        ("ai4e.decode.dispatch", ("bound",)),
         ("ai4e.decode.device_wait", ()),
         ("ai4e.decode.bookkeeping", ()),
         ("ai4e.decode.prefill", ("bucket", "slot")),
@@ -389,3 +389,5 @@ class TestProfilerAnnotations:
             ticks = [int(s["tick"]) for s in host_events[name]]
             assert len(ticks) == 3 and ticks == sorted(ticks)
             assert {int(s["active"]) for s in host_events[name]} == {1}
+            # ``tiny_runtime``'s cache of 24 has the one rung.
+            assert {int(s["bound"]) for s in host_events[name]} == {24}

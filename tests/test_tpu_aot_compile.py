@@ -150,112 +150,139 @@ class TestFourChipHost:
             q, k, v, interpret=False, mesh=v5e_host_mesh), qkv, qkv, qkv)
 
 
-def test_decode_step_at_the_benchmark_cell_writes_rows_in_place(v5e_sharding):
-    """The ``step`` program ``PagedDecodeRuntime`` builds, donated, at the
-    shape of the benchmark's ``gpt2m.chat`` cell (GPT-2-medium, 32 slots):
-    the new token's K/V go into the pool as one ``dynamic-update-slice``
-    per slot and tensor, on the donated parameters. No whole-pool ``copy``
-    (XLA's answer to a scatter: it re-lays the pool out and back, 6.5 GB of
-    temporaries), no fusion that rewrites a pool (the one-hot blend: 3.3 GB).
-    About 8 s."""
-    import re
-    from ai4e_tpu.models.seqformer import SeqFormerLM, create_seqformer_lm
+def _benchmark_cell(config_file, create, model_cls, keys):
+    """``(runtime, spec)`` of a decode cell as ``benchmark/configs`` states
+    it: ``model_cls(**spec[keys])`` at its widths, with the parameters
+    ``create`` would draw as shapes, the cell's slots, the programs built
+    donated."""
     from ai4e_tpu.runtime.kvcache import LMServable, PagedDecodeRuntime
-    with open(os.path.join(REPO, "benchmark", "configs",
-                           "gpt2-medium.json")) as f:
+    with open(os.path.join(REPO, "benchmark", "configs", config_file)) as f:
         config = json.load(f)
     spec = config["models"]["models"][0]
-    slots = int(config["worker_env"]["AI4E_RUNTIME_KV_SLOTS"])
-    dims = {key: spec[key]
-            for key in ("vocab_size", "max_len", "dim", "depth", "heads")}
-    params = jax.eval_shape(lambda: create_seqformer_lm(**dims)[1])
+    dims = {key: spec[key] for key in keys}
+    params = jax.eval_shape(lambda: create(**dims)[1])
     runtime = PagedDecodeRuntime(
-        LMServable(name="lm", model=SeqFormerLM(**dims), params=params,
+        LMServable(name="lm", model=model_cls(**dims), params=params,
                    vocab_size=spec["vocab_size"], max_len=spec["max_len"]),
-        slots=slots, donate=True)
+        slots=int(config["worker_env"]["AI4E_RUNTIME_KV_SLOTS"]),
+        donate=True)
     runtime._build_programs()
-    pool_shape = (spec["depth"], slots, spec["heads"], spec["max_len"],
-                  spec["dim"] // spec["heads"])
-    pool = _on(v5e_sharding, (pool_shape, jnp.float32))
-    ints = _on(v5e_sharding, ((slots,), jnp.int32))
-    compiled = runtime._programs["step"].lower(
-        _on(v5e_sharding, params), ints, pool, pool, ints).compile()
+    return runtime, spec
 
-    pool_type = "f32[" + ",".join(map(str, pool_shape)) + "]"
+
+def _compile_step(runtime, sharding, bound):
+    """The step program of one rung, compiled for the chip."""
+    pool_shape, pool_dtype = runtime.cache_spec()
+    pool = _on(sharding, (pool_shape, pool_dtype))
+    ints = _on(sharding, ((runtime.slots,), jnp.int32))
+    return runtime._programs["step"].lower(
+        _on(sharding, runtime.servable.params), ints, pool, pool, ints,
+        bound).compile()
+
+
+def _entry_results(compiled):
+    """``[(result type, operation)]`` of every instruction of the entry
+    computation: what the program makes outside its fusions."""
+    import re
     entry = re.search(r"ENTRY [^\n]*\{\n(.*?)\n\}", compiled.as_text(),
                       re.S).group(1)
-    makers = []
+    out = []
     for line in entry.splitlines():
         m = re.match(r"\s*(?:ROOT )?%?\S+ = (\S+) ([\w\-]+)\(", line)
-        if m and m.group(1).startswith(pool_type):
-            makers.append(m.group(2))
+        if m:
+            out.append((m.group(1), m.group(2)))
+    return out
+
+
+def _hlo_type(shape, dtype):
+    name = {"float32": "f32", "bfloat16": "bf16"}[jnp.dtype(dtype).name]
+    return name + "[" + ",".join(map(str, shape)) + "]"
+
+
+def _assert_step_reads_in_place(runtime, compiled, bound, temp_limit):
+    """The pool is made only by 2 x slots row writes on the two donated
+    parameters — no whole-pool ``copy`` (XLA's answer to a scatter: it
+    re-lays the pool out and back, 6.5 GB of temporaries), no fusion that
+    rewrites a pool (the one-hot blend: 3.3 GB) — and the cut at ``bound``
+    fuses into the attention's reads: nothing outside a fusion holds one
+    layer's K or V, whole or cut."""
+    pool_shape, pool_dtype = runtime.cache_spec()
+    _, slots, heads, max_len, head_dim = pool_shape
+    pool_type = _hlo_type(pool_shape, pool_dtype)
+    results = _entry_results(compiled)
+    makers = [op for kind, op in results if kind.startswith(pool_type)]
     assert sorted(set(makers)) == ["dynamic-update-slice", "parameter"], (
         sorted(set(makers)))
     assert makers.count("dynamic-update-slice") == 2 * slots
     assert makers.count("parameter") == 2
+    for length in {bound, max_len}:
+        layer_type = _hlo_type((slots, heads, length, head_dim), pool_dtype)
+        assert not [r for r in results if r[0].startswith(layer_type)], (
+            [r for r in results if r[0].startswith(layer_type)])
     memory = compiled.memory_analysis()
-    assert memory.temp_size_in_bytes < 0.5e9, memory.temp_size_in_bytes
+    assert memory.temp_size_in_bytes < temp_limit, memory.temp_size_in_bytes
     # Both pool tensors are aliased input to output: the pool exists once.
-    assert memory.alias_size_in_bytes >= 2 * 4 * np.prod(pool_shape)
+    assert memory.alias_size_in_bytes >= runtime.cache_nbytes()
+    return memory
 
 
-def test_olmoe_step_at_the_benchmark_cell_writes_rows_in_place(v5e_sharding):
+@pytest.fixture(scope="module")
+def gpt2m_cell():
+    from ai4e_tpu.models.seqformer import SeqFormerLM, create_seqformer_lm
+    return _benchmark_cell(
+        "gpt2-medium.json", create_seqformer_lm, SeqFormerLM,
+        ("vocab_size", "max_len", "dim", "depth", "heads"))
+
+
+@pytest.fixture(scope="module")
+def olmoe_cell():
+    from ai4e_tpu.models.olmoe import OlmoeLM, create_olmoe_lm
+    return _benchmark_cell(
+        "olmoe-1b-7b.json", create_olmoe_lm, OlmoeLM,
+        ("vocab_size", "dim", "depth", "heads", "experts",
+         "experts_per_token", "expert_dim", "rms_eps", "rope_theta"))
+
+
+@pytest.mark.parametrize("rung", [0, 1])
+def test_decode_step_at_the_benchmark_cell_writes_rows_in_place(
+        v5e_sharding, gpt2m_cell, rung):
+    """The ``step`` programs ``PagedDecodeRuntime`` builds, donated, at the
+    shape of the benchmark's ``gpt2m.chat`` cell (GPT-2-medium, 32 slots,
+    a float32 pool), one case a rung of its ladder (768 / 1,024 attended
+    positions): ``_assert_step_reads_in_place``. About 6 s each."""
+    runtime, spec = gpt2m_cell
+    assert runtime.step_bounds == (768, 1024)
+    assert runtime.cache_spec() == ((24, 32, 16, 1024, 64), jnp.float32)
+    bound = runtime.step_bounds[rung]
+    _assert_step_reads_in_place(
+        runtime, _compile_step(runtime, v5e_sharding, bound), bound, 0.5e9)
+
+
+@pytest.mark.parametrize("rung", [0, 1])
+def test_olmoe_step_at_the_benchmark_cell_writes_rows_in_place(
+        v5e_sharding, olmoe_cell, rung):
     """The same, for the ``olmoe.decode`` cell, read from
     ``benchmark/configs/olmoe-1b-7b.json``: a bfloat16 pool of
     ``[8,32,16,2048,128]`` is another layout (bf16 tiles, head dimension
-    128), and the step must still make it only by row writes — 2 x slots
-    ``dynamic-update-slice``, no pool-shaped copy or fusion — with the pool
-    aliased input to output. Then the whole worker's memory: weights + pool
-    + the largest program's temporaries (the top prefill bucket) stay under
-    the 15 GB line the configuration states. About 25 s."""
-    import re
-    from ai4e_tpu.models.olmoe import OlmoeLM, create_olmoe_lm
-    from ai4e_tpu.runtime.kvcache import LMServable, PagedDecodeRuntime
-    with open(os.path.join(REPO, "benchmark", "configs",
-                           "olmoe-1b-7b.json")) as f:
-        config = json.load(f)
-    spec = config["models"]["models"][0]
-    slots = int(config["worker_env"]["AI4E_RUNTIME_KV_SLOTS"])
-    dims = {key: spec[key] for key in (
-        "vocab_size", "dim", "depth", "heads", "experts",
-        "experts_per_token", "expert_dim", "rms_eps", "rope_theta")}
-    params = jax.eval_shape(lambda: create_olmoe_lm(**dims)[1])
-    runtime = PagedDecodeRuntime(
-        LMServable(name="lm", model=OlmoeLM(**dims), params=params,
-                   vocab_size=spec["vocab_size"], max_len=spec["max_len"]),
-        slots=slots, donate=True)
-    runtime._build_programs()
-    params = _on(v5e_sharding, params)
-    pool_shape, pool_dtype = runtime.cache_spec()
-    assert pool_shape == (8, 32, 16, 2048, 128)
-    assert pool_dtype == jnp.bfloat16
-    pool = _on(v5e_sharding, (pool_shape, pool_dtype))
-    ints = _on(v5e_sharding, ((slots,), jnp.int32))
-    step = runtime._programs["step"].lower(
-        params, ints, pool, pool, ints).compile()
-
-    pool_type = "bf16[" + ",".join(map(str, pool_shape)) + "]"
-    entry = re.search(r"ENTRY [^\n]*\{\n(.*?)\n\}", step.as_text(),
-                      re.S).group(1)
-    makers = []
-    for line in entry.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%?\S+ = (\S+) ([\w\-]+)\(", line)
-        if m and m.group(1).startswith(pool_type):
-            makers.append(m.group(2))
-    assert sorted(set(makers)) == ["dynamic-update-slice", "parameter"], (
-        sorted(set(makers)))
-    assert makers.count("dynamic-update-slice") == 2 * slots
-    assert makers.count("parameter") == 2
-    memory = step.memory_analysis()
-    assert memory.temp_size_in_bytes < 0.1e9, memory.temp_size_in_bytes
-    pool_bytes = 2 * 2 * int(np.prod(pool_shape))
-    assert memory.alias_size_in_bytes >= pool_bytes
-    assert runtime.cache_nbytes() == pool_bytes
+    128), and every rung (1,536 / 2,048) must still make it only by
+    row writes, with the pool aliased input to output. At the top rung,
+    the whole worker's memory too: weights + pool + the largest program's
+    temporaries (the top prefill bucket) stay under the 15 GB line the
+    configuration states. About 4 s and 10 s."""
+    runtime, spec = olmoe_cell
+    assert runtime.step_bounds == (1536, 2048)
+    assert runtime.cache_spec() == ((8, 32, 16, 2048, 128), jnp.bfloat16)
+    bound = runtime.step_bounds[rung]
+    memory = _assert_step_reads_in_place(
+        runtime, _compile_step(runtime, v5e_sharding, bound), bound, 0.1e9)
+    if bound < runtime.max_len:
+        return
 
     top = runtime.prompt_buckets[-1]
     assert top == spec["max_len"] == 2048
     prefill = runtime._programs["prefill"].lower(
-        params, _on(v5e_sharding, ((1, top), jnp.int32)),
+        _on(v5e_sharding, runtime.servable.params),
+        _on(v5e_sharding, ((1, top), jnp.int32)),
         _on(v5e_sharding, ((1,), jnp.int32))).compile().memory_analysis()
     resident = memory.argument_size_in_bytes      # weights + pool (+ ints)
     assert 11.3e9 < resident < 11.5e9, resident
