@@ -13,7 +13,9 @@ Two checks, run once over the whole project:
 
 1. every metric name registered in code — a string literal as the first
    argument of a ``.counter("ai4e_…")`` / ``.gauge(…)`` /
-   ``.histogram(…)`` call — appears in ``docs/METRICS.md``;
+   ``.histogram(…)`` call, or a key of a model's ``step_report_series``
+   dict literal (``DecodeEngine`` registers ``ai4e_decode_<key>`` for
+   each) — appears in ``docs/METRICS.md``;
 2. every ``ai4e_*`` token in ``docs/METRICS.md`` corresponds to a
    registered name (exact, a documented ``name_*`` family mention, or a
    histogram/counter exposition suffix ``_bucket``/``_sum``/``_count``
@@ -37,17 +39,29 @@ _DOC_FILE = os.path.join("docs", "METRICS.md")
 # Prometheus exposition suffixes a doc may legitimately spell out.
 _EXPO_SUFFIXES = ("_bucket", "_sum", "_count")
 _NEVER_METRICS = {"ai4e_tpu"}  # the package name, not a metric
+# A decode backend's model declares its step's figures by name in a dict
+# literal of this name; ``runtime/decode.py`` registers each under the prefix.
+_DECLARED_SERIES = ("step_report_series", "ai4e_decode_")
 
 
 def _registered_names(module) -> list[tuple[str, int]]:
     """(metric_name, lineno) for every registry-registration call with a
-    literal name. Attribute-based matching (anything ``.counter(…)``)
-    deliberately over-collects: a non-registry object with a ``counter``
+    literal name, and for every declared step-report series.
+    Attribute-based matching (anything ``.counter(…)``) deliberately
+    over-collects: a non-registry object with a ``counter``
     method taking an ``ai4e_``-prefixed string literal is not a thing
     this codebase has, and under-collecting would let real metrics ship
     undocumented."""
     out = []
+    attr, prefix = _DECLARED_SERIES
     for node in ast.walk(module.tree):
+        if (isinstance(node, ast.Assign) and isinstance(node.value, ast.Dict)
+                and any(isinstance(t, ast.Name) and t.id == attr
+                        for t in node.targets)):
+            out.extend((prefix + key.value, key.lineno)
+                       for key in node.value.keys
+                       if isinstance(key, ast.Constant)
+                       and isinstance(key.value, str))
         if not isinstance(node, ast.Call) or not node.args:
             continue
         func = node.func

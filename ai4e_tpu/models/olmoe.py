@@ -28,10 +28,11 @@ the step is bound by that read, not by the 8 × more multiplies. At prefill
 it spends 8 × the multiplies a sorted, grouped form would (``ROADMAP.md``
 Speed 12 is that form); nothing is dropped either way.
 
-Entry points as ``SeqFormerLM``'s (``runtime/kvcache.py`` calls them by
-name): ``prefill``, ``decode_step``, ``cache_spec``; ``step_report`` reads
-what ``decode_step`` appends to its ids. Weights and cache are ``dtype``
-(bfloat16 as served), accumulation float32.
+The entry points of an LM family (``runtime/kvcache.py`` ``LMServable``
+calls them by name): ``prefill``, ``decode_step``, ``cache_spec``;
+``step_report`` reads what ``decode_step`` appends to its ids. The K/V
+pool, the attention over it and its writes are ``ops/kv_pool.py``'s.
+Weights and cache are ``dtype`` (bfloat16 as served), accumulation float32.
 """
 
 from __future__ import annotations
@@ -43,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .seqformer import write_kv_rows
+from ..ops import kv_pool
 
 
 @partial(jax.jit, static_argnums=(1, 2, 3, 4))
@@ -181,56 +182,37 @@ class _OlmoeLayer(nn.Module):
 
     def prefill(self, x, mask):
         """x: (B, P, D); mask: (B, P) valid-token mask. Returns
-        ``(y, k, v)`` with k/v of shape (B, H, P, hd)."""
+        ``(y, k, v)`` with k/v of shape (B, P, H, hd)."""
         b, p, _ = x.shape
         q, k, v = self._qkv(x, jnp.broadcast_to(jnp.arange(p), (b, p)))
-        with jax.named_scope("attention"):
-            scores = (_dot("bqhd,bkhd->bhqk", q, k)
-                      / np.sqrt(self.dim // self.heads))
-            allowed = (jnp.tril(jnp.ones((p, p), bool))[None, None]
-                       & mask[:, None, None, :])
-            w = jax.nn.softmax(jnp.where(allowed, scores, -1e30), axis=-1)
-            o = _dot("bhqk,bkhd->bqhd", w.astype(self.dtype),
-                     v).astype(self.dtype)
-        x = x + _dot("...d,de->...e", o.reshape(b, p, self.dim),
+        o = kv_pool.prefill_attention(q, k, v, mask)
+        x = x + _dot("...d,de->...e", o.reshape(x.shape),
                      self.wo).astype(self.dtype)
         x, _ = self._moe(x)
-        return x, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        return x, k, v
 
-    def step(self, x, k_cache, v_cache, position):
-        """One token per slot against the pool, as ``_LMBlock.step``: x
-        (S, D); k_cache/v_cache (S, H, L, hd), read and never rewritten (L
-        may be a prefix of the cache that holds every live position); the
-        new token's own key and value enter the softmax as one more term.
+    def step(self, x, k_pool, v_pool, layer, position, bound):
+        """One token per slot against the pool: x (S, D), attending this
+        block's ``layer`` of it as ``kv_pool.decode_attention`` says.
         Returns ``(y, k_new, v_new, experts)`` with k_new/v_new (S, H, hd)
-        and ``experts`` (S, K) the slot's chosen experts."""
-        s, _ = x.shape
-        length = k_cache.shape[2]
+        — the rows ``OlmoeLM.decode_step`` stores — and ``experts`` (S, K)
+        the slot's chosen experts."""
         q, k_new, v_new = self._qkv(x, position)
-        with jax.named_scope("attention"):
-            scale = 1.0 / np.sqrt(self.dim // self.heads)
-            scores = _dot("shd,shld->shl", q, k_cache) * scale
-            valid = jnp.arange(length)[None, :] < position[:, None]
-            scores = jnp.where(valid[:, None, :], scores, -1e30)
-            own = _dot("shd,shd->sh", q, k_new) * scale
-            top = jnp.maximum(scores.max(axis=-1), own)
-            w = jnp.exp(scores - top[..., None])
-            w_own = jnp.exp(own - top)
-            o = ((_dot("shl,shld->shd", w.astype(self.dtype), v_cache)
-                  + w_own[..., None] * v_new.astype(jnp.float32))
-                 / (w.sum(axis=-1) + w_own)[..., None]).astype(self.dtype)
-        x = x + _dot("sd,de->se", o.reshape(s, self.dim),
+        o = kv_pool.decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
+                                     position, bound)
+        x = x + _dot("sd,de->se", o.reshape(x.shape),
                      self.wo).astype(self.dtype)
         x, experts = self._moe(x)
         return x, k_new, v_new, experts
 
 
 class OlmoeLM(nn.Module):
-    """Causal LM over the OLMoE block stack, with ``SeqFormerLM``'s two
-    serving entry points (same shapes: the pool is ``(depth, slots, heads,
-    max_len, head_dim)``). ``decode_step`` returns its ids followed by
-    every layer's chosen experts, in one int32 vector, so the routing
-    counters ride the fetch the step makes anyway (``step_report``)."""
+    """Causal LM over the OLMoE block stack, with the two serving entry
+    points of an LM family (``prefill`` returns ``kv_pool.prompt_block``s,
+    ``decode_step`` takes and returns the pool). ``decode_step`` returns
+    its ids followed by every layer's chosen experts, in one int32 vector,
+    so the routing counters ride the fetch the step makes anyway
+    (``step_report``)."""
 
     vocab_size: int
     dim: int = 64
@@ -274,21 +256,19 @@ class OlmoeLM(nn.Module):
             h, k, v = layer.prefill(h, mask)
             ks.append(k)
             vs.append(v)
-        return h, jnp.stack(ks), jnp.stack(vs)
+        return h, kv_pool.prompt_block(ks), kv_pool.prompt_block(vs)
 
     def _step(self, tokens, k_cache, v_cache, position, bound):
         with jax.named_scope("embedding"):
             h = self.embed[tokens]
         k_rows, v_rows, experts = [], [], []
         for i, layer in enumerate(self.layers):
-            # One static slice, layer and bound at once, as ``SeqFormerLM``.
-            h, k, v, e = layer.step(h, k_cache[i, :, :, :bound],
-                                    v_cache[i, :, :, :bound], position)
+            h, k, v, e = layer.step(h, k_cache, v_cache, i, position, bound)
             k_rows.append(k)
             v_rows.append(v)
             experts.append(e)
-        k_cache, v_cache = write_kv_rows(k_cache, v_cache, k_rows, v_rows,
-                                         position)
+        k_cache, v_cache = kv_pool.write_rows(k_cache, v_cache, k_rows,
+                                              v_rows, position)
         return h, k_cache, v_cache, jnp.stack(experts)
 
     def prefill(self, tokens, length):
@@ -298,10 +278,10 @@ class OlmoeLM(nn.Module):
         return jnp.argmax(self._logits(last), axis=-1).astype(jnp.int32), k, v
 
     def decode_step(self, tokens, k_cache, v_cache, position, bound=None):
-        """As ``SeqFormerLM.decode_step``, ``bound`` included: attention
-        reads the cached positions ``< bound`` (a Python int, static under
-        jit; default the whole cache), which must be ``>=`` the largest
-        position of a slot whose output is read."""
+        """One token for every slot of the pool. Attention reads the cached
+        positions ``< bound`` (a Python int, static under jit; default the
+        whole cache), which must be ``>=`` the largest position of a slot
+        whose output is read (``kv_pool.decode_attention``)."""
         h, k_cache, v_cache, experts = self._step(tokens, k_cache, v_cache,
                                                   position, bound)
         ids = jnp.argmax(self._logits(h), axis=-1).astype(jnp.int32)
@@ -318,6 +298,21 @@ class OlmoeLM(nn.Module):
         h, k_cache, v_cache, _ = self._step(tokens, k_cache, v_cache,
                                             position, bound)
         return self._logits(h), k_cache, v_cache
+
+    # What ``step_report`` returns, as the decode engine exposes it: each
+    # name a histogram ``ai4e_decode_<name>``, with its help and buckets.
+    step_report_series = {
+        "experts_touched": (
+            "Experts with at least one LIVE token, a MoE layer a decode "
+            "step (mean over the step's layers)",
+            (*(2 ** i for i in range(11)), float("inf"))),
+        "expert_peak_load": (
+            "The fullest expert's live tokens over the mean load (live "
+            "slots x experts a token / experts), a MoE layer a decode "
+            "step: the straggler measure",
+            (1.0, 1.25, 1.5, 1.75, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0,
+             float("inf"))),
+    }
 
     @nn.nowrap
     def step_report(self, extra: np.ndarray, active) -> dict[str, float]:

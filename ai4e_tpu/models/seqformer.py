@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import kv_pool
+
 
 class SeqAttention(nn.Module):
     dim: int
@@ -108,10 +110,10 @@ class _LMBlock(nn.Module):
     """One causal decoder block with the two attention entry points the
     serving runtime needs: ``prefill`` (full causal attention over the
     prompt, returning the K/V it computed) and ``step`` (one token per
-    sequence against a K/V cache, returning the cache with the new
-    token's K/V written at ``position``). Both run through the SAME
-    parameters — ``setup`` instead of ``nn.compact`` so the two methods
-    share the module tree."""
+    sequence against the K/V pool, returning the new token's K/V). Both
+    run through the SAME parameters — ``setup`` instead of ``nn.compact``
+    so the two methods share the module tree. The attention itself and
+    everything about the pool are ``ops/kv_pool.py``'s."""
 
     dim: int
     heads: int
@@ -127,85 +129,35 @@ class _LMBlock(nn.Module):
         self.mlp_up = nn.Dense(self.dim * 4, dtype=self.dtype, name="mlp_up")
         self.mlp_down = nn.Dense(self.dim, dtype=self.dtype, name="mlp_down")
 
+    def _qkv(self, x):
+        """``x (..., D)`` → q, k, v ``(..., H, hd)``."""
+        qkv = self.qkv(self.ln1(x)).reshape(
+            *x.shape[:-1], 3, self.heads, self.dim // self.heads)
+        return qkv[..., 0, :, :], qkv[..., 1, :, :], qkv[..., 2, :, :]
+
     def prefill(self, x, mask):
         """x: (B, S, D); mask: (B, S) True on real tokens. Returns
-        ``(y, k, v)`` with k/v of shape (B, H, S, hd) — the block's
+        ``(y, k, v)`` with k/v of shape (B, S, H, hd) — the block's
         contribution to the sequence's KV cache."""
-        b, s, _ = x.shape
-        hd = self.dim // self.heads
-        h = self.ln1(x)
-        qkv = self.qkv(h).reshape(b, s, 3, self.heads, hd)
-        q, k, v = (qkv[:, :, i].transpose(0, 2, 1, 3) for i in range(3))
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / jnp.sqrt(hd)
-        causal = jnp.tril(jnp.ones((s, s), bool))
-        keep = causal[None, None] & mask[:, None, None, :]
-        scores = jnp.where(keep, scores, jnp.asarray(-1e30, scores.dtype))
-        o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(scores, axis=-1), v)
-        x = x + self.proj(o.transpose(0, 2, 1, 3).reshape(b, s, self.dim))
+        q, k, v = self._qkv(x)
+        o = kv_pool.prefill_attention(q, k, v, mask)
+        x = x + self.proj(o.reshape(x.shape))
         x = x + self.mlp_down(nn.gelu(self.mlp_up(self.ln2(x))))
         return x, k, v
 
-    def step(self, x, k_cache, v_cache, position):
+    def step(self, x, k_pool, v_pool, layer, position, bound):
         """One decode step over the slot pool. x: (S, D) — one new token
-        per slot; k_cache/v_cache: (S, H, L, hd), read and never
-        rewritten: positions ``< position`` hold the sequence so far;
-        position: (S,) — the cache index the new token belongs at. The
-        new token's own key and value enter the softmax as one more term
-        beside the cached ones, so attention needs no updated cache.
-        L may be a prefix of the cache that holds every live slot's
-        position (``SeqFormerLM.decode_step``'s ``bound``). Returns
-        ``(y, k_new, v_new)`` with k_new/v_new of shape (S, H, hd) — the
-        rows ``SeqFormerLM.decode_step`` stores."""
-        s, _ = x.shape
-        hd = self.dim // self.heads
-        length = k_cache.shape[2]
-        h = self.ln1(x)
-        qkv = self.qkv(h).reshape(s, 3, self.heads, hd)
-        q, k_new, v_new = qkv[:, 0], qkv[:, 1], qkv[:, 2]  # (S, H, hd)
-        # Scopes name the device side for the trace's readers; they are
-        # metadata and change no program.
-        with jax.named_scope("attention"):
-            scores = jnp.einsum("shd,shld->shl", q, k_cache) / jnp.sqrt(hd)
-            valid = (jnp.arange(length)[None, :]
-                     < position[:, None])  # keys before the new token
-            scores = jnp.where(valid[:, None, :], scores,
-                               jnp.asarray(-1e30, scores.dtype))
-            own = jnp.einsum("shd,shd->sh", q, k_new) / jnp.sqrt(hd)
-            # softmax over [cached keys, the new token's key], by hand:
-            # the new key is not in the cache yet.
-            top = jnp.maximum(scores.max(axis=-1), own)
-            w = jnp.exp(scores - top[..., None])
-            w_own = jnp.exp(own - top)
-            o = ((jnp.einsum("shl,shld->shd", w, v_cache)
-                  + w_own[..., None] * v_new)
-                 / (w.sum(axis=-1) + w_own)[..., None])
-        x = x + self.proj(o.reshape(s, self.dim))
+        per slot, attending this block's ``layer`` of the pool as
+        ``kv_pool.decode_attention`` says. Returns ``(y, k_new, v_new)``
+        with k_new/v_new of shape (S, H, hd) — the rows
+        ``SeqFormerLM.decode_step`` stores."""
+        q, k_new, v_new = self._qkv(x)
+        o = kv_pool.decode_attention(q, k_new, v_new, k_pool, v_pool, layer,
+                                     position, bound)
+        x = x + self.proj(o.reshape(x.shape))
         with jax.named_scope("mlp"):
             x = x + self.mlp_down(nn.gelu(self.mlp_up(self.ln2(x))))
         return x, k_new, v_new
-
-
-def write_kv_rows(k_cache, v_cache, k_rows, v_rows, position):
-    """Store one decode step's K/V: ``k_rows``/``v_rows`` are per-layer lists
-    of (S, H, hd), the pool is (depth, S, H, L, hd), ``position`` (S,).
-
-    One row per slot, all layers at once, written where the pool already
-    lives. A Python loop of dynamic_update_slice on purpose: a scatter
-    (``.at[].set``), a vmap or a fori_loop of the same writes makes XLA:TPU
-    re-lay or copy the whole pool every step (CHANGES.md PR 25 has the
-    compiled programs side by side). A position past the last row is
-    clamped onto it, not dropped: the engine retires a sequence before it
-    gets there."""
-    with jax.named_scope("cache_update"):
-        k_rows = jnp.stack(k_rows)[:, :, :, None, :]  # (depth, S, H, 1, hd)
-        v_rows = jnp.stack(v_rows)[:, :, :, None, :]
-        for slot in range(position.shape[0]):
-            at = (0, slot, 0, position[slot], 0)
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k_rows[:, slot:slot + 1], at)
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v_rows[:, slot:slot + 1], at)
-    return k_cache, v_cache
 
 
 class SeqFormerLM(nn.Module):
@@ -214,18 +166,16 @@ class SeqFormerLM(nn.Module):
     points, applied via ``method=``:
 
     - ``prefill(tokens (B, P), length (B,))`` → ``(next-token ids (B,),
-      k, v)`` with k/v of shape (depth, B, H, P, hd) — the prompt's KV
-      block, inserted into a slot of the pooled cache by the decode
-      runtime (``runtime/kvcache.py``);
-    - ``decode_step(tokens (S,), k (depth, S, H, L, hd), v, position
-      (S,), bound=None)`` → ``(next-token ids (S,), k, v)`` — ONE token
-      for every slot in the pool per call, inactive slots riding along
+      k, v)`` — k/v the prompt's blocks (``kv_pool.prompt_block``), which
+      the decode runtime (``runtime/kvcache.py``) inserts into a slot of
+      the pool;
+    - ``decode_step(tokens (S,), k, v, position (S,), bound=None)`` →
+      ``(next-token ids (S,), k, v)`` — k/v the pool (``ops/kv_pool.py``):
+      ONE token for every slot of it per call, inactive slots riding along
       masked (their cache rows are garbage a later prefill overwrites).
-      ``bound`` (a Python int, static under jit; default L) cuts every
-      layer's attention to the cached positions ``< bound``: the same
-      result, to the order of a float32 sum, for any bound ``>=`` the
-      largest position of a slot whose output is read. The row writes
-      take the whole pool either way.
+      ``bound`` cuts every layer's attention to the cached positions
+      ``< bound`` (``kv_pool.decode_attention``). The row writes take the
+      whole pool either way.
 
     Greedy decoding is computed on-device (argmax over the tied-embedding
     logits) so each step ships S int32s back to the host, not S×V logits.
@@ -275,7 +225,7 @@ class SeqFormerLM(nn.Module):
                 axis=1)[:, 0]
             next_token = jnp.argmax(self._logits(last),
                                     axis=-1).astype(jnp.int32)
-        return next_token, jnp.stack(ks), jnp.stack(vs)
+        return next_token, kv_pool.prompt_block(ks), kv_pool.prompt_block(vs)
 
     def _step(self, tokens, k_cache, v_cache, position, bound):
         with jax.named_scope("embedding"):
@@ -283,16 +233,11 @@ class SeqFormerLM(nn.Module):
                  + self.pos_emb[position].astype(self.dtype))  # (S, D)
         k_rows, v_rows = [], []
         for i, blk in enumerate(self.blocks):
-            # One static slice a layer and tensor, layer and bound at once:
-            # XLA:TPU fuses it into the attention's reads. A cut of the
-            # layer's view (``k_cache[i][:, :, :bound]``) costs a copy of
-            # every layer's K and V (tests/test_tpu_aot_compile.py).
-            h, k, v = blk.step(h, k_cache[i, :, :, :bound],
-                               v_cache[i, :, :, :bound], position)
+            h, k, v = blk.step(h, k_cache, v_cache, i, position, bound)
             k_rows.append(k)
             v_rows.append(v)
-        k_cache, v_cache = write_kv_rows(k_cache, v_cache, k_rows, v_rows,
-                                         position)
+        k_cache, v_cache = kv_pool.write_rows(k_cache, v_cache, k_rows,
+                                              v_rows, position)
         return h, k_cache, v_cache
 
     def decode_step(self, tokens, k_cache, v_cache, position, bound=None):

@@ -190,9 +190,10 @@ class DecodeEngine:
     - ``prefill_into(slot, tokens) -> first generated token id``;
     - ``step(tokens, positions, active) -> next token id per slot``
       (plain int lists — the backend owns array conversion);
-    - optionally ``step_report`` (attribute): figures of the step just
-      run, by name, from a model that reports on it — observed as
-      ``ai4e_decode_experts_touched`` / ``ai4e_decode_expert_peak_load``;
+    - optionally ``step_report_series`` (attribute, ``{name: (help,
+      buckets)}``) and ``step_report`` (attribute, ``{name: value}``): what
+      a model that reports on its step declares, and its figures of the
+      step just run — registered and observed as ``ai4e_decode_<name>``;
     - optionally ``step_bound`` (attribute): the positions of every slot
       the step just run attended — counted as ``slots x step_bound``
       attended K/V positions and observed as ``ai4e_decode_step_bound``;
@@ -211,19 +212,12 @@ class DecodeEngine:
     executor thread — the device is the serial resource, same discipline
     as the batcher) or async (the race tests' fakes, explored under the
     virtual loop).
-
-    ``continuous=False`` is the whole-batch baseline the bench A/Bs
-    against: admission only when the pool is EMPTY, so a running batch
-    drains completely before anyone joins — the old contract, kept
-    measurable.
     """
 
     def __init__(self, backend, max_pending: int = 64,
-                 continuous: bool = True,
                  metrics: MetricsRegistry | None = None):
         self.backend = backend
         self.max_pending = max_pending
-        self.continuous = continuous
         self.pool = SlotPool(backend.slots)
         self._queue: deque[_Sequence] = deque()
         self._active: dict[int, _Sequence] = {}
@@ -269,22 +263,13 @@ class DecodeEngine:
             "the backend's step programs it ran; max_len without rungs)",
             buckets=(*getattr(backend, "step_bounds", (backend.max_len,)),
                      float("inf")))
-        # From a backend whose model reports on its step (``step_report``:
-        # the sparse-expert LM); a dense model's worker never observes them.
+        # What a backend whose model reports on its step declares; a model
+        # that reports nothing registers nothing.
         self._step_report = {
-            "experts_touched": self.metrics.histogram(
-                "ai4e_decode_experts_touched",
-                "Experts with at least one LIVE token, a MoE layer a decode "
-                "step (mean over the step's layers)",
-                buckets=(*(2 ** i for i in range(11)), float("inf"))),
-            "expert_peak_load": self.metrics.histogram(
-                "ai4e_decode_expert_peak_load",
-                "The fullest expert's live tokens over the mean load (live "
-                "slots x experts a token / experts), a MoE layer a decode "
-                "step: the straggler measure",
-                buckets=(1.0, 1.25, 1.5, 1.75, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0,
-                         float("inf"))),
-        }
+            name: self.metrics.histogram(f"ai4e_decode_{name}", help_text,
+                                         buckets=buckets)
+            for name, (help_text, buckets) in getattr(
+                backend, "step_report_series", {}).items()}
         self._kv_positions = self.metrics.counter(
             "ai4e_decode_kv_positions_total",
             "K/V positions per decode step: live (sum of position + 1 over "
@@ -523,11 +508,7 @@ class DecodeEngine:
 
     async def _admit(self) -> None:
         """Prefill queued requests into free KV-cache slots — BETWEEN
-        decode steps, the continuous-batching join. Whole-batch mode
-        (``continuous=False``) gates admission on an EMPTY pool (checked
-        once at entry), then fills every slot it can: the old whole-
-        batch-in/whole-batch-out contract, kept measurable as the bench
-        baseline."""
+        decode steps, the continuous-batching join."""
         if self._draining:
             # Anything that raced past the submit-side refusal is retired
             # here rather than prefilled onto a leaving worker.
@@ -536,8 +517,6 @@ class DecodeEngine:
                     self._retire(seq, "cancelled",
                                  error=DrainingError(
                                      "decode engine draining; redeliver"))
-            return
-        if not self.continuous and self._active:
             return
         while self._queue:
             slot = self.pool.acquire()

@@ -1,16 +1,14 @@
 """Pooled KV-cache decode runtime — the device side of continuous
 batching (``runtime/decode.py`` owns the scheduling).
 
-The cache is ONE preallocated slot-pool buffer per tensor::
-
-    k, v : (layers, slots, heads, max_len, head_dim)
-
-keyed by ``(model, params_version)`` — a hot weight reload bumps the
-version and the engine invalidates (``reset_cache``) then re-prefills,
-the same key contract as rescache (a KV block computed under old weights
-is a stale cached result). Slots are rows of that buffer; admission and
-release are pure bookkeeping in ``decode.SlotPool`` — the device never
-reallocates per request.
+The cache is ONE preallocated slot-pool buffer per tensor
+(``ops/kv_pool.py`` owns its layout and every operation on it), keyed by
+``(model, params_version)`` — a hot weight reload bumps the version and
+the engine invalidates (``reset_cache``) then re-prefills, the same key
+contract as rescache (a KV block computed under old weights is a stale
+cached result). Slots are rows of that buffer; admission and release are
+pure bookkeeping in ``decode.SlotPool`` — the device never reallocates
+per request.
 
 Three kinds of compiled program serve the whole path, none of which may
 compile on the serving path (``warm()`` executes every one — the AOT-warm
@@ -20,16 +18,14 @@ discipline ``ModelRuntime.warmup`` applies to batch buckets):
   prompt bucket (``ladder.DECODE_PROMPT_BUCKETS``: prompts pad to the
   smallest fitting bucket, so XLA compiles ``len(buckets)`` prefill
   programs, not one per prompt length);
-- **insert** — ``dynamic_update_slice`` of a prefill's KV block into a
-  slot row (slot index is a traced scalar: one program per bucket, any
-  slot);
+- **insert** — a prefill's KV block written into a slot's rows (slot
+  index is a traced scalar: one program per bucket, any slot);
 - **step** — one decode step over the WHOLE pool: every slot advances
   one token (inactive slots ride along masked; their rows are garbage a
   later prefill overwrites). The layers read the pool as it came in and
   the new token's K/V are stored afterwards as ONE row per slot (all
-  layers at once), each a ``dynamic_update_slice`` at
-  ``(0, slot, 0, position[slot], 0)``: the step produces nothing else of
-  the pool's shape. Attention reads only the first ``bound`` positions of
+  layers at once), in place: the step produces nothing else of the
+  pool's shape. Attention reads only the first ``bound`` positions of
   every slot: one program per rung of ``step_bounds`` (three quarters of
   ``max_len`` and ``max_len``), and each step runs the smallest rung that holds its
   longest LIVE sequence — the same pool, the same row writes, fewer dead
@@ -52,6 +48,7 @@ from typing import Any
 import numpy as np
 
 from ..observability.tracing import device_trace
+from ..ops import kv_pool
 
 log = logging.getLogger("ai4e_tpu.kvcache")
 
@@ -65,10 +62,11 @@ class LMServable:
 
     name: str
     # A flax module with the LM entry points, called by name:
-    # ``prefill(tokens (B, P), length (B,))`` → ids, K block, V block;
-    # ``decode_step(tokens (S,), k, v, position (S,), bound)`` → ids (S,)
-    # then, optionally, more int32s the model's own ``step_report(extra,
-    # active)`` turns into per-step figures; k, v — attending cached
+    # ``prefill(tokens (B, P), length (B,))`` → ids, K block, V block
+    # (``kv_pool.prompt_block``); ``decode_step(tokens (S,), k, v, position
+    # (S,), bound)`` → ids (S,) then, optionally, more int32s the model's
+    # own ``step_report(extra, active)`` turns into per-step figures
+    # (declared by its ``step_report_series``); k, v — attending cached
     # positions ``< bound`` only (a Python int: one program a value); and
     # ``cache_spec()`` → ``((layers, heads, head_dim), dtype)`` of the
     # pool. ``runtime/families.py`` (``LM_FAMILIES``) builds them.
@@ -138,7 +136,8 @@ class PagedDecodeRuntime:
         # (``compile``). None (and during ``warm()``): nothing is reported.
         self.phase_hook = None
         # Figures of the last step from a model that reports on it (the
-        # engine observes each as ``ai4e_decode_<name>``); else empty.
+        # engine observes each as ``ai4e_decode_<name>``, registered from
+        # ``step_report_series``); else empty.
         self.step_report: dict[str, float] = {}
         # Positions a slot the last step attended (the engine counts
         # ``slots x step_bound`` as attended).
@@ -154,8 +153,8 @@ class PagedDecodeRuntime:
         """``(shape, dtype)`` of each pool tensor: the model's layers, heads
         and head size and its cache dtype, this runtime's slots and
         length."""
-        (layers, heads, head_dim), dtype = self.servable.model.cache_spec()
-        return (layers, self.slots, heads, self.max_len, head_dim), dtype
+        spec, dtype = self.servable.model.cache_spec()
+        return kv_pool.pool_shape(spec, self.slots, self.max_len), dtype
 
     def cache_nbytes(self) -> int:
         """Resident bytes of the pooled cache (both tensors) — the
@@ -166,14 +165,13 @@ class PagedDecodeRuntime:
     def reset_cache(self) -> None:
         """Drop + reallocate the pooled cache (hot-reload invalidation:
         blocks computed under the old weights must never serve)."""
-        import jax.numpy as jnp
         shape, dtype = self.cache_spec()
         # The old pool goes first: while it lives, building the new one
         # holds three pool tensors on the device at once, which would be
         # the allocator's peak of the whole worker.
         self._k = self._v = None
-        self._k = jnp.zeros(shape, dtype)
-        self._v = jnp.zeros(shape, dtype)
+        self._k = kv_pool.allocate(shape, dtype)
+        self._v = kv_pool.allocate(shape, dtype)
 
     def _ensure(self) -> None:
         if self._k is None:
@@ -198,13 +196,9 @@ class PagedDecodeRuntime:
             return model.apply(params, tokens, k, v, position, bound,
                                method="decode_step")
 
+        # A wrapper for its name: the trace's module stays ``jit_insert``.
         def insert(k, v, k_block, v_block, slot):
-            zero = (0, slot, 0, 0, 0)
-            # Blocks arrive as (depth, 1, H, P, hd) — rank-matched to the
-            # pool, so one dynamic_update_slice lands the whole prompt.
-            with jax.named_scope("cache_insert"):
-                return (jax.lax.dynamic_update_slice(k, k_block, zero),
-                        jax.lax.dynamic_update_slice(v, v_block, zero))
+            return kv_pool.insert_block(k, v, k_block, v_block, slot)
 
         self._programs = {
             "prefill": jax.jit(prefill),
@@ -230,6 +224,13 @@ class PagedDecodeRuntime:
         return out
 
     # -- engine backend surface -------------------------------------------
+
+    @property
+    def step_report_series(self) -> dict:
+        """What the model's ``step_report`` returns, as it declares it:
+        ``{name: (help, buckets)}``; empty from a model that reports
+        nothing."""
+        return getattr(self.servable.model, "step_report_series", {})
 
     def bucket_for(self, n: int) -> int:
         for b in self.prompt_buckets:

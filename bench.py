@@ -1687,161 +1687,6 @@ async def run_bench(args) -> dict:
     }
 
 
-async def run_stream_bench(args) -> dict:
-    """``--stream``: continuous batching vs whole-batch decode
-    (docs/streaming.md) on a mixed short/long completion workload.
-
-    Both modes run the SAME seqformer-LM through the SAME
-    ``PagedDecodeRuntime`` KV-cache slot pool; the only difference is
-    the engine's admission gate — ``continuous=True`` joins new
-    requests between decode steps, ``continuous=False`` (the old
-    whole-batch-in/whole-batch-out contract) admits only into an empty
-    pool, so a long completion holds every short one hostage. The
-    claim this preset records is **time-to-first-token and tail
-    inter-token latency at equal offered load** — slot-level
-    scheduling, honest on CPU — not raw token throughput (the tiny LM's
-    step time is not a TPU number).
-
-    Every token ALSO rides the real chunk path: ``TaskEventHub``
-    publish under a tracked per-request id, so the bounded chunk
-    replay (truncated marker) is exercised at bench rates.
-    """
-    import random
-
-    from ai4e_tpu.metrics.registry import MetricsRegistry
-    from ai4e_tpu.pipeline.events import CHUNK, TaskEventHub
-    from ai4e_tpu.runtime.decode import DecodeEngine
-    from ai4e_tpu.runtime.kvcache import PagedDecodeRuntime, build_lm_servable
-
-    short_new, long_new = 8, args.stream_long_tokens
-    long_ratio = 0.3
-    duration = args.duration
-    servable = build_lm_servable(
-        name="streamlm", vocab_size=256,
-        max_len=long_new + 32, dim=64, depth=2, heads=4)
-
-    def pctl(values, q):
-        if not values:
-            return None
-        values = sorted(values)
-        idx = min(len(values) - 1, int(q * (len(values) - 1) + 0.5))
-        return round(values[idx] * 1e3, 2)  # ms
-
-    async def one_mode(continuous: bool) -> dict:
-        backend = PagedDecodeRuntime(servable, slots=args.stream_slots,
-                                     prompt_buckets=(4, 16))
-        t0 = time.perf_counter()
-        backend.warm()
-        warmup_s = round(time.perf_counter() - t0, 1)
-        reg = MetricsRegistry()
-        hub = TaskEventHub(metrics=reg)
-        engine = DecodeEngine(backend, max_pending=512,
-                              continuous=continuous, metrics=reg)
-        await engine.start()
-        rng = random.Random(20260804)
-        stop_at = time.perf_counter() + duration
-        records: list[dict] = []
-        occupancy: list[float] = []
-
-        async def client(cid: int) -> None:
-            n = 0
-            while time.perf_counter() < stop_at:
-                is_long = rng.random() < long_ratio
-                max_new = long_new if is_long else short_new
-                prompt = [rng.randrange(1, 256)
-                          for _ in range(rng.randrange(2, 12))]
-                task_id = f"s{cid}-{n}"
-                n += 1
-                hub.track(task_id)
-                stamps: list[float] = []
-
-                def on_token(i, tok, _tid=task_id, _s=stamps):
-                    _s.append(time.perf_counter())
-                    hub.publish(_tid, CHUNK,
-                                {"stage": "streamlm", "index": i,
-                                 "data": {"token": tok}})
-
-                t_submit = time.perf_counter()
-                toks = await engine.submit(prompt, max_new,
-                                           on_token=on_token)
-                records.append({"long": is_long, "submit": t_submit,
-                                "stamps": stamps, "tokens": len(toks)})
-
-        async def sampler() -> None:
-            while time.perf_counter() < stop_at:
-                occupancy.append(engine.pool.busy_count
-                                 / engine.pool.slots)
-                await asyncio.sleep(0.05)
-
-        t_open = time.perf_counter()
-        await asyncio.gather(*(client(i)
-                               for i in range(args.stream_clients)),
-                             sampler())
-        wall = time.perf_counter() - t_open
-        await engine.stop()
-        engine.pool.check_conservation()
-
-        ttfts = [r["stamps"][0] - r["submit"]
-                 for r in records if r["stamps"]]
-        itls = [b - a for r in records
-                for a, b in zip(r["stamps"], r["stamps"][1:])]
-        short_ttfts = [r["stamps"][0] - r["submit"] for r in records
-                       if r["stamps"] and not r["long"]]
-        # Orca-style normalized per-token latency: end-to-end seconds /
-        # generated tokens, per request. THE continuous-vs-whole-batch
-        # inter-token claim: raw generation gaps are one decode step in
-        # both modes, but a short completion gated behind a whole-batch
-        # drain pays the long batch-mate's queue wait on every one of
-        # its few tokens.
-        normalized = [(r["stamps"][-1] - r["submit"]) / r["tokens"]
-                      for r in records if r["stamps"] and r["tokens"]]
-        short_norm = [(r["stamps"][-1] - r["submit"]) / r["tokens"]
-                      for r in records
-                      if r["stamps"] and r["tokens"] and not r["long"]]
-        tokens = sum(r["tokens"] for r in records)
-        return {
-            "mode": "continuous" if continuous else "whole_batch",
-            "warmup_s": warmup_s,
-            "sequences": len(records),
-            "tokens": tokens,
-            "sequences_per_s": round(len(records) / wall, 2),
-            "tokens_per_s": round(tokens / wall, 1),
-            "ttft_ms": {"p50": pctl(ttfts, 0.50), "p99": pctl(ttfts, 0.99)},
-            "ttft_short_ms": {"p50": pctl(short_ttfts, 0.50),
-                              "p99": pctl(short_ttfts, 0.99)},
-            "intertoken_gap_ms": {"p50": pctl(itls, 0.50),
-                                  "p99": pctl(itls, 0.99)},
-            "intertoken_normalized_ms": {"p50": pctl(normalized, 0.50),
-                                         "p99": pctl(normalized, 0.99)},
-            "intertoken_normalized_short_ms": {
-                "p50": pctl(short_norm, 0.50),
-                "p99": pctl(short_norm, 0.99)},
-            "slot_occupancy_mean": round(
-                sum(occupancy) / len(occupancy), 3) if occupancy else None,
-        }
-
-    log("stream bench: continuous mode")
-    continuous = await one_mode(True)
-    log("stream bench: whole-batch baseline")
-    whole_batch = await one_mode(False)
-    return {
-        "model": "streamlm",
-        "preset": "stream",
-        "workload": {
-            "clients": args.stream_clients,
-            "slots": args.stream_slots,
-            "short_tokens": short_new,
-            "long_tokens": long_new,
-            "long_ratio": long_ratio,
-            "duration_s": duration,
-            "kv_max_len": servable.max_len,
-            "closed_loop": True,
-        },
-        "continuous": continuous,
-        "whole_batch": whole_batch,
-    }
-
-
 async def run_pipeline_dag_bench(args) -> dict:
     """``--pipeline``: the declared-DAG preset (docs/pipelines.md) — a
     2-stage echo chain (`s1 -> s2`, both through the real runtime +
@@ -2277,23 +2122,6 @@ def main() -> None:
                              "beside end-to-end latency. Async-only; "
                              "honest on CPU (no model weight — it "
                              "measures the DAG-coordination path).")
-    parser.add_argument("--stream", action="store_true",
-                        help="continuous-batching streaming preset "
-                             "(docs/streaming.md): a seqformer-LM decode "
-                             "engine on a mixed short/long completion "
-                             "workload, run TWICE — iteration-level "
-                             "continuous batching vs the whole-batch "
-                             "baseline — reporting TTFT p50/p99 and "
-                             "inter-token p99 beside slot-level goodput. "
-                             "Honest on CPU: the claim is the scheduling "
-                             "gap, not token throughput")
-    parser.add_argument("--stream-slots", type=int, default=4,
-                        help="--stream: KV-cache slot-pool size")
-    parser.add_argument("--stream-clients", type=int, default=12,
-                        help="--stream: closed-loop streaming clients")
-    parser.add_argument("--stream-long-tokens", type=int, default=96,
-                        help="--stream: completion length of the LONG "
-                             "class (short class is 8)")
     parser.add_argument("--cpu", action="store_true",
                         help="explicit CPU test mode, sized to finish "
                              "promptly: correctness and counts only. "
@@ -2329,11 +2157,7 @@ def main() -> None:
         _require_tpu()
     log(f"devices: {jax.devices()}")
 
-    if args.stream:
-        # Streaming preset: the claim is the scheduling gap between
-        # continuous and whole-batch decode at equal offered load.
-        result = asyncio.run(run_stream_bench(args))
-    elif args.pipeline:
+    if args.pipeline:
         # Declared-DAG preset: the echo chain carries no model weight — it
         # measures the DAG-coordination path.
         if args.mode == "sync":

@@ -1351,6 +1351,30 @@ class TestMetricsDrift:
                     return metrics.counter("not_ai4e_prefixed", "x")
             """)) == []
 
+    def test_a_declared_step_report_series_registers_its_names(
+            self, tmp_path):
+        """A decode model names its step's figures in a
+        ``step_report_series`` dict literal and the engine registers
+        ``ai4e_decode_<name>`` for each: both checks follow the
+        declaration."""
+        findings = self._project(
+            tmp_path,
+            "| `ai4e_decode_window_fill` | histogram |\n"
+            "| `ai4e_decode_gone` | histogram |\n",
+            code=textwrap.dedent("""
+                class LM:
+                    step_report_series = {
+                        "window_fill": ("help", (0.5, 1.0)),
+                        "state_fill": ("help", (0.5, 1.0)),
+                    }
+            """))
+        assert sorted(f.message.split(" ", 2)[1] for f in findings
+                      if "registered in code" in f.message) == [
+            "ai4e_decode_state_fill"]
+        assert [f.line for f in findings
+                if "documents ai4e_decode_gone" in f.message] == [2]
+        assert len(findings) == 2
+
     def test_whole_repo_in_sync(self):
         """The real tree: every registered ai4e_* metric documented in
         docs/METRICS.md and vice versa — the gate CI now enforces (the

@@ -7,8 +7,7 @@ Three layers:
   joins, backpressure, per-step deadline sweeps, cancellation,
   hot-reload re-prefill, slot conservation — plus THE acceptance
   property: a request arriving mid-decode of a long sequence receives
-  its first token before that sequence finishes (and provably does NOT
-  under the whole-batch baseline);
+  its first token before that sequence finishes;
 - **device path** (JAX): the KV-cache step function's correctness
   oracle — token-by-token decode must equal greedy re-prefill over the
   growing history — and the AOT-warm discipline (no serving-path
@@ -205,13 +204,11 @@ class TestEngineScheduling:
         """THE acceptance property: a request arriving mid-decode of a
         long sequence gets its first chunk while that sequence is still
         decoding — its TTFT is smaller than the remaining decode time of
-        the running sequence. The whole-batch baseline provably inverts
-        this (the joiner waits for the full drain)."""
+        the running sequence."""
 
-        async def drive(continuous):
+        async def drive():
             backend = FakeBackend(slots=2, step_s=0.002)
-            engine = DecodeEngine(backend, continuous=continuous,
-                                  metrics=MetricsRegistry())
+            engine = DecodeEngine(backend, metrics=MetricsRegistry())
             await engine.start()
             stamps = {}
 
@@ -231,37 +228,12 @@ class TestEngineScheduling:
             remaining = t_long_done - t_join
             return ttft, remaining, len(joiner)
 
-        ttft, remaining, n = run(drive(continuous=True))
+        ttft, remaining, n = run(drive())
         assert n == 3
         assert ttft < remaining, (
             f"continuous batching must stream the late joiner before the "
             f"running sequence finishes: TTFT {ttft * 1e3:.1f}ms vs "
             f"{remaining * 1e3:.1f}ms remaining")
-
-        async def whole_batch():
-            backend = FakeBackend(slots=2, step_s=0.002)
-            engine = DecodeEngine(backend, continuous=False,
-                                  metrics=MetricsRegistry())
-            await engine.start()
-            stamps = {}
-            long_done = {}
-
-            long_task = asyncio.ensure_future(engine.submit([1], 30))
-            long_task.add_done_callback(
-                lambda _: long_done.setdefault("t", time.perf_counter()))
-            await wait_until(lambda: backend.steps >= 5)
-            await engine.submit(
-                [40], 3,
-                on_token=lambda i, t: stamps.setdefault(
-                    "first", time.perf_counter()))
-            await long_task
-            await engine.stop()
-            return stamps["first"], long_done["t"]
-
-        t_first, t_long_done = run(whole_batch())
-        assert t_first >= t_long_done, (
-            "whole-batch baseline must NOT admit the joiner before the "
-            "running batch drains")
 
     def test_deadline_sweep_frees_slot_mid_decode(self):
         async def main():
@@ -338,8 +310,43 @@ class TestEngineScheduling:
             "ai4e_decode_sequences_total", "ai4e_decode_reprefills_total",
             "ai4e_decode_tick_seconds", "ai4e_decode_queue_wait_seconds",
             "ai4e_decode_step_active_slots", "ai4e_decode_step_bound",
-            "ai4e_decode_kv_positions_total",
-            "ai4e_decode_experts_touched", "ai4e_decode_expert_peak_load"}
+            "ai4e_decode_kv_positions_total"}
+
+    @pytest.mark.parametrize("declared", [
+        {"window_fill": ("Share of a layer's window that is live",
+                         (0.25, 0.5, 1.0, float("inf")))},
+        {}])
+    def test_a_backend_declares_its_own_step_report(self, declared):
+        """What the backend's model reports of its step is the backend's to
+        name: the engine registers ``ai4e_decode_<name>`` with the declared
+        help and buckets and observes it each step; a backend that
+        declares nothing registers nothing beyond the engine's own."""
+
+        class Reporting(FakeBackend):
+            step_report_series = declared
+
+            def step(self, tokens, positions, active):
+                self.step_report = dict.fromkeys(declared, 0.5)
+                return super().step(tokens, positions, active)
+
+        async def main():
+            reg = MetricsRegistry()
+            engine = DecodeEngine(Reporting(slots=1), metrics=reg)
+            await engine.start()
+            await engine.submit([1], 4)
+            await engine.stop()
+            return reg
+
+        reg, plain = run(main()), MetricsRegistry()
+        DecodeEngine(FakeBackend(), metrics=plain)
+        assert (set(reg._metrics) - set(plain._metrics)
+                == {f"ai4e_decode_{name}" for name in declared})
+        for name, (help_text, buckets) in declared.items():
+            metric = reg._metrics[f"ai4e_decode_{name}"]
+            assert (metric.help, metric.buckets) == (help_text, buckets)
+            (_, _, _, value), = metric.collect()
+            # 4 tokens: one from the prefill, three steps.
+            assert (value["count"], value["sum"]) == (3, 1.5)
 
     def test_default_worker_has_no_decode_metrics(self):
         """Decode-engine-off identity (acceptance): nothing in the
