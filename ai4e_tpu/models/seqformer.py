@@ -173,9 +173,10 @@ class SeqFormerLM(nn.Module):
       ``(next-token ids (S,), k, v)`` — k/v the pool (``ops/kv_pool.py``):
       ONE token for every slot of it per call, inactive slots riding along
       masked (their cache rows are garbage a later prefill overwrites).
-      ``bound`` cuts every layer's attention to the cached positions
-      ``< bound`` (``kv_pool.decode_attention``). The row writes take the
-      whole pool either way.
+      Every layer reads a slot as far as it has written
+      (``kv_pool.decode_attention``); ``bound`` trims that read's grid to
+      the cached positions ``< bound``. The row writes take the whole pool
+      either way.
 
     Greedy decoding is computed on-device (argmax over the tied-embedding
     logits) so each step ships S int32s back to the host, not S×V logits.

@@ -2,19 +2,30 @@
 
 One preallocated buffer per tensor::
 
-    k, v : (layers, slots, heads, max_len, head_dim)
+    k, v : (layers, slots, max_len, heads * head_dim)
 
 A slot is a row of it (``runtime/decode.SlotPool`` hands slots out; the
-device never reallocates per request). Everything that knows this layout
-is here: the allocation, the view a layer's attention reads, the decode
-attention over that view, the row a step writes, the block a prefill
-returns and its insert. An LM family (``models/``) owns its block's own
-math — norms, projections, positions, MLP or experts — and calls these;
-``runtime/kvcache.py`` owns the compiled programs and asks here for
-shapes and the insert. A change of layout or of the read (a per-slot
-kernel, a contiguous row, a block table) is a change to this file.
+device never reallocates per request), and one position of a slot is one
+contiguous row of ``heads x head_dim`` elements: whole lane tiles (1,024
+float32 or 2,048 bfloat16 in the configurations served — 4 KB either way),
+``max_len`` on the sublanes, nothing padded. A step writes a position as
+that one row, and a kernel can take blocks of positions straight from the
+pool; with ``head_dim`` minor (64 wide in float32) the chip laid ``max_len``
+on the lanes, a position was a column through thousands of tiles, and no
+Mosaic operand could hold the pool unpadded (CHANGES.md PR 25, PR 30).
 
-Pure ``jax.numpy``; scopes name the device side for the trace's readers
+Everything that knows this layout is here and in the kernel this file
+calls (``ops/pallas/decode_attention.py``): the allocation, the decode
+attention over the pool, the row a step writes, the block a prefill returns
+and its insert. An LM family (``models/``) owns its block's own math —
+norms, projections, positions, MLP or experts — and calls these;
+``runtime/kvcache.py`` owns the compiled programs and asks here for shapes,
+the insert and what a step read. A change of layout or of the read (a block
+table, another block rule) is a change to these two files.
+
+The decode read is a Pallas kernel (Mosaic on the chip, the interpreter
+elsewhere: ``ops/pallas/lowering.resolve_interpret``); the rest is
+``jax.numpy``. Scopes name the device side for the trace's readers
 (``benchmark/lib/xplane_spans.py``) — they are metadata and change no
 program.
 """
@@ -25,12 +36,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .pallas.decode_attention import pooled_attention
+
+# What one grid step of the decode read fetches of each tensor: 256
+# positions of a 4 KB row. Smaller, and the grid's ~0.35 us a step shows;
+# larger, and a short sequence pays for positions it has not written.
+READ_BLOCK_BYTES = 1 << 20
+
 
 def pool_shape(spec: tuple, slots: int, max_len: int) -> tuple:
     """Shape of each pool tensor for a model whose ``cache_spec()`` gives
     ``spec = (layers, heads, head_dim)``."""
     layers, heads, head_dim = spec
-    return layers, slots, heads, max_len, head_dim
+    return layers, slots, max_len, heads * head_dim
 
 
 def allocate(shape: tuple, dtype):
@@ -39,6 +57,25 @@ def allocate(shape: tuple, dtype):
     pool tensors on the device at once, which would be the allocator's
     peak of the whole worker."""
     return jnp.zeros(shape, dtype)
+
+
+def read_block(shape: tuple, dtype) -> int:
+    """Positions a grid step of ``decode_attention`` fetches from a pool of
+    ``shape``: a block of one tensor within ``READ_BLOCK_BYTES``, on whole
+    sublane tiles of any dtype, or the pool's whole length."""
+    fit = max(READ_BLOCK_BYTES // (shape[-1] * np.dtype(dtype).itemsize), 1)
+    return min(fit - fit % 32 or fit, shape[2])
+
+
+def positions_read(shape: tuple, dtype, position, active, bound: int) -> int:
+    """Cached positions plus new tokens one step's attention reads, a
+    layer: every block ``decode_attention`` fetches, whole — a slot at
+    ``position`` p the blocks under ``min(p, bound)``, a slot at 0 none —
+    and each active slot's own new token. ``position``, ``active``: per
+    slot, host values."""
+    block = read_block(shape, dtype)
+    return sum(-(-min(p, bound) // block) * block for p in position) + sum(
+        map(bool, active))
 
 
 def _dot(eq, a, b):
@@ -62,8 +99,10 @@ def prefill_attention(q, k, v, mask):
 def prompt_block(rows):
     """A prefill's K (or V) as the block ``insert_block`` takes: ``rows`` —
     per-layer (B, P, H, hd), as ``prefill_attention`` reads them — become
-    (layers, B, H, P, hd), rank-matched to the pool."""
-    return jnp.stack(rows).transpose(0, 1, 3, 2, 4)
+    (layers, B, P, H * hd), rank-matched to the pool: a stack and a
+    reshape, nothing moves."""
+    rows = jnp.stack(rows)
+    return rows.reshape(*rows.shape[:3], -1)
 
 
 def insert_block(k_pool, v_pool, k_block, v_block, slot):
@@ -71,14 +110,14 @@ def insert_block(k_pool, v_pool, k_block, v_block, slot):
     of ``slot``'s rows — ``slot`` may be traced: one program a block length,
     any slot. Blocks are rank-matched to the pool, so one
     dynamic_update_slice a tensor lands the whole prompt."""
-    zero = (0, slot, 0, 0, 0)
+    zero = (0, slot, 0, 0)
     with jax.named_scope("cache_insert"):
         return (jax.lax.dynamic_update_slice(k_pool, k_block, zero),
                 jax.lax.dynamic_update_slice(v_pool, v_block, zero))
 
 
 def decode_attention(q, k_new, v_new, k_pool, v_pool, layer: int, position,
-                     bound: int | None = None):
+                     bound: int | None = None, interpret: bool | None = None):
     """One layer's attention of one decode step: one new token per slot
     against the pool. q, k_new, v_new: (S, H, hd) — the new token's;
     k_pool, v_pool: the pool, read and never rewritten: a slot's positions
@@ -89,30 +128,27 @@ def decode_attention(q, k_new, v_new, k_pool, v_pool, layer: int, position,
     jit; default the whole length) cuts the read to the cached positions
     ``< bound``: the same result, to the order of a float32 sum, for any
     bound ``>=`` the largest position of a slot whose output is read.
-    Float32 scores and accumulation; the weights are cast to the cache's
-    dtype for the value product. Returns (S, H, hd) in ``q``'s dtype."""
-    # ONE static slice a tensor, layer and bound at once: XLA:TPU fuses it
-    # into the attention's reads. A cut of the layer's view
-    # (``k_pool[layer][:, :, :bound]``) costs a copy of every layer's K and
-    # V (tests/test_tpu_aot_compile.py).
-    k_view = k_pool[layer, :, :, :bound]  # (S, H, L, hd)
-    v_view = v_pool[layer, :, :, :bound]
-    length = k_view.shape[2]
+    Float32 scores and accumulation, the weights never rounded. Returns
+    (S, H, hd) in ``q``'s dtype.
+
+    All of it is ``pallas.decode_attention.pooled_attention``: per slot,
+    blocks of ``read_block`` positions up to that slot's position and no
+    further — a slot at position 0 reads nothing — then the new token's own
+    term, so ``bound`` only trims the kernel's grid. The kernel is handed the pool tensors whole,
+    with ``layer`` as a value: a slice of the pool (``k_pool[layer]``) that
+    reaches a custom call is a copy of that layer's K and V, every layer,
+    every step (tests/test_tpu_aot_compile.py holds the compiled programs
+    to that)."""
+    slots, heads, head_dim = q.shape
+    bound = k_pool.shape[2] if bound is None else bound
     with jax.named_scope("attention"):
-        scale = 1.0 / np.sqrt(q.shape[-1])
-        scores = _dot("shd,shld->shl", q, k_view) * scale
-        valid = (jnp.arange(length)[None, :]
-                 < position[:, None])  # keys before the new token
-        scores = jnp.where(valid[:, None, :], scores, -1e30)
-        own = _dot("shd,shd->sh", q, k_new) * scale
-        # softmax over [cached keys, the new token's key], by hand: the
-        # new key is not in the cache yet.
-        top = jnp.maximum(scores.max(axis=-1), own)
-        w = jnp.exp(scores - top[..., None])
-        w_own = jnp.exp(own - top)
-        return ((_dot("shl,shld->shd", w.astype(v_view.dtype), v_view)
-                 + w_own[..., None] * v_new.astype(jnp.float32))
-                / (w.sum(axis=-1) + w_own)[..., None]).astype(q.dtype)
+        return pooled_attention(
+            q.reshape(slots, -1).astype(k_pool.dtype),
+            k_new.reshape(slots, -1).astype(k_pool.dtype),
+            v_new.reshape(slots, -1), k_pool, v_pool, layer, position,
+            heads=heads, bound=bound,
+            block=read_block(k_pool.shape, k_pool.dtype),
+            interpret=interpret).reshape(q.shape).astype(q.dtype)
 
 
 def write_rows(k_pool, v_pool, k_rows, v_rows, position):
@@ -120,17 +156,19 @@ def write_rows(k_pool, v_pool, k_rows, v_rows, position):
     of (S, H, hd), ``position`` (S,).
 
     One row per slot, all layers at once, written where the pool already
-    lives. A Python loop of dynamic_update_slice on purpose: a scatter
+    lives: ``layers`` contiguous rows of whole lane tiles a slot and a
+    tensor. A Python loop of dynamic_update_slice on purpose: a scatter
     (``.at[].set``), a vmap or a fori_loop of the same writes makes XLA:TPU
     re-lay or copy the whole pool every step (CHANGES.md PR 25 has the
     compiled programs side by side). A position past the last row is
     clamped onto it, not dropped: the engine retires a sequence before it
     gets there."""
     with jax.named_scope("cache_update"):
-        k_rows = jnp.stack(k_rows)[:, :, :, None, :]  # (layers, S, H, 1, hd)
-        v_rows = jnp.stack(v_rows)[:, :, :, None, :]
+        rows = (len(k_rows), position.shape[0], 1, k_pool.shape[-1])
+        k_rows = jnp.stack(k_rows).reshape(rows)
+        v_rows = jnp.stack(v_rows).reshape(rows)
         for slot in range(position.shape[0]):
-            at = (0, slot, 0, position[slot], 0)
+            at = (0, slot, position[slot], 0)
             k_pool = jax.lax.dynamic_update_slice(
                 k_pool, k_rows[:, slot:slot + 1], at)
             v_pool = jax.lax.dynamic_update_slice(

@@ -1,0 +1,230 @@
+"""Pallas TPU kernel: one decode step's attention over the K/V pool, read
+per slot only as far as that slot has written.
+
+The pool (``ops/kv_pool.py``) holds a position's K (V) as one contiguous
+row of ``heads x head_dim`` lanes — whole lane tiles — with ``max_len`` on
+the sublanes::
+
+    k, v : (layers, slots, max_len, heads * head_dim)
+
+so a block of positions is a plain ``(block, row)`` tile in VMEM and the
+kernel takes the pool tensor itself, never a slice of it (a slice that
+reaches a custom call is a copy of a layer's K and V every step).
+
+Grid ``(slots, blocks under bound)``, the block axis fastest, with the
+step's plan and the layer as scalar-prefetch operands: how far each slot is
+read (``min(position, bound)``), and which block its grid steps fetch. A
+live slot fetches blocks ``0 .. last`` — those that hold a position under
+its limit — and its later steps ask again for ``last``; a dead slot
+(position 0: every inactive slot) asks for the block the step before it
+held, or the one the first live slot starts with. Pallas skips a DMA whose
+block index did not change, so a dead block and a dead slot cost a grid
+step (~0.25 us on a v5e) and no bytes.
+
+Heads share a row, so a head's scores are a segment of it. The row of ``q``
+becomes a block-diagonal ``(heads, row)`` matrix (head ``h`` holds ``q`` on
+its own lanes, zero elsewhere) and both products run on the MXU with the
+heads on the sublanes: ``scores (heads, block) = q_bd . k^T`` contracts the
+whole row, ``acc (heads, row) += p . v`` gives every head every lane, and
+the lanes a head does not own are dropped at the end. An online softmax
+(running max and sum a head, a float32 accumulator) carries a slot across
+its blocks in VMEM scratch. Float32 operands multiply at
+``Precision.HIGHEST`` (the MXU's default would round them to bfloat16);
+bfloat16 operands multiply exactly as they are, with float32 sums, and the
+float32 weights meet a bfloat16 V as three bfloat16 terms that add up to
+them: no weight is rounded (the XLA read before this kernel cast them to
+V's dtype). On a v5e the products hide under the blocks' DMA in both
+dtypes: a busy grid runs at ~735 GB/s of K and V (CHANGES.md PR 30).
+
+The new token's own key and value are one more term of the same softmax,
+joined in the slot's last grid step, which normalises and writes the slot's
+row. (Joined outside, on ``(slots, heads)``-sized values in ``jax.numpy``,
+the same arithmetic was ~17 small XLA operations a layer at head width 64 —
+lane spreads and relayouts — a seventh of the step program's operations.)
+"""
+
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .lowering import resolve_interpret
+
+NEG_INF = -1e30  # finite: a slot with nothing cached keeps exp() defined
+
+# Rows of the plan, (3, slots): with the layer, (1,), the kernel's
+# scalar-prefetch operands.
+LIMIT, SOURCE, HOLD = range(3)
+
+
+def block_plan(position, bound: int, block: int):
+    """The plan of a step that attends the cached positions ``< bound``
+    in blocks of ``block``. Row LIMIT: ``min(position, bound)``, (slots,)
+    int32 — the positions of a slot the step reads. A live slot (limit > 0)
+    fetches from itself (SOURCE) the blocks up to HOLD, its last live one;
+    a dead slot holds what the step before it left in VMEM — the last live
+    slot's HOLD — and dead slots before any live one hold block 0 of the
+    first live slot, which that slot starts with. The same for every
+    layer of a step: XLA computes it once."""
+    slots = position.shape[0]
+    limit = jnp.minimum(position, bound)
+    live = limit > 0
+    last = jnp.maximum(-(-limit // block) - 1, 0)
+    before = jax.lax.cummax(jnp.where(live, jnp.arange(slots), -1))
+    source = jnp.where(before >= 0, before, jnp.argmax(live))
+    hold = jnp.where(before >= 0, last[source], 0)
+    return jnp.stack([limit, source, hold]).astype(jnp.int32)
+
+
+def _pool_index(s, b, plan, layer):
+    """Block index into a pool tensor for grid step (s, b)."""
+    hold = plan[HOLD, s]
+    block = jnp.where(plan[LIMIT, s] > 0, jnp.minimum(b, hold), hold)
+    return layer[0], plan[SOURCE, s], block, 0
+
+
+def _slot_index(s, b, plan, layer):
+    return s, 0, 0
+
+
+def _kernel(plan_ref, layer_ref, q_ref, k_new_ref, v_new_ref, k_ref, v_ref,
+            out_ref, q_bd, acc, m, l, *, block: int, head_dim: int,
+            scale: float):
+    # q_ref, k_new_ref, v_new_ref, out_ref: (1, row) — the slot's new
+    # token; k_ref, v_ref: (block, row). Scratch, carried across a slot's
+    # blocks: q_bd, acc (heads, row); m, l (heads, 1).
+    heads, row = q_bd.shape
+    # program_id is read at the top level: inside a pl.when branch it
+    # escapes the trace in the interpreter (flash_attention.py).
+    s, b = pl.program_id(0), pl.program_id(1)
+    limit = plan_ref[LIMIT, s]
+
+    def own_lanes():
+        """(heads, row), True where the lane is one of the head's own.
+        Built where it is used: a step over a dead block runs none of it."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, (heads, row), 1)
+        first = jax.lax.broadcasted_iota(jnp.int32, (heads, row),
+                                         0) * head_dim
+        return (lane >= first) & (lane < first + head_dim)
+
+    @pl.when(b == 0)
+    def _init():
+        q_bd[...] = jnp.where(own_lanes(), q_ref[...].astype(jnp.float32),
+                              0.0).astype(q_bd.dtype)
+        acc[...] = jnp.zeros_like(acc)
+        m[...] = jnp.full_like(m, NEG_INF)
+        l[...] = jnp.zeros_like(l)
+
+    def accumulate(ragged: bool):
+        k, v = k_ref[...], v_ref[...]
+        # Float32 operands in float32; Mosaic takes no precision with
+        # bfloat16 ones, whose products are exact as they are.
+        precision = (jax.lax.Precision.HIGHEST if k.dtype == jnp.float32
+                     else None)
+        scores = jax.lax.dot_general(
+            q_bd[...], k, (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32) * scale    # (heads, block)
+        if ragged:
+            # The slot's last block: what lies at or above its limit is
+            # another sequence's or nothing's. The scores are masked; V's
+            # rows are zeroed, since 0 x NaN is NaN.
+            start = b * block
+            cols = start + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+            rows = start + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+            scores = jnp.where(cols < limit, scores, NEG_INF)
+            v = jnp.where(rows < limit, v, 0)
+        m_prev = m[...]
+        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+        p = jnp.exp(scores - m_new)
+        shrink = jnp.exp(m_prev - m_new)
+        l[...] = l[...] * shrink + p.sum(axis=-1, keepdims=True)
+        m[...] = m_new
+        # p (float32) x v, (heads, row): against a float32 V at HIGHEST;
+        # against a narrower V as three terms of V's dtype that add up to
+        # p — each product exact, the sums float32, no rounded weights.
+        weighted = jnp.zeros(acc.shape, jnp.float32)
+        for _ in range(1 if v.dtype == jnp.float32 else 3):
+            term = p.astype(v.dtype)
+            weighted += jax.lax.dot_general(
+                term, v, (((1,), (0,)), ((), ())), precision=precision,
+                preferred_element_type=jnp.float32)
+            p = p - term.astype(jnp.float32)
+        acc[...] = acc[...] * shrink + weighted
+
+    pl.when((b + 1) * block <= limit)(partial(accumulate, False))
+    pl.when((b * block < limit) & (limit < (b + 1) * block))(
+        partial(accumulate, True))
+
+    @pl.when(b == pl.num_programs(1) - 1)
+    def _finish():
+        # The new token's own key and value: one more term of the same
+        # softmax (they are not in the pool yet). A slot that read nothing
+        # has m = NEG_INF and l = 0: its new value comes back bit for bit.
+        k_new = k_new_ref[...].astype(jnp.float32)
+        v_new = v_new_ref[...].astype(jnp.float32)
+        own = (q_bd[...].astype(jnp.float32) * k_new).sum(
+            axis=-1, keepdims=True) * scale                 # (heads, 1)
+        top = jnp.maximum(m[...], own)
+        w_pool, w_own = jnp.exp(m[...] - top), jnp.exp(own - top)
+        rows = ((acc[...] * w_pool + w_own * v_new)
+                / (l[...] * w_pool + w_own))                # (heads, row)
+        # each head keeps its own lanes of its row
+        out_ref[...] = jnp.where(own_lanes(), rows, 0.0).sum(
+            axis=0, keepdims=True).astype(out_ref.dtype)
+
+
+@partial(jax.jit, static_argnames=("heads", "bound", "block", "interpret"))
+def _pooled(q, k_new, v_new, k_pool, v_pool, layer, position, *, heads: int,
+            bound: int, block: int, interpret: bool):
+    """Jitted on its own so that the layers of a step program share one
+    traced and lowered kernel: ``layer`` is a value, not a constant."""
+    slots, row = q.shape
+    plan = block_plan(position, bound, block)
+    pool = pl.BlockSpec((None, None, block, row), _pool_index)
+    per_slot = pl.BlockSpec((None, 1, row), _slot_index)
+    return pl.pallas_call(
+        partial(_kernel, block=block, head_dim=row // heads,
+                scale=float((row // heads) ** -0.5)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(slots, -(-bound // block)),
+            in_specs=[per_slot, per_slot, per_slot, pool, pool],
+            out_specs=per_slot,
+            scratch_shapes=[pltpu.VMEM((heads, row), q.dtype),
+                            pltpu.VMEM((heads, row), jnp.float32),
+                            pltpu.VMEM((heads, 1), jnp.float32),
+                            pltpu.VMEM((heads, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((slots, 1, row), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="decode_attention",
+    )(plan, layer.reshape(1), q[:, None], k_new[:, None], v_new[:, None],
+      k_pool, v_pool)[:, 0]
+
+
+def pooled_attention(q, k_new, v_new, k_pool, v_pool, layer, position, *,
+                     heads: int, bound: int, block: int,
+                     interpret: bool | None = None):
+    """Attention of one new token a slot over ``layer``'s cached positions
+    ``< min(position[slot], bound)`` and the new token itself.
+
+    q, k_new, v_new: (slots, row) — the new token's query, key and value,
+    ``row = heads * head_dim``, q and k_new in the pool's dtype; k_pool,
+    v_pool: (layers, slots, max_len, row), whole; layer: an int or an
+    int32 scalar; position: (slots,) int32; ``block``: positions a grid
+    step fetches, at most ``max_len``. Returns (slots, row) in ``q``'s
+    dtype: the softmax over [cached keys, the new key] of each head, times
+    the values. A slot at position 0 reads nothing of the pool and returns
+    its new value."""
+    if not 0 < bound <= k_pool.shape[2] or not 0 < block <= k_pool.shape[2]:
+        raise ValueError(f"bound {bound} and block {block} must lie within "
+                         f"the pool's {k_pool.shape[2]} positions")
+    return _pooled(q, k_new, v_new, k_pool, v_pool,
+                   jnp.asarray(layer, jnp.int32), position.astype(jnp.int32),
+                   heads=heads, bound=bound, block=block,
+                   interpret=resolve_interpret("decode_attention", interpret))

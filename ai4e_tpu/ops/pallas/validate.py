@@ -1,7 +1,7 @@
 """On-device Pallas kernel validation (VERDICT r1 next-round #5).
 
-The three serving kernels (``flash_attention``, ``segmentation_argmax``,
-``normalize_image``) default to interpret mode off-TPU, so CPU CI never
+The serving kernels (``flash_attention``, ``segmentation_argmax``,
+``normalize_image``, ``decode_attention``) default to interpret mode off-TPU, so CPU CI never
 proves they compile to Mosaic and fit VMEM on real hardware. This module is
 that proof: ``validate_kernels()`` runs each kernel with ``interpret=False``
 (on TPU) against a pure-XLA oracle and asserts its working set fits the
@@ -42,10 +42,20 @@ def normalize_image_vmem_bytes(tile_h: int, w: int, c: int) -> int:
     return 2 * ((tile_h * row) * 1 + 2 * row * 4 + (tile_h * row) * 4)
 
 
+def decode_attention_vmem_bytes(block: int, heads: int, row: int,
+                                dtype_bytes: int) -> int:
+    """Double-buffered K and V blocks, the new token's three rows and the
+    output row; scratch (block-diagonal q, accumulator, max, sum) once."""
+    blocks = (2 * block + 4) * row * dtype_bytes
+    scratch = heads * row * (dtype_bytes + 4) + 2 * heads * 128 * 4
+    return 2 * blocks + scratch
+
+
 def validate_kernels(interpret: bool = False) -> dict:
     """Run each kernel against its XLA oracle; returns per-kernel
     {ok, max_err, vmem_bytes}. ``interpret=True`` runs the same checks in the
     pallas interpreter (CPU CI coverage of this module's own logic)."""
+    from .. import kv_pool
     from .flash_attention import flash_attention
     from .image_preprocess import normalize_image
     from .seg_postprocess import segmentation_argmax
@@ -107,6 +117,47 @@ def validate_kernels(interpret: bool = False) -> dict:
     assert vmem <= VMEM_BUDGET_BYTES, f"normalize VMEM {vmem}"
     results["normalize_image"] = {
         "ok": bool(err < 1e-5), "max_err": round(err, 7), "vmem_bytes": vmem}
+
+    # decode attention over the K/V pool vs a plain float32 softmax — the
+    # two served rows (16 heads of 64 in float32, of 128 in bfloat16; 4 KB
+    # either way, so blocks of 256 positions), one layer of a pool of eight
+    # slots at ragged positions: dead, a block's edges, the whole length.
+    position = np.asarray([0, 1, 255, 256, 257, 600, 1023, 1024], np.int32)
+    for dtype, head_dim, tol in (("float32", 64, 1e-4),
+                                 ("bfloat16", 128, 0.04)):
+        heads, length = 16, 1024
+        shape = kv_pool.pool_shape((1, heads, head_dim), len(position),
+                                   length)
+        k_pool, v_pool = (jax.numpy.asarray(rng.standard_normal(shape), dtype)
+                          for _ in range(2))
+        q, k_new, v_new = (
+            jax.numpy.asarray(
+                rng.standard_normal((len(position), heads, head_dim)), dtype)
+            for _ in range(3))
+        got = np.asarray(jax.jit(
+            lambda *a: kv_pool.decode_attention(
+                *a, 0, position, interpret=interpret)
+        )(q, k_new, v_new, k_pool, v_pool), np.float32)
+        f32 = [np.asarray(x, np.float32) for x in (q, k_new, v_new)]
+        cached = [np.asarray(x, np.float32)[0].reshape(
+            len(position), length, heads, head_dim) for x in (k_pool, v_pool)]
+        err = 0.0
+        for slot, p in enumerate(position):
+            keys = np.concatenate([cached[0][slot, :p], f32[1][slot][None]])
+            values = np.concatenate([cached[1][slot, :p], f32[2][slot][None]])
+            scores = np.einsum("lhd,hd->hl", keys, f32[0][slot]) / np.sqrt(
+                head_dim)
+            w = np.exp(scores - scores.max(-1, keepdims=True))
+            want = np.einsum("hl,lhd->hd", w / w.sum(-1, keepdims=True),
+                             values)
+            err = max(err, float(np.max(np.abs(got[slot] - want))))
+        block = kv_pool.read_block(shape, dtype)
+        vmem = decode_attention_vmem_bytes(block, heads, shape[-1],
+                                           np.dtype(dtype).itemsize)
+        assert vmem <= VMEM_BUDGET_BYTES, f"decode attention VMEM {vmem}"
+        results[f"decode_attention_{dtype}"] = {
+            "ok": bool(err < tol), "max_err": round(err, 6),
+            "vmem_bytes": vmem}
 
     results["all_ok"] = all(r["ok"] for r in results.values()
                             if isinstance(r, dict))

@@ -195,9 +195,10 @@ class DecodeEngine:
       a model that reports on its step declares, and its figures of the
       step just run — registered and observed as ``ai4e_decode_<name>``;
     - optionally ``step_bound`` (attribute): the positions of every slot
-      the step just run attended — counted as ``slots x step_bound``
-      attended K/V positions and observed as ``ai4e_decode_step_bound``;
-      a backend without it attends ``max_len``;
+      the step just run covered — observed as ``ai4e_decode_step_bound``;
+      a backend without it covers ``max_len`` — and ``step_attended``
+      (attribute): the K/V positions that step read, counted as attended;
+      a backend without it reads ``slots x`` its bound;
     - optionally ``bound_for(longest)``: the bound a step whose largest
       live position is ``longest`` will run — the ``bound=`` of the
       ``ai4e.decode.tick`` region, which opens before the step;
@@ -259,7 +260,7 @@ class DecodeEngine:
             buckets=(*range(1, backend.slots + 1), float("inf")))
         self._step_bound = self.metrics.histogram(
             "ai4e_decode_step_bound",
-            "Positions of every slot each decode step attended (the rung of "
+            "Positions of every slot each decode step covered (the rung of "
             "the backend's step programs it ran; max_len without rungs)",
             buckets=(*getattr(backend, "step_bounds", (backend.max_len,)),
                      float("inf")))
@@ -273,7 +274,8 @@ class DecodeEngine:
         self._kv_positions = self.metrics.counter(
             "ai4e_decode_kv_positions_total",
             "K/V positions per decode step: live (sum of position + 1 over "
-            "active slots) and attended (slots x the step's bound)")
+            "active slots) and attended (what the step's attention read: "
+            "the backend's count, else slots x the step's bound)")
         self._occupancy = self.metrics.gauge(
             "ai4e_decode_slot_occupancy",
             "Occupied KV-cache slots / total slots per model")
@@ -614,7 +616,8 @@ class DecodeEngine:
                     sum(position + 1 for _, _, position in snapshot),
                     model=self._model, kind="live")
                 self._kv_positions.inc(
-                    self.pool.slots * bound,
+                    getattr(self.backend, "step_attended",
+                            self.pool.slots * bound),
                     model=self._model, kind="attended")
                 for name, value in getattr(self.backend, "step_report",
                                            {}).items():

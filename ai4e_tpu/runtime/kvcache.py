@@ -25,11 +25,12 @@ discipline ``ModelRuntime.warmup`` applies to batch buckets):
   later prefill overwrites). The layers read the pool as it came in and
   the new token's K/V are stored afterwards as ONE row per slot (all
   layers at once), in place: the step produces nothing else of the
-  pool's shape. Attention reads only the first ``bound`` positions of
-  every slot: one program per rung of ``step_bounds`` (three quarters of
-  ``max_len`` and ``max_len``), and each step runs the smallest rung that holds its
-  longest LIVE sequence — the same pool, the same row writes, fewer dead
-  positions read.
+  pool's shape. Attention reads each slot only as far as it has written
+  (``kv_pool.decode_attention``: a kernel over blocks of positions), and
+  its grid covers the first ``bound`` positions: one program per rung of
+  ``step_bounds`` (three quarters of ``max_len`` and ``max_len``), and
+  each step runs the smallest rung that holds its longest LIVE sequence —
+  the same pool, the same row writes, a shorter grid.
 
 Buffer donation: the step and insert programs consume the cache and
 return the updated one; on non-CPU backends the input buffer is donated
@@ -139,9 +140,11 @@ class PagedDecodeRuntime:
         # engine observes each as ``ai4e_decode_<name>``, registered from
         # ``step_report_series``); else empty.
         self.step_report: dict[str, float] = {}
-        # Positions a slot the last step attended (the engine counts
-        # ``slots x step_bound`` as attended).
+        # The rung of ``step_bounds`` the last step ran at, and the K/V
+        # positions its attention read, a layer
+        # (``kv_pool.positions_read``): the engine counts them as attended.
         self.step_bound = self.max_len
+        self.step_attended = 0
 
     # -- cache lifecycle ---------------------------------------------------
 
@@ -274,13 +277,16 @@ class PagedDecodeRuntime:
         inactive rows are garbage the engine never reads. ``positions`` and
         ``active`` choose the program: the one compiled for
         ``bound_for`` the largest position among the ACTIVE slots (an
-        inactive slot's stale position does not count), which attends that
-        many positions of every slot and is otherwise the same step
-        (``step_bound`` says which it was). ``active`` also tells a model
+        inactive slot's stale position does not count), whose attention
+        covers that many positions and is otherwise the same step
+        (``step_bound`` says which it was, ``step_attended`` what it
+        read). ``active`` also tells a model
         that reports on its step (``step_report``) which slots to count."""
         self._ensure()
         self.step_bound = self.bound_for(max(
             (p for p, live in zip(positions, active) if live), default=0))
+        self.step_attended = kv_pool.positions_read(
+            *self.cache_spec(), positions, active, self.step_bound)
         with device_trace("ai4e.decode.dispatch", bound=self.step_bound):
             out, self._k, self._v = self._run(
                 "step", self.servable.params, np.asarray(tokens, np.int32),

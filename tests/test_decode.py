@@ -462,7 +462,9 @@ class TestPagedDecodeRuntime:
 # place; nothing else of the pool is touched.
 
 _POOL = dict(depth=2, slots=5, heads=4, max_len=32, head_dim=8)
-_POOL_SHAPE = tuple(_POOL.values())   # (layers, slots, heads, max_len, hd)
+# (layers, slots, max_len, heads x hd): a position is one row
+_POOL_SHAPE = (_POOL["depth"], _POOL["slots"], _POOL["max_len"],
+               _POOL["heads"] * _POOL["head_dim"])
 
 
 @pytest.fixture(scope="module", params=list(_LM_FAMILIES))
@@ -525,13 +527,13 @@ def _step_over_histories(lm, positions, seed=0):
         padded = np.zeros((1, length), np.int32)
         padded[0, :p + 1] = history
         tok, k, v = lm.prefill(padded, np.asarray([p + 1], np.int32))
-        k, v = np.asarray(k), np.asarray(v)   # (depth, 1, H, L, hd)
-        k0[:, slot, :, :p] = k[:, 0, :, :p]
-        v0[:, slot, :, :p] = v[:, 0, :, :p]
+        k, v = np.asarray(k), np.asarray(v)   # (depth, 1, L, H x hd)
+        k0[:, slot, :p] = k[:, 0, :p]
+        v0[:, slot, :p] = v[:, 0, :p]
         tokens.append(int(history[-1]))
         want_tok.append(int(tok[0]))
-        want_k.append(k[:, 0, :, p])
-        want_v.append(v[:, 0, :, p])
+        want_k.append(k[:, 0, p])
+        want_v.append(v[:, 0, p])
     out, k1, v1 = lm.step(lm.params, np.asarray(tokens, np.int32), k0, v0,
                           np.asarray(positions, np.int32))
     return SimpleNamespace(
@@ -558,9 +560,9 @@ class TestStepWritesOneRowInPlace:
                                        got.want_rows):
             written = np.zeros(before.shape, bool)
             for slot, p in enumerate(positions):
-                written[:, slot, :, p, :] = True
+                written[:, slot, p] = True
                 np.testing.assert_allclose(
-                    after[:, slot, :, p].astype(np.float32),
+                    after[:, slot, p].astype(np.float32),
                     rows[slot].astype(np.float32),
                     rtol=tiny_lm.tol, atol=tiny_lm.tol)
             changed = _bits(before) != _bits(after)
@@ -587,7 +589,7 @@ class TestStepWritesOneRowInPlace:
         for before, after in ((k0, k1), (v0, v1)):
             changed = _bits(before) != _bits(after)
             rows = {(int(s), int(p))
-                    for _, s, _, p, _ in zip(*np.nonzero(changed))}
+                    for _, s, p, _ in zip(*np.nonzero(changed))}
             assert rows == {(0, length - 1), (1, length - 1),
                             (2, 2), (3, 2), (4, 2)}
 
@@ -614,20 +616,29 @@ class TestStepWritesOneRowInPlace:
             a, b = np.asarray(a), np.asarray(b)
             assert (_bits(a[:, active]) == _bits(b[:, active])).all()
             # and the inactive slots' garbage is still theirs, but for row 0
-            assert (_bits(b[:, inactive, :, 1:])
-                    == _bits(bad[:, inactive, :, 1:])).all()
+            assert (_bits(b[:, inactive, 1:])
+                    == _bits(bad[:, inactive, 1:])).all()
 
     def test_lowered_step_makes_the_pool_only_by_row_writes(self, tiny_lm):
-        """Every operation of the lowered program whose result has the
+        """Every operation of the lowered step whose result has the
         pool's shape is a ``dynamic_update_slice``: no blend, no stack of
-        rewritten layers, no scatter, no copy to write into."""
+        rewritten layers, no scatter, no copy to write into. The step's
+        own function: the attention kernel is one function beside it,
+        called a layer with the pool whole (here the interpreter's loop,
+        which carries its operands; on the chip a Mosaic call —
+        ``tests/test_tpu_aot_compile.py``)."""
         import re
         import jax
         import jax.numpy as jnp
         pool = jax.ShapeDtypeStruct(_POOL_SHAPE, tiny_lm.dtype)
         ints = jax.ShapeDtypeStruct((_POOL["slots"],), jnp.int32)
-        text = tiny_lm.step.lower(
+        module = tiny_lm.step.lower(
             tiny_lm.params, ints, pool, pool, ints).as_text()
+        text = re.search(r"func\.func public @main.*?\n  }\n", module,
+                         re.S).group(0)
+        kernel = re.findall(r"func\.func private @_pooled\(", module)
+        assert len(kernel) == 1, "one traced kernel for every layer"
+        assert text.count("call @_pooled(") == _POOL["depth"]
         pool_type = ("tensor<" + "x".join(map(str, _POOL_SHAPE)) + "x"
                      + {4: "f32", 2: "bf16"}[tiny_lm.dtype.itemsize] + ">")
         makers = []

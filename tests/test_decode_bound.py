@@ -9,9 +9,11 @@
   takes, stale positions of inactive slots ignored;
 - ``warm()`` compiles every rung, a reload keeps them, a rung that was not
   warmed is counted as a compile;
-- the engine counts ``slots x bound`` attended positions and observes
-  ``ai4e_decode_step_bound``; a backend that reports no bound counts
-  ``max_len``.
+- the engine counts as attended what its backend says the step read
+  (``step_attended``: the runtime's count of the blocks its attention
+  fetched), ``slots x bound`` for a backend that reports a bound alone and
+  ``slots x max_len`` for one that reports nothing, and observes
+  ``ai4e_decode_step_bound``.
 """
 
 import asyncio
@@ -77,7 +79,7 @@ def run_step(lm, positions, bound, pool=None):
     k, v = pool or lm.pool
     ids = np.asarray(lm.ids(tokens, k, v, positions, bound)[0])[:SLOTS]
     logits, k, v = lm.logits(tokens, k, v, positions, bound)
-    rows = [np.stack([np.asarray(pool)[:, slot, :, p].astype(np.float32)
+    rows = [np.stack([np.asarray(pool)[:, slot, p].astype(np.float32)
                       for slot, p in enumerate(positions)])
             for pool in (k, v)]
     return ids, np.asarray(logits, np.float32), rows
@@ -109,7 +111,7 @@ class TestABoundAboveEveryLivePositionChangesNothing:
         bound keep theirs."""
         k, v = lm.pool
         v = v.copy()
-        v[:, :, :, bound:] *= 8
+        v[:, :, bound:] *= 8
         _, logits, _ = run_step(lm, LIVE[longest], bound, (k, v))
         _, want, _ = run_step(lm, LIVE[longest], None, (k, v))
         cut = int(np.argmax(LIVE[longest]))
@@ -285,6 +287,23 @@ class TestTheEngineCountsTheBound:
         assert reg._metrics["ai4e_decode_step_bound"].buckets == (
             512, 768, 1024, float("inf"))
 
+    def test_a_backend_that_says_what_it_read_is_counted_by_that(self):
+        """``step_attended`` replaces ``slots x bound``: here a backend
+        that reads its active slot alone, in blocks of 256."""
+        class ReadBackend(RungBackend):
+            def step(self, tokens, positions, active):
+                self.step_attended = sum(
+                    -(-p // 256) * 256 + 1
+                    for p, live in zip(positions, active) if live)
+                return super().step(tokens, positions, active)
+
+        reg, out = serve(ReadBackend(), [(list(range(510)), 5)])
+        assert len(out[0]) == 5
+        assert series(reg, "ai4e_decode_kv_positions_total", model="lm",
+                      kind="attended") == 3 * (512 + 1) + (768 + 1)
+        assert series(reg, "ai4e_decode_kv_positions_total", model="lm",
+                      kind="live") == 511 + 512 + 513 + 514
+
     def test_a_backend_that_reports_no_bound_counts_max_len(self):
         reg, out = serve(NoBoundBackend(), [([1, 2, 3], 4)])
         assert len(out[0]) == 4
@@ -303,6 +322,11 @@ class TestTheEngineCountsTheBound:
         assert [len(t) for t in out] == [4, 3]
         total, steps = series(reg, "ai4e_decode_step_bound", model="lm")
         assert steps == 3 and total == 3 * RUNGS[0]
+        # Five slot-steps ({A,B}, {A,B}, {A}), each one block — this narrow
+        # pool's whole 512 positions — and its own token; the idle third
+        # slot reads nothing.
         assert series(reg, "ai4e_decode_kv_positions_total", model="lm",
-                      kind="attended") == 3 * SLOTS * RUNGS[0]
+                      kind="attended") == 5 * (MAX_LEN + 1)
+        assert series(reg, "ai4e_decode_kv_positions_total", model="lm",
+                      kind="live") == (3 + 1) + (2 + 1) + (4 + 1) + (3 + 1) + 6
         assert "ai4e_device_phase_seconds" not in reg._metrics

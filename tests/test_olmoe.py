@@ -19,6 +19,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 from ai4e_tpu.models.olmoe import OlmoeLM, create_olmoe_lm  # noqa: E402
+from ai4e_tpu.ops import kv_pool  # noqa: E402
 from benchmark.references import olmoe as reference  # noqa: E402
 
 SPEC = dict(vocab_size=97, dim=64, depth=2, heads=4, experts=8,
@@ -89,9 +90,9 @@ def _served_logits(lm, seq, prompt_len, slot, slots=3):
     pool of garbage, then one decode step a token, teacher-forced, the other
     slots riding along at position 0."""
     apply = lm.model.apply
-    (layers, heads, head_dim), dtype = lm.model.cache_spec()
+    spec, dtype = lm.model.cache_spec()
     rng = np.random.default_rng(slot)
-    shape = (layers, slots, heads, CACHE, head_dim)
+    shape = kv_pool.pool_shape(spec, slots, CACHE)
     k = jnp.asarray(rng.standard_normal(shape), dtype)
     v = jnp.asarray(rng.standard_normal(shape), dtype)
     bucket = 16 if prompt_len <= 16 else 32
@@ -101,8 +102,7 @@ def _served_logits(lm, seq, prompt_len, slot, slots=3):
         lm.params, padded, np.asarray([prompt_len], np.int32),
         method="prefill_logits")
     out = [np.asarray(logits[0, :prompt_len], np.float32)]
-    k = jax.lax.dynamic_update_slice(k, k_block, (0, slot, 0, 0, 0))
-    v = jax.lax.dynamic_update_slice(v, v_block, (0, slot, 0, 0, 0))
+    k, v = kv_pool.insert_block(k, v, k_block, v_block, slot)
     step = jax.jit(lambda *a: apply(lm.params, *a, method="decode_logits"))
     for position in range(prompt_len, len(seq)):
         tokens = np.zeros((slots,), np.int32)

@@ -253,7 +253,8 @@ class TestValidationHarness:
         results = validate_kernels(interpret=True)
         assert results["all_ok"], results
         for name in ("flash_attention", "segmentation_argmax",
-                     "normalize_image"):
+                     "normalize_image", "decode_attention_float32",
+                     "decode_attention_bfloat16"):
             assert results[name]["vmem_bytes"] <= VMEM_BUDGET_BYTES
         # The flash kernel's footprint depends only on block sizes and head
         # dim — never sequence length (the k-axis is a grid axis) — so even

@@ -171,13 +171,20 @@ def _benchmark_cell(config_file, create, model_cls, keys):
 
 
 def _compile_step(runtime, sharding, bound):
-    """The step program of one rung, compiled for the chip."""
+    """The step program of one rung, compiled for the chip — its attention
+    kernel by Mosaic, as on the chip: the program asks the default backend
+    (``lowering.resolve_interpret``), which here is the CPU, so the test
+    answers for it."""
+    from ai4e_tpu.ops.pallas import decode_attention
     pool_shape, pool_dtype = runtime.cache_spec()
     pool = _on(sharding, (pool_shape, pool_dtype))
     ints = _on(sharding, ((runtime.slots,), jnp.int32))
-    return runtime._programs["step"].lower(
-        _on(sharding, runtime.servable.params), ints, pool, pool, ints,
-        bound).compile()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(decode_attention, "resolve_interpret",
+                      lambda kernel, interpret: False)
+        return runtime._programs["step"].lower(
+            _on(sharding, runtime.servable.params), ints, pool, pool, ints,
+            bound).compile()
 
 
 def _entry_results(compiled):
@@ -203,11 +210,14 @@ def _assert_step_reads_in_place(runtime, compiled, bound, temp_limit):
     """The pool is made only by 2 x slots row writes on the two donated
     parameters — no whole-pool ``copy`` (XLA's answer to a scatter: it
     re-lays the pool out and back, 6.5 GB of temporaries), no fusion that
-    rewrites a pool (the one-hot blend: 3.3 GB) — and the cut at ``bound``
-    fuses into the attention's reads: nothing outside a fusion holds one
-    layer's K or V, whole or cut."""
+    rewrites a pool (the one-hot blend: 3.3 GB) — and read only by the
+    attention kernel, one Mosaic call a layer whose K and V operands are
+    the pool parameters themselves: nothing outside a fusion, and no
+    fusion's result, holds one layer's K or V, whole or cut (a slice that
+    reached the kernel would be such a copy, every layer, every step)."""
+    import re
     pool_shape, pool_dtype = runtime.cache_spec()
-    _, slots, heads, max_len, head_dim = pool_shape
+    layers, slots, max_len, row = pool_shape
     pool_type = _hlo_type(pool_shape, pool_dtype)
     results = _entry_results(compiled)
     makers = [op for kind, op in results if kind.startswith(pool_type)]
@@ -216,9 +226,22 @@ def _assert_step_reads_in_place(runtime, compiled, bound, temp_limit):
     assert makers.count("dynamic-update-slice") == 2 * slots
     assert makers.count("parameter") == 2
     for length in {bound, max_len}:
-        layer_type = _hlo_type((slots, heads, length, head_dim), pool_dtype)
-        assert not [r for r in results if r[0].startswith(layer_type)], (
-            [r for r in results if r[0].startswith(layer_type)])
+        for view in ((slots, length, row), (1, slots, length, row)):
+            layer_type = _hlo_type(view, pool_dtype)
+            assert not [r for r in results if r[0].startswith(layer_type)], (
+                [r for r in results if r[0].startswith(layer_type)])
+    entry = re.search(r"ENTRY [^\n]*\{\n(.*?)\n\}", compiled.as_text(),
+                      re.S).group(1)
+    pools = re.findall(r"(%\S+) = " + re.escape(pool_type)
+                       + r"\S* parameter\(", entry)
+    assert len(pools) == 2, pools
+    kernels = [line for line in entry.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == layers, len(kernels)
+    for line in kernels:
+        operands = re.findall(
+            r"%[\w.\-]+", re.search(r"custom-call\(([^)]*)\)", line).group(1))
+        assert all(pool in operands for pool in pools), line
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < temp_limit, memory.temp_size_in_bytes
     # Both pool tensors are aliased input to output: the pool exists once.
@@ -252,7 +275,7 @@ def test_decode_step_at_the_benchmark_cell_writes_rows_in_place(
     positions): ``_assert_step_reads_in_place``. About 6 s each."""
     runtime, spec = gpt2m_cell
     assert runtime.step_bounds == (768, 1024)
-    assert runtime.cache_spec() == ((24, 32, 16, 1024, 64), jnp.float32)
+    assert runtime.cache_spec() == ((24, 32, 1024, 1024), jnp.float32)
     bound = runtime.step_bounds[rung]
     _assert_step_reads_in_place(
         runtime, _compile_step(runtime, v5e_sharding, bound), bound, 0.5e9)
@@ -271,7 +294,7 @@ def test_olmoe_step_at_the_benchmark_cell_writes_rows_in_place(
     configuration states. About 4 s and 10 s."""
     runtime, spec = olmoe_cell
     assert runtime.step_bounds == (1536, 2048)
-    assert runtime.cache_spec() == ((8, 32, 16, 2048, 128), jnp.bfloat16)
+    assert runtime.cache_spec() == ((8, 32, 2048, 2048), jnp.bfloat16)
     bound = runtime.step_bounds[rung]
     memory = _assert_step_reads_in_place(
         runtime, _compile_step(runtime, v5e_sharding, bound), bound, 0.1e9)
