@@ -1,0 +1,144 @@
+"""The expert product of the sparse-expert LM families — one owner of
+routing over ``total`` experts and of the part of the result the experts
+held here give.
+
+A MoE layer, for a row ``h`` (after its norm)::
+
+    p = softmax(h W_r)                      over ALL ``total`` experts, float32
+    the K largest p and their experts       (a tie to the lower index)
+    p <- p / sum of the K                   where the family renormalises
+    y = sum over the row's K experts e of p_e * W_down,e(silu(W_gate,e h) * W_up,e h)
+
+and this process holds the experts ``first_held .. first_held + held - 1``
+(``held`` = the leading axis of the weights it is given): it routes over all
+``total`` — the router keeps its published width — and sums the terms of the
+experts it holds. What the others would add is another chip's part of an
+expert-parallel layer (its exchange is not here: on one chip the layer runs
+without it). A family that holds every expert passes ``first_held = 0`` and
+all of them.
+
+Two forms of the same sum, chosen by the caller for the program it builds:
+
+- ``dense``: every held expert computes every row, and a row's un-chosen
+  experts are multiplied by zero before the down projection. Each expert's
+  weights are read exactly once; the least work where nearly every expert is
+  touched anyway, or where the read of the weights bounds the program (a
+  decode step).
+- ``routed``: the (row, pick) pairs that land on a held expert are sorted
+  by expert and each expert multiplies its own rows only
+  (``jax.lax.ragged_dot``), so the multiplies are the published ``K`` a
+  row — what a prefill of thousands of rows over 128 held experts needs:
+  dense there is ``held / (K * held / total)`` times the work.
+
+No capacity, no drop, in either form.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+def _dot(eq, a, b):
+    return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
+
+
+def route(h, router, k: int, renormalise: bool = False):
+    """``h (..., D)`` (after the layer's norm), ``router (D, total)`` → the
+    chosen experts ``(..., K)``, ids over ``total``, and their weights
+    ``(..., K)`` in float32: the softmax probabilities, divided by their sum
+    where ``renormalise``. ``top_k`` breaks a tie toward the lower expert
+    index and returns exactly K."""
+    with jax.named_scope("router"):
+        p = jax.nn.softmax(_dot("...d,de->...e", h, router), axis=-1)
+        top_p, top_e = jax.lax.top_k(p, k)
+        if renormalise:
+            top_p = top_p / top_p.sum(axis=-1, keepdims=True)
+        return top_e, top_p
+
+
+def gate_matrix(top_e, top_p, held: int, first_held: int = 0):
+    """``P (..., held)``: each row's weights at the columns of its chosen
+    experts that are held here, zero elsewhere (an expert held elsewhere has
+    no column: ``one_hot`` of an index out of range is zero)."""
+    with jax.named_scope("router"):
+        local = top_e - first_held if first_held else top_e
+        chosen = jax.nn.one_hot(local, held, dtype=jnp.float32)
+        return (chosen * top_p[..., None]).sum(axis=-2)
+
+
+def dense(h, gate, w_gate, w_up, w_down):
+    """``(silu(h W_gate) * h W_up * P) W_down`` over the held experts:
+    ``h (..., D)``, ``gate`` = ``gate_matrix``'s ``P (..., held)``, weights
+    ``(held, D, F)`` and ``(held, F, D)``. Returns ``(..., D)`` in ``h``'s
+    dtype."""
+    with jax.named_scope("experts"):
+        g = _dot("...d,edf->...ef", h, w_gate)
+        u = _dot("...d,edf->...ef", h, w_up)
+        a = (jax.nn.silu(g) * u * gate[..., None]).astype(h.dtype)
+        return _dot("...ef,efd->...d", a, w_down).astype(h.dtype)
+
+
+def routed(h, top_e, top_p, w_gate, w_up, w_down, first_held: int = 0):
+    """The same sum with each held expert multiplying only the rows that
+    chose it. ``h (T, D)``, ``top_e``, ``top_p (T, K)``. The ``T x K`` (row,
+    pick) pairs are sorted by held expert, pairs of experts held elsewhere
+    last and outside every group: what ``ragged_dot`` leaves in their rows
+    is not defined on every backend, so they are zeroed by hand. Each
+    pair's output is weighted, brought back to its row's place and the
+    row's ``K`` summed. Returns ``(T, D)`` in ``h``'s dtype."""
+    held = w_gate.shape[0]
+    t, k = top_e.shape
+    with jax.named_scope("experts"):
+        local = (top_e - first_held).reshape(-1)
+        here = (local >= 0) & (local < held)
+        group = jnp.where(here, local, held)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+        x = h[order // k]
+        grouped = here[order][:, None]
+        weight = top_p.reshape(-1)[order][:, None]
+        g = jax.lax.ragged_dot(x, w_gate, sizes,
+                               preferred_element_type=jnp.float32)
+        u = jax.lax.ragged_dot(x, w_up, sizes,
+                               preferred_element_type=jnp.float32)
+        a = jnp.where(grouped, jax.nn.silu(g) * u * weight,
+                      0.0).astype(h.dtype)
+        y = jnp.where(grouped, jax.lax.ragged_dot(
+            a, w_down, sizes, preferred_element_type=jnp.float32), 0.0)
+        back = jnp.argsort(order)
+        return y[back].reshape(t, k, -1).sum(axis=1).astype(h.dtype)
+
+
+def shared(h, gate_w, w_gate, w_up, w_down):
+    """A shared expert every row passes through, behind a sigmoid gate of its
+    own: ``sigmoid(h w_s) * W_down(silu(W_gate h) * W_up h)``. ``gate_w (D,
+    1)``, weights ``(D, F)`` and ``(F, D)``."""
+    with jax.named_scope("shared_expert"):
+        a = (jax.nn.silu(_dot("...d,df->...f", h, w_gate))
+             * _dot("...d,df->...f", h, w_up)).astype(h.dtype)
+        y = _dot("...f,fd->...d", a, w_down)
+        return (jax.nn.sigmoid(_dot("...d,do->...o", h, gate_w))
+                * y).astype(h.dtype)
+
+
+def load_report(picks: np.ndarray, total: int, held: int,
+                first_held: int = 0) -> dict[str, float]:
+    """A decode step's routing figures from its chosen experts, on the host:
+    ``picks (layers, live slots, K)``, ids over ``total``. Over the experts
+    HELD here: ``experts_touched`` — those with at least one live token —
+    and ``expert_peak_load`` — the fullest one's tokens over the mean load
+    (live × K ÷ ``total``) —, each the mean over the layers; and
+    ``held_picks_share``, the share of the picks that land here (1 for a
+    family that holds every expert)."""
+    layers, live, k = picks.shape
+    local = picks - first_held
+    here = (local >= 0) & (local < held)
+    layer = np.broadcast_to(np.arange(layers)[:, None, None], picks.shape)
+    load = np.bincount((local + held * layer)[here],
+                       minlength=layers * held).reshape(layers, held)
+    return {"experts_touched": float((load > 0).sum(axis=1).mean()),
+            "expert_peak_load": float(load.max(axis=1).mean()
+                                      / (live * k / total)),
+            "held_picks_share": float(here.mean())}

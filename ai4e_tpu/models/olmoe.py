@@ -16,7 +16,8 @@ written from its equations. For hidden ``x``:
   renormalised; ``y = Σ_e p_e · W_down,e(silu(W_gate,e x) ⊙ W_up,e x)``;
 - final ``RMSNorm``, an untied ``lm_head``, greedy argmax on the device.
 
-Dispatch, one form for both programs: every expert computes every row and
+Dispatch (``models/experts.py``, its ``dense`` form, which holds all the
+experts here), one form for both programs: every expert computes every row and
 the rows' un-chosen experts are multiplied by zero before the down
 projection, so ``y = (silu(x W_gate) ⊙ x W_up ⊙ P) W_down`` with ``P`` the
 (rows, experts) matrix that holds a row's ``experts_per_token`` weights and
@@ -29,7 +30,8 @@ it spends 8 × the multiplies a sorted, grouped form would (``ROADMAP.md``
 Speed 12 is that form); nothing is dropped either way.
 
 The entry points of an LM family (``runtime/kvcache.py`` ``LMServable``
-calls them by name): ``prefill``, ``decode_step``, ``cache_spec``;
+calls them by name): ``prefill``, ``decode_step``, ``cache_spec`` (K/V
+only: the ``state`` it is handed and hands back is empty);
 ``step_report`` reads what ``decode_step`` appends to its ids. The K/V
 pool, the attention over it and its writes are ``ops/kv_pool.py``'s.
 Weights and cache are ``dtype`` (bfloat16 as served), accumulation float32.
@@ -45,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import kv_pool
+from . import experts as expert_layer
 
 
 @partial(jax.jit, static_argnums=(1, 2, 3, 4))
@@ -162,23 +165,15 @@ class _OlmoeLayer(nn.Module):
     def route(self, h):
         """``h (..., D)`` (after ``norm_post``) → the chosen experts
         ``(..., K)`` and ``P (..., E)``: each row's K un-normalised weights
-        at its experts' columns, zero elsewhere. ``top_k`` breaks a tie
-        toward the lower expert index and returns exactly K."""
-        with jax.named_scope("router"):
-            p = jax.nn.softmax(_dot("...d,de->...e", h, self.router), axis=-1)
-            top_p, top_e = jax.lax.top_k(p, self.experts_per_token)
-            chosen = jax.nn.one_hot(top_e, self.experts, dtype=jnp.float32)
-            return top_e, (chosen * top_p[..., None]).sum(axis=-2)
+        at its experts' columns, zero elsewhere (``models/experts.py``)."""
+        top_e, top_p = expert_layer.route(h, self.router, self.experts_per_token)
+        return top_e, expert_layer.gate_matrix(top_e, top_p, self.experts)
 
     def _moe(self, x):
         h = rms_norm(x, self.norm_post, self.eps)
         top_e, gate = self.route(h)
-        with jax.named_scope("experts"):
-            g = _dot("...d,edf->...ef", h, self.w_gate)
-            u = _dot("...d,edf->...ef", h, self.w_up)
-            a = (jax.nn.silu(g) * u * gate[..., None]).astype(self.dtype)
-            y = _dot("...ef,efd->...d", a, self.w_down).astype(self.dtype)
-        return x + y, top_e
+        return x + expert_layer.dense(h, gate, self.w_gate, self.w_up,
+                                      self.w_down), top_e
 
     def prefill(self, x, mask):
         """x: (B, P, D); mask: (B, P) valid-token mask. Returns
@@ -239,8 +234,9 @@ class OlmoeLM(nn.Module):
 
     @nn.nowrap
     def cache_spec(self):
-        """``((layers, heads, head_dim), dtype)`` of the K/V pool."""
-        return (self.depth, self.heads, self.dim // self.heads), self.dtype
+        """What a slot holds (``kv_pool.SlotSpec``): K/V of every layer."""
+        return kv_pool.SlotSpec(
+            (self.depth, self.heads, self.dim // self.heads), self.dtype)
 
     def _logits(self, h):
         with jax.named_scope("head"):
@@ -275,9 +271,11 @@ class OlmoeLM(nn.Module):
         h, k, v = self._prefill(tokens, length)
         last = jnp.take_along_axis(
             h, (length - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-        return jnp.argmax(self._logits(last), axis=-1).astype(jnp.int32), k, v
+        return (jnp.argmax(self._logits(last), axis=-1).astype(jnp.int32),
+                k, v, {})
 
-    def decode_step(self, tokens, k_cache, v_cache, position, bound=None):
+    def decode_step(self, tokens, k_cache, v_cache, state, position,
+                    bound=None):
         """One token for every slot of the pool. Attention reads the cached
         positions ``< bound`` (a Python int, static under jit; default the
         whole cache), which must be ``>=`` the largest position of a slot
@@ -286,7 +284,7 @@ class OlmoeLM(nn.Module):
                                                   position, bound)
         ids = jnp.argmax(self._logits(h), axis=-1).astype(jnp.int32)
         return (jnp.concatenate([ids, experts.astype(jnp.int32).reshape(-1)]),
-                k_cache, v_cache)
+                k_cache, v_cache, state)
 
     # Logits, for tests only: the serving programs ship ids.
 
@@ -324,14 +322,8 @@ class OlmoeLM(nn.Module):
         if not live.size:
             return {}
         picks = extra.reshape(self.depth, -1, self.experts_per_token)[:, live]
-        picks = picks + self.experts * np.arange(self.depth)[:, None, None]
-        load = np.bincount(picks.ravel(),
-                           minlength=self.depth * self.experts
-                           ).reshape(self.depth, self.experts)
-        mean_load = live.size * self.experts_per_token / self.experts
-        return {"experts_touched": float((load > 0).sum(axis=1).mean()),
-                "expert_peak_load": float(load.max(axis=1).mean()
-                                          / mean_load)}
+        report = expert_layer.load_report(picks, self.experts, self.experts)
+        return {name: report[name] for name in self.step_report_series}
 
 
 def create_olmoe_lm(rng=None, vocab_size: int = 512, dim: int = 64,

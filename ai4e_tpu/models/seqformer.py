@@ -166,11 +166,12 @@ class SeqFormerLM(nn.Module):
     points, applied via ``method=``:
 
     - ``prefill(tokens (B, P), length (B,))`` → ``(next-token ids (B,),
-      k, v)`` — k/v the prompt's blocks (``kv_pool.prompt_block``), which
-      the decode runtime (``runtime/kvcache.py``) inserts into a slot of
-      the pool;
-    - ``decode_step(tokens (S,), k, v, position (S,), bound=None)`` →
-      ``(next-token ids (S,), k, v)`` — k/v the pool (``ops/kv_pool.py``):
+      k, v, state)`` — k/v the prompt's blocks (``kv_pool.prompt_block``),
+      which the decode runtime (``runtime/kvcache.py``) inserts into a slot
+      of the pool; ``state`` what else a slot holds: nothing here, ``{}``;
+    - ``decode_step(tokens (S,), k, v, state, position (S,), bound=None)``
+      → ``(next-token ids (S,), k, v, state)`` — k/v the pool
+      (``ops/kv_pool.py``):
       ONE token for every slot of it per call, inactive slots riding along
       masked (their cache rows are garbage a later prefill overwrites).
       Every layer reads a slot as far as it has written
@@ -200,8 +201,9 @@ class SeqFormerLM(nn.Module):
 
     @nn.nowrap
     def cache_spec(self):
-        """``((layers, heads, head_dim), dtype)`` of the K/V pool."""
-        return (self.depth, self.heads, self.dim // self.heads), jnp.float32
+        """What a slot holds (``kv_pool.SlotSpec``): K/V of every layer."""
+        return kv_pool.SlotSpec(
+            (self.depth, self.heads, self.dim // self.heads), jnp.float32)
 
     def _logits(self, h):
         # Tied embedding head: attend() reuses the embedding matrix, so
@@ -226,7 +228,8 @@ class SeqFormerLM(nn.Module):
                 axis=1)[:, 0]
             next_token = jnp.argmax(self._logits(last),
                                     axis=-1).astype(jnp.int32)
-        return next_token, kv_pool.prompt_block(ks), kv_pool.prompt_block(vs)
+        return (next_token, kv_pool.prompt_block(ks),
+                kv_pool.prompt_block(vs), {})
 
     def _step(self, tokens, k_cache, v_cache, position, bound):
         with jax.named_scope("embedding"):
@@ -241,13 +244,14 @@ class SeqFormerLM(nn.Module):
                                               v_rows, position)
         return h, k_cache, v_cache
 
-    def decode_step(self, tokens, k_cache, v_cache, position, bound=None):
+    def decode_step(self, tokens, k_cache, v_cache, state, position,
+                    bound=None):
         h, k_cache, v_cache = self._step(tokens, k_cache, v_cache, position,
                                          bound)
         with jax.named_scope("head"):
             next_token = jnp.argmax(self._logits(h),
                                     axis=-1).astype(jnp.int32)
-        return next_token, k_cache, v_cache
+        return next_token, k_cache, v_cache, state
 
     def decode_logits(self, tokens, k_cache, v_cache, position, bound=None):
         """``decode_step`` with the logits in place of their argmax: for

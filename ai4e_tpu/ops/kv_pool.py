@@ -2,13 +2,16 @@
 
 One preallocated buffer per tensor::
 
-    k, v : (layers, slots, max_len, heads * head_dim)
+    k, v : (layers, slots, max_len, kv_heads * head_dim)
 
 A slot is a row of it (``runtime/decode.SlotPool`` hands slots out; the
 device never reallocates per request), and one position of a slot is one
-contiguous row of ``heads x head_dim`` elements: whole lane tiles (1,024
-float32 or 2,048 bfloat16 in the configurations served — 4 KB either way),
-``max_len`` on the sublanes, nothing padded. A step writes a position as
+contiguous row of ``kv_heads x head_dim`` elements: whole lane tiles (1,024
+float32, 2,048 or — two K/V heads under sixteen query heads — 512 bfloat16
+in the configurations served: 4 KB or 1 KB), ``max_len`` on the sublanes,
+nothing padded. ``layers`` counts the layers that keep K/V: a family whose
+other layers keep a fixed-size state a slot declares that beside it
+(``SlotSpec.state``; ``ops/state_pool.py`` holds it). A step writes a position as
 that one row, and a kernel can take blocks of positions straight from the
 pool; with ``head_dim`` minor (64 wide in float32) the chip laid ``max_len``
 on the lanes, a position was a column through thousands of tiles, and no
@@ -36,17 +39,33 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from typing import Any, NamedTuple
+
 from .pallas.decode_attention import pooled_attention
 
 # What one grid step of the decode read fetches of each tensor: 256
-# positions of a 4 KB row. Smaller, and the grid's ~0.35 us a step shows;
-# larger, and a short sequence pays for positions it has not written.
+# positions of a 4 KB row, 1,024 of a 1 KB one. Smaller, and the grid's
+# ~0.35 us a step shows; larger, and a short sequence pays for positions it
+# has not written.
 READ_BLOCK_BYTES = 1 << 20
 
 
+class SlotSpec(NamedTuple):
+    """Everything one slot of a family's cache holds — what its
+    ``cache_spec()`` declares and ``runtime/kvcache.py`` allocates: the K/V
+    of ``kv`` = (layers that keep K/V, K/V heads, head_dim) in ``dtype``,
+    and ``state``: fixed-size tensors, each ``(name, shape a slot, dtype)``
+    (``ops/state_pool.py``); none for a family whose every layer keeps
+    K/V."""
+
+    kv: tuple
+    dtype: Any
+    state: tuple = ()
+
+
 def pool_shape(spec: tuple, slots: int, max_len: int) -> tuple:
-    """Shape of each pool tensor for a model whose ``cache_spec()`` gives
-    ``spec = (layers, heads, head_dim)``."""
+    """Shape of each pool tensor for a model whose ``cache_spec().kv`` is
+    ``spec = (layers, kv_heads, head_dim)``."""
     layers, heads, head_dim = spec
     return layers, slots, max_len, heads * head_dim
 
@@ -83,15 +102,24 @@ def _dot(eq, a, b):
 
 
 def prefill_attention(q, k, v, mask):
-    """Materialised causal attention over a padded prompt. q, k, v:
-    (B, P, H, hd); mask: (B, P), True on real tokens. Float32 scores and
+    """Materialised causal attention over a padded prompt. q: (B, P, H,
+    hd); k, v: (B, P, KVH, hd), query head ``h`` reading K/V head ``h // (H
+    // KVH)``; mask: (B, P), True on real tokens. Float32 scores and
     softmax, the weights cast to ``v``'s dtype for the value product.
     Returns (B, P, H, hd) in ``q``'s dtype."""
     p = q.shape[1]
     with jax.named_scope("attention"):
-        scores = _dot("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
         allowed = (jnp.tril(jnp.ones((p, p), bool))[None, None]
                    & mask[:, None, None, :])
+        if q.shape[2] != k.shape[2]:
+            grouped = (*q.shape[:2], k.shape[2], -1, q.shape[3])
+            scores = _dot("bqhgd,bkhd->bhgqk", q.reshape(grouped),
+                          k) / np.sqrt(q.shape[-1])
+            w = jax.nn.softmax(jnp.where(allowed[:, :, None], scores, -1e30),
+                               axis=-1)
+            return _dot("bhgqk,bkhd->bqhgd", w.astype(v.dtype),
+                        v).reshape(q.shape).astype(q.dtype)
+        scores = _dot("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
         w = jax.nn.softmax(jnp.where(allowed, scores, -1e30), axis=-1)
         return _dot("bhqk,bkhd->bqhd", w.astype(v.dtype), v).astype(q.dtype)
 
@@ -119,8 +147,9 @@ def insert_block(k_pool, v_pool, k_block, v_block, slot):
 def decode_attention(q, k_new, v_new, k_pool, v_pool, layer: int, position,
                      bound: int | None = None, interpret: bool | None = None):
     """One layer's attention of one decode step: one new token per slot
-    against the pool. q, k_new, v_new: (S, H, hd) — the new token's;
-    k_pool, v_pool: the pool, read and never rewritten: a slot's positions
+    against the pool. q: (S, H, hd), k_new, v_new: (S, KVH, hd) — the new
+    token's, query head ``h`` reading K/V head ``h // (H // KVH)``; k_pool,
+    v_pool: the pool, read and never rewritten: a slot's positions
     ``< position`` hold its sequence so far; position: (S,) — the cache
     index the new token belongs at. The new token's own key and value
     enter the softmax as one more term beside the cached ones, so

@@ -26,7 +26,13 @@ becomes a block-diagonal ``(heads, row)`` matrix (head ``h`` holds ``q`` on
 its own lanes, zero elsewhere) and both products run on the MXU with the
 heads on the sublanes: ``scores (heads, block) = q_bd . k^T`` contracts the
 whole row, ``acc (heads, row) += p . v`` gives every head every lane, and
-the lanes a head does not own are dropped at the end. An online softmax
+the lanes a head does not own are dropped at the end. Grouped heads are the
+same matrix with more rows than the pool's row has heads: the row holds
+``kv_heads x head_dim`` lanes, the ``group = heads / kv_heads`` query heads
+of one K/V head all lie on that head's lanes (rows ``g * group .. (g + 1) *
+group - 1`` of ``q_bd``), and ``q`` and the output travel as ``(group,
+row)`` — line ``j`` holds the ``j``-th query head of every K/V head, each
+on its K/V head's lanes. One query head a K/V head is ``group = 1``. An online softmax
 (running max and sum a head, a float32 accumulator) carries a slot across
 its blocks in VMEM scratch. Float32 operands multiply at
 ``Precision.HIGHEST`` (the MXU's default would round them to bfloat16);
@@ -94,10 +100,12 @@ def _slot_index(s, b, plan, layer):
 def _kernel(plan_ref, layer_ref, q_ref, k_new_ref, v_new_ref, k_ref, v_ref,
             out_ref, q_bd, acc, m, l, *, block: int, head_dim: int,
             scale: float):
-    # q_ref, k_new_ref, v_new_ref, out_ref: (1, row) — the slot's new
-    # token; k_ref, v_ref: (block, row). Scratch, carried across a slot's
-    # blocks: q_bd, acc (heads, row); m, l (heads, 1).
+    # k_new_ref, v_new_ref: (1, row) — the slot's new token; q_ref,
+    # out_ref: (group, row), its query heads; k_ref, v_ref: (block, row).
+    # Scratch, carried across a slot's blocks: q_bd, acc (heads, row); m, l
+    # (heads, 1).
     heads, row = q_bd.shape
+    group = q_ref.shape[0]
     # program_id is read at the top level: inside a pl.when branch it
     # escapes the trace in the interpreter (flash_attention.py).
     s, b = pl.program_id(0), pl.program_id(1)
@@ -107,14 +115,18 @@ def _kernel(plan_ref, layer_ref, q_ref, k_new_ref, v_new_ref, k_ref, v_ref,
         """(heads, row), True where the lane is one of the head's own.
         Built where it is used: a step over a dead block runs none of it."""
         lane = jax.lax.broadcasted_iota(jnp.int32, (heads, row), 1)
-        first = jax.lax.broadcasted_iota(jnp.int32, (heads, row),
-                                         0) * head_dim
+        head = jax.lax.broadcasted_iota(jnp.int32, (heads, row), 0)
+        if group > 1:   # rows g * group .. (g + 1) * group - 1: K/V head g
+            head = head // group
+        first = head * head_dim
         return (lane >= first) & (lane < first + head_dim)
 
     @pl.when(b == 0)
     def _init():
-        q_bd[...] = jnp.where(own_lanes(), q_ref[...].astype(jnp.float32),
-                              0.0).astype(q_bd.dtype)
+        q = q_ref[...].astype(jnp.float32)
+        if group > 1:   # K/V head g's query heads: rows g * group + j
+            q = jnp.concatenate([q] * (heads // group), axis=0)
+        q_bd[...] = jnp.where(own_lanes(), q, 0.0).astype(q_bd.dtype)
         acc[...] = jnp.zeros_like(acc)
         m[...] = jnp.full_like(m, NEG_INF)
         l[...] = jnp.zeros_like(l)
@@ -172,9 +184,14 @@ def _kernel(plan_ref, layer_ref, q_ref, k_new_ref, v_new_ref, k_ref, v_ref,
         w_pool, w_own = jnp.exp(m[...] - top), jnp.exp(own - top)
         rows = ((acc[...] * w_pool + w_own * v_new)
                 / (l[...] * w_pool + w_own))                # (heads, row)
-        # each head keeps its own lanes of its row
-        out_ref[...] = jnp.where(own_lanes(), rows, 0.0).sum(
-            axis=0, keepdims=True).astype(out_ref.dtype)
+        # each head keeps its own lanes of its row; a K/V head's lanes
+        # hold its ``group`` query heads, one a line
+        rows = jnp.where(own_lanes(), rows, 0.0)
+        if group == 1:
+            out = rows.sum(axis=0, keepdims=True)
+        else:
+            out = sum(rows[g:g + group] for g in range(0, heads, group))
+        out_ref[...] = out.astype(out_ref.dtype)
 
 
 @partial(jax.jit, static_argnames=("heads", "bound", "block", "interpret"))
@@ -182,29 +199,42 @@ def _pooled(q, k_new, v_new, k_pool, v_pool, layer, position, *, heads: int,
             bound: int, block: int, interpret: bool):
     """Jitted on its own so that the layers of a step program share one
     traced and lowered kernel: ``layer`` is a value, not a constant."""
-    slots, row = q.shape
+    slots, row = k_new.shape
+    head_dim = q.shape[1] // heads
+    kv_heads = row // head_dim
+    group = heads // kv_heads
+    # (slots, heads * head_dim) -> (slots, group, row): line j holds query
+    # head g * group + j of every K/V head g, on g's lanes. One head a K/V
+    # head: nothing moves, and the program is what it was before groups.
+    if group > 1:
+        q = q.reshape(slots, kv_heads, group, head_dim).swapaxes(1, 2)
+    q = q.reshape(slots, group, row)
     plan = block_plan(position, bound, block)
     pool = pl.BlockSpec((None, None, block, row), _pool_index)
     per_slot = pl.BlockSpec((None, 1, row), _slot_index)
-    return pl.pallas_call(
-        partial(_kernel, block=block, head_dim=row // heads,
-                scale=float((row // heads) ** -0.5)),
+    per_group = pl.BlockSpec((None, group, row), _slot_index)
+    out = pl.pallas_call(
+        partial(_kernel, block=block, head_dim=head_dim,
+                scale=float(head_dim ** -0.5)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(slots, -(-bound // block)),
-            in_specs=[per_slot, per_slot, per_slot, pool, pool],
-            out_specs=per_slot,
+            in_specs=[per_group, per_slot, per_slot, pool, pool],
+            out_specs=per_group,
             scratch_shapes=[pltpu.VMEM((heads, row), q.dtype),
                             pltpu.VMEM((heads, row), jnp.float32),
                             pltpu.VMEM((heads, 1), jnp.float32),
                             pltpu.VMEM((heads, 1), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((slots, 1, row), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((slots, group, row), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="decode_attention",
-    )(plan, layer.reshape(1), q[:, None], k_new[:, None], v_new[:, None],
-      k_pool, v_pool)[:, 0]
+    )(plan, layer.reshape(1), q, k_new[:, None], v_new[:, None],
+      k_pool, v_pool)
+    if group > 1:
+        out = out.reshape(slots, group, kv_heads, head_dim).swapaxes(1, 2)
+    return out.reshape(slots, heads * head_dim)
 
 
 def pooled_attention(q, k_new, v_new, k_pool, v_pool, layer, position, *,
@@ -213,14 +243,19 @@ def pooled_attention(q, k_new, v_new, k_pool, v_pool, layer, position, *,
     """Attention of one new token a slot over ``layer``'s cached positions
     ``< min(position[slot], bound)`` and the new token itself.
 
-    q, k_new, v_new: (slots, row) — the new token's query, key and value,
-    ``row = heads * head_dim``, q and k_new in the pool's dtype; k_pool,
-    v_pool: (layers, slots, max_len, row), whole; layer: an int or an
+    k_new, v_new: (slots, row) — the new token's key and value, ``row =
+    kv_heads * head_dim``; q: (slots, heads * head_dim), its query heads,
+    ``heads`` a multiple of the row's K/V heads and query head ``h`` reading
+    K/V head ``h // (heads // kv_heads)``; q and k_new in the pool's dtype;
+    k_pool, v_pool: (layers, slots, max_len, row), whole; layer: an int or an
     int32 scalar; position: (slots,) int32; ``block``: positions a grid
-    step fetches, at most ``max_len``. Returns (slots, row) in ``q``'s
-    dtype: the softmax over [cached keys, the new key] of each head, times
+    step fetches, at most ``max_len``. Returns q's shape and dtype: the softmax over [cached keys, the new key] of each head, times
     the values. A slot at position 0 reads nothing of the pool and returns
     its new value."""
+    head_dim = q.shape[1] // heads
+    if k_new.shape[1] % head_dim or heads % (k_new.shape[1] // head_dim):
+        raise ValueError(f"{heads} query heads of {head_dim} do not group "
+                         f"onto a row of {k_new.shape[1]}")
     if not 0 < bound <= k_pool.shape[2] or not 0 < block <= k_pool.shape[2]:
         raise ValueError(f"bound {bound} and block {block} must lie within "
                          f"the pool's {k_pool.shape[2]} positions")

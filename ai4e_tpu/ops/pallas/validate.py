@@ -119,32 +119,42 @@ def validate_kernels(interpret: bool = False) -> dict:
         "ok": bool(err < 1e-5), "max_err": round(err, 7), "vmem_bytes": vmem}
 
     # decode attention over the K/V pool vs a plain float32 softmax — the
-    # two served rows (16 heads of 64 in float32, of 128 in bfloat16; 4 KB
-    # either way, so blocks of 256 positions), one layer of a pool of eight
-    # slots at ragged positions: dead, a block's edges, the whole length.
-    position = np.asarray([0, 1, 255, 256, 257, 600, 1023, 1024], np.int32)
-    for dtype, head_dim, tol in (("float32", 64, 1e-4),
-                                 ("bfloat16", 128, 0.04)):
-        heads, length = 16, 1024
-        shape = kv_pool.pool_shape((1, heads, head_dim), len(position),
+    # served rows (16 heads of 64 in float32, of 128 in bfloat16: 4 KB
+    # either way, so blocks of 256 positions; 16 query heads on 2 K/V heads
+    # of 256 in bfloat16: a 1 KB row, blocks of 1,024), one layer of a pool
+    # of eight slots at ragged positions: dead, a block's edges, the whole
+    # length.
+    for name, dtype, kv_heads, head_dim, length, tol in (
+            ("float32", "float32", 16, 64, 1024, 1e-4),
+            ("bfloat16", "bfloat16", 16, 128, 1024, 0.04),
+            ("grouped_bfloat16", "bfloat16", 2, 256, 3072, 0.04)):
+        heads = 16
+        edge = kv_pool.read_block((1, 1, length, kv_heads * head_dim), dtype)
+        position = np.asarray([0, 1, edge - 1, edge, edge + 1, 600,
+                               length - 1, length], np.int32)
+        shape = kv_pool.pool_shape((1, kv_heads, head_dim), len(position),
                                    length)
         k_pool, v_pool = (jax.numpy.asarray(rng.standard_normal(shape), dtype)
                           for _ in range(2))
         q, k_new, v_new = (
             jax.numpy.asarray(
-                rng.standard_normal((len(position), heads, head_dim)), dtype)
-            for _ in range(3))
+                rng.standard_normal((len(position), n, head_dim)), dtype)
+            for n in (heads, kv_heads, kv_heads))
         got = np.asarray(jax.jit(
             lambda *a: kv_pool.decode_attention(
                 *a, 0, position, interpret=interpret)
         )(q, k_new, v_new, k_pool, v_pool), np.float32)
         f32 = [np.asarray(x, np.float32) for x in (q, k_new, v_new)]
         cached = [np.asarray(x, np.float32)[0].reshape(
-            len(position), length, heads, head_dim) for x in (k_pool, v_pool)]
+            len(position), length, kv_heads, head_dim)
+            for x in (k_pool, v_pool)]
         err = 0.0
         for slot, p in enumerate(position):
-            keys = np.concatenate([cached[0][slot, :p], f32[1][slot][None]])
-            values = np.concatenate([cached[1][slot, :p], f32[2][slot][None]])
+            # each K/V head under its heads // kv_heads query heads
+            keys, values = (np.repeat(
+                np.concatenate([c[slot, :p], new[slot][None]]),
+                heads // kv_heads, axis=1)
+                for c, new in zip(cached, f32[1:]))
             scores = np.einsum("lhd,hd->hl", keys, f32[0][slot]) / np.sqrt(
                 head_dim)
             w = np.exp(scores - scores.max(-1, keepdims=True))
@@ -155,7 +165,7 @@ def validate_kernels(interpret: bool = False) -> dict:
         vmem = decode_attention_vmem_bytes(block, heads, shape[-1],
                                            np.dtype(dtype).itemsize)
         assert vmem <= VMEM_BUDGET_BYTES, f"decode attention VMEM {vmem}"
-        results[f"decode_attention_{dtype}"] = {
+        results[f"decode_attention_{name}"] = {
             "ok": bool(err < tol), "max_err": round(err, 6),
             "vmem_bytes": vmem}
 

@@ -198,7 +198,10 @@ class DecodeEngine:
       the step just run covered — observed as ``ai4e_decode_step_bound``;
       a backend without it covers ``max_len`` — and ``step_attended``
       (attribute): the K/V positions that step read, counted as attended;
-      a backend without it reads ``slots x`` its bound;
+      a backend without it reads ``slots x`` its bound; and
+      ``step_cache_bytes`` (attribute, ``{kind: bytes}``): what that step
+      read and wrote of each kind of cache a slot holds (K/V rows, fixed-size
+      state) — counted as ``ai4e_decode_cache_bytes_total{kind}``;
     - optionally ``bound_for(longest)``: the bound a step whose largest
       live position is ``longest`` will run — the ``bound=`` of the
       ``ai4e.decode.tick`` region, which opens before the step;
@@ -276,6 +279,12 @@ class DecodeEngine:
             "K/V positions per decode step: live (sum of position + 1 over "
             "active slots) and attended (what the step's attention read: "
             "the backend's count, else slots x the step's bound)")
+        self._cache_bytes = self.metrics.counter(
+            "ai4e_decode_cache_bytes_total",
+            "Bytes of the slots' cache a decode step read and wrote, by "
+            "kind: kv (the K/V rows its attention read and the row a live "
+            "slot wrote) and state (fixed-size per-slot state, read and "
+            "written whole), as the backend counts them")
         self._occupancy = self.metrics.gauge(
             "ai4e_decode_slot_occupancy",
             "Occupied KV-cache slots / total slots per model")
@@ -619,6 +628,10 @@ class DecodeEngine:
                     getattr(self.backend, "step_attended",
                             self.pool.slots * bound),
                     model=self._model, kind="attended")
+                for kind, nbytes in getattr(self.backend, "step_cache_bytes",
+                                            {}).items():
+                    self._cache_bytes.inc(nbytes, model=self._model,
+                                          kind=kind)
                 for name, value in getattr(self.backend, "step_report",
                                            {}).items():
                     self._step_report[name].observe(value, model=self._model)

@@ -626,11 +626,32 @@ def build_olmoe_lm(name: str = "lm", vocab_size: int = 512,
                       vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
 
 
+def build_qwen3_next_lm(name: str = "lm", vocab_size: int = 512,
+                        max_len: int = 256, eos_id: int | None = None,
+                        rng=None, dtype: str = "bfloat16", **dims):
+    """The hybrid decoder (``models/qwen3_next.py`` ``Qwen3NextLM``): Gated
+    DeltaNet layers with a recurrent state a slot, a gated grouped-query
+    attention layer every ``full_interval``-th, top-K of E experts of which
+    this process holds ``experts_held`` from ``first_expert``, a shared
+    expert, untied head, bfloat16 weights and K/V. ``dims``: the model's
+    fields (``dim``, ``depth``, ``heads``, ``kv_heads``, ``head_dim``,
+    ``rotary_dim``, ``lin_k_heads``, ``lin_v_heads``, ``lin_dim``, ``conv``,
+    ``experts``, ...); a key the family does not know is an error, not a
+    default."""
+    from ..models.qwen3_next import create_qwen3_next_lm
+    from .kvcache import LMServable
+    model, params = create_qwen3_next_lm(rng=rng, vocab_size=vocab_size,
+                                         dtype=dtype, **dims)
+    return LMServable(name=name, model=model, params=params,
+                      vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
+
+
 # LM families ride the decode engine (``runtime/decode.py``), never the
 # MicroBatcher: ``cli`` tells them from the batch families by this table.
 LM_FAMILIES = {
     "seqformer-lm": build_seqformer_lm,
     "olmoe": build_olmoe_lm,
+    "qwen3-next": build_qwen3_next_lm,
 }
 
 

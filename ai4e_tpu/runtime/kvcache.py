@@ -1,8 +1,13 @@
 """Pooled KV-cache decode runtime — the device side of continuous
 batching (``runtime/decode.py`` owns the scheduling).
 
-The cache is ONE preallocated slot-pool buffer per tensor
-(``ops/kv_pool.py`` owns its layout and every operation on it), keyed by
+The cache is ONE preallocated slot-pool buffer per tensor: K and V of
+the layers that keep them (``ops/kv_pool.py`` owns that layout and every
+operation on it) and, for a family whose other layers keep a fixed-size
+state a sequence, one tensor a state it declares (``ops/state_pool.py``).
+What a slot holds is the family's to say (``cache_spec()`` →
+``kv_pool.SlotSpec``) and this runtime's to hold: it allocates, inserts,
+donates, resets and counts both kinds alike and knows no family. Keyed by
 ``(model, params_version)`` — a hot weight reload bumps the version and
 the engine invalidates (``reset_cache``) then re-prefills, the same key
 contract as rescache (a KV block computed under old weights is a stale
@@ -18,8 +23,10 @@ discipline ``ModelRuntime.warmup`` applies to batch buckets):
   prompt bucket (``ladder.DECODE_PROMPT_BUCKETS``: prompts pad to the
   smallest fitting bucket, so XLA compiles ``len(buckets)`` prefill
   programs, not one per prompt length);
-- **insert** — a prefill's KV block written into a slot's rows (slot
-  index is a traced scalar: one program per bucket, any slot);
+- **insert** — a prefill's KV block written into a slot's rows, and the
+  state it reached after the prompt's ``length`` tokens into the slot's
+  index of every state tensor (slot index is a traced scalar: one program
+  per bucket, any slot);
 - **step** — one decode step over the WHOLE pool: every slot advances
   one token (inactive slots ride along masked; their rows are garbage a
   later prefill overwrites). The layers read the pool as it came in and
@@ -49,7 +56,7 @@ from typing import Any
 import numpy as np
 
 from ..observability.tracing import device_trace
-from ..ops import kv_pool
+from ..ops import kv_pool, state_pool
 
 log = logging.getLogger("ai4e_tpu.kvcache")
 
@@ -64,13 +71,15 @@ class LMServable:
     name: str
     # A flax module with the LM entry points, called by name:
     # ``prefill(tokens (B, P), length (B,))`` → ids, K block, V block
-    # (``kv_pool.prompt_block``); ``decode_step(tokens (S,), k, v, position
-    # (S,), bound)`` → ids (S,) then, optionally, more int32s the model's
-    # own ``step_report(extra, active)`` turns into per-step figures
-    # (declared by its ``step_report_series``); k, v — attending cached
+    # (``kv_pool.prompt_block``), state (``{name: (B, *shape)}`` after
+    # ``length`` tokens; ``{}`` from a family that keeps K/V only);
+    # ``decode_step(tokens (S,), k, v, state, position (S,), bound)`` → ids
+    # (S,) then, optionally, more int32s the model's own
+    # ``step_report(extra, active)`` turns into per-step figures (declared
+    # by its ``step_report_series``); k, v, state — attending cached
     # positions ``< bound`` only (a Python int: one program a value); and
-    # ``cache_spec()`` → ``((layers, heads, head_dim), dtype)`` of the
-    # pool. ``runtime/families.py`` (``LM_FAMILIES``) builds them.
+    # ``cache_spec()`` → ``kv_pool.SlotSpec``: everything a slot holds.
+    # ``runtime/families.py`` (``LM_FAMILIES``) builds them.
     model: Any
     params: Any
     vocab_size: int
@@ -129,6 +138,8 @@ class PagedDecodeRuntime:
              for rung in (3 * self.max_len // 4, self.max_len)}))
         self._k = None
         self._v = None
+        self._state = None
+        self._state_nbytes = 0
         self._donate = donate
         self._programs = None
         # ``hook(phase, seconds)``, installed by the DecodeEngine: told the
@@ -145,6 +156,12 @@ class PagedDecodeRuntime:
         # (``kv_pool.positions_read``): the engine counts them as attended.
         self.step_bound = self.max_len
         self.step_attended = 0
+        # Bytes of each kind of cache the last step moved: ``kv`` — what
+        # its attention read (``step_attended`` rows of K and of V, every
+        # K/V layer) and the one row a live slot wrote; ``state`` — every
+        # state tensor read once and written once (the step is over the
+        # pool: an idle slot's state moves too). The engine counts them.
+        self.step_cache_bytes: dict[str, int] = {}
 
     # -- cache lifecycle ---------------------------------------------------
 
@@ -156,14 +173,20 @@ class PagedDecodeRuntime:
         """``(shape, dtype)`` of each pool tensor: the model's layers, heads
         and head size and its cache dtype, this runtime's slots and
         length."""
-        spec, dtype = self.servable.model.cache_spec()
-        return kv_pool.pool_shape(spec, self.slots, self.max_len), dtype
+        spec = self.servable.model.cache_spec()
+        return kv_pool.pool_shape(spec.kv, self.slots, self.max_len), spec.dtype
+
+    def state_spec(self) -> tuple:
+        """``((name, shape a slot, dtype), ...)`` of what a slot holds
+        beside K/V, as the model declares it; empty for K/V alone."""
+        return tuple(self.servable.model.cache_spec().state)
 
     def cache_nbytes(self) -> int:
-        """Resident bytes of the pooled cache (both tensors) — the
-        number the memory math in docs/streaming.md bounds."""
+        """Resident bytes of the pooled cache (K, V and every state
+        tensor) — the number the memory math in docs/streaming.md bounds."""
         shape, dtype = self.cache_spec()
-        return 2 * int(np.prod(shape)) * np.dtype(dtype).itemsize
+        return (2 * int(np.prod(shape)) * np.dtype(dtype).itemsize
+                + state_pool.nbytes(self.state_spec(), self.slots))
 
     def reset_cache(self) -> None:
         """Drop + reallocate the pooled cache (hot-reload invalidation:
@@ -172,9 +195,11 @@ class PagedDecodeRuntime:
         # The old pool goes first: while it lives, building the new one
         # holds three pool tensors on the device at once, which would be
         # the allocator's peak of the whole worker.
-        self._k = self._v = None
+        self._k = self._v = self._state = None
         self._k = kv_pool.allocate(shape, dtype)
         self._v = kv_pool.allocate(shape, dtype)
+        self._state = state_pool.allocate(self.state_spec(), self.slots)
+        self._state_nbytes = state_pool.nbytes(self.state_spec(), self.slots)
 
     def _ensure(self) -> None:
         if self._k is None:
@@ -189,26 +214,27 @@ class PagedDecodeRuntime:
             # CPU XLA cannot donate (every run would warn); on device
             # backends donation keeps the pool resident exactly once.
             self._donate = jax.default_backend() != "cpu"
-        donate_step = (2, 3) if self._donate else ()
-        donate_insert = (0, 1) if self._donate else ()
+        donate_step = (2, 3, 4) if self._donate else ()
+        donate_insert = (0, 1, 2) if self._donate else ()
 
         def prefill(params, tokens, length):
             return model.apply(params, tokens, length, method="prefill")
 
-        def step(params, tokens, k, v, position, bound):
-            return model.apply(params, tokens, k, v, position, bound,
+        def step(params, tokens, k, v, state, position, bound):
+            return model.apply(params, tokens, k, v, state, position, bound,
                                method="decode_step")
 
         # A wrapper for its name: the trace's module stays ``jit_insert``.
-        def insert(k, v, k_block, v_block, slot):
-            return kv_pool.insert_block(k, v, k_block, v_block, slot)
+        def insert(k, v, state, k_block, v_block, state_block, slot):
+            return (*kv_pool.insert_block(k, v, k_block, v_block, slot),
+                    state_pool.insert(state, state_block, slot))
 
         self._programs = {
             "prefill": jax.jit(prefill),
             # ``bound`` is static: one entry of this jit's cache per rung,
             # so ``_run`` sees a rung that was not warmed as a compile.
             "step": jax.jit(step, donate_argnums=donate_step,
-                            static_argnums=(5,)),
+                            static_argnums=(6,)),
             "insert": jax.jit(insert, donate_argnums=donate_insert),
         }
 
@@ -254,12 +280,13 @@ class PagedDecodeRuntime:
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = tokens
         with device_trace("ai4e.decode.prefill", bucket=bucket, slot=slot):
-            token, k_block, v_block = self._run(
+            token, k_block, v_block, state_block = self._run(
                 "prefill", self.servable.params, padded,
                 np.asarray([n], np.int32))
         with device_trace("ai4e.decode.insert", slot=slot):
-            self._k, self._v = self._run(
-                "insert", self._k, self._v, k_block, v_block, np.int32(slot))
+            self._k, self._v, self._state = self._run(
+                "insert", self._k, self._v, self._state, k_block, v_block,
+                state_block, np.int32(slot))
         return int(token[0])   # waits for the prefill program's run
 
     def bound_for(self, longest: int) -> int:
@@ -285,13 +312,18 @@ class PagedDecodeRuntime:
         self._ensure()
         self.step_bound = self.bound_for(max(
             (p for p, live in zip(positions, active) if live), default=0))
+        shape, dtype = self.cache_spec()
         self.step_attended = kv_pool.positions_read(
-            *self.cache_spec(), positions, active, self.step_bound)
+            shape, dtype, positions, active, self.step_bound)
+        row_bytes = 2 * shape[0] * shape[-1] * np.dtype(dtype).itemsize
+        self.step_cache_bytes = {
+            "kv": row_bytes * (self.step_attended + sum(map(bool, active))),
+            "state": 2 * self._state_nbytes}
         with device_trace("ai4e.decode.dispatch", bound=self.step_bound):
-            out, self._k, self._v = self._run(
+            out, self._k, self._v, self._state = self._run(
                 "step", self.servable.params, np.asarray(tokens, np.int32),
-                self._k, self._v, np.asarray(positions, np.int32),
-                self.step_bound)
+                self._k, self._v, self._state,
+                np.asarray(positions, np.int32), self.step_bound)
         t0 = time.perf_counter()
         with device_trace("ai4e.decode.device_wait"):
             out = np.asarray(out)   # the device's run and the ids' d2h

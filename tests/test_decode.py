@@ -310,7 +310,8 @@ class TestEngineScheduling:
             "ai4e_decode_sequences_total", "ai4e_decode_reprefills_total",
             "ai4e_decode_tick_seconds", "ai4e_decode_queue_wait_seconds",
             "ai4e_decode_step_active_slots", "ai4e_decode_step_bound",
-            "ai4e_decode_kv_positions_total"}
+            "ai4e_decode_kv_positions_total",
+            "ai4e_decode_cache_bytes_total"}
 
     @pytest.mark.parametrize("declared", [
         {"window_fill": ("Share of a layer's window that is live",
@@ -480,12 +481,13 @@ def tiny_lm(request):
         dim=_POOL["heads"] * _POOL["head_dim"], depth=_POOL["depth"],
         heads=_POOL["heads"], **_LM_FAMILIES[request.param])
     model, params = servable.model, servable.params
-    spec, dtype = model.cache_spec()
+    spec, dtype, state = model.cache_spec()
     assert spec == (_POOL["depth"], _POOL["heads"], _POOL["head_dim"])
+    assert state == ()   # these families keep K/V only
 
     def step(params, tokens, k, v, position):
-        return model.apply(params, tokens, k, v, position,
-                           method="decode_step")
+        return model.apply(params, tokens, k, v, {}, position,
+                           method="decode_step")[:3]
 
     def prefill(tokens, length):
         return model.apply(params, tokens, length, method="prefill")
@@ -526,7 +528,7 @@ def _step_over_histories(lm, positions, seed=0):
         history = rng.integers(1, 64, size=p + 1)
         padded = np.zeros((1, length), np.int32)
         padded[0, :p + 1] = history
-        tok, k, v = lm.prefill(padded, np.asarray([p + 1], np.int32))
+        tok, k, v, _ = lm.prefill(padded, np.asarray([p + 1], np.int32))
         k, v = np.asarray(k), np.asarray(v)   # (depth, 1, L, H x hd)
         k0[:, slot, :p] = k[:, 0, :p]
         v0[:, slot, :p] = v[:, 0, :p]

@@ -61,14 +61,15 @@ def lm(request):
     k = rng.standard_normal(shape).astype(dtype)
     v = rng.standard_normal(shape).astype(dtype)
 
-    def program(method):
+    def program(method, *state):
         return jax.jit(
             lambda tokens, k, v, position, bound: model.apply(
-                params, tokens, k, v, position, bound, method=method),
+                params, tokens, k, v, *state, position, bound,
+                method=method),
             static_argnums=(4,))
 
     return SimpleNamespace(
-        runtime=runtime, ids=program("decode_step"),
+        runtime=runtime, ids=program("decode_step", {}),
         logits=program("decode_logits"), pool=(k, v),
         tol=TOLERANCE[np.dtype(dtype).itemsize])
 

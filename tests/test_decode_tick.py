@@ -316,7 +316,8 @@ class TestNamedScopes:
             zeros = np.zeros(3, np.int32)
             return runtime._programs["step"].lower(
                 runtime.servable.params, zeros, runtime._k, runtime._v,
-                zeros, runtime.max_len).as_text(debug_info=debug_info)
+                runtime._state, zeros,
+                runtime.max_len).as_text(debug_info=debug_info)
 
         scoped = tiny_runtime()
         with_scopes = decode_tokens(scoped)

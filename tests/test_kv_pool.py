@@ -392,3 +392,107 @@ def test_decode_kernel_at_the_served_row_widths(dtype, head_dim):
     np.testing.assert_allclose(top, plain_decode_attention(pool, 0, position),
                                rtol=0, atol=pool.tol)
     assert (attend(pool, 0, position, 640)[:4] == top[:4]).all()
+
+
+# -- grouped heads: more query heads than the row has K/V heads ---------------
+
+def _grouped(dtype, heads, kv_heads, head_dim, slots, max_len, seed=31):
+    """A one-layer pool of ``kv_heads`` heads a row and one step's new rows
+    for ``heads`` query heads, as float32 numpy beside the device values."""
+    rng = np.random.default_rng(seed)
+    shape = kv_pool.pool_shape((1, kv_heads, head_dim), slots, max_len)
+    k, v = (jnp.asarray(rng.standard_normal(shape), dtype) for _ in range(2))
+    q, k_new, v_new = (
+        jnp.asarray(rng.standard_normal((slots, n, head_dim)), dtype)
+        for n in (heads, kv_heads, kv_heads))
+    return shape, (q, k_new, v_new, k, v)
+
+
+def _plain_grouped(args, position, kv_heads):
+    """The float32 oracle: query head ``h`` reads K/V head ``h // group``."""
+    q, k_new, v_new, k, v = (np.asarray(a, np.float32) for a in args)
+    slots, heads, head_dim = q.shape
+    group = heads // kv_heads
+    k, v = (a[0].reshape(slots, -1, kv_heads, head_dim) for a in (k, v))
+    out = np.zeros(q.shape, np.float32)
+    for s, p in enumerate(position):
+        for h in range(heads):
+            g = h // group
+            keys = np.concatenate([k[s, :p, g], k_new[s, g][None]])
+            values = np.concatenate([v[s, :p, g], v_new[s, g][None]])
+            out[s, h] = softmax(keys @ q[s, h] / np.sqrt(head_dim)) @ values
+    return out
+
+
+@pytest.mark.parametrize("dtype", list(TOLERANCE))
+@pytest.mark.parametrize("heads, kv_heads", [(16, 2), (8, 8), (16, 16)],
+                         ids=["16on2", "8on8", "16on16"])
+def test_decode_kernel_with_grouped_heads(dtype, heads, kv_heads,
+                                          monkeypatch):
+    """16 query heads on 2 K/V heads (eight a K/V head, laid onto its lanes
+    in the kernel's block-diagonal operand), and one a K/V head at 8 and at
+    16: ragged positions over three blocks a slot against the float32
+    oracle, and a bound below a slot's position cuts at the bound."""
+    head_dim, max_len = 16, 96
+    row = kv_heads * head_dim * jnp.dtype(dtype).itemsize
+    monkeypatch.setattr(kv_pool, "READ_BLOCK_BYTES", 32 * row)
+    position = (0, 1, 31, 32, 33, 95, 96)
+    shape, args = _grouped(dtype, heads, kv_heads, head_dim, len(position),
+                           max_len)
+    assert kv_pool.read_block(shape, dtype) == 32
+    pos = jnp.asarray(position, jnp.int32)
+    got = np.asarray(kv_pool.decode_attention(*args, 0, pos), np.float32)
+    assert got.shape == (len(position), heads, head_dim)
+    np.testing.assert_allclose(got, _plain_grouped(args, position, kv_heads),
+                               rtol=0, atol=TOLERANCE[dtype])
+    cut = np.asarray(kv_pool.decode_attention(*args, 0, pos, 64), np.float32)
+    assert (cut[:5] == got[:5]).all()
+    # the two slots past the bound read their first 64 positions
+    tail = [a[5:] if i < 3 else a[:, 5:] for i, a in enumerate(args)]
+    np.testing.assert_allclose(cut[5:], _plain_grouped(tail, (64, 64),
+                                                       kv_heads),
+                               rtol=0, atol=TOLERANCE[dtype])
+
+
+def test_decode_kernel_at_the_grouped_served_row_width():
+    """16 query heads on 2 K/V heads of 256 in bfloat16 — a row of 1 KB, so
+    by the same 1 MB rule the block is 1,024 positions — over three blocks a
+    slot of a 3,072-position pool: ragged positions against the float32
+    oracle at the top rung, and the same bits at the rung below for the
+    slots it holds."""
+    shape, args = _grouped("bfloat16", 16, 2, 256, 6, 3072)
+    assert shape == (1, 6, 3072, 512)
+    assert kv_pool.read_block(shape, jnp.bfloat16) == 1024
+    position = (0, 1023, 1024, 1025, 3071, 3072)
+    pos = jnp.asarray(position, jnp.int32)
+    top = np.asarray(kv_pool.decode_attention(*args, 0, pos), np.float32)
+    np.testing.assert_allclose(top, _plain_grouped(args, position, 2),
+                               rtol=0, atol=TOLERANCE["bfloat16"])
+    below = np.asarray(kv_pool.decode_attention(*args, 0, pos, 2304),
+                       np.float32)
+    assert (below[:4] == top[:4]).all()
+
+
+@pytest.mark.parametrize("dtype", list(TOLERANCE))
+def test_prefill_attention_with_grouped_heads(dtype):
+    """Four query heads on two K/V heads over a padded prompt: a causal
+    softmax over the real tokens, head ``h`` reading K/V head ``h // 2``."""
+    batch, prompt, heads, kv_heads, head_dim = 2, 6, 4, 2, 8
+    length = (prompt, 3)
+    rng = np.random.default_rng(37)
+    q = jnp.asarray(rng.standard_normal((batch, prompt, heads, head_dim)),
+                    dtype)
+    k, v = (jnp.asarray(rng.standard_normal(
+        (batch, prompt, kv_heads, head_dim)), dtype) for _ in range(2))
+    mask = jnp.arange(prompt)[None, :] < jnp.asarray(length)[:, None]
+    got = np.asarray(kv_pool.prefill_attention(q, k, v, mask), np.float32)
+    assert got.shape == q.shape
+    qf, kf, vf = (np.asarray(a, np.float32) for a in (q, k, v))
+    for b in range(batch):
+        for i in range(length[b]):
+            for h in range(heads):
+                w = softmax(kf[b, :i + 1, h // 2] @ qf[b, i, h]
+                            / np.sqrt(head_dim))
+                np.testing.assert_allclose(
+                    got[b, i, h], w @ vf[b, :i + 1, h // 2], rtol=0,
+                    atol=TOLERANCE[dtype])

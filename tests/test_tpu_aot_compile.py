@@ -182,9 +182,11 @@ def _compile_step(runtime, sharding, bound):
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(decode_attention, "resolve_interpret",
                       lambda kernel, interpret: False)
+        state = {name: _on(sharding, ((runtime.slots, *shape), dtype))
+                 for name, shape, dtype in runtime.state_spec()}
         return runtime._programs["step"].lower(
-            _on(sharding, runtime.servable.params), ints, pool, pool, ints,
-            bound).compile()
+            _on(sharding, runtime.servable.params), ints, pool, pool, state,
+            ints, bound).compile()
 
 
 def _entry_results(compiled):
@@ -313,3 +315,77 @@ def test_olmoe_step_at_the_benchmark_cell_writes_rows_in_place(
                           prefill.temp_size_in_bytes
                           + prefill.output_size_in_bytes)
     assert peak < 15e9, peak
+
+
+@pytest.fixture(scope="module")
+def qnext_cell():
+    from ai4e_tpu.models.qwen3_next import Qwen3NextLM, create_qwen3_next_lm
+    from benchmark.references.qwen3_next import MODEL_KEYS
+    return _benchmark_cell("qwen3-next-80b-a3b.json", create_qwen3_next_lm,
+                           Qwen3NextLM, MODEL_KEYS)
+
+
+@pytest.mark.parametrize("rung", [0, 1])
+def test_qnext_step_at_the_benchmark_cell_moves_no_pool(
+        v5e_sharding, qnext_cell, rung):
+    """The same, for the ``qnext.docqa`` cell
+    (``benchmark/configs/qwen3-next-80b-a3b.json``): a K/V pool of the three
+    full-attention layers only, rows of 1 KB under sixteen query heads (one
+    Mosaic kernel a K/V layer, the grouped-head form), made only by row
+    writes; and beside it the state pool — nine ``f32[32,32,128,128]``
+    tensors and nine convolution tails — every tensor aliased input to
+    output, with temporaries smaller than ONE state tensor: no copy of a
+    state tensor, of the state pool or of the K/V pool exists while the step
+    runs. At the top rung, the whole worker's memory: weights + both pools +
+    the widest prefill's (2,048, and the cache length 3,072 the runtime adds)
+    temporaries and outputs stay under the 15 GB line. About 8 s and 60 s."""
+    from ai4e_tpu.ops import state_pool
+    runtime, spec = qnext_cell
+    assert runtime.step_bounds == (2304, 3072)
+    assert runtime.cache_spec() == ((3, 32, 3072, 512), jnp.bfloat16)
+    state = runtime.state_spec()
+    assert len(state) == 18
+    assert state[0] == ("delta0", (32, 128, 128), jnp.float32)
+    one_state = 32 * 32 * 128 * 128 * 4
+    bound = runtime.step_bounds[rung]
+    compiled = _compile_step(runtime, v5e_sharding, bound)
+    memory = _assert_step_reads_in_place(runtime, compiled, bound, one_state)
+    pools = runtime.cache_nbytes()
+    assert pools == 2 * 3 * 32 * 3072 * 512 * 2 + state_pool.nbytes(state, 32)
+    assert memory.alias_size_in_bytes >= pools
+    # nothing makes a state tensor by a plain copy (a re-layout)
+    state_type = _hlo_type((32, 32, 128, 128), jnp.float32)
+    assert not [r for r in _entry_results(compiled)
+                if r[0].startswith(state_type) and r[1] == "copy"]
+    if bound < runtime.max_len:
+        return
+
+    resident = memory.argument_size_in_bytes   # weights + pools (+ ints)
+    assert 12.0e9 < resident < 12.2e9, resident
+    # the cell's widest bucket, and the cache length the runtime adds
+    assert runtime.max_len == spec["max_len"] == 3072
+    for top in (2048, runtime.max_len):
+        prefill = runtime._programs["prefill"].lower(
+            _on(v5e_sharding, runtime.servable.params),
+            _on(v5e_sharding, ((1, top), jnp.int32)),
+            _on(v5e_sharding, ((1,), jnp.int32))).compile().memory_analysis()
+        peak = resident + max(memory.temp_size_in_bytes,
+                              prefill.temp_size_in_bytes
+                              + prefill.output_size_in_bytes)
+        assert peak < 15e9, (top, peak)
+
+
+def test_decode_kernel_with_grouped_heads_compiles(v5e_sharding):
+    """The decode-attention kernel at the grouped shape alone: sixteen
+    query heads on two K/V heads of 256, blocks of 1,024 positions of a 1 KB
+    row, by Mosaic."""
+    from ai4e_tpu.ops import kv_pool
+    pool = _on(v5e_sharding, ((3, 32, 3072, 512), jnp.bfloat16))
+    q = _on(v5e_sharding, ((32, 16, 256), jnp.bfloat16))
+    new = _on(v5e_sharding, ((32, 2, 256), jnp.bfloat16))
+    ints = _on(v5e_sharding, ((32,), jnp.int32))
+    compiled = _compile(
+        lambda q, k_new, v_new, k, v, position: kv_pool.decode_attention(
+            q, k_new, v_new, k, v, 1, position, 2304, interpret=False),
+        q, new, new, pool, pool, ints)
+    assert "tpu_custom_call" in compiled.as_text()
