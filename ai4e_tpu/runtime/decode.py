@@ -24,13 +24,27 @@ second serving path, beside the batcher, where scheduling happens
   re-prefilled from their token history under the new weights, keeping
   their slots.
 
+The loop runs ONE STEP BEHIND the device, always: step N+1 is launched
+before step N's ids are read, so the device has its next step queued while
+the host fetches, notes tokens, sweeps and admits. What a launch needs but
+the ids — positions, the rung, an end by token budget or cache length — the
+host counts ahead; the ids themselves stay with the backend between steps,
+and only a slot admitted (or re-prefilled) since the last launch is fed from
+the host. A sequence that ends by count is never in the next launch; one
+that ends by ``eos_id``, a cancel, an expiry or a drain while a launched
+step holds it costs that one slot-step, discarded at its fetch and counted.
+
 Slot conservation is THE invariant (tests/test_race_regressions.py):
 a slot is never double-assigned, never leaked, and freed exactly once.
 Every release funnels through ``_retire`` — a single-segment method
 (docs/concurrency.md): the ``done`` guard and the slot release share one
 atomicity segment, and every post-``await`` consumer re-checks ``done``
 before acting on a sequence (the step/prefill awaits are the suspension
-windows a cancel or expiry sweep can slot into).
+windows a cancel or expiry sweep can slot into; so is the window between
+a step's launch and its fetch). A slot whose sequence is retired while a
+launched step still has it live stays busy — parked — until that step is
+fetched or voided: a join is never written under a step launched for the
+slot's previous tenant.
 
 Backpressure: ``pending_count`` at ``max_pending`` → ``submit`` raises
 ``DecodeSaturated`` and the worker answers 503 through the existing
@@ -45,6 +59,7 @@ scheduler.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import inspect
 import logging
 import time
@@ -133,6 +148,63 @@ class SlotPool:
                 f"!= {self.slots}")
 
 
+@dataclass
+class LaunchedStep:
+    """One decode step as its backend launched it. What the backend worked
+    out at the launch travels with the step it describes; ``fetch`` fills in
+    what only the device knew."""
+
+    bound: int                    # positions of every slot the step covers
+    # K/V positions its attention reads; None: ``slots x bound``.
+    attended: int | None = None
+    # Bytes of each kind of cache it reads and writes, ``{kind: bytes}``.
+    cache_bytes: dict = field(default_factory=dict)
+    active: list = field(default_factory=list)   # the launch's live slots
+    out: object = None            # the backend's own hold on the unread ids
+    ids: list | None = None       # after ``fetch``: next token id per slot
+    # After ``fetch``: the model's figures of this step, ``{name: value}``.
+    report: dict = field(default_factory=dict)
+
+
+class _BlockingSteps:
+    """``launch``/``fetch`` over a backend that has only a blocking
+    ``step(tokens, positions, active) -> ids`` (sync, or async as the race
+    tests' fakes): the launch runs the whole step and holds its result, so
+    the engine has one loop whatever it is handed. The ids of the last step
+    stay here, as they stay on the device in ``runtime/kvcache.py``."""
+
+    def __init__(self, backend):
+        self.backend = backend
+        self._ids = [0] * backend.slots
+        if inspect.iscoroutinefunction(backend.step):
+            self.launch = self._launch_async
+
+    def _tokens(self, fresh, active) -> list:
+        return [0 if not live else self._ids[slot] if token is None else token
+                for slot, (token, live) in enumerate(zip(fresh, active))]
+
+    def _record(self, ids, active) -> LaunchedStep:
+        backend = self.backend
+        self._ids = [int(t) for t in ids]
+        return LaunchedStep(
+            bound=getattr(backend, "step_bound", backend.max_len),
+            attended=getattr(backend, "step_attended", None),
+            cache_bytes=dict(getattr(backend, "step_cache_bytes", {})),
+            active=active, ids=self._ids,
+            report=dict(getattr(backend, "step_report", {})))
+
+    def launch(self, fresh, positions, active) -> LaunchedStep:
+        return self._record(self.backend.step(
+            self._tokens(fresh, active), positions, active), active)
+
+    async def _launch_async(self, fresh, positions, active) -> LaunchedStep:
+        return self._record(await self.backend.step(
+            self._tokens(fresh, active), positions, active), active)
+
+    def fetch(self, step: LaunchedStep) -> LaunchedStep:
+        return step
+
+
 class _CallClock:
     """``time.perf_counter`` of one backend call, read where each thing
     happens: ``submit`` on the loop before the hop to the device thread,
@@ -154,6 +226,23 @@ class _CallClock:
             return fn(*args)
         finally:
             self.left = time.perf_counter()
+
+
+@dataclass
+class _Flight:
+    """A launched step the host has not read: who rides it. ``step`` is the
+    backend's record once the launching call has returned."""
+
+    snapshot: list                # (slot, sequence, position) of its launch
+    tick: int                     # the tick that launched it
+    step: LaunchedStep | None = None
+
+    def __post_init__(self):
+        self._slots = frozenset(slot for slot, _, _ in self.snapshot)
+
+    def holds(self, slot: int) -> bool:
+        """Whether this step has ``slot`` live."""
+        return slot in self._slots
 
 
 @dataclass
@@ -188,26 +277,30 @@ class DecodeEngine:
     - ``reset_cache()``: drop + reallocate the pooled cache (reload
       invalidation);
     - ``prefill_into(slot, tokens) -> first generated token id``;
-    - ``step(tokens, positions, active) -> next token id per slot``
-      (plain int lists — the backend owns array conversion);
+    - ``launch(fresh, positions, active) -> LaunchedStep``: start one decode
+      step and return without reading it. Plain lists, one entry a slot:
+      ``fresh[slot]`` is the token the host feeds (a slot admitted or
+      re-prefilled since the last launch) or None — the slot's token is the
+      id the LAST LAUNCHED step gave it, which the backend kept. The record
+      carries what the backend worked out at the launch: ``bound`` (observed
+      as ``ai4e_decode_step_bound``), ``attended`` (counted as attended K/V
+      positions), ``cache_bytes`` (``ai4e_decode_cache_bytes_total{kind}``);
+    - ``fetch(step) -> step``: block until that step has run and fill in
+      ``ids`` (next token id per slot) and ``report`` (``{name: value}``,
+      observed as ``ai4e_decode_<name>``). Steps are fetched in the order
+      they were launched; a failure surfaces here;
+    - or, in place of the two, only a blocking ``step(tokens, positions,
+      active) -> ids`` with its figures left in ``step_bound`` /
+      ``step_attended`` / ``step_cache_bytes`` / ``step_report``
+      attributes (the tests' fakes): ``_BlockingSteps`` adapts it;
     - optionally ``step_report_series`` (attribute, ``{name: (help,
-      buckets)}``) and ``step_report`` (attribute, ``{name: value}``): what
-      a model that reports on its step declares, and its figures of the
-      step just run — registered and observed as ``ai4e_decode_<name>``;
-    - optionally ``step_bound`` (attribute): the positions of every slot
-      the step just run covered — observed as ``ai4e_decode_step_bound``;
-      a backend without it covers ``max_len`` — and ``step_attended``
-      (attribute): the K/V positions that step read, counted as attended;
-      a backend without it reads ``slots x`` its bound; and
-      ``step_cache_bytes`` (attribute, ``{kind: bytes}``): what that step
-      read and wrote of each kind of cache a slot holds (K/V rows, fixed-size
-      state) — counted as ``ai4e_decode_cache_bytes_total{kind}``;
+      buckets)}``): what a model that reports on its step declares;
     - optionally ``bound_for(longest)``: the bound a step whose largest
       live position is ``longest`` will run — the ``bound=`` of the
-      ``ai4e.decode.tick`` region, which opens before the step;
+      ``ai4e.decode.tick`` region, which opens before the launch;
     - optionally ``phase_hook`` (attribute, None until the engine installs
       ``hook(phase, seconds)``): called inside a backend call with
-      ``device_wait`` (seconds of ``step`` blocked on the device; the rest
+      ``device_wait`` (seconds of ``fetch`` blocked on the device; the rest
       of the in-thread time is ``dispatch``) and ``compile`` (a call that
       grew a program's dispatch cache). Without it the whole in-thread time
       of a step is booked as ``device_wait``.
@@ -221,10 +314,25 @@ class DecodeEngine:
     def __init__(self, backend, max_pending: int = 64,
                  metrics: MetricsRegistry | None = None):
         self.backend = backend
+        # The one step surface: a backend with only a blocking ``step`` is
+        # adapted here and nowhere else.
+        self._steps = (backend if hasattr(backend, "launch")
+                       else _BlockingSteps(backend))
+        self._advance = (
+            self._advance_async
+            if inspect.iscoroutinefunction(self._steps.launch)
+            else self._advance_in_thread)
         self.max_pending = max_pending
         self.pool = SlotPool(backend.slots)
         self._queue: deque[_Sequence] = deque()
         self._active: dict[int, _Sequence] = {}
+        # Steps launched and not yet read, oldest first: one between ticks,
+        # two while a tick's call launches the next and fetches the last.
+        self._launched: deque[_Flight] = deque()
+        # Slots whose sequence was retired while a launched step had them
+        # live: busy in ``pool`` with no tenant, released once no launched
+        # step holds them.
+        self._parked: set[int] = set()
         self._wakeup = asyncio.Event()
         self._stop = False
         # Rollout drain (rollout/drain.py): stop admitting prefills but
@@ -303,6 +411,15 @@ class DecodeEngine:
         self._expired_total = self.metrics.counter(
             "ai4e_admission_expired_total",
             "Requests dropped on deadline expiry, by hop/priority")
+        self._launches = self.metrics.counter(
+            "ai4e_decode_step_launches_total",
+            "Decode steps launched, by kind: all, and ahead (launched while "
+            "the step before it was unread, so the device had it queued "
+            "before the host read the last ids)")
+        self._discarded = self.metrics.counter(
+            "ai4e_decode_discarded_slot_steps_total",
+            "Slot-steps computed for a sequence that had ended (EOS, "
+            "cancel, expiry, drain) while a launched step held it")
         self._tick_no = 0
         # Seconds booked since the previous step's submit, every phase but
         # ``yield``; ``_last_submit`` is None after an idle wait or a tick
@@ -392,8 +509,10 @@ class DecodeEngine:
 
     @property
     def drain_complete(self) -> bool:
-        """Draining AND quiesced: no queued, no active sequences."""
-        return self._draining and not self._active and not self._queue
+        """Draining AND quiesced: no queued, no active sequences, no
+        launched step unread."""
+        return (self._draining and not self._active and not self._queue
+                and not self._launched)
 
     def force_drain(self) -> int:
         """Retire the ACTIVE stragglers past the drain budget with
@@ -407,6 +526,9 @@ class DecodeEngine:
                                  "decode drain budget exhausted; "
                                  "redeliver"))
                 forced += 1
+        # A step in flight holds their slots parked: the loop reads it,
+        # discards what it computed for them and frees the slots.
+        self._wakeup.set()
         return forced
 
     def resume_from_drain(self) -> None:
@@ -427,6 +549,11 @@ class DecodeEngine:
         if self._loop_task is not None:
             await self._loop_task
             self._loop_task = None
+        try:
+            await self._settle()   # nothing stays unread on the executor
+        except Exception:  # noqa: BLE001 — the stop goes on: every sequence is failed just below
+            log.exception("decode step in flight failed at stop")
+            self._void_launched()
         for seq in list(self._active.values()) + list(self._queue):
             self._retire(seq, "cancelled",
                          error=RuntimeError("decode engine stopped"))
@@ -437,7 +564,7 @@ class DecodeEngine:
 
     async def _run(self) -> None:
         while not self._stop:
-            if not self._active and not self._queue:
+            if not self._active and not self._queue and not self._launched:
                 self._last_submit = None
                 self._wakeup.clear()
                 try:
@@ -451,13 +578,16 @@ class DecodeEngine:
             except Exception:  # noqa: BLE001 — a backend crash fails the affected sequences below, never the loop
                 log.exception("decode tick failed; failing active sequences")
                 self._last_submit = None
+                # The step that failed and any launched after it are void.
+                self._void_launched()
                 for seq in list(self._active.values()):
                     self._retire(seq, "failed",
                                  error=RuntimeError("decode step failed"))
 
     async def _tick(self) -> None:
         """One scheduling iteration: reload check → expiry/cancel sweep →
-        admission (prefill into free slots) → one decode step."""
+        admission (prefill into free slots) → launch the next decode step
+        and read the last one."""
         self._tick_no += 1
         await self._check_reload()
         self._sweep()
@@ -475,6 +605,9 @@ class DecodeEngine:
         version = self.backend.params_version
         if version == self._cache_version:
             return
+        # Never reset the cache under a launched step: read it first (its
+        # ids were computed under the old weights, like every token before).
+        await self._settle()
         first_attach = self._cache_version is None
         self._cache_version = version
         if first_attach and not self._active:
@@ -580,74 +713,151 @@ class DecodeEngine:
                         else f"{len(tokens)} tokens"))
         return int(token)
 
-    async def _step(self) -> None:
-        """One decode step over the whole slot pool: every active
-        sequence advances one token; inactive slots ride along masked."""
+    async def _step(self, launch: bool = True) -> None:
+        """Launch the next decode step over the whole slot pool (every
+        active sequence advances one token; inactive slots ride along
+        masked), then read the step launched before it — one call to the
+        backend, in that order, so the device has the next step queued
+        while the host fetches and notes the last one's ids."""
         entered = time.perf_counter()
-        snapshot = [(slot, seq, seq.position)
-                    for slot, seq in sorted(self._active.items())
-                    if not seq.done]
-        if not snapshot:
+        unread = self._launched[-1] if self._launched else None
+        snapshot = []
+        if launch:
+            for slot, seq in sorted(self._active.items()):
+                if seq.done:
+                    continue
+                # A sequence the unread step carries has one token the host
+                # has not seen: count it. One that ends by count when that
+                # token is read is never in this launch.
+                ahead = unread is not None and unread.holds(slot)
+                position = seq.position + ahead
+                if ahead and (len(seq.tokens) + 1 >= seq.max_new_tokens
+                              or position >= self.backend.max_len):
+                    continue
+                snapshot.append((slot, seq, position, ahead))
+        if not snapshot and unread is None:
             self._last_submit = None
             return
-        bound_for = getattr(self.backend, "bound_for", None)
-        with device_trace(
+        # One region a launched step, as numbered; a call that only reads
+        # the last step of a burst opens none.
+        region = contextlib.nullcontext()
+        if snapshot:
+            bound_for = getattr(self.backend, "bound_for", None)
+            region = device_trace(
                 "ai4e.decode.tick", tick=self._tick_no, active=len(snapshot),
-                bound=(bound_for(max(p for _, _, p in snapshot)) if bound_for
-                       else self.backend.max_len)):
-            with device_trace("ai4e.decode.prepare"):
-                tokens = [0] * self.pool.slots
-                positions = [0] * self.pool.slots
-                active = [False] * self.pool.slots
-                for slot, seq, position in snapshot:
-                    tokens[slot] = seq.tokens[-1]
-                    positions[slot] = position
-                    active[slot] = True
-            out, clock = await self._call(self.backend.step, tokens,
-                                          positions, active)
-            self._step_hist.observe(clock.resumed - clock.submit,
-                                    phase="decode", model=self._model)
-            self._close_tick(clock.submit, entered)
-            # This step's own phases open the next interval.
+                bound=(bound_for(max(p for _, _, p, _ in snapshot))
+                       if bound_for else self.backend.max_len))
+        with region:
+            args = flight = None
+            if snapshot:
+                with device_trace("ai4e.decode.prepare"):
+                    fresh = [None] * self.pool.slots
+                    positions = [0] * self.pool.slots
+                    active = [False] * self.pool.slots
+                    for slot, seq, position, ahead in snapshot:
+                        if not ahead:   # the host has its last token
+                            fresh[slot] = seq.tokens[-1]
+                        positions[slot] = position
+                        active[slot] = True
+                    args = (fresh, positions, active)
+                flight = _Flight([entry[:3] for entry in snapshot],
+                                 self._tick_no)
+                self._launches.inc(model=self._model, kind="all")
+                if unread is not None:
+                    self._launches.inc(model=self._model, kind="ahead")
+                # Registered before the call: a retire that runs while it
+                # is awaited must see that this step holds the slot.
+                self._launched.append(flight)
+            step, clock = await self._call(
+                self._advance, args, unread and unread.step)
             phase = self._phase
+            if flight is not None:
+                flight.step = step
+                self._close_tick(clock.submit, entered)
+            else:
+                phase["prepare"] += clock.submit - entered
+            # This call's own phases open (or, without a launch, extend)
+            # the interval to the next launch.
             in_thread = clock.left - clock.entered
             wait = in_thread if clock.wait is None else clock.wait
-            phase["handoff"] = clock.entered - clock.submit
-            phase["dispatch"] = in_thread - wait
-            phase["device_wait"] = wait
-            phase["return"] = clock.resumed - clock.left
-            with device_trace("ai4e.decode.bookkeeping"):
-                self._step_active.observe(len(snapshot), model=self._model)
-                bound = getattr(self.backend, "step_bound",
-                                self.backend.max_len)
-                self._step_bound.observe(bound, model=self._model)
-                self._kv_positions.inc(
-                    sum(position + 1 for _, _, position in snapshot),
-                    model=self._model, kind="live")
-                self._kv_positions.inc(
-                    getattr(self.backend, "step_attended",
-                            self.pool.slots * bound),
-                    model=self._model, kind="attended")
-                for kind, nbytes in getattr(self.backend, "step_cache_bytes",
-                                            {}).items():
-                    self._cache_bytes.inc(nbytes, model=self._model,
-                                          kind=kind)
-                for name, value in getattr(self.backend, "step_report",
-                                           {}).items():
-                    self._step_report[name].observe(value, model=self._model)
-                for slot, seq, position in snapshot:
-                    if seq.done or seq.slot != slot:
-                        continue  # re-check after the await: retired mid-step
-                    seq.position = position + 1
-                    self._note_token(seq, int(out[slot]))
-            phase["bookkeeping"] = time.perf_counter() - clock.resumed
+            phase["handoff"] += clock.entered - clock.submit
+            phase["dispatch"] += in_thread - wait
+            phase["device_wait"] += wait
+            phase["return"] += clock.resumed - clock.left
+            if unread is not None:
+                self._step_hist.observe(clock.resumed - clock.submit,
+                                        phase="decode", model=self._model)
+                self._launched.remove(unread)   # read: it holds nothing now
+                with device_trace("ai4e.decode.bookkeeping"):
+                    self._note_step(unread)
+                self._release_parked()
+            phase["bookkeeping"] += time.perf_counter() - clock.resumed
+
+    def _advance_in_thread(self, args, unread):
+        """On the device thread: launch, then fetch — in that order."""
+        step = self._steps.launch(*args) if args is not None else None
+        if unread is not None:
+            self._steps.fetch(unread)
+        return step
+
+    async def _advance_async(self, args, unread):
+        step = await self._steps.launch(*args) if args is not None else None
+        if unread is not None:
+            fetched = self._steps.fetch(unread)
+            if inspect.isawaitable(fetched):
+                await fetched
+        return step
+
+    async def _settle(self) -> None:
+        """Read every launched step, launching nothing: what ``reset_cache``
+        and ``stop`` need before they touch the cache or the executor."""
+        while self._launched:
+            await self._step(launch=False)
+
+    def _void_launched(self) -> None:
+        """Forget every launched step unread (a failure, a stop): nothing
+        stays marked in flight, and the slots they held parked are freed."""
+        self._launched.clear()
+        self._release_parked()
+
+    def _release_parked(self) -> None:
+        for slot in [s for s in self._parked
+                     if not any(f.holds(s) for f in self._launched)]:
+            self._parked.discard(slot)
+            self.pool.release(slot)
+            self._occupancy.set(self.pool.busy_count / self.pool.slots,
+                                model=self._model)
+
+    def _note_step(self, flight: _Flight) -> None:
+        """Account one step whose ids were just fetched — single segment:
+        its counters, then each rider's token. A rider retired since the
+        launch (or whose slot has a new tenant) is a discarded slot-step."""
+        step, snapshot = flight.step, flight.snapshot
+        self._step_active.observe(len(snapshot), model=self._model)
+        self._step_bound.observe(step.bound, model=self._model)
+        self._kv_positions.inc(
+            sum(position + 1 for _, _, position in snapshot),
+            model=self._model, kind="live")
+        self._kv_positions.inc(
+            self.pool.slots * step.bound if step.attended is None
+            else step.attended, model=self._model, kind="attended")
+        for kind, nbytes in step.cache_bytes.items():
+            self._cache_bytes.inc(nbytes, model=self._model, kind=kind)
+        for name, value in step.report.items():
+            self._step_report[name].observe(value, model=self._model)
+        for slot, seq, position in snapshot:
+            if seq.done or seq.slot != slot:
+                self._discarded.inc(model=self._model)
+                continue
+            seq.position = position + 1
+            self._note_token(seq, int(step.ids[slot]), flight.tick)
 
     def _close_tick(self, submit: float, entered: float) -> None:
         """A step was submitted at ``submit``: observe the interval since
         the previous step's submit, one observation of each phase, and
         start the next. After an idle wait there is no interval to close."""
         phase = self._phase
-        phase["prepare"] = submit - entered
+        phase["prepare"] += submit - entered
         if self._last_submit is not None:
             rest = submit - self._last_submit - sum(phase.values())
             for name, seconds in phase.items():
@@ -678,10 +888,12 @@ class DecodeEngine:
 
     # -- bookkeeping (single-segment: no suspension points below) ---------
 
-    def _note_token(self, seq: _Sequence, token: int) -> None:
+    def _note_token(self, seq: _Sequence, token: int,
+                    tick: int | None = None) -> None:
         """Account one generated token: callback (chunk emission), TTFT /
         inter-token latency, and the finish decision (EOS, token budget,
-        KV-cache slot full)."""
+        KV-cache slot full). ``tick``: the tick that launched the step it
+        came from (a prefill's: this one)."""
         now = time.perf_counter()
         first = not seq.tokens
         seq.tokens.append(token)
@@ -711,8 +923,8 @@ class DecodeEngine:
             if seq.ledger is not None:
                 seq.ledger.stamp(
                     "decoded", "decode",
-                    reason=f"{len(seq.tokens)} tokens ticks "
-                           f"{seq.first_tick}..{self._tick_no}")
+                    reason=f"{len(seq.tokens)} tokens ticks {seq.first_tick}"
+                           f"..{self._tick_no if tick is None else tick}")
             self._retire(seq, "completed")
 
     def _retire(self, seq: _Sequence, outcome: str, error=None) -> None:
@@ -725,7 +937,12 @@ class DecodeEngine:
         seq.done = True
         if seq.slot is not None:
             self._active.pop(seq.slot, None)
-            self.pool.release(seq.slot)
+            if any(flight.holds(seq.slot) for flight in self._launched):
+                # A launched step has the slot live: it stays busy until
+                # that step is read, so no join is written under it.
+                self._parked.add(seq.slot)
+            else:
+                self.pool.release(seq.slot)
             seq.slot = None
             self._occupancy.set(self.pool.busy_count / self.pool.slots,
                                 model=self._model)

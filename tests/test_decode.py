@@ -249,7 +249,9 @@ class TestEngineScheduling:
                 # slot instead of completing late.
                 await engine.submit([1], 10_000,
                                     deadline_at=time.time() + 0.05)
-            assert engine.pool.free_count == 1
+            # The step in flight still has the slot live: it comes back
+            # when that step has been read, one tick on.
+            await wait_until(lambda: engine.pool.free_count == 1)
             expired = reg.counter("ai4e_admission_expired_total")
             assert expired.value(hop="decode", priority="interactive") == 1
             await engine.stop()
@@ -266,7 +268,8 @@ class TestEngineScheduling:
             await wait_until(lambda: engine.active_count)
             fut.cancel()
             await wait_until(lambda: not engine.active_count)
-            assert engine.pool.free_count == 1
+            # Parked while the step in flight has it live; then freed.
+            await wait_until(lambda: engine.pool.free_count == 1)
             await engine.stop()
             engine.pool.check_conservation()
 
@@ -311,7 +314,9 @@ class TestEngineScheduling:
             "ai4e_decode_tick_seconds", "ai4e_decode_queue_wait_seconds",
             "ai4e_decode_step_active_slots", "ai4e_decode_step_bound",
             "ai4e_decode_kv_positions_total",
-            "ai4e_decode_cache_bytes_total"}
+            "ai4e_decode_cache_bytes_total",
+            "ai4e_decode_step_launches_total",
+            "ai4e_decode_discarded_slot_steps_total"}
 
     @pytest.mark.parametrize("declared", [
         {"window_fill": ("Share of a layer's window that is live",

@@ -171,9 +171,10 @@ class TestTheRungRule:
     def test_a_step_takes_the_rung_of_its_live_slots(self, lm, positions,
                                                      active, bound):
         runtime = lm.runtime
-        out = runtime.step([1] * SLOTS, list(positions), list(active))
-        assert len(out) == SLOTS
-        assert runtime.step_bound == bound
+        step = runtime.fetch(runtime.launch([1] * SLOTS, list(positions),
+                                            list(active)))
+        assert len(step.ids) == SLOTS
+        assert step.bound == bound
 
 
 class TestEveryRungIsWarmed:
@@ -189,8 +190,9 @@ class TestEveryRungIsWarmed:
         assert warmed._programs["step"]._cache_size() == len(RUNGS)
         phases = []
         warmed.phase_hook = lambda phase, seconds: phases.append(phase)
-        warmed.step([1] * SLOTS, [bound - 1, 0, 0], [True, False, False])
-        assert warmed.step_bound == bound
+        step = warmed.fetch(warmed.launch([1] * SLOTS, [bound - 1, 0, 0],
+                                          [True, False, False]))
+        assert step.bound == bound
         assert phases == ["device_wait"]
 
     def test_a_reload_keeps_the_programs(self, warmed):

@@ -313,11 +313,11 @@ class TestNamedScopes:
         import numpy as np
 
         def lowered(runtime, debug_info):
-            zeros = np.zeros(3, np.int32)
             return runtime._programs["step"].lower(
-                runtime.servable.params, zeros, runtime._k, runtime._v,
-                runtime._state, zeros,
-                runtime.max_len).as_text(debug_info=debug_info)
+                runtime.servable.params, np.zeros((3, 3), np.int32),
+                np.zeros(3, np.int32), runtime._k, runtime._v,
+                runtime._state, runtime.max_len).as_text(
+                    debug_info=debug_info)
 
         scoped = tiny_runtime()
         with_scopes = decode_tokens(scoped)

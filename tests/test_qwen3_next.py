@@ -249,12 +249,13 @@ def test_cache_spec_declares_kv_of_full_layers_and_state_of_the_rest():
     state = 6 * 3 * (4 * 16 * 16 * 4 + 3 * 128 * 4)
     assert runtime.cache_nbytes() == kv + state
     runtime.warm()
-    runtime.step([0] * 3, [5, 0, 9], [True, False, True])
+    stepped = runtime.fetch(runtime.launch([0] * 3, [5, 0, 9],
+                                           [True, False, True]))
     # one block of the whole tiny cache a live slot (the dead one reads
     # nothing) + its new token read + its row written, K and V of both K/V
     # layers; every slot's
     # state once in and once out
-    assert runtime.step_cache_bytes == {
+    assert stepped.cache_bytes == {
         "kv": 2 * 2 * 64 * 4 * (2 * 96 + 2 + 2), "state": 2 * state}
 
 
@@ -410,7 +411,7 @@ def test_the_worker_serves_the_family_through_the_same_wiring():
     moved = {labels["kind"]: value for _, _, labels, value in
              engine.metrics._metrics["ai4e_decode_cache_bytes_total"
                                      ].collect()}
-    assert moved["state"] == steps * backend.step_cache_bytes["state"]
+    assert moved["state"] == steps * 2 * backend._state_nbytes
     assert moved["kv"] > 0
 
 
@@ -425,9 +426,10 @@ def test_the_other_families_declare_no_state():
         assert runtime.state_spec() == ()
         runtime.warm()
         assert runtime._state == {}
-        runtime.step([1, 2], [3, 0], [True, False])
-        assert runtime.step_cache_bytes["state"] == 0
-        assert runtime.step_cache_bytes["kv"] > 0
+        moved = runtime.fetch(runtime.launch([1, 2], [3, 0],
+                                             [True, False])).cache_bytes
+        assert moved["state"] == 0
+        assert moved["kv"] > 0
 
 
 def test_an_unknown_key_of_the_spec_is_an_error():

@@ -1306,10 +1306,13 @@ def _decode_drain(engine, results):
     resolve and conservation be audited."""
     for seq in (list(engine._active.values()) + list(engine._queue)):
         engine._retire(seq, "cancelled", error=RuntimeError("drained"))
+    # A step still in flight holds its riders' slots parked: void it, as
+    # the engine's own stop and failure paths do.
+    engine._void_launched()
     results["drained"] = True
 
 
-def _slot_conservation_scenario(engine_cls, ticks=120):
+def _slot_conservation_scenario(engine_cls, ticks=120, gap=6):
     """Join vs decode-step vs expiry-sweep vs cancel vs hot-reload:
     the full verb mix over a 2-slot pool."""
 
@@ -1355,7 +1358,12 @@ def _slot_conservation_scenario(engine_cls, ticks=120):
                 return
             seq = next(iter(engine._active.values()))
             seq.deadline_at = 1.0        # long past: next sweep dooms it
-            await yield_point()
+            # ``gap`` scheduling points later: where, under the explorer's
+            # shallow schedules, the tick that sweeps the expiry has its
+            # window open (a tick launches the next step before it reads
+            # the last one, so that tick starts later than it used to).
+            for _ in range(gap):
+                await yield_point()
             engine.cancel(seq.future)
 
         async def reloader():
@@ -1402,6 +1410,168 @@ class TestDecodeSlotConservation:
             "the budget is too small")
         assert any("Slot" in type(r.error).__name__
                    or "released" in str(r.error)
+                   for r in report.failures), report.describe()
+
+
+# -- decode engine: retired between a step's launch and its fetch (PR 33) -----
+#
+# The engine launches step N+1 before it reads step N, so between a launch
+# and its fetch there is a window in which ``_retire`` can run (a cancel, an
+# expiry sweep, a drain) on a sequence the launched step has live. The slot
+# must then stay busy — parked — until that step is read: freed exactly
+# once, the step's token for it discarded, and no join written under a step
+# launched for the slot's previous tenant.
+
+from ai4e_tpu.runtime.decode import LaunchedStep
+
+
+class _AheadDecodeBackend(_FakeDecodeBackend):
+    """``launch`` / ``fetch`` as ``runtime/kvcache.py`` has them, every
+    call a real suspension. It knows which launched steps are unread, and
+    refuses a prefill into a slot one of them has live: on the device that
+    step and the join's insert would be ordered by the host's word alone."""
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        self.unread = []
+        self._ids = [0] * self.slots
+
+    def _check(self, slot):
+        assert not any(step.active[slot] for step in self.unread), (
+            f"join written into slot {slot} under a step launched for its "
+            f"previous tenant")
+
+    async def prefill_into(self, slot, tokens):
+        self._check(slot)
+        await yield_point()
+        self._check(slot)
+        return int(tokens[-1]) + 1
+
+    async def launch(self, fresh, positions, active):
+        await yield_point()
+        self._ids = [(self._ids[slot] if token is None else token) + 1
+                     for slot, token in enumerate(fresh)]
+        step = LaunchedStep(bound=self.max_len, active=list(active),
+                            out=list(self._ids))
+        self.unread.append(step)
+        return step
+
+    async def fetch(self, step):
+        await yield_point()
+        self.unread.remove(step)
+        step.ids, step.out = step.out, None
+        return step
+
+
+class _EagerReleaseEngine(DecodeEngine):
+    """Verbatim pre-PR-33 ``_retire``: the slot goes back to the pool the
+    moment its sequence is retired, also while a launched step has it
+    live — the next ``_admit`` hands it to a join under that step."""
+
+    def _retire(self, seq, outcome, error=None):
+        if seq.done:
+            return
+        seq.done = True
+        if seq.slot is not None:
+            self._active.pop(seq.slot, None)
+            self.pool.release(seq.slot)
+            seq.slot = None
+            self._occupancy.set(self.pool.busy_count / self.pool.slots,
+                                model=self._model)
+        else:
+            try:
+                self._queue.remove(seq)
+            except ValueError:
+                pass  # already popped by admission
+            self._pending_gauge.set(self.pending_count, model=self._model)
+        self._sequences_total.inc(model=self._model, outcome=outcome)
+        if not seq.future.done():
+            if error is not None:
+                seq.future.set_exception(error)
+            else:
+                seq.future.set_result(list(seq.tokens))
+
+
+def _retired_in_flight_scenario(engine_cls, ticks=80):
+    """One slot, "b" queued behind "a"; "a" is cancelled while a launched
+    step has it live."""
+
+    def make():
+        backend = _AheadDecodeBackend(slots=1, max_len=16)
+        engine = engine_cls(backend, max_pending=8,
+                            metrics=MetricsRegistry())
+        results, delivered = {}, []
+
+        async def driver():
+            for _ in range(ticks):
+                if "a" in results and "b" in results:
+                    break
+                await yield_point()
+                await engine._tick()
+            _decode_drain(engine, results)
+
+        async def submit(tag, prompt, max_new):
+            try:
+                results[tag] = await engine.submit(
+                    prompt, max_new,
+                    on_token=lambda i, t: delivered.append((tag, i, t)))
+            except BaseException as exc:  # noqa: BLE001 — the outcome IS the result under exploration
+                results[tag] = exc
+
+        async def canceller():
+            for _ in range(60):
+                if backend.unread and engine._active:
+                    break
+                await yield_point()
+            else:
+                return
+            seq = next(iter(engine._active.values()))
+            results["cancelled"] = ("a" if seq.prompt == (1,) else "b",
+                                    len(seq.tokens))
+            engine.cancel(seq.future)
+
+        coros = [driver(), submit("a", [1], 8), submit("b", [10], 2),
+                 canceller()]
+        whole = {"a": list(range(2, 10)), "b": [11, 12]}
+
+        def check():
+            engine.pool.check_conservation()
+            assert engine.pool.free_count == engine.pool.slots, (
+                f"slot leak: {engine.pool.busy_count} busy after drain")
+            assert not engine._active and not engine._queue
+            assert not engine._parked and not engine._launched
+            tag, had = results.get("cancelled", (None, 0))
+            for name, want in whole.items():
+                if name == tag:
+                    # Nothing reached it after its retire.
+                    want = want[:had]
+                    assert sum(t == name for t, _, _ in delivered) == had
+                # The other — a join into the freed slot, under most
+                # schedules — was served whole and unharmed.
+                assert results.get(name) == want, results
+
+        return coros, check
+
+    return make
+
+
+class TestRetiredBetweenLaunchAndFetch:
+    def test_fixed_engine_parks_the_slot_until_the_step_is_read(self):
+        report = explore_interleavings(
+            _retired_in_flight_scenario(DecodeEngine),
+            schedules=SCHEDULES, seed=SEED)
+        assert report.ok, report.describe()
+
+    def test_eager_release_revert_caught(self):
+        report = explore_interleavings(
+            _retired_in_flight_scenario(_EagerReleaseEngine),
+            schedules=SCHEDULES, seed=SEED)
+        assert not report.ok, (
+            "the retire-between-launch-and-fetch window was not reachable "
+            "— either the scenario no longer cancels under a launched step "
+            "or the budget is too small")
+        assert any("previous tenant" in str(r.error)
+                   or "previous tenant" in repr(r.error)
                    for r in report.failures), report.describe()
 
 

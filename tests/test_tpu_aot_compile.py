@@ -176,17 +176,23 @@ def _compile_step(runtime, sharding, bound):
     (``lowering.resolve_interpret``), which here is the CPU, so the test
     answers for it."""
     from ai4e_tpu.ops.pallas import decode_attention
+    from ai4e_tpu.runtime import kvcache
     pool_shape, pool_dtype = runtime.cache_spec()
     pool = _on(sharding, (pool_shape, pool_dtype))
-    ints = _on(sharding, ((runtime.slots,), jnp.int32))
+    # What a launch hands it: the host's three rows a slot, and the last
+    # step's ids, which stayed on the device.
+    host = _on(sharding, ((3, runtime.slots), jnp.int32))
+    previous = _on(sharding, ((runtime.slots,), jnp.int32))
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(decode_attention, "resolve_interpret",
                       lambda kernel, interpret: False)
         state = {name: _on(sharding, ((runtime.slots, *shape), dtype))
                  for name, shape, dtype in runtime.state_spec()}
+        # The default backend here is the CPU: the chip's options by hand.
         return runtime._programs["step"].lower(
-            _on(sharding, runtime.servable.params), ints, pool, pool, state,
-            ints, bound).compile()
+            _on(sharding, runtime.servable.params), host, previous, pool,
+            pool, state, bound).compile(
+                compiler_options=kvcache.STEP_COMPILER_OPTIONS["tpu"])
 
 
 def _entry_results(compiled):
@@ -208,7 +214,19 @@ def _hlo_type(shape, dtype):
     return name + "[" + ",".join(map(str, shape)) + "]"
 
 
-def _assert_step_reads_in_place(runtime, compiled, bound, temp_limit):
+# ``bytes accessed`` of each cell's step program on the tree before the
+# token select (commit 35ac25d, the same at both rungs), compiled with the
+# options the chip's compile is given now (``STEP_COMPILER_OPTIONS``: XLA's
+# count reads 5,367,156,224 / 8,007,884,800 / 18,796,433,408 with sliced
+# prefetches, on both trees alike). The select, the three host rows and the
+# ids kept for the next launch may add tens of KB (padded tiles of a few
+# hundred bytes of ints), nothing of the pool's or the weights' size.
+STEP_BYTES_BEFORE_THE_SELECT = {
+    "gpt2m": 6_516_489_728, "olmoe": 8_252_233_728, "qnext": 20_230_184_960}
+
+
+def _assert_step_reads_in_place(runtime, compiled, bound, temp_limit,
+                                cell=None):
     """The pool is made only by 2 x slots row writes on the two donated
     parameters — no whole-pool ``copy`` (XLA's answer to a scatter: it
     re-lays the pool out and back, 6.5 GB of temporaries), no fusion that
@@ -248,6 +266,11 @@ def _assert_step_reads_in_place(runtime, compiled, bound, temp_limit):
     assert memory.temp_size_in_bytes < temp_limit, memory.temp_size_in_bytes
     # Both pool tensors are aliased input to output: the pool exists once.
     assert memory.alias_size_in_bytes >= runtime.cache_nbytes()
+    if cell is not None:
+        cost = compiled.cost_analysis()
+        cost = cost[0] if isinstance(cost, list) else cost
+        added = cost["bytes accessed"] - STEP_BYTES_BEFORE_THE_SELECT[cell]
+        assert 0 <= added < 65_536, added
     return memory
 
 
@@ -280,7 +303,8 @@ def test_decode_step_at_the_benchmark_cell_writes_rows_in_place(
     assert runtime.cache_spec() == ((24, 32, 1024, 1024), jnp.float32)
     bound = runtime.step_bounds[rung]
     _assert_step_reads_in_place(
-        runtime, _compile_step(runtime, v5e_sharding, bound), bound, 0.5e9)
+        runtime, _compile_step(runtime, v5e_sharding, bound), bound, 0.5e9,
+        cell="gpt2m")
 
 
 @pytest.mark.parametrize("rung", [0, 1])
@@ -299,7 +323,8 @@ def test_olmoe_step_at_the_benchmark_cell_writes_rows_in_place(
     assert runtime.cache_spec() == ((8, 32, 2048, 2048), jnp.bfloat16)
     bound = runtime.step_bounds[rung]
     memory = _assert_step_reads_in_place(
-        runtime, _compile_step(runtime, v5e_sharding, bound), bound, 0.1e9)
+        runtime, _compile_step(runtime, v5e_sharding, bound), bound, 0.1e9,
+        cell="olmoe")
     if bound < runtime.max_len:
         return
 
@@ -349,7 +374,8 @@ def test_qnext_step_at_the_benchmark_cell_moves_no_pool(
     one_state = 32 * 32 * 128 * 128 * 4
     bound = runtime.step_bounds[rung]
     compiled = _compile_step(runtime, v5e_sharding, bound)
-    memory = _assert_step_reads_in_place(runtime, compiled, bound, one_state)
+    memory = _assert_step_reads_in_place(runtime, compiled, bound, one_state,
+                                         cell="qnext")
     pools = runtime.cache_nbytes()
     assert pools == 2 * 3 * 32 * 3072 * 512 * 2 + state_pool.nbytes(state, 32)
     assert memory.alias_size_in_bytes >= pools
