@@ -101,25 +101,31 @@ def _dot(eq, a, b):
     return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
 
 
-def prefill_attention(q, k, v, mask):
+def prefill_attention(q, k, v, mask, scale: float | None = None):
     """Materialised causal attention over a padded prompt. q: (B, P, H,
     hd); k, v: (B, P, KVH, hd), query head ``h`` reading K/V head ``h // (H
     // KVH)``; mask: (B, P), True on real tokens. Float32 scores and
     softmax, the weights cast to ``v``'s dtype for the value product.
-    Returns (B, P, H, hd) in ``q``'s dtype."""
+    ``scale`` multiplies ``q . k`` where a family hands one over; without
+    it the scores are divided by ``sqrt(hd)``. Returns (B, P, H, hd) in
+    ``q``'s dtype."""
     p = q.shape[1]
+
+    def scaled(scores):
+        return (scores / np.sqrt(q.shape[-1]) if scale is None
+                else scores * scale)
+
     with jax.named_scope("attention"):
         allowed = (jnp.tril(jnp.ones((p, p), bool))[None, None]
                    & mask[:, None, None, :])
         if q.shape[2] != k.shape[2]:
             grouped = (*q.shape[:2], k.shape[2], -1, q.shape[3])
-            scores = _dot("bqhgd,bkhd->bhgqk", q.reshape(grouped),
-                          k) / np.sqrt(q.shape[-1])
+            scores = scaled(_dot("bqhgd,bkhd->bhgqk", q.reshape(grouped), k))
             w = jax.nn.softmax(jnp.where(allowed[:, :, None], scores, -1e30),
                                axis=-1)
             return _dot("bhgqk,bkhd->bqhgd", w.astype(v.dtype),
                         v).reshape(q.shape).astype(q.dtype)
-        scores = _dot("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        scores = scaled(_dot("bqhd,bkhd->bhqk", q, k))
         w = jax.nn.softmax(jnp.where(allowed, scores, -1e30), axis=-1)
         return _dot("bhqk,bkhd->bqhd", w.astype(v.dtype), v).astype(q.dtype)
 
@@ -145,7 +151,8 @@ def insert_block(k_pool, v_pool, k_block, v_block, slot):
 
 
 def decode_attention(q, k_new, v_new, k_pool, v_pool, layer: int, position,
-                     bound: int | None = None, interpret: bool | None = None):
+                     bound: int | None = None, interpret: bool | None = None,
+                     scale: float | None = None):
     """One layer's attention of one decode step: one new token per slot
     against the pool. q: (S, H, hd), k_new, v_new: (S, KVH, hd) — the new
     token's, query head ``h`` reading K/V head ``h // (H // KVH)``; k_pool,
@@ -157,7 +164,8 @@ def decode_attention(q, k_new, v_new, k_pool, v_pool, layer: int, position,
     jit; default the whole length) cuts the read to the cached positions
     ``< bound``: the same result, to the order of a float32 sum, for any
     bound ``>=`` the largest position of a slot whose output is read.
-    Float32 scores and accumulation, the weights never rounded. Returns
+    ``scale`` multiplies ``q . k`` (``head_dim ** -0.5`` when None). Float32
+    scores and accumulation, the weights never rounded. Returns
     (S, H, hd) in ``q``'s dtype.
 
     All of it is ``pallas.decode_attention.pooled_attention``: per slot,
@@ -176,7 +184,7 @@ def decode_attention(q, k_new, v_new, k_pool, v_pool, layer: int, position,
             k_new.reshape(slots, -1).astype(k_pool.dtype),
             v_new.reshape(slots, -1), k_pool, v_pool, layer, position,
             heads=heads, bound=bound,
-            block=read_block(k_pool.shape, k_pool.dtype),
+            block=read_block(k_pool.shape, k_pool.dtype), scale=scale,
             interpret=interpret).reshape(q.shape).astype(q.dtype)
 
 

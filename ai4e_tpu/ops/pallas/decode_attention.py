@@ -32,7 +32,17 @@ same matrix with more rows than the pool's row has heads: the row holds
 of one K/V head all lie on that head's lanes (rows ``g * group .. (g + 1) *
 group - 1`` of ``q_bd``), and ``q`` and the output travel as ``(group,
 row)`` — line ``j`` holds the ``j``-th query head of every K/V head, each
-on its K/V head's lanes. One query head a K/V head is ``group = 1``. An online softmax
+on its K/V head's lanes. One query head a K/V head is ``group = 1``. A
+group of whole float32 sublane tiles (a multiple of 8) stacks ``q``'s lines
+by a concatenate and unstacks the output by slices, both tile-aligned; any
+other group (4 query heads a K/V head, ...) has no aligned piece to stack,
+so each row of ``q_bd`` selects its line (row ``r`` holds line ``r % group``)
+and each line of the output sums the rows that hold it — the same matrix,
+built from whole ``(heads, row)`` tiles. A K/V head may own any part of a
+lane tile (64 lanes are half of one): its lanes are a mask, not a slice.
+The scores are scaled by ``scale``, ``head_dim ** -0.5`` unless the family
+hands over its own (a model that multiplies ``q . k`` by a constant of its
+configuration). An online softmax
 (running max and sum a head, a float32 accumulator) carries a slot across
 its blocks in VMEM scratch. Float32 operands multiply at
 ``Precision.HIGHEST`` (the MXU's default would round them to bfloat16);
@@ -65,6 +75,8 @@ NEG_INF = -1e30  # finite: a slot with nothing cached keeps exp() defined
 # Rows of the plan, (3, slots): with the layer, (1,), the kernel's
 # scalar-prefetch operands.
 LIMIT, SOURCE, HOLD = range(3)
+
+SUBLANES = 8   # rows of a float32 tile: what Mosaic stacks and slices whole
 
 
 def block_plan(position, bound: int, block: int):
@@ -121,11 +133,22 @@ def _kernel(plan_ref, layer_ref, q_ref, k_new_ref, v_new_ref, k_ref, v_ref,
         first = head * head_dim
         return (lane >= first) & (lane < first + head_dim)
 
+    def holds_line(j):
+        """(heads, 1), True on the rows that hold line ``j`` of the group:
+        rows ``g * group + j``."""
+        return jax.lax.broadcasted_iota(jnp.int32, (heads, 1), 0) % group == j
+
     @pl.when(b == 0)
     def _init():
-        q = q_ref[...].astype(jnp.float32)
-        if group > 1:   # K/V head g's query heads: rows g * group + j
-            q = jnp.concatenate([q] * (heads // group), axis=0)
+        if group % SUBLANES:   # no aligned piece to stack: rows pick lines
+            q = jnp.zeros((heads, row), jnp.float32)
+            for j in range(group):
+                q = jnp.where(holds_line(j),
+                              q_ref[j:j + 1, :].astype(jnp.float32), q)
+        else:
+            q = q_ref[...].astype(jnp.float32)
+            if group > 1:   # K/V head g's query heads: rows g * group + j
+                q = jnp.concatenate([q] * (heads // group), axis=0)
         q_bd[...] = jnp.where(own_lanes(), q, 0.0).astype(q_bd.dtype)
         acc[...] = jnp.zeros_like(acc)
         m[...] = jnp.full_like(m, NEG_INF)
@@ -188,15 +211,22 @@ def _kernel(plan_ref, layer_ref, q_ref, k_new_ref, v_new_ref, k_ref, v_ref,
         # hold its ``group`` query heads, one a line
         rows = jnp.where(own_lanes(), rows, 0.0)
         if group == 1:
-            out = rows.sum(axis=0, keepdims=True)
+            out_ref[...] = rows.sum(axis=0, keepdims=True).astype(
+                out_ref.dtype)
+        elif group % SUBLANES:
+            for j in range(group):
+                out_ref[j:j + 1, :] = jnp.where(holds_line(j), rows, 0.0).sum(
+                    axis=0, keepdims=True).astype(out_ref.dtype)
         else:
-            out = sum(rows[g:g + group] for g in range(0, heads, group))
-        out_ref[...] = out.astype(out_ref.dtype)
+            out_ref[...] = sum(
+                rows[g:g + group] for g in range(0, heads, group)).astype(
+                    out_ref.dtype)
 
 
-@partial(jax.jit, static_argnames=("heads", "bound", "block", "interpret"))
+@partial(jax.jit, static_argnames=("heads", "bound", "block", "scale",
+                                   "interpret"))
 def _pooled(q, k_new, v_new, k_pool, v_pool, layer, position, *, heads: int,
-            bound: int, block: int, interpret: bool):
+            bound: int, block: int, scale: float, interpret: bool):
     """Jitted on its own so that the layers of a step program share one
     traced and lowered kernel: ``layer`` is a value, not a constant."""
     slots, row = k_new.shape
@@ -214,8 +244,7 @@ def _pooled(q, k_new, v_new, k_pool, v_pool, layer, position, *, heads: int,
     per_slot = pl.BlockSpec((None, 1, row), _slot_index)
     per_group = pl.BlockSpec((None, group, row), _slot_index)
     out = pl.pallas_call(
-        partial(_kernel, block=block, head_dim=head_dim,
-                scale=float(head_dim ** -0.5)),
+        partial(_kernel, block=block, head_dim=head_dim, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(slots, -(-bound // block)),
@@ -239,6 +268,7 @@ def _pooled(q, k_new, v_new, k_pool, v_pool, layer, position, *, heads: int,
 
 def pooled_attention(q, k_new, v_new, k_pool, v_pool, layer, position, *,
                      heads: int, bound: int, block: int,
+                     scale: float | None = None,
                      interpret: bool | None = None):
     """Attention of one new token a slot over ``layer``'s cached positions
     ``< min(position[slot], bound)`` and the new token itself.
@@ -249,8 +279,9 @@ def pooled_attention(q, k_new, v_new, k_pool, v_pool, layer, position, *,
     K/V head ``h // (heads // kv_heads)``; q and k_new in the pool's dtype;
     k_pool, v_pool: (layers, slots, max_len, row), whole; layer: an int or an
     int32 scalar; position: (slots,) int32; ``block``: positions a grid
-    step fetches, at most ``max_len``. Returns q's shape and dtype: the softmax over [cached keys, the new key] of each head, times
-    the values. A slot at position 0 reads nothing of the pool and returns
+    step fetches, at most ``max_len``; ``scale``: what multiplies ``q . k``,
+    ``head_dim ** -0.5`` when None. Returns q's shape and dtype: the softmax
+    over [cached keys, the new key] of each head, times the values. A slot at position 0 reads nothing of the pool and returns
     its new value."""
     head_dim = q.shape[1] // heads
     if k_new.shape[1] % head_dim or heads % (k_new.shape[1] // head_dim):
@@ -262,4 +293,5 @@ def pooled_attention(q, k_new, v_new, k_pool, v_pool, layer, position, *,
     return _pooled(q, k_new, v_new, k_pool, v_pool,
                    jnp.asarray(layer, jnp.int32), position.astype(jnp.int32),
                    heads=heads, bound=bound, block=block,
+                   scale=float(head_dim ** -0.5 if scale is None else scale),
                    interpret=resolve_interpret("decode_attention", interpret))

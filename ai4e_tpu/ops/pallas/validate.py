@@ -121,14 +121,17 @@ def validate_kernels(interpret: bool = False) -> dict:
     # decode attention over the K/V pool vs a plain float32 softmax — the
     # served rows (16 heads of 64 in float32, of 128 in bfloat16: 4 KB
     # either way, so blocks of 256 positions; 16 query heads on 2 K/V heads
-    # of 256 in bfloat16: a 1 KB row, blocks of 1,024), one layer of a pool
-    # of eight slots at ragged positions: dead, a block's edges, the whole
-    # length.
-    for name, dtype, kv_heads, head_dim, length, tol in (
-            ("float32", "float32", 16, 64, 1024, 1e-4),
-            ("bfloat16", "bfloat16", 16, 128, 1024, 0.04),
-            ("grouped_bfloat16", "bfloat16", 2, 256, 3072, 0.04)):
-        heads = 16
+    # of 256 in bfloat16: a 1 KB row, blocks of 1,024; 32 query heads on 8
+    # K/V heads of 64 in bfloat16 — a group of 4, no whole sublane tile, a
+    # K/V head on half a lane tile — with the scores multiplied by 1/64 as
+    # that family hands it over), one layer of a pool of eight slots at
+    # ragged positions: dead, a block's edges, the whole length.
+    for name, dtype, heads, kv_heads, head_dim, length, scale, tol in (
+            ("float32", "float32", 16, 16, 64, 1024, None, 1e-4),
+            ("bfloat16", "bfloat16", 16, 16, 128, 1024, None, 0.04),
+            ("grouped_bfloat16", "bfloat16", 16, 2, 256, 3072, None, 0.04),
+            ("narrow_group_bfloat16", "bfloat16", 32, 8, 64, 1024, 1.0 / 64,
+             0.04)):
         edge = kv_pool.read_block((1, 1, length, kv_heads * head_dim), dtype)
         position = np.asarray([0, 1, edge - 1, edge, edge + 1, 600,
                                length - 1, length], np.int32)
@@ -142,7 +145,7 @@ def validate_kernels(interpret: bool = False) -> dict:
             for n in (heads, kv_heads, kv_heads))
         got = np.asarray(jax.jit(
             lambda *a: kv_pool.decode_attention(
-                *a, 0, position, interpret=interpret)
+                *a, 0, position, interpret=interpret, scale=scale)
         )(q, k_new, v_new, k_pool, v_pool), np.float32)
         f32 = [np.asarray(x, np.float32) for x in (q, k_new, v_new)]
         cached = [np.asarray(x, np.float32)[0].reshape(
@@ -155,8 +158,8 @@ def validate_kernels(interpret: bool = False) -> dict:
                 np.concatenate([c[slot, :p], new[slot][None]]),
                 heads // kv_heads, axis=1)
                 for c, new in zip(cached, f32[1:]))
-            scores = np.einsum("lhd,hd->hl", keys, f32[0][slot]) / np.sqrt(
-                head_dim)
+            scores = np.einsum("lhd,hd->hl", keys, f32[0][slot]) * (
+                head_dim ** -0.5 if scale is None else scale)
             w = np.exp(scores - scores.max(-1, keepdims=True))
             want = np.einsum("hl,lhd->hd", w / w.sum(-1, keepdims=True),
                              values)

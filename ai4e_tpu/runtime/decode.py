@@ -159,6 +159,10 @@ class LaunchedStep:
     attended: int | None = None
     # Bytes of each kind of cache it reads and writes, ``{kind: bytes}``.
     cache_bytes: dict = field(default_factory=dict)
+    # Of the fixed-size state: ``moved`` (what it reads and writes) and
+    # ``live`` (the part of that which belongs to slots with a live
+    # sequence); empty from a backend whose slots hold K/V alone.
+    state_bytes: dict = field(default_factory=dict)
     active: list = field(default_factory=list)   # the launch's live slots
     out: object = None            # the backend's own hold on the unread ids
     ids: list | None = None       # after ``fetch``: next token id per slot
@@ -190,6 +194,7 @@ class _BlockingSteps:
             bound=getattr(backend, "step_bound", backend.max_len),
             attended=getattr(backend, "step_attended", None),
             cache_bytes=dict(getattr(backend, "step_cache_bytes", {})),
+            state_bytes=dict(getattr(backend, "step_state_bytes", {})),
             active=active, ids=self._ids,
             report=dict(getattr(backend, "step_report", {})))
 
@@ -284,14 +289,16 @@ class DecodeEngine:
       id the LAST LAUNCHED step gave it, which the backend kept. The record
       carries what the backend worked out at the launch: ``bound`` (observed
       as ``ai4e_decode_step_bound``), ``attended`` (counted as attended K/V
-      positions), ``cache_bytes`` (``ai4e_decode_cache_bytes_total{kind}``);
+      positions), ``cache_bytes`` (``ai4e_decode_cache_bytes_total{kind}``),
+      ``state_bytes`` (``ai4e_decode_state_bytes_total{kind}``);
     - ``fetch(step) -> step``: block until that step has run and fill in
       ``ids`` (next token id per slot) and ``report`` (``{name: value}``,
       observed as ``ai4e_decode_<name>``). Steps are fetched in the order
       they were launched; a failure surfaces here;
     - or, in place of the two, only a blocking ``step(tokens, positions,
       active) -> ids`` with its figures left in ``step_bound`` /
-      ``step_attended`` / ``step_cache_bytes`` / ``step_report``
+      ``step_attended`` / ``step_cache_bytes`` / ``step_state_bytes`` /
+      ``step_report``
       attributes (the tests' fakes): ``_BlockingSteps`` adapts it;
     - optionally ``step_report_series`` (attribute, ``{name: (help,
       buckets)}``): what a model that reports on its step declares;
@@ -393,6 +400,18 @@ class DecodeEngine:
             "kind: kv (the K/V rows its attention read and the row a live "
             "slot wrote) and state (fixed-size per-slot state, read and "
             "written whole), as the backend counts them")
+        self._state_bytes = self.metrics.counter(
+            "ai4e_decode_state_bytes_total",
+            "Bytes of the slots' fixed-size state a decode step read and "
+            "wrote, by kind: moved (all of them) and live (those of slots "
+            "with a live sequence), as the backend counts them; live / "
+            "moved is what a step over live slots only would leave")
+        self._tick_joins = self.metrics.histogram(
+            "ai4e_decode_tick_joins",
+            "Prefills admitted between two launched decode steps, observed "
+            "on every tick that admitted at least one: each stops every "
+            "live stream for its duration",
+            buckets=(*range(1, backend.slots + 1), float("inf")))
         self._occupancy = self.metrics.gauge(
             "ai4e_decode_slot_occupancy",
             "Occupied KV-cache slots / total slots per model")
@@ -421,6 +440,7 @@ class DecodeEngine:
             "Slot-steps computed for a sequence that had ended (EOS, "
             "cancel, expiry, drain) while a launched step held it")
         self._tick_no = 0
+        self._joins = 0   # prefills admitted since the last launched step
         # Seconds booked since the previous step's submit, every phase but
         # ``yield``; ``_last_submit`` is None after an idle wait or a tick
         # without a step, so an idle engine is not a long tick.
@@ -594,6 +614,9 @@ class DecodeEngine:
         t0 = time.perf_counter()
         await self._admit()
         self._phase["admit"] += time.perf_counter() - t0
+        if self._joins:
+            self._tick_joins.observe(self._joins, model=self._model)
+            self._joins = 0
         await self._step()
 
     async def _check_reload(self) -> None:
@@ -684,6 +707,7 @@ class DecodeEngine:
             if seq.ledger is not None:
                 seq.ledger.stamp("slot", "decode", ms=wait * 1e3,
                                  reason=f"slot {slot} tick {self._tick_no}")
+            self._joins += 1
             token = await self._prefill(seq, seq.prompt)
             if token is None or seq.done:
                 continue  # failed, or re-check after the await: retired
@@ -843,6 +867,8 @@ class DecodeEngine:
             else step.attended, model=self._model, kind="attended")
         for kind, nbytes in step.cache_bytes.items():
             self._cache_bytes.inc(nbytes, model=self._model, kind=kind)
+        for kind, nbytes in step.state_bytes.items():
+            self._state_bytes.inc(nbytes, model=self._model, kind=kind)
         for name, value in step.report.items():
             self._step_report[name].observe(value, model=self._model)
         for slot, seq, position in snapshot:

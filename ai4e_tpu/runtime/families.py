@@ -646,12 +646,33 @@ def build_qwen3_next_lm(name: str = "lm", vocab_size: int = 512,
                       vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
 
 
+def build_granite_hybrid_lm(name: str = "lm", vocab_size: int = 512,
+                            max_len: int = 256, eos_id: int | None = None,
+                            rng=None, dtype: str = "bfloat16", **dims):
+    """The state-space hybrid (``models/granite_hybrid.py``
+    ``GraniteHybridLM``): Mamba-2 layers with a recurrent state a slot,
+    grouped-query attention without positions at ``attention_layers``, a
+    dense gated MLP, four scalar multipliers, the head tied to the
+    embedding, bfloat16 weights and K/V. ``dims``: the model's fields
+    (``dim``, ``depth``, ``attention_layers``, ``heads``, ``kv_heads``,
+    ``head_dim``, ``mlp_dim``, ``ssm_heads``, ``ssm_head_dim``,
+    ``ssm_state``, ``conv``, ``chunk``, the multipliers, ...); a key the
+    family does not know is an error, not a default."""
+    from ..models.granite_hybrid import create_granite_hybrid_lm
+    from .kvcache import LMServable
+    model, params = create_granite_hybrid_lm(rng=rng, vocab_size=vocab_size,
+                                             dtype=dtype, **dims)
+    return LMServable(name=name, model=model, params=params,
+                      vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
+
+
 # LM families ride the decode engine (``runtime/decode.py``), never the
 # MicroBatcher: ``cli`` tells them from the batch families by this table.
 LM_FAMILIES = {
     "seqformer-lm": build_seqformer_lm,
     "olmoe": build_olmoe_lm,
     "qwen3-next": build_qwen3_next_lm,
+    "granite-hybrid": build_granite_hybrid_lm,
 }
 
 

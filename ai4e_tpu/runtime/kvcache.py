@@ -332,7 +332,9 @@ class PagedDecodeRuntime:
         ``kv_pool.positions_read``) and ``cache_bytes`` — ``kv``: those rows
         of K and of V, every K/V layer, and the one row a live slot writes;
         ``state``: every state tensor read once and written once (the step
-        is over the pool: an idle slot's state moves too)."""
+        is over the pool: an idle slot's state moves too) — and, where a
+        slot holds state, ``state_bytes``: those bytes as ``moved`` and the
+        live slots' share of them as ``live``."""
         self._ensure()
         bound = self.bound_for(max(
             (p for p, live in zip(positions, active) if live), default=0))
@@ -356,11 +358,13 @@ class PagedDecodeRuntime:
         except Exception:
             self._ids = None   # nothing launched: the next launch feeds all
             raise
+        live = sum(map(bool, active))
+        moved = 2 * self._state_nbytes
         return LaunchedStep(
             bound=bound, attended=attended, active=list(active), out=out,
-            cache_bytes={
-                "kv": row_bytes * (attended + sum(map(bool, active))),
-                "state": 2 * self._state_nbytes})
+            cache_bytes={"kv": row_bytes * (attended + live), "state": moved},
+            state_bytes=({"moved": moved, "live": moved * live // self.slots}
+                         if moved else {}))
 
     def fetch(self, step: LaunchedStep) -> LaunchedStep:
         """Wait for a launched step and read what it returned: ``ids``, and

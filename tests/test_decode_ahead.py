@@ -27,7 +27,7 @@ from ai4e_tpu.runtime.decode import DecodeEngine, LaunchedStep
 from test_decode import wait_until
 from test_decode_tick import series
 
-# -- the three families against the plain loop --------------------------------
+# -- the four families against the plain loop --------------------------------
 
 MAX_LEN, SLOTS = 48, 3
 FAMILIES = {
@@ -38,6 +38,10 @@ FAMILIES = {
                        head_dim=32, rotary_dim=8, lin_k_heads=2,
                        lin_v_heads=4, lin_dim=16, experts=16, experts_held=8,
                        experts_per_token=3, expert_dim=32, shared_dim=32),
+    "granite-hybrid": dict(vocab_size=97, dim=64, depth=4,
+                           attention_layers=(2,), heads=8, kv_heads=2,
+                           head_dim=16, mlp_dim=96, ssm_heads=4,
+                           ssm_head_dim=16, ssm_state=16, chunk=8),
 }
 LONG = tuple(range(3, 3 + MAX_LEN - 5))      # leaves room for six tokens
 PROMPTS = {"a": (5, 9, 12), "b": (7,), "c": LONG, "d": (11, 2, 30, 4),

@@ -408,10 +408,12 @@ def _grouped(dtype, heads, kv_heads, head_dim, slots, max_len, seed=31):
     return shape, (q, k_new, v_new, k, v)
 
 
-def _plain_grouped(args, position, kv_heads):
-    """The float32 oracle: query head ``h`` reads K/V head ``h // group``."""
+def _plain_grouped(args, position, kv_heads, scale=None):
+    """The float32 oracle: query head ``h`` reads K/V head ``h // group``;
+    the scores by ``scale``, ``1/√head_dim`` when None."""
     q, k_new, v_new, k, v = (np.asarray(a, np.float32) for a in args)
     slots, heads, head_dim = q.shape
+    scale = head_dim ** -0.5 if scale is None else scale
     group = heads // kv_heads
     k, v = (a[0].reshape(slots, -1, kv_heads, head_dim) for a in (k, v))
     out = np.zeros(q.shape, np.float32)
@@ -420,19 +422,23 @@ def _plain_grouped(args, position, kv_heads):
             g = h // group
             keys = np.concatenate([k[s, :p, g], k_new[s, g][None]])
             values = np.concatenate([v[s, :p, g], v_new[s, g][None]])
-            out[s, h] = softmax(keys @ q[s, h] / np.sqrt(head_dim)) @ values
+            out[s, h] = softmax(keys @ q[s, h] * scale) @ values
     return out
 
 
 @pytest.mark.parametrize("dtype", list(TOLERANCE))
-@pytest.mark.parametrize("heads, kv_heads", [(16, 2), (8, 8), (16, 16)],
-                         ids=["16on2", "8on8", "16on16"])
+@pytest.mark.parametrize("heads, kv_heads", [(16, 2), (8, 8), (16, 16),
+                                             (32, 8), (6, 2), (4, 2)],
+                         ids=["16on2", "8on8", "16on16", "32on8", "6on2",
+                              "4on2"])
 def test_decode_kernel_with_grouped_heads(dtype, heads, kv_heads,
                                           monkeypatch):
     """16 query heads on 2 K/V heads (eight a K/V head, laid onto its lanes
-    in the kernel's block-diagonal operand), and one a K/V head at 8 and at
-    16: ragged positions over three blocks a slot against the float32
-    oracle, and a bound below a slot's position cuts at the bound."""
+    in the kernel's block-diagonal operand), one a K/V head at 8 and at
+    16, and groups that are no whole sublane tile — 4 a K/V head at 32 on 8
+    and at 4 on 2, 3 at 6 on 2, whose rows select their line: ragged
+    positions over three blocks a slot against the float32 oracle, and a
+    bound below a slot's position cuts at the bound."""
     head_dim, max_len = 16, 96
     row = kv_heads * head_dim * jnp.dtype(dtype).itemsize
     monkeypatch.setattr(kv_pool, "READ_BLOCK_BYTES", 32 * row)
@@ -454,6 +460,31 @@ def test_decode_kernel_with_grouped_heads(dtype, heads, kv_heads,
                                rtol=0, atol=TOLERANCE[dtype])
 
 
+@pytest.mark.parametrize("scale", [None, 1.0 / 64, 0.3])
+def test_decode_kernel_at_the_narrow_group_served_row_width(scale):
+    """32 query heads on 8 K/V heads of 64 in bfloat16 — a group of 4, a K/V
+    head on half a lane tile, a row of 1 KB and so one block of the whole
+    1,024-position pool — at ragged positions against the float32 oracle,
+    with the family's own score multiplier (1/64, not 1/√64) handed over,
+    another one, and the default; the same bits at the rung below for the
+    slots it holds."""
+    shape, args = _grouped("bfloat16", 32, 8, 64, 6, 1024)
+    assert shape == (1, 6, 1024, 512)
+    assert kv_pool.read_block(shape, jnp.bfloat16) == 1024
+    position = (0, 1, 511, 767, 1023, 1024)
+    pos = jnp.asarray(position, jnp.int32)
+    top = np.asarray(kv_pool.decode_attention(*args, 0, pos, scale=scale),
+                     np.float32)
+    np.testing.assert_allclose(top, _plain_grouped(args, position, 8, scale),
+                               rtol=0, atol=TOLERANCE["bfloat16"])
+    if scale is not None:   # and it is not the default's result
+        default = _plain_grouped(args, position, 8)
+        assert np.abs(top - default).max() > 10 * TOLERANCE["bfloat16"]
+    below = np.asarray(kv_pool.decode_attention(*args, 0, pos, 768,
+                                                scale=scale), np.float32)
+    assert (below[:4] == top[:4]).all()
+
+
 def test_decode_kernel_at_the_grouped_served_row_width():
     """16 query heads on 2 K/V heads of 256 in bfloat16 — a row of 1 KB, so
     by the same 1 MB rule the block is 1,024 positions — over three blocks a
@@ -473,10 +504,12 @@ def test_decode_kernel_at_the_grouped_served_row_width():
     assert (below[:4] == top[:4]).all()
 
 
+@pytest.mark.parametrize("scale", [None, 1.0 / 64])
 @pytest.mark.parametrize("dtype", list(TOLERANCE))
-def test_prefill_attention_with_grouped_heads(dtype):
+def test_prefill_attention_with_grouped_heads(dtype, scale):
     """Four query heads on two K/V heads over a padded prompt: a causal
-    softmax over the real tokens, head ``h`` reading K/V head ``h // 2``."""
+    softmax over the real tokens, head ``h`` reading K/V head ``h // 2``,
+    the scores by ``1/√head_dim`` or by the multiplier handed over."""
     batch, prompt, heads, kv_heads, head_dim = 2, 6, 4, 2, 8
     length = (prompt, 3)
     rng = np.random.default_rng(37)
@@ -485,14 +518,15 @@ def test_prefill_attention_with_grouped_heads(dtype):
     k, v = (jnp.asarray(rng.standard_normal(
         (batch, prompt, kv_heads, head_dim)), dtype) for _ in range(2))
     mask = jnp.arange(prompt)[None, :] < jnp.asarray(length)[:, None]
-    got = np.asarray(kv_pool.prefill_attention(q, k, v, mask), np.float32)
+    got = np.asarray(kv_pool.prefill_attention(q, k, v, mask, scale=scale),
+                     np.float32)
     assert got.shape == q.shape
     qf, kf, vf = (np.asarray(a, np.float32) for a in (q, k, v))
     for b in range(batch):
         for i in range(length[b]):
             for h in range(heads):
                 w = softmax(kf[b, :i + 1, h // 2] @ qf[b, i, h]
-                            / np.sqrt(head_dim))
+                            * (head_dim ** -0.5 if scale is None else scale))
                 np.testing.assert_allclose(
                     got[b, i, h], w @ vf[b, :i + 1, h // 2], rtol=0,
                     atol=TOLERANCE[dtype])

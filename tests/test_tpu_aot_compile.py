@@ -401,6 +401,96 @@ def test_qnext_step_at_the_benchmark_cell_moves_no_pool(
         assert peak < 15e9, (top, peak)
 
 
+@pytest.fixture(scope="module")
+def granite_cell():
+    from ai4e_tpu.models.granite_hybrid import (GraniteHybridLM,
+                                                create_granite_hybrid_lm)
+    from benchmark.references.granite_hybrid import MODEL_KEYS
+
+    def model(**dims):   # the spec's JSON list as the module's tuple
+        return GraniteHybridLM(**dict(dims, attention_layers=tuple(
+            dims["attention_layers"])))
+
+    return _benchmark_cell("granite-4.0-h-micro.json",
+                           create_granite_hybrid_lm, model, MODEL_KEYS)
+
+
+@pytest.mark.parametrize("rung", [0, 1])
+def test_granite_step_at_the_benchmark_cell_moves_no_pool(
+        v5e_sharding, granite_cell, rung):
+    """The same, for the ``granite.burstchat`` cell
+    (``benchmark/configs/granite-4.0-h-micro.json``), the whole model: a K/V
+    pool of the four attention layers, rows of 1 KB under 32 query heads on 8
+    K/V heads of 64 (one Mosaic kernel a K/V layer, the narrow-group form),
+    made only by row writes; and beside it the state pool — 36
+    ``f32[64,64,64,128]`` tensors (4.83 GB) and 36 convolution tails — every
+    tensor aliased input to output, each made by ONE multi-output fusion
+    that reads ``S`` once, writes its successor and takes ``S C`` from the
+    same pass, with temporaries smaller than one state tensor. At the top
+    rung, the whole worker's memory: weights (the tied table once) + both
+    pools + the widest prefill's (512, and the cache length 1,024 the runtime
+    adds) temporaries and outputs stay under the 15 GB line. About 8 s and
+    50 s."""
+    from ai4e_tpu.ops import state_pool
+    runtime, spec = granite_cell
+    assert runtime.step_bounds == (768, 1024)
+    assert runtime.cache_spec() == ((4, 64, 1024, 512), jnp.bfloat16)
+    state = runtime.state_spec()
+    assert len(state) == 72
+    assert state[0] == ("ssm0", (64, 64, 128), jnp.float32)
+    assert state[1] == ("conv0", (3, 4352), jnp.bfloat16)
+    one_state = 64 * 64 * 64 * 128 * 4
+    bound = runtime.step_bounds[rung]
+    compiled = _compile_step(runtime, v5e_sharding, bound)
+    memory = _assert_step_reads_in_place(runtime, compiled, bound, one_state)
+    pools = runtime.cache_nbytes()
+    assert pools == 2 * 4 * 64 * 1024 * 512 * 2 + state_pool.nbytes(state, 64)
+    assert memory.alias_size_in_bytes >= pools
+    # a state tensor is a parameter or one element of a fusion's tuple:
+    # nothing copies or re-lays one
+    state_type = _hlo_type((64, 64, 64, 128), jnp.float32)
+    makers = sorted(op for kind, op in _entry_results(compiled)
+                    if kind.startswith(state_type))
+    assert makers == ["get-tuple-element"] * 36 + ["parameter"] * 36, makers
+    # the table is one parameter: embedding and head read the same array
+    table = _hlo_type((spec["vocab_size"], spec["dim"]), jnp.bfloat16)
+    assert [op for kind, op in _entry_results(compiled)
+            if kind.startswith(table)] == ["parameter"]
+    if bound < runtime.max_len:
+        return
+
+    resident = memory.argument_size_in_bytes   # weights + pools (+ ints)
+    assert 11.7e9 < resident < 11.9e9, resident
+    assert runtime.max_len == spec["max_len"] == 1024
+    for top in (512, runtime.max_len):
+        prefill = runtime._programs["prefill"].lower(
+            _on(v5e_sharding, runtime.servable.params),
+            _on(v5e_sharding, ((1, top), jnp.int32)),
+            _on(v5e_sharding, ((1,), jnp.int32))).compile().memory_analysis()
+        peak = resident + max(memory.temp_size_in_bytes,
+                              prefill.temp_size_in_bytes
+                              + prefill.output_size_in_bytes)
+        assert peak < 15e9, (top, peak)
+
+
+def test_decode_kernel_with_a_narrow_group_compiles(v5e_sharding):
+    """The decode-attention kernel at the narrow-group shape alone: 32 query
+    heads on 8 K/V heads of 64 — a group of 4, no whole sublane tile, a K/V
+    head on half a lane tile — one block of the 1,024 positions of a 1 KB
+    row, with a scale handed over, by Mosaic."""
+    from ai4e_tpu.ops import kv_pool
+    pool = _on(v5e_sharding, ((4, 64, 1024, 512), jnp.bfloat16))
+    q = _on(v5e_sharding, ((64, 32, 64), jnp.bfloat16))
+    new = _on(v5e_sharding, ((64, 8, 64), jnp.bfloat16))
+    ints = _on(v5e_sharding, ((64,), jnp.int32))
+    compiled = _compile(
+        lambda q, k_new, v_new, k, v, position: kv_pool.decode_attention(
+            q, k_new, v_new, k, v, 1, position, 768, interpret=False,
+            scale=1.0 / 64),
+        q, new, new, pool, pool, ints)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_decode_kernel_with_grouped_heads_compiles(v5e_sharding):
     """The decode-attention kernel at the grouped shape alone: sixteen
     query heads on two K/V heads of 256, blocks of 1,024 positions of a 1 KB
