@@ -35,10 +35,14 @@ layer ``i`` mixes by attention iff ``i`` is in ``attention_layers``.
   runs over all ``I`` lanes — and ``W_out``.
 
 What a slot holds (``cache_spec``): K/V of the attention layers only, and
-per Mamba layer its state ``ssm<j>`` (float32) and the convolution's last
-``conv − 1`` inputs ``conv<j>`` (``ops/state_pool.py``). ``decode_step`` is
-the recurrence as written, one token a slot, every slot of the pool.
-``prefill`` runs the same recurrence ``chunk`` tokens at a time (the "SSD"
+per Mamba layer its state ``ssm<j>`` (float32, laid out ``(N, H · P)``: the
+state's index on the sublanes, a head's lanes side by side — ``ssd_block``
+says why) and the convolution's last ``conv − 1`` inputs ``conv<j>``
+(``ops/state_pool.py``). ``decode_step`` is the recurrence as written, one
+token a slot: ``ssm<j>`` advances at the live slots only, in place
+(``ssd_update``: ``state_pool.update_live`` with ``ssd_block`` as the slot's
+math), the convolution's few KB a slot at every slot. ``prefill`` runs the
+same recurrence ``chunk`` tokens at a time (the "SSD"
 form: inside a chunk one masked matrix product, across chunks the state
 carried by a scan), with padded positions at ``Δ = 0`` — they neither decay
 nor feed the state — and outside the convolution's tail, so the state it
@@ -58,7 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import kv_pool
+from ..ops import kv_pool, state_pool
 from .olmoe import norm_scale, seeded
 
 # The seeded init's gains (``create_granite_hybrid_lm`` says why these): the
@@ -116,13 +120,63 @@ def ramp(lo: float, hi: float, stride: int = 1, log: bool = False,
 
 
 def ssd_step(state, x, dt, a, b, c):
-    """One token of the recurrence for every (slot, head). state: (..., H,
-    P, N) float32; x: (..., H, P); dt: (..., H) — ``Δ``; a: (H,) — ``A``;
-    b, c: (..., N). Returns ``(S C (..., H, P), new state)``."""
+    """One token of the recurrence for every (slot, head), in ``jax.numpy``
+    — the equation ``ssd_block`` is held to. state: (..., H, P, N) float32;
+    x: (..., H, P); dt: (..., H) — ``Δ``; a: (H,) — ``A``; b, c: (..., N).
+    Returns ``(S C (..., H, P), new state)``."""
     decay = jnp.exp(dt * a)[..., None, None]
     state = state * decay + (dt[..., None] * x)[..., None] * b[
         ..., None, None, :]
     return (state * c[..., None, None, :]).sum(axis=-1), state
+
+
+def ssd_block(state_ref, packed_ref, y_ref, successor_ref):
+    """``ssd_step`` on one slot's block, in VMEM
+    (``state_pool.update_live``'s ``body``). state_ref, successor_ref: (N, W)
+    with ``W = H · P``, lane ``h · P + p`` of row ``n`` holding ``S[h, p,
+    n]``; packed_ref: (2 W / N + 2, N) — ``Δ A`` a lane, N lanes a row,
+    then ``Δ x`` likewise, then B, then C; y_ref: (W / N, N) — ``S C``, N
+    lanes a row.
+
+    In this layout everything a lane needs of its head and channel is a row
+    (``e^{Δ A}``, ``Δ x``: rows of the operand as XLA hands them) and the
+    read-out ``S C`` is a sum down the sublanes, which the vector units do
+    with adds; only B and C have to stand as columns, once a slot (a square
+    transpose). With N on the lanes instead the read-out is a cross-lane sum
+    a vector register and ``Δ x`` a lane broadcast a register, and the
+    kernel ran at half the blocks' DMA rate on a v5e (PERF.md section 6, PR
+    35). N lanes of all N rows at a time, so nothing of the block's size is
+    held as a value."""
+    n, width = state_ref.shape
+    chunks = width // n
+    b_col = jnp.broadcast_to(packed_ref[2 * chunks:2 * chunks + 1, :],
+                             (n, n)).T                     # [k, :] = B[k]
+    c_col = jnp.broadcast_to(packed_ref[2 * chunks + 1:, :], (n, n)).T
+    for j in range(chunks):
+        lanes = slice(j * n, (j + 1) * n)
+        new = (state_ref[:, lanes] * jnp.exp(packed_ref[j:j + 1, :])
+               + b_col * packed_ref[chunks + j:chunks + j + 1, :])
+        successor_ref[:, lanes] = new
+        y_ref[j:j + 1, :] = (new * c_col).sum(axis=0, keepdims=True)
+
+
+def ssd_update(state, x, dt, a, b, c, position, interpret=None):
+    """``ssd_step`` at the live slots of the pool (``position > 0``) only,
+    in place. state: (S, N, H · P) — the pool's tensor; x: (S, H, P); dt:
+    (S, H); a: (H,); b, c: (S, N). Returns ``(S C (S, H · P) — zeros at a
+    dead slot —, the tensor's successor)``; a dead slot's state is what it
+    was."""
+    slots, n, width = state.shape
+
+    def rows(v):   # (S, H, 1 or P) -> (S, H · P / N, N)
+        return jnp.broadcast_to(v, x.shape).reshape(slots, -1, n)
+
+    y, state = state_pool.update_live(
+        state, (jnp.concatenate(
+            [rows((dt * a)[..., None]), rows(dt[..., None] * x), b[:, None],
+             c[:, None]], axis=1),),
+        position, ssd_block, ((width // n, n), jnp.float32), interpret)
+    return y.reshape(slots, width), state
 
 
 def ssd_chunked(x, dt, a, b, c, chunk: int):
@@ -285,12 +339,13 @@ class _Layer(nn.Module):
         return x, mixed[..., i:i + n], mixed[..., i + n:]
 
     def _ssm_out(self, x_in, y, x, z):
-        """``y = S C`` and the heads' inputs ``x`` → the skip, the gate, the
-        norm over all lanes, ``W_out`` and the residual."""
+        """``y = S C`` and the heads' inputs ``x``, both ``(..., I)`` — a
+        head's lanes side by side — → the skip, the gate, the norm over all
+        lanes, ``W_out`` and the residual."""
         with jax.named_scope("gated_norm"):
-            y = y + self.d_skip.astype(jnp.float32)[:, None] * x
-            y = y.reshape(*x_in.shape[:-1], -1) * jax.nn.silu(
-                z.astype(jnp.float32))
+            y = y + jnp.repeat(self.d_skip.astype(jnp.float32),
+                               self.ssm_head_dim) * x
+            y = y * jax.nn.silu(z.astype(jnp.float32))
             y = rms_norm(y, self.norm_g, self.eps).astype(self.dtype)
         with jax.named_scope("out_proj"):
             return self._add(x_in, _dot("...e,ed->...d", y, self.out_proj))
@@ -300,8 +355,8 @@ class _Layer(nn.Module):
     def prefill(self, x, mask, length):
         """x: (B, P, D); mask: (B, P) valid tokens; length: (B,). Returns
         ``(y, cache)``: an attention layer's cache is ``(k, v)`` of (B, P,
-        KVH, hd), a Mamba layer's ``(state (B, H, P, N), tail (B, conv − 1,
-        I + 2N))`` after ``length`` tokens."""
+        KVH, hd), a Mamba layer's ``(state (B, N, H · P) — the pool's layout
+        —, tail (B, conv − 1, I + 2N))`` after ``length`` tokens."""
         p = x.shape[1]
         if self.attention:
             q, k, v = self._qkv(x)
@@ -330,15 +385,22 @@ class _Layer(nn.Module):
                         xs, jnp.where(mask[..., None], dt, 0.0),
                         -jnp.exp(self.a_log.astype(jnp.float32)), b, c,
                         self.chunk)
-                x, cache = self._ssm_out(x, y, xs, z), (state, tail)
+                    # (B, H, P, N) as the scan leaves it -> the pool's
+                    # (B, N, H · P): one transpose of 2 MB a layer a prompt
+                    state = state.reshape(state.shape[0], -1,
+                                          self.ssm_state).swapaxes(1, 2)
+                x = self._ssm_out(x, y.reshape(*y.shape[:2], -1),
+                                  out[..., :self.inner], z)
+                cache = state, tail
         return self._mlp(x), cache
 
     def step(self, x, cache, position, bound):
         """One token per slot: x (S, D). An attention layer's ``cache`` is
         ``(k pool, v pool, its K/V layer)`` and it returns the new token's
         ``(k, v)`` (S, KVH, hd) for ``kv_pool.write_rows``; a Mamba layer's
-        is ``(state, tail)`` of every slot and it returns their
-        successors."""
+        is ``(state, tail)`` of every slot and it returns their successors:
+        the state advanced at the live slots (``position > 0``) only, a
+        dead slot's as it was."""
         if self.attention:
             k_pool, v_pool, layer = cache
             q, k_new, v_new = self._qkv(x)
@@ -358,10 +420,12 @@ class _Layer(nn.Module):
                         + self.conv_b.astype(jnp.float32))
                 with jax.named_scope("state_update"):
                     xs, b, c = self._split(out)
-                    y, state = ssd_step(
+                    y, state = ssd_update(
                         state, xs, dt,
-                        -jnp.exp(self.a_log.astype(jnp.float32)), b, c)
-                x, cache = self._ssm_out(x, y, xs, z), (state, window[:, 1:])
+                        -jnp.exp(self.a_log.astype(jnp.float32)), b, c,
+                        position)
+                x = self._ssm_out(x, y, out[..., :self.inner], z)
+                cache = state, window[:, 1:]
         return self._mlp(x), cache
 
 
@@ -411,16 +475,18 @@ class GraniteHybridLM(nn.Module):
     def cache_spec(self):
         """What a slot holds (``kv_pool.SlotSpec``): K/V of the attention
         layers, and of the ``j``-th Mamba layer its state ``ssm<j>``
-        (float32) and its convolution's last inputs ``conv<j>``."""
-        channels = self.ssm_heads * self.ssm_head_dim + 2 * self.ssm_state
+        (float32; stepped at the live slots only) and its convolution's last
+        inputs ``conv<j>`` (stepped at every slot)."""
+        inner = self.ssm_heads * self.ssm_head_dim
+        mamba = range(self.depth - len(self.attention_layers))
         state = []
-        for j in range(self.depth - len(self.attention_layers)):
-            state += [(f"ssm{j}", (self.ssm_heads, self.ssm_head_dim,
-                                   self.ssm_state), jnp.float32),
-                      (f"conv{j}", (self.conv - 1, channels), self.dtype)]
+        for j in mamba:
+            state += [(f"ssm{j}", (self.ssm_state, inner), jnp.float32),
+                      (f"conv{j}", (self.conv - 1, inner + 2 * self.ssm_state),
+                       self.dtype)]
         return kv_pool.SlotSpec(
             (len(self.attention_layers), self.kv_heads, self.head_dim),
-            self.dtype, tuple(state))
+            self.dtype, tuple(state), tuple(f"ssm{j}" for j in mamba))
 
     def _embed(self, tokens):
         with jax.named_scope("embedding"):
@@ -476,7 +542,7 @@ class GraniteHybridLM(nn.Module):
                     bound=None):
         """One token for every slot of the pool. Attention reads the cached
         positions ``< bound`` (``kv_pool.decode_attention``); the Mamba
-        layers read and replace every slot's state."""
+        layers advance the state of the slots at a position > 0."""
         h, k_cache, v_cache, state = self._step(
             tokens, k_cache, v_cache, state, position, bound)
         return (jnp.argmax(self._logits(h), axis=-1).astype(jnp.int32),
@@ -529,6 +595,11 @@ def create_granite_hybrid_lm(rng=None, vocab_size: int = 512,
                             **dims)
     if model.heads % model.kv_heads:
         raise ValueError("query heads must group onto K/V heads")
+    if (model.ssm_heads * model.ssm_head_dim) % model.ssm_state:
+        raise ValueError(
+            f"the step lays the state out {model.ssm_state} lanes at a time: "
+            f"{model.ssm_heads} heads of {model.ssm_head_dim} are no whole "
+            "number of them")
     if model.ssm_groups != 1:
         raise ValueError(f"written for one group of B and C, not "
                          f"{model.ssm_groups}")

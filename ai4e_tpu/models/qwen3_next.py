@@ -41,8 +41,11 @@ What a slot holds (``cache_spec``): K/V of the full-attention layers only,
 and per linear layer its state ``S`` (float32) and the convolution's last
 ``conv − 1`` inputs — fixed-size tensors a slot, named ``delta<j>`` and
 ``conv<j>`` (``ops/state_pool.py``). ``decode_step`` is the recurrence as
-written, one token a slot, every slot of the pool (an idle slot's state
-moves too and is replaced whole by the next prefill's). ``prefill`` runs
+written, one token a slot: ``delta<j>`` advances at the live slots only, in
+place (``delta_rule_update``: ``state_pool.update_live`` with
+``delta_rule_block`` as the slot's math; a dead slot's state stays what it
+was until the next prefill's replaces it whole), the convolution's few KB a
+slot at every slot. ``prefill`` runs
 the same recurrence in chunks of ``CHUNK`` tokens (the WY form of the
 published implementation: within a chunk the ``δ`` of every token is solved
 at once from a triangular system, across chunks the state is carried), with
@@ -64,7 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import kv_pool
+from ..ops import kv_pool, state_pool
 from . import experts as expert_layer
 from .olmoe import norm_scale, seeded
 
@@ -126,7 +129,8 @@ def l2_norm(x):
 
 
 def delta_rule_step(state, q, k, v, g, beta):
-    """One token of the gated delta rule for every (slot, head). state:
+    """One token of the gated delta rule for every (slot, head), in
+    ``jax.numpy`` — the equation ``delta_rule_block`` is held to. state:
     (..., dk, dv) float32; q, k: (..., dk) — normalised, q scaled; v: (...,
     dv); g, beta: (...). Returns ``(o (..., dv), new state)``. Both readings
     of the old state (``Sᵀk``, ``Sᵀq``) are taken in one pass and the new
@@ -137,6 +141,43 @@ def delta_rule_step(state, q, k, v, g, beta):
     delta = beta[..., None] * (v - decay * sk)
     o = decay * sq + (k * q).sum(axis=-1, keepdims=True) * delta
     return o, state * decay[..., None] + k[..., :, None] * delta[..., None, :]
+
+
+def delta_rule_block(state_ref, qk_ref, v_ref, gates_ref, o_ref,
+                     successor_ref):
+    """``delta_rule_step`` on one slot's block, in VMEM
+    (``state_pool.update_live``'s ``body``). state_ref, successor_ref: (H,
+    dk, dv); qk_ref: (2, dk, H) — q and k with a head a LANE, so that a
+    head's q (k) is a column down the sublanes as the products with ``S``
+    need it; v_ref, o_ref: (H, dv); gates_ref: (3, H) — ``g``, ``β`` and
+    ``k · q``. A head at a time: both readings of the old state are sums
+    down the sublanes, the new state one pass."""
+    heads, _, dv = state_ref.shape
+
+    def gate(i, h):   # a head's scalar as a row (Mosaic broadcasts one way)
+        return jnp.broadcast_to(gates_ref[i:i + 1, h:h + 1], (1, dv))
+
+    for h in range(heads):
+        s = state_ref[h]
+        q, k = qk_ref[0, :, h:h + 1], qk_ref[1, :, h:h + 1]   # (dk, 1)
+        decay = jnp.exp(gate(0, h))
+        sk = (s * k).sum(axis=0, keepdims=True)               # (1, dv)
+        sq = (s * q).sum(axis=0, keepdims=True)
+        delta = gate(1, h) * (v_ref[h:h + 1, :] - decay * sk)
+        o_ref[h:h + 1, :] = decay * sq + gate(2, h) * delta
+        successor_ref[h] = s * decay + k * delta
+
+
+def delta_rule_update(state, q, k, v, g, beta, position, interpret=None):
+    """``delta_rule_step`` at the live slots of the pool (``position > 0``)
+    only, in place. state: (S, H, dk, dv) — the pool's tensor; the rest as
+    ``delta_rule_step`` takes them, a slot each. Returns ``(o (S, H, dv) —
+    zeros at a dead slot —, the tensor's successor)``; a dead slot's state
+    is what it was."""
+    return state_pool.update_live(
+        state, (jnp.stack([q, k], axis=1).swapaxes(-1, -2), v,
+                jnp.stack([g, beta, (k * q).sum(axis=-1)], axis=1)),
+        position, delta_rule_block, (v.shape[1:], jnp.float32), interpret)
 
 
 def delta_rule_chunked(q, k, v, g, beta, chunk: int = CHUNK):
@@ -396,8 +437,9 @@ class _Layer(nn.Module):
         """One token per slot: x (S, D). A full layer's ``cache`` is ``(k
         pool, v pool, its K/V layer)`` and it returns the new token's ``(k,
         v)`` (S, KVH, hd) for ``kv_pool.write_rows``; a linear layer's is
-        ``(state, tail)`` of every slot and it returns their successors.
-        Then ``(y, new cache, experts (S, K))``."""
+        ``(state, tail)`` of every slot and it returns their successors: the
+        state advanced at the live slots (``position > 0``) only, a dead
+        slot's as it was. Then ``(y, new cache, experts (S, K))``."""
         if self.full:
             k_pool, v_pool, layer = cache
             q, gate, k_new, v_new = self._qkv(x, position)
@@ -416,7 +458,8 @@ class _Layer(nn.Module):
                 with jax.named_scope("delta_rule"):
                     q, k, v = self._heads(out)
                     with jax.named_scope("state_update"):
-                        o, state = delta_rule_step(state, q, k, v, g, beta)
+                        o, state = delta_rule_update(state, q, k, v, g, beta,
+                                                     position)
                 x, cache = self._lin_out(x, o, z), (state, window[:, 1:])
         y, experts = self._moe(x, routed=False)
         return y, cache, experts
@@ -475,8 +518,8 @@ class Qwen3NextLM(nn.Module):
     def cache_spec(self):
         """What a slot holds (``kv_pool.SlotSpec``): K/V of the
         full-attention layers, and of the ``j``-th linear layer its state
-        ``delta<j>`` (float32) and its convolution's last inputs
-        ``conv<j>``."""
+        ``delta<j>`` (float32; stepped at the live slots only) and its
+        convolution's last inputs ``conv<j>`` (stepped at every slot)."""
         full = sum(map(self.is_full, range(self.depth)))
         channels = (2 * self.lin_k_heads + self.lin_v_heads) * self.lin_dim
         state = []
@@ -484,8 +527,9 @@ class Qwen3NextLM(nn.Module):
             state += [(f"delta{j}", (self.lin_v_heads, self.lin_dim,
                                      self.lin_dim), jnp.float32),
                       (f"conv{j}", (self.conv - 1, channels), self.dtype)]
-        return kv_pool.SlotSpec((full, self.kv_heads, self.head_dim),
-                                self.dtype, tuple(state))
+        return kv_pool.SlotSpec(
+            (full, self.kv_heads, self.head_dim), self.dtype, tuple(state),
+            tuple(f"delta{j}" for j in range(self.depth - full)))
 
     def _logits(self, h):
         with jax.named_scope("head"):
@@ -539,7 +583,7 @@ class Qwen3NextLM(nn.Module):
                     bound=None):
         """One token for every slot of the pool. Attention reads the cached
         positions ``< bound`` (``kv_pool.decode_attention``); the linear
-        layers read and replace every slot's state."""
+        layers advance the state of the slots at a position > 0."""
         h, k_cache, v_cache, state, experts = self._step(
             tokens, k_cache, v_cache, state, position, bound)
         ids = jnp.argmax(self._logits(h), axis=-1).astype(jnp.int32)

@@ -56,11 +56,14 @@ class SlotSpec(NamedTuple):
     of ``kv`` = (layers that keep K/V, K/V heads, head_dim) in ``dtype``,
     and ``state``: fixed-size tensors, each ``(name, shape a slot, dtype)``
     (``ops/state_pool.py``); none for a family whose every layer keeps
-    K/V."""
+    K/V. ``live``: the names of the state tensors its step advances at the
+    live slots only (``state_pool.update_live``); a tensor not named there
+    the step reads and writes at every slot."""
 
     kv: tuple
     dtype: Any
     state: tuple = ()
+    live: tuple = ()
 
 
 def pool_shape(spec: tuple, slots: int, max_len: int) -> tuple:

@@ -1,7 +1,8 @@
 """On-device Pallas kernel validation (VERDICT r1 next-round #5).
 
 The serving kernels (``flash_attention``, ``segmentation_argmax``,
-``normalize_image``, ``decode_attention``) default to interpret mode off-TPU, so CPU CI never
+``normalize_image``, ``decode_attention``, ``state_update``) default to
+interpret mode off-TPU, so CPU CI never
 proves they compile to Mosaic and fit VMEM on real hardware. This module is
 that proof: ``validate_kernels()`` runs each kernel with ``interpret=False``
 (on TPU) against a pure-XLA oracle and asserts its working set fits the
@@ -49,6 +50,20 @@ def decode_attention_vmem_bytes(block: int, heads: int, row: int,
     blocks = (2 * block + 4) * row * dtype_bytes
     scratch = heads * row * (dtype_bytes + 4) + 2 * heads * 128 * 4
     return 2 * blocks + scratch
+
+
+def state_update_vmem_bytes(block_bytes: int, small_bytes: int) -> int:
+    """One slot's block of the state tensor double-buffered in and out, the
+    slot's operands and read-out double-buffered, and the headroom the call
+    asks Mosaic for beside them (``state_update.vmem_bytes``: the same
+    number is the call's ``vmem_limit_bytes``)."""
+    from .state_update import vmem_bytes
+    return vmem_bytes(block_bytes, small_bytes)
+
+
+# What the chip has of VMEM, which a call may ask for beyond the scoped
+# default of ``VMEM_BUDGET_BYTES`` (``vmem_limit_bytes``).
+VMEM_PHYSICAL_BYTES = 128 * 1024 * 1024
 
 
 def validate_kernels(interpret: bool = False) -> dict:
@@ -170,6 +185,67 @@ def validate_kernels(interpret: bool = False) -> dict:
         assert vmem <= VMEM_BUDGET_BYTES, f"decode attention VMEM {vmem}"
         results[f"decode_attention_{name}"] = {
             "ok": bool(err < tol), "max_err": round(err, 6),
+            "vmem_bytes": vmem}
+
+    # the state update at the live slots of the pool vs the families' own
+    # jax.numpy recurrences — the two cells' blocks (Mamba-2: 64 heads of 64
+    # by a state of 128, the pool's (128, 4096) a slot; the delta rule: 32
+    # heads of 128 x 128; 2 MB a slot both), eight slots of which five are
+    # live: the first, the last and a dead one between; a dead slot's state
+    # must come back bit for bit.
+    from ...models import granite_hybrid, qwen3_next
+    position = np.asarray([3, 0, 9, 1, 0, 0, 700, 12], np.int32)
+    live = position > 0
+    slots = len(position)
+
+    def normal(*shape, scale=1.0):
+        return jax.numpy.asarray(rng.standard_normal(shape) * scale,
+                                 jax.numpy.float32)
+
+    h, p, n = 64, 64, 128
+    state = normal(slots, h, p, n)
+    x, dt, a = normal(slots, h, p), abs(normal(slots, h, scale=0.1)), -abs(
+        normal(h, scale=4.0))
+    b, c = normal(slots, n), normal(slots, n)
+
+    def ssd(state, *rest):   # the pool holds S (H, P, N) as (N, H · P)
+        out, new = granite_hybrid.ssd_update(
+            jax.numpy.moveaxis(state, 3, 1).reshape(slots, n, h * p), *rest,
+            position, interpret=interpret)
+        return out, jax.numpy.moveaxis(new.reshape(slots, n, h, p), 1, 3)
+
+    hv, dk = 32, 128
+    delta = normal(slots, hv, dk, dk)
+    q, k = normal(slots, hv, dk, scale=0.1), normal(slots, hv, dk, scale=0.1)
+    v, g = normal(slots, hv, dk), -abs(normal(slots, hv, scale=0.1))
+    beta = abs(normal(slots, hv, scale=0.5))
+
+    def delta_rule(*args):
+        return qwen3_next.delta_rule_update(*args, position,
+                                            interpret=interpret)
+
+    for name, run, oracle, args, flat in (
+            ("ssd", ssd, granite_hybrid.ssd_step,
+             (state, x, dt, a, b, c), (slots, h * p)),
+            ("delta_rule", delta_rule, qwen3_next.delta_rule_step,
+             (delta, q, k, v, g, beta), (slots, hv, dk))):
+        out, new = (np.asarray(r) for r in jax.jit(run)(*args))
+        want_out, want_new = (np.asarray(r) for r in oracle(*args))
+        err = max(
+            float(np.abs(out.reshape(flat)
+                         - want_out.reshape(flat))[live].max()),
+            float(np.abs(new.reshape(want_new.shape)
+                         - want_new)[live].max()))
+        untouched = bool(
+            np.array_equal(new.reshape(want_new.shape)[~live],
+                           np.asarray(args[0])[~live])
+            and not out[~live].any())
+        small = sum(int(np.prod(r.shape[1:])) * 4 for r in args[1:])
+        vmem = state_update_vmem_bytes(int(np.prod(new.shape[1:])) * 4,
+                                       small + out[0].size * 4)
+        assert vmem <= VMEM_PHYSICAL_BYTES // 2, f"state update VMEM {vmem}"
+        results[f"state_update_{name}"] = {
+            "ok": bool(err < 1e-4 and untouched), "max_err": round(err, 7),
             "vmem_bytes": vmem}
 
     results["all_ok"] = all(r["ok"] for r in results.values()

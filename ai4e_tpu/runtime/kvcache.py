@@ -29,7 +29,9 @@ discipline ``ModelRuntime.warmup`` applies to batch buckets):
   per bucket, any slot);
 - **step** — one decode step over the WHOLE pool: every slot advances
   one token (inactive slots ride along masked; their rows are garbage a
-  later prefill overwrites). A slot's token is the id the step before
+  later prefill overwrites; of the recurrent state only the live slots'
+  moves — ``state_pool.update_live`` — and a dead slot's stays what it
+  was). A slot's token is the id the step before
   gave it, which never left the device, unless the host feeds one (a
   slot prefilled since): ``launch`` dispatches the step and returns,
   ``fetch`` reads its ids, so the engine launches step N+1 before it
@@ -156,7 +158,9 @@ class PagedDecodeRuntime:
         self._k = None
         self._v = None
         self._state = None
-        self._state_nbytes = 0
+        # Bytes of state a slot holds: in the tensors a step advances at its
+        # live slots only, and in those it moves at every slot.
+        self._state_slot_bytes = (0, 0)
         # The ids of the last launched step, on the device: what the next
         # launch feeds every slot the host does not. None: there is no such
         # step (start, reset, a failure) and every live slot is fed.
@@ -205,7 +209,8 @@ class PagedDecodeRuntime:
         self._k = kv_pool.allocate(shape, dtype)
         self._v = kv_pool.allocate(shape, dtype)
         self._state = state_pool.allocate(self.state_spec(), self.slots)
-        self._state_nbytes = state_pool.nbytes(self.state_spec(), self.slots)
+        self._state_slot_bytes = state_pool.slot_bytes(
+            self.state_spec(), self.servable.model.cache_spec().live)
 
     def _ensure(self) -> None:
         if self._k is None:
@@ -331,11 +336,19 @@ class PagedDecodeRuntime:
         K/V positions its attention reads, a layer —
         ``kv_pool.positions_read``) and ``cache_bytes`` — ``kv``: those rows
         of K and of V, every K/V layer, and the one row a live slot writes;
-        ``state``: every state tensor read once and written once (the step
-        is over the pool: an idle slot's state moves too) — and, where a
-        slot holds state, ``state_bytes``: those bytes as ``moved`` and the
-        live slots' share of them as ``live``."""
+        ``state``: what the step reads and writes of the state pool, once
+        in and once out (``state_pool.slot_bytes``: the live slots' blocks
+        of a tensor the family steps through ``update_live``, every slot's
+        of one it does not) — and, where a slot holds state,
+        ``state_bytes``: those bytes as ``moved`` and what of them belongs
+        to live slots as ``live``. A slot is live to the device iff its
+        position is > 0 (the K/V read and the state update both skip a
+        slot at 0), so an active slot there is refused."""
         self._ensure()
+        if any(live and p < 1 for p, live in zip(positions, active)):
+            raise ValueError(
+                f"an active slot at position 0 (positions {list(positions)}, "
+                f"active {list(active)}): the step would skip it")
         bound = self.bound_for(max(
             (p for p, live in zip(positions, active) if live), default=0))
         shape, dtype = self.cache_spec()
@@ -359,12 +372,14 @@ class PagedDecodeRuntime:
             self._ids = None   # nothing launched: the next launch feeds all
             raise
         live = sum(map(bool, active))
-        moved = 2 * self._state_nbytes
+        sparse, dense = self._state_slot_bytes
+        moved = 2 * (live * sparse + self.slots * dense)
         return LaunchedStep(
             bound=bound, attended=attended, active=list(active), out=out,
             cache_bytes={"kv": row_bytes * (attended + live), "state": moved},
-            state_bytes=({"moved": moved, "live": moved * live // self.slots}
-                         if moved else {}))
+            state_bytes=({"moved": moved,
+                          "live": 2 * live * (sparse + dense)}
+                         if sparse + dense else {}))
 
     def fetch(self, step: LaunchedStep) -> LaunchedStep:
         """Wait for a launched step and read what it returned: ``ids``, and

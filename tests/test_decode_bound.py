@@ -163,10 +163,10 @@ class TestTheRungRule:
 
     @pytest.mark.parametrize("positions,active,bound", [
         ((10, 500, 500), (True, False, False), 384),   # stale and long
-        ((384, 385, 0), (True, False, True), 384),
+        ((384, 385, 1), (True, False, True), 384),
         ((384, 385, 0), (True, True, False), 512),
         ((0, 0, 0), (False, False, False), 384),       # nobody lives
-        ((385, 0, 0), (True, True, True), 512),
+        ((385, 1, 1), (True, True, True), 512),   # an active slot is past 0
     ])
     def test_a_step_takes_the_rung_of_its_live_slots(self, lm, positions,
                                                      active, bound):

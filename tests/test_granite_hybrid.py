@@ -276,8 +276,10 @@ def test_cache_spec_declares_kv_of_attention_layers_and_state_of_the_rest():
     assert spec.kv == (1, 2, 16) and spec.dtype == jnp.bfloat16
     assert [s[0] for s in spec.state] == [
         name for j in range(7) for name in (f"ssm{j}", f"conv{j}")]
-    assert spec.state[0][1:] == ((4, 16, 16), jnp.float32)
+    assert spec.state[0][1:] == ((16, 4 * 16), jnp.float32)
     assert spec.state[1][1:] == ((3, 4 * 16 + 2 * 16), jnp.bfloat16)
+    # the step advances the recurrence's state at the live slots only
+    assert spec.live == tuple(f"ssm{j}" for j in range(7))
     runtime = _runtime()
     kv = 2 * 1 * 3 * 96 * 32 * 4
     state = 7 * 3 * (4 * 16 * 16 * 4 + 3 * 96 * 4)
@@ -285,10 +287,13 @@ def test_cache_spec_declares_kv_of_attention_layers_and_state_of_the_rest():
     runtime.warm()
     stepped = runtime.fetch(runtime.launch([0] * 3, [5, 0, 9],
                                            [True, False, True]))
-    # every slot's state once in and once out; two of the three slots live
-    assert stepped.cache_bytes["state"] == 2 * state
-    assert stepped.state_bytes == {"moved": 2 * state,
-                                   "live": 2 * state * 2 // 3}
+    # once in and once out: the recurrence's state of the two live slots,
+    # the convolution's tail of all three
+    ssm, tail = 7 * 4 * 16 * 16 * 4, 7 * 3 * 96 * 4
+    assert stepped.cache_bytes["state"] == 2 * (2 * ssm + 3 * tail)
+    assert stepped.state_bytes == {"moved": 2 * (2 * ssm + 3 * tail),
+                                   "live": 2 * 2 * (ssm + tail)}
+    assert 3 * (ssm + tail) == state
 
 
 def test_roofline_counts_at_the_cell():
@@ -347,7 +352,7 @@ def test_the_worker_serves_the_family_through_the_same_wiring():
     assert backend.max_len == 64 and backend.prompt_buckets == (8, 64)
     assert backend._k.shape == (1, 4, 64, 32)
     assert backend._k.dtype == jnp.bfloat16
-    assert backend._state["ssm0"].shape == (4, 4, 16, 16)
+    assert backend._state["ssm0"].shape == (4, 16, 4 * 16)
     assert backend._state["ssm0"].dtype == jnp.float32
     assert "/lm-stream-async" in worker.service.endpoints
 
@@ -371,10 +376,11 @@ def test_the_worker_serves_the_family_through_the_same_wiring():
     state = {labels["kind"]: value for _, _, labels, value in
              engine.metrics._metrics["ai4e_decode_state_bytes_total"
                                      ].collect()}
-    assert state["moved"] == steps * 2 * backend._state_nbytes
-    # each step's live share is floor(moved x live / slots)
-    assert 0 <= state["moved"] * live_slots / (4 * steps) - state["live"] < (
-        steps)
+    # a step moves its live slots' recurrent state and every slot's tail
+    sparse, dense = backend._state_slot_bytes
+    assert sparse > dense > 0
+    assert state["moved"] == 2 * (live_slots * sparse + steps * 4 * dense)
+    assert state["live"] == 2 * live_slots * (sparse + dense)
     assert 0 < state["live"] < state["moved"]
     cache = {labels["kind"]: value for _, _, labels, value in
              engine.metrics._metrics["ai4e_decode_cache_bytes_total"
@@ -392,7 +398,12 @@ def test_a_family_without_state_counts_no_state_bytes():
         "seqformer-lm", vocab_size=64, max_len=32, dim=32, depth=1, heads=2),
         slots=2, prompt_buckets=(8,))
     runtime.warm()
+    assert runtime.servable.model.cache_spec().live == ()
+    assert runtime._state_slot_bytes == (0, 0)
     step = runtime.fetch(runtime.launch([1, 2], [3, 0], [True, False]))
+    assert step.state_bytes == {} and step.cache_bytes["state"] == 0
+    # with every slot live too: nothing of a state to count
+    step = runtime.fetch(runtime.launch([1, 2], [3, 5], [True, True]))
     assert step.state_bytes == {} and step.cache_bytes["state"] == 0
 
 

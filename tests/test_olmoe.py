@@ -90,7 +90,7 @@ def _served_logits(lm, seq, prompt_len, slot, slots=3):
     pool of garbage, then one decode step a token, teacher-forced, the other
     slots riding along at position 0."""
     apply = lm.model.apply
-    spec, dtype, _ = lm.model.cache_spec()
+    spec, dtype, _ = lm.model.cache_spec()[:3]
     rng = np.random.default_rng(slot)
     shape = kv_pool.pool_shape(spec, slots, CACHE)
     k = jnp.asarray(rng.standard_normal(shape), dtype)

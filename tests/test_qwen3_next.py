@@ -253,10 +253,16 @@ def test_cache_spec_declares_kv_of_full_layers_and_state_of_the_rest():
                                            [True, False, True]))
     # one block of the whole tiny cache a live slot (the dead one reads
     # nothing) + its new token read + its row written, K and V of both K/V
-    # layers; every slot's
-    # state once in and once out
+    # layers; once in and once out the two live slots' recurrent state and
+    # every slot's convolution tail
+    assert spec.live == tuple(f"delta{j}" for j in range(6))
+    delta, tail = 6 * 4 * 16 * 16 * 4, 6 * 3 * 128 * 4
+    assert 3 * (delta + tail) == state
     assert stepped.cache_bytes == {
-        "kv": 2 * 2 * 64 * 4 * (2 * 96 + 2 + 2), "state": 2 * state}
+        "kv": 2 * 2 * 64 * 4 * (2 * 96 + 2 + 2),
+        "state": 2 * (2 * delta + 3 * tail)}
+    assert stepped.state_bytes == {"moved": 2 * (2 * delta + 3 * tail),
+                                   "live": 2 * 2 * (delta + tail)}
 
 
 # -- the share of experts a process holds -------------------------------------
@@ -411,7 +417,10 @@ def test_the_worker_serves_the_family_through_the_same_wiring():
     moved = {labels["kind"]: value for _, _, labels, value in
              engine.metrics._metrics["ai4e_decode_cache_bytes_total"
                                      ].collect()}
-    assert moved["state"] == steps * 2 * backend._state_nbytes
+    sparse, dense = backend._state_slot_bytes
+    live_slots, _ = series("ai4e_decode_step_active_slots")
+    assert moved["state"] == 2 * (live_slots * sparse
+                                  + steps * backend.slots * dense)
     assert moved["kv"] > 0
 
 

@@ -172,10 +172,10 @@ def _benchmark_cell(config_file, create, model_cls, keys):
 
 def _compile_step(runtime, sharding, bound):
     """The step program of one rung, compiled for the chip — its attention
-    kernel by Mosaic, as on the chip: the program asks the default backend
-    (``lowering.resolve_interpret``), which here is the CPU, so the test
-    answers for it."""
-    from ai4e_tpu.ops.pallas import decode_attention
+    and state-update kernels by Mosaic, as on the chip: the program asks the
+    default backend (``lowering.resolve_interpret``), which here is the CPU,
+    so the test answers for it."""
+    from ai4e_tpu.ops.pallas import decode_attention, state_update
     from ai4e_tpu.runtime import kvcache
     pool_shape, pool_dtype = runtime.cache_spec()
     pool = _on(sharding, (pool_shape, pool_dtype))
@@ -184,8 +184,9 @@ def _compile_step(runtime, sharding, bound):
     host = _on(sharding, ((3, runtime.slots), jnp.int32))
     previous = _on(sharding, ((runtime.slots,), jnp.int32))
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(decode_attention, "resolve_interpret",
-                      lambda kernel, interpret: False)
+        for kernel in (decode_attention, state_update):
+            patch.setattr(kernel, "resolve_interpret",
+                          lambda kernel, interpret: False)
         state = {name: _on(sharding, ((runtime.slots, *shape), dtype))
                  for name, shape, dtype in runtime.state_spec()}
         # The default backend here is the CPU: the chip's options by hand.
@@ -195,18 +196,74 @@ def _compile_step(runtime, sharding, bound):
                 compiler_options=kvcache.STEP_COMPILER_OPTIONS["tpu"])
 
 
+def _entry(compiled):
+    """The text of the entry computation's body."""
+    import re
+    return re.search(r"ENTRY [^\n]*\{\n(.*?)\n\}", compiled.as_text(),
+                     re.S).group(1)
+
+
 def _entry_results(compiled):
     """``[(result type, operation)]`` of every instruction of the entry
     computation: what the program makes outside its fusions."""
     import re
-    entry = re.search(r"ENTRY [^\n]*\{\n(.*?)\n\}", compiled.as_text(),
-                      re.S).group(1)
+    entry = _entry(compiled)
     out = []
     for line in entry.splitlines():
         m = re.match(r"\s*(?:ROOT )?%?\S+ = (\S+) ([\w\-]+)\(", line)
         if m:
             out.append((m.group(1), m.group(2)))
     return out
+
+
+def _mosaic_calls(compiled, kernel):
+    """``[(name, [operand names], line)]`` of the entry computation's Mosaic
+    calls of the kernel named ``kernel`` (``pallas_call``'s ``name``)."""
+    import re
+    entry = _entry(compiled)
+    calls = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(%" + kernel + r"[\w.\-]*) = .*? custom-call\("
+                     r"([^)]*)\), custom_call_target=\"tpu_custom_call\"",
+                     line)
+        if m:
+            calls.append((m.group(1), re.findall(r"%[\w.\-]+", m.group(2)),
+                          line))
+    return calls
+
+
+def _assert_state_steps_in_place(compiled, state_type, tensors):
+    """Each of the ``tensors`` state tensors of ``state_type`` is advanced
+    by ONE Mosaic call (``state_update``) whose operand is the donated
+    parameter itself and whose result is aliased onto it: a state tensor is
+    a parameter or that call's own result, nothing copies or re-lays one,
+    and no fusion makes one."""
+    import re
+    results = _entry_results(compiled)
+    makers = sorted(op for kind, op in results if kind.startswith(state_type))
+    # the call's result is a tuple (read-out, successor): the successor is
+    # taken from it, and nothing else has the tensor's type
+    assert makers == (["get-tuple-element"] * tensors
+                      + ["parameter"] * tensors), makers
+    entry = _entry(compiled)
+    parameters = set(re.findall(
+        r"(%\S+) = " + re.escape(state_type) + r"\S* parameter\(", entry))
+    assert len(parameters) == tensors
+    calls = _mosaic_calls(compiled, "state_update")
+    assert len(calls) == tensors, len(calls)
+    stepped = set()
+    for name, operands, line in calls:
+        # result 1, the successor, lies on the operand it was made from
+        aliased = re.search(
+            r"output_to_operand_aliasing=\{\{1\}: \((\d+), \{\}\)\}", line)
+        assert aliased, line[:400]
+        tensor = operands[int(aliased.group(1))]
+        assert tensor in parameters, (tensor, line[:400])
+        stepped.add(tensor)
+        # the successor is read off this call and nothing else
+        assert re.search(re.escape(state_type) + r"\S* get-tuple-element\("
+                         + re.escape(name) + r"\), index=1", entry), name
+    assert stepped == parameters
 
 
 def _hlo_type(shape, dtype):
@@ -221,8 +278,11 @@ def _hlo_type(shape, dtype):
 # prefetches, on both trees alike). The select, the three host rows and the
 # ids kept for the next launch may add tens of KB (padded tiles of a few
 # hundred bytes of ints), nothing of the pool's or the weights' size.
+# ``qnext``: PR 35's program — each ``delta<j>`` stepped by a Mosaic call
+# (XLA counts the call's operands and results whole) where 35ac25d's fusions
+# read 20,230,184,960.
 STEP_BYTES_BEFORE_THE_SELECT = {
-    "gpt2m": 6_516_489_728, "olmoe": 8_252_233_728, "qnext": 20_230_184_960}
+    "gpt2m": 6_516_489_728, "olmoe": 8_252_233_728, "qnext": 15_237_463_040}
 
 
 def _assert_step_reads_in_place(runtime, compiled, bound, temp_limit,
@@ -250,18 +310,17 @@ def _assert_step_reads_in_place(runtime, compiled, bound, temp_limit,
             layer_type = _hlo_type(view, pool_dtype)
             assert not [r for r in results if r[0].startswith(layer_type)], (
                 [r for r in results if r[0].startswith(layer_type)])
-    entry = re.search(r"ENTRY [^\n]*\{\n(.*?)\n\}", compiled.as_text(),
-                      re.S).group(1)
+    entry = _entry(compiled)
     pools = re.findall(r"(%\S+) = " + re.escape(pool_type)
                        + r"\S* parameter\(", entry)
     assert len(pools) == 2, pools
-    kernels = [line for line in entry.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = _mosaic_calls(compiled, "decode_attention")
     assert len(kernels) == layers, len(kernels)
-    for line in kernels:
-        operands = re.findall(
-            r"%[\w.\-]+", re.search(r"custom-call\(([^)]*)\)", line).group(1))
-        assert all(pool in operands for pool in pools), line
+    for _, operands, _ in kernels:
+        assert all(pool in operands for pool in pools), operands
+    # every Mosaic call of the program is one of the two kernels it names
+    assert len(kernels) + len(_mosaic_calls(compiled, "state_update")) == (
+        compiled.as_text().count('custom_call_target="tpu_custom_call"'))
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < temp_limit, memory.temp_size_in_bytes
     # Both pool tensors are aliased input to output: the pool exists once.
@@ -358,8 +417,9 @@ def test_qnext_step_at_the_benchmark_cell_moves_no_pool(
     full-attention layers only, rows of 1 KB under sixteen query heads (one
     Mosaic kernel a K/V layer, the grouped-head form), made only by row
     writes; and beside it the state pool — nine ``f32[32,32,128,128]``
-    tensors and nine convolution tails — every tensor aliased input to
-    output, with temporaries smaller than ONE state tensor: no copy of a
+    tensors, each advanced by one ``state_update`` Mosaic call on the donated
+    parameter itself, and nine convolution tails — every tensor aliased input
+    to output, with temporaries smaller than ONE state tensor: no copy of a
     state tensor, of the state pool or of the K/V pool exists while the step
     runs. At the top rung, the whole worker's memory: weights + both pools +
     the widest prefill's (2,048, and the cache length 3,072 the runtime adds)
@@ -379,10 +439,10 @@ def test_qnext_step_at_the_benchmark_cell_moves_no_pool(
     pools = runtime.cache_nbytes()
     assert pools == 2 * 3 * 32 * 3072 * 512 * 2 + state_pool.nbytes(state, 32)
     assert memory.alias_size_in_bytes >= pools
-    # nothing makes a state tensor by a plain copy (a re-layout)
-    state_type = _hlo_type((32, 32, 128, 128), jnp.float32)
-    assert not [r for r in _entry_results(compiled)
-                if r[0].startswith(state_type) and r[1] == "copy"]
+    # nothing makes a state tensor by a plain copy (a re-layout): each is
+    # stepped where it lies by its own Mosaic call
+    _assert_state_steps_in_place(
+        compiled, _hlo_type((32, 32, 128, 128), jnp.float32), 9)
     if bound < runtime.max_len:
         return
 
@@ -423,10 +483,12 @@ def test_granite_step_at_the_benchmark_cell_moves_no_pool(
     pool of the four attention layers, rows of 1 KB under 32 query heads on 8
     K/V heads of 64 (one Mosaic kernel a K/V layer, the narrow-group form),
     made only by row writes; and beside it the state pool — 36
-    ``f32[64,64,64,128]`` tensors (4.83 GB) and 36 convolution tails — every
-    tensor aliased input to output, each made by ONE multi-output fusion
-    that reads ``S`` once, writes its successor and takes ``S C`` from the
-    same pass, with temporaries smaller than one state tensor. At the top
+    ``f32[64,128,4096]`` tensors (4.83 GB: a slot's state as ``(N, H · P)``)
+    and 36 convolution tails — every tensor aliased input to output, each
+    state advanced by ONE ``state_update`` Mosaic call on the donated
+    parameter itself, which reads a live slot's ``S`` once, writes its
+    successor and takes ``S C`` from the same pass, with temporaries smaller
+    than one state tensor. At the top
     rung, the whole worker's memory: weights (the tied table once) + both
     pools + the widest prefill's (512, and the cache length 1,024 the runtime
     adds) temporaries and outputs stay under the 15 GB line. About 8 s and
@@ -437,7 +499,7 @@ def test_granite_step_at_the_benchmark_cell_moves_no_pool(
     assert runtime.cache_spec() == ((4, 64, 1024, 512), jnp.bfloat16)
     state = runtime.state_spec()
     assert len(state) == 72
-    assert state[0] == ("ssm0", (64, 64, 128), jnp.float32)
+    assert state[0] == ("ssm0", (128, 64 * 64), jnp.float32)
     assert state[1] == ("conv0", (3, 4352), jnp.bfloat16)
     one_state = 64 * 64 * 64 * 128 * 4
     bound = runtime.step_bounds[rung]
@@ -446,12 +508,10 @@ def test_granite_step_at_the_benchmark_cell_moves_no_pool(
     pools = runtime.cache_nbytes()
     assert pools == 2 * 4 * 64 * 1024 * 512 * 2 + state_pool.nbytes(state, 64)
     assert memory.alias_size_in_bytes >= pools
-    # a state tensor is a parameter or one element of a fusion's tuple:
-    # nothing copies or re-lays one
-    state_type = _hlo_type((64, 64, 64, 128), jnp.float32)
-    makers = sorted(op for kind, op in _entry_results(compiled)
-                    if kind.startswith(state_type))
-    assert makers == ["get-tuple-element"] * 36 + ["parameter"] * 36, makers
+    # a state tensor is a parameter or the result of the Mosaic call that
+    # steps it where it lies: nothing copies or re-lays one
+    _assert_state_steps_in_place(
+        compiled, _hlo_type((64, 128, 4096), jnp.float32), 36)
     # the table is one parameter: embedding and head read the same array
     table = _hlo_type((spec["vocab_size"], spec["dim"]), jnp.bfloat16)
     assert [op for kind, op in _entry_results(compiled)
