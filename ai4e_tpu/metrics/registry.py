@@ -149,6 +149,10 @@ class MetricsRegistry:
     def __init__(self):
         self._metrics: dict[str, Counter | Gauge | Histogram] = {}
         self._lock = threading.Lock()
+        # Called, in order, at the start of every render on the rendering
+        # thread: whoever books an interval only when it closes brings the
+        # open one up to date, so two scrapes bound what lies between them.
+        self.scrape_hooks: list = []
 
     def counter(self, name: str, help_: str = "") -> Counter:
         return self._get_or_create(name, lambda: Counter(name, help_), Counter)
@@ -173,6 +177,8 @@ class MetricsRegistry:
         (replaces App Insights + azure-k8s-metrics-adapter,
         ``deploy_custom_metrics_adapter.sh:6-52``)."""
         lines: list[str] = []
+        for hook in list(self.scrape_hooks):
+            hook()
         with self._lock:
             metrics = list(self._metrics.values())
         kind_by_cls = {Counter: "counter", Gauge: "gauge", Histogram: "histogram"}

@@ -82,6 +82,12 @@ TICK_PHASES = ("prepare", "handoff", "dispatch", "device_wait", "return",
 # ladder starts at 1 ms and would put seven of the eight in its first bucket.
 _TICK_BUCKETS = (25e-6, 50e-6, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3, 0.01,
                  0.025, 0.05, 0.1, 0.25, 1.0, float("inf"))
+# The device thread's ledger (docs/observability.md "The device thread's
+# ledger"): why the device had nothing queued, what a join's seconds waited
+# for, what a request's queue wait was spent behind.
+UNQUEUED_CAUSES = ("empty", "join", "loop")
+JOIN_PARTS = ("hops", "dispatch", "behind_step", "run", "turnaround")
+QUEUE_WAIT_PARTS = ("slot", "joins", "tick")
 
 
 class DecodeSaturated(RuntimeError):
@@ -165,6 +171,9 @@ class LaunchedStep:
     state_bytes: dict = field(default_factory=dict)
     active: list = field(default_factory=list)   # the launch's live slots
     out: object = None            # the backend's own hold on the unread ids
+    # The step before it was unread and already finished at this launch: the
+    # device was idle, and the host set the pace.
+    starved: bool = False
     ids: list | None = None       # after ``fetch``: next token id per slot
     # After ``fetch``: the model's figures of this step, ``{name: value}``.
     report: dict = field(default_factory=dict)
@@ -217,13 +226,16 @@ class _CallClock:
     ``resumed`` when the awaiting coroutine runs again. ``wait`` is what the
     backend's ``phase_hook`` reported as blocked on the device (None from a
     backend without the hook); ``ledger`` is the request the call serves,
-    for the hook's ``compile`` stamp."""
+    for the hook's ``compile`` stamp; ``join`` is None, or of a prefill the
+    seconds the hook reported by part (``behind_step``, ``run``)."""
 
-    __slots__ = ("submit", "entered", "left", "resumed", "wait", "ledger")
+    __slots__ = ("submit", "entered", "left", "resumed", "wait", "ledger",
+                 "join")
 
-    def __init__(self, ledger=None):
+    def __init__(self, ledger=None, join=None):
         self.wait = None
         self.ledger = ledger
+        self.join = join
 
     def run(self, fn, args):
         self.entered = time.perf_counter()
@@ -268,6 +280,7 @@ class _Sequence:
     enqueued: float = field(default_factory=time.perf_counter)
     last_token_at: float = 0.0
     first_tick: int = 0           # the tick that gave it its slot
+    lacked: float = 0.0           # ``_lacked_slot`` when it was enqueued
 
 
 class DecodeEngine:
@@ -310,7 +323,11 @@ class DecodeEngine:
       ``device_wait`` (seconds of ``fetch`` blocked on the device; the rest
       of the in-thread time is ``dispatch``) and ``compile`` (a call that
       grew a program's dispatch cache). Without it the whole in-thread time
-      of a step is booked as ``device_wait``.
+      of a step is booked as ``device_wait``. The same hook feeds the device
+      thread's ledger — ``enqueue`` (0 seconds: the line before a prefill or
+      a step is dispatched), ``behind_step`` and ``run`` (a prefill's two
+      waits), ``readback`` (of a fetch's ``device_wait``, the ids' copy) —
+      and a backend without it registers none of that ledger's series.
 
     Backend methods may be sync (run on the engine's single device
     executor thread — the device is the serial resource, same discipline
@@ -439,6 +456,42 @@ class DecodeEngine:
             "ai4e_decode_discarded_slot_steps_total",
             "Slot-steps computed for a sequence that had ended (EOS, "
             "cancel, expiry, drain) while a launched step held it")
+        # The device thread's ledger. ``_drained``: the instant that thread
+        # saw the device's queue empty (a prefill's wait returned, or a fetch
+        # with nothing launched after it) and whether a prefill's wait it
+        # was; None while the device has work queued, or nobody knows.
+        self._books = hasattr(backend, "phase_hook")
+        self._drained: tuple[float, bool] | None = None
+        self._idled = self._idling = False   # the idle wait: passed, inside
+        # Seconds an ``_admit`` pass had returned for want of a free slot:
+        # the closed periods, and since when the open one.
+        self._no_slot_s, self._no_slot_from = 0.0, None
+        if self._books:
+            self._unqueued = self.metrics.counter(
+                "ai4e_decode_device_unqueued_seconds_total",
+                "Seconds the device had nothing queued by the decode thread, "
+                "by cause: from that thread seeing the queue drained (a "
+                "prefill's wait returned, only its insert trailing; or a "
+                "fetch with no later step launched) to its next dispatch. "
+                "empty: the engine passed through its idle wait (the load's); "
+                "join: begins at a prefill's wait or ends at a prefill's "
+                "dispatch; loop: the rest (a settle followed by a step)")
+            self._join_hist = self.metrics.histogram(
+                "ai4e_decode_join_seconds",
+                "One prefill's seconds by what it waited for "
+                "(hops/dispatch/behind_step/run add up to its "
+                "ai4e_decode_step_seconds{phase=prefill}), and turnaround: "
+                "the join-caused unqueued interval that follows its wait",
+                buckets=_TICK_BUCKETS)
+            self._readback = self.metrics.histogram(
+                "ai4e_decode_fetch_readback_seconds",
+                "Of a fetch's device_wait, the ids' copy to the host after "
+                "the step was seen finished", buckets=_TICK_BUCKETS)
+            self._queue_part = self.metrics.histogram(
+                "ai4e_decode_queue_wait_part_seconds",
+                "ai4e_decode_queue_wait_seconds by what the request waited "
+                "behind: slot (no free slot), joins (the same admit pass's "
+                "earlier prefills), tick (the rest: the loop was elsewhere)")
         self._tick_no = 0
         self._joins = 0   # prefills admitted since the last launched step
         # Seconds booked since the previous step's submit, every phase but
@@ -491,6 +544,7 @@ class DecodeEngine:
                         max_new_tokens=max_new_tokens, on_token=on_token,
                         priority=priority, deadline_at=deadline_at,
                         ledger=ledger)
+        seq.lacked = self._lacked_slot(seq.enqueued)
         self._queue.append(seq)
         if ledger is not None:
             ledger.stamp("queued", "decode")
@@ -561,6 +615,8 @@ class DecodeEngine:
 
     async def start(self) -> None:
         self._stop = False
+        if self._books:
+            self.metrics.scrape_hooks.append(self._book_idle_so_far)
         self._loop_task = asyncio.get_running_loop().create_task(self._run())
 
     async def stop(self) -> None:
@@ -579,6 +635,9 @@ class DecodeEngine:
                          error=RuntimeError("decode engine stopped"))
         if self._executor is not None:
             self._executor.shutdown(wait=True)
+        if self._books and (self._book_idle_so_far
+                            in self.metrics.scrape_hooks):
+            self.metrics.scrape_hooks.remove(self._book_idle_so_far)
 
     # -- engine loop -------------------------------------------------------
 
@@ -586,11 +645,14 @@ class DecodeEngine:
         while not self._stop:
             if not self._active and not self._queue and not self._launched:
                 self._last_submit = None
+                self._idled = self._idling = True
                 self._wakeup.clear()
                 try:
                     await asyncio.wait_for(self._wakeup.wait(), timeout=0.5)
                 except asyncio.TimeoutError:
                     continue
+                finally:
+                    self._idling = False
             if self._stop:
                 return
             try:
@@ -685,9 +747,14 @@ class DecodeEngine:
                                  error=DrainingError(
                                      "decode engine draining; redeliver"))
             return
+        began = time.perf_counter()
+        if self._no_slot_from is not None and self.pool.free_count:
+            self._no_slot_s += began - self._no_slot_from
+            self._no_slot_from = None
         while self._queue:
             slot = self.pool.acquire()
             if slot is None:
+                self._no_slot_from = self._no_slot_from or time.perf_counter()
                 return
             seq = self._queue.popleft()
             self._pending_gauge.set(self.pending_count, model=self._model)
@@ -702,8 +769,16 @@ class DecodeEngine:
             self._active[slot] = seq
             self._occupancy.set(self.pool.busy_count / self.pool.slots,
                                 model=self._model)
-            wait = time.perf_counter() - seq.enqueued
+            now = time.perf_counter()
+            wait = now - seq.enqueued
             self._queue_wait.observe(wait, model=self._model)
+            if self._books:
+                lacked = min(self._lacked_slot(now) - seq.lacked, wait)
+                joins = min(now - max(seq.enqueued, began), wait - lacked)
+                for part, value in zip(QUEUE_WAIT_PARTS, (
+                        lacked, joins, wait - lacked - joins)):
+                    self._queue_part.observe(value, part=part,
+                                             model=self._model)
             if seq.ledger is not None:
                 seq.ledger.stamp("slot", "decode", ms=wait * 1e3,
                                  reason=f"slot {slot} tick {self._tick_no}")
@@ -722,19 +797,30 @@ class DecodeEngine:
         try:
             token, clock = await self._call(
                 self.backend.prefill_into, seq.slot, list(tokens),
-                ledger=seq.ledger)
+                ledger=seq.ledger, join={})
         except Exception as exc:  # noqa: BLE001; ai4e: noqa[AIL005] — delivered to the sequence's waiter as its failure
             self._retire(seq, "failed", error=exc)
             return None
         seconds = clock.resumed - clock.submit
         self._step_hist.observe(seconds, phase="prefill", model=self._model)
+        if self._books:
+            self._drained = (clock.left, True)
+            behind = clock.join.get("behind_step", 0.0)
+            run = clock.join.get("run", 0.0)
+            in_thread = clock.left - clock.entered
+            for part, value in zip(JOIN_PARTS, (
+                    seconds - in_thread, in_thread - behind - run, behind,
+                    run)):
+                self._join_hist.observe(value, part=part, model=self._model)
         if seq.ledger is not None:
             bucket_for = getattr(self.backend, "bucket_for", None)
             seq.ledger.stamp(
                 "prefill", "decode", t=time.time() - seconds,
                 ms=seconds * 1e3,
                 reason=(f"bucket {bucket_for(len(tokens))}" if bucket_for
-                        else f"{len(tokens)} tokens"))
+                        else f"{len(tokens)} tokens") + (
+                    f" behind {behind * 1e3:.3f} run {run * 1e3:.3f}"
+                    if self._books else ""))
         return int(token)
 
     async def _step(self, launch: bool = True) -> None:
@@ -797,9 +883,13 @@ class DecodeEngine:
             phase = self._phase
             if flight is not None:
                 flight.step = step
+                if step.starved:
+                    self._launches.inc(model=self._model, kind="starved")
                 self._close_tick(clock.submit, entered)
             else:
                 phase["prepare"] += clock.submit - entered
+                if self._books:   # a fetch, and nothing launched after it
+                    self._drained = (clock.left, False)
             # This call's own phases open (or, without a launch, extend)
             # the interval to the next launch.
             in_thread = clock.left - clock.entered
@@ -842,6 +932,7 @@ class DecodeEngine:
         """Forget every launched step unread (a failure, a stop): nothing
         stays marked in flight, and the slots they held parked are freed."""
         self._launched.clear()
+        self._drained = None
         self._release_parked()
 
     def _release_parked(self) -> None:
@@ -909,8 +1000,47 @@ class DecodeEngine:
             if clock is not None and clock.ledger is not None:
                 clock.ledger.stamp("compile", "device",
                                    t=time.time() - seconds, ms=seconds * 1e3)
-        elif phase == "device_wait" and clock is not None:
+        elif clock is None:
+            pass   # a call the engine did not make
+        elif phase == "device_wait":
             clock.wait = (clock.wait or 0.0) + seconds
+        elif phase == "enqueue":
+            self._close_unqueued(clock.join is not None)
+        elif phase == "readback":
+            self._readback.observe(seconds, model=self._model)
+        elif clock.join is not None:
+            clock.join[phase] = seconds
+
+    def _close_unqueued(self, at_prefill: bool) -> None:
+        """The device thread is about to dispatch: book the seconds the
+        device had nothing queued to their cause, decided here."""
+        idled, self._idled = self._idled, False
+        if self._drained is None:
+            return
+        (since, after_prefill), self._drained = self._drained, None
+        seconds = time.perf_counter() - since
+        cause = ("empty" if idled
+                 else "join" if after_prefill or at_prefill else "loop")
+        self._unqueued.inc(seconds, model=self._model, cause=cause)
+        if cause == "join" and after_prefill:
+            self._join_hist.observe(seconds, part="turnaround",
+                                    model=self._model)
+
+    def _book_idle_so_far(self) -> None:
+        """The registry's scrape hook: an idle engine's open interval is
+        booked up to now, not when the next request closes it, so two scrapes
+        bound the idle seconds between them."""
+        if self._idling and self._drained is not None:
+            now = time.perf_counter()
+            self._unqueued.inc(now - self._drained[0], model=self._model,
+                               cause="empty")
+            self._drained = (now, self._drained[1])
+
+    def _lacked_slot(self, now: float) -> float:
+        """Running total of the seconds, up to ``now``, during which the last
+        ``_admit`` pass had returned for want of a free slot."""
+        return self._no_slot_s + (
+            0.0 if self._no_slot_from is None else now - self._no_slot_from)
 
     # -- bookkeeping (single-segment: no suspension points below) ---------
 
@@ -985,12 +1115,12 @@ class DecodeEngine:
             else:
                 seq.future.set_result(list(seq.tokens))
 
-    async def _call(self, fn, /, *args, ledger=None):
+    async def _call(self, fn, /, *args, ledger=None, join=None):
         """Invoke a backend method: async backends (race-test fakes)
         await inline; sync backends (the JAX runtime) run on the single
         device executor thread — the device is the serial resource.
         Returns ``(result, clock)``: the one timer every caller reads."""
-        clock = _CallClock(ledger)
+        clock = _CallClock(ledger, join)
         if inspect.iscoroutinefunction(fn):
             clock.submit = clock.entered = time.perf_counter()
             out = await fn(*args)

@@ -165,12 +165,19 @@ class PagedDecodeRuntime:
         # launch feeds every slot the host does not. None: there is no such
         # step (start, reset, a failure) and every live slot is fed.
         self._ids = None
+        # The last launched step: unread for as long as it has its ``out``.
+        self._newest = None
         self._donate = donate
         self._programs = None
         # ``hook(phase, seconds)``, installed by the DecodeEngine: told the
         # seconds a fetch spent blocked on the device (``device_wait``) and
-        # the seconds of any call that had to build its program
-        # (``compile``). None (and during ``warm()``): nothing is reported.
+        # of those its read-back alone (``readback``), the seconds a prefill
+        # waited for the step launched before it (``behind_step``) and then
+        # for its own run (``run``), the instant before a prefill or a step
+        # is enqueued (``enqueue``, 0 seconds: the device thread's ledger
+        # closes there) and the seconds of any call that had to build its
+        # program (``compile``). None (and during ``warm()``): nothing is
+        # reported.
         self.phase_hook = None
 
     # -- cache lifecycle ---------------------------------------------------
@@ -271,9 +278,13 @@ class PagedDecodeRuntime:
         before = fn._cache_size()
         t0 = time.perf_counter()
         out = fn(*args)
-        if fn._cache_size() > before and self.phase_hook is not None:
-            self.phase_hook("compile", time.perf_counter() - t0)
+        if fn._cache_size() > before:
+            self._tell("compile", time.perf_counter() - t0)
         return out
+
+    def _tell(self, phase: str, seconds: float = 0.0) -> None:
+        if self.phase_hook is not None:
+            self.phase_hook(phase, seconds)
 
     # -- engine backend surface -------------------------------------------
 
@@ -302,6 +313,7 @@ class PagedDecodeRuntime:
         bucket = self.bucket_for(n)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = tokens
+        self._tell("enqueue")
         with device_trace("ai4e.decode.prefill", bucket=bucket, slot=slot):
             token, k_block, v_block, state_block = self._run(
                 "prefill", self.servable.params, padded,
@@ -310,7 +322,19 @@ class PagedDecodeRuntime:
             self._k, self._v, self._state = self._run(
                 "insert", self._k, self._v, self._state, k_block, v_block,
                 state_block, np.int32(slot))
-        return int(token[0])   # waits for the prefill program's run
+        # The device runs in order: first whatever step was launched before
+        # this prefill (its ids are the last thing it writes), then the
+        # prefill, whose one id the host reads; the insert trails.
+        t0 = time.perf_counter()
+        if self._ids is not None:
+            with device_trace("ai4e.decode.join.behind_step"):
+                self._ids.block_until_ready()
+        t1 = time.perf_counter()
+        with device_trace("ai4e.decode.join.run"):
+            first = int(token[0])   # waits for the prefill program's run
+        self._tell("behind_step", t1 - t0)
+        self._tell("run", time.perf_counter() - t1)
+        return first
 
     def bound_for(self, longest: int) -> int:
         """The smallest rung of ``step_bounds`` that holds every key a step
@@ -360,11 +384,18 @@ class PagedDecodeRuntime:
             if token is not None:
                 host[0, slot], host[1, slot] = 1, token
         host[2] = positions
+        # A step still unread that has already finished: the device has sat
+        # idle since, and goes on until this launch lands. For how long the
+        # host cannot know (``is_ready`` does not block, and says no more).
+        starved = (self._ids is not None and self._newest is not None
+                   and self._newest.out is not None and self._ids.is_ready())
         if self._ids is None:
             import jax.numpy as jnp
             self._ids = jnp.zeros((self.slots,), jnp.int32)
+        self._tell("enqueue")
         try:
-            with device_trace("ai4e.decode.dispatch", bound=bound):
+            with device_trace("ai4e.decode.dispatch", bound=bound,
+                              starved=int(starved)):
                 out, self._ids, self._k, self._v, self._state = self._run(
                     "step", self.servable.params, host, self._ids, self._k,
                     self._v, self._state, bound)
@@ -374,12 +405,14 @@ class PagedDecodeRuntime:
         live = sum(map(bool, active))
         sparse, dense = self._state_slot_bytes
         moved = 2 * (live * sparse + self.slots * dense)
-        return LaunchedStep(
+        self._newest = LaunchedStep(
             bound=bound, attended=attended, active=list(active), out=out,
+            starved=starved,
             cache_bytes={"kv": row_bytes * (attended + live), "state": moved},
             state_bytes=({"moved": moved,
                           "live": 2 * live * (sparse + dense)}
                          if sparse + dense else {}))
+        return self._newest
 
     def fetch(self, step: LaunchedStep) -> LaunchedStep:
         """Wait for a launched step and read what it returned: ``ids``, and
@@ -389,14 +422,18 @@ class PagedDecodeRuntime:
         t0 = time.perf_counter()
         try:
             with device_trace("ai4e.decode.device_wait"):
-                out = np.asarray(step.out)   # the device's run and the d2h
+                step.out.block_until_ready()   # what is left of its run
+                t1 = time.perf_counter()
+                with device_trace("ai4e.decode.fetch.readback"):
+                    out = np.asarray(step.out)   # the d2h alone
         except Exception:
             self._ids = None   # a step launched after this one is void too
             raise
         finally:
             step.out = None
-        if self.phase_hook is not None:
-            self.phase_hook("device_wait", time.perf_counter() - t0)
+        now = time.perf_counter()
+        self._tell("device_wait", now - t0)
+        self._tell("readback", now - t1)
         step.ids = out[:self.slots].tolist()
         if out.shape[0] > self.slots:
             step.report = self.servable.model.step_report(
