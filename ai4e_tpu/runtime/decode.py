@@ -29,10 +29,20 @@ before step N's ids are read, so the device has its next step queued while
 the host fetches, notes tokens, sweeps and admits. What a launch needs but
 the ids — positions, the rung, an end by token budget or cache length — the
 host counts ahead; the ids themselves stay with the backend between steps,
-and only a slot admitted (or re-prefilled) since the last launch is fed from
-the host. A sequence that ends by count is never in the next launch; one
-that ends by ``eos_id``, a cancel, an expiry or a drain while a launched
-step holds it costs that one slot-step, discarded at its fetch and counted.
+and only a slot re-prefilled since the last launch is fed from the host. A
+sequence that ends by count is never in the next launch; one that ends by
+``eos_id``, a cancel, an expiry or a drain while a launched step holds it
+costs that one slot-step, discarded at its fetch and counted.
+
+A join does not stop the loop either. ``_admit`` dispatches each queued
+prompt's prefill (``join``) and goes straight on to the next: a prompt's
+FIRST id stays with the backend too, the step launched after the pass feeds
+the slot from it, and the host reads it with that step's fetch (``fed``),
+beside the second. So a sequence is: dispatched, carried by a step, read. A
+request for one token, or whose first id is ``eos_id``, is not known to be
+finished when that step is launched and rides it as a discarded slot-step;
+where nothing else rides, nothing is launched and the ids are read alone
+(``first_ids``).
 
 Slot conservation is THE invariant (tests/test_race_regressions.py):
 a slot is never double-assigned, never leaked, and freed exactly once.
@@ -175,48 +185,80 @@ class LaunchedStep:
     # device was idle, and the host set the pace.
     starved: bool = False
     ids: list | None = None       # after ``fetch``: next token id per slot
+    # After ``fetch``: the token each slot was fed. None at a slot: the
+    # backend kept that to itself (one the host fed, or a step's id).
+    fed: list | None = None
     # After ``fetch``: the model's figures of this step, ``{name: value}``.
     report: dict = field(default_factory=dict)
 
 
-class _BlockingSteps:
-    """``launch``/``fetch`` over a backend that has only a blocking
-    ``step(tokens, positions, active) -> ids`` (sync, or async as the race
-    tests' fakes): the launch runs the whole step and holds its result, so
-    the engine has one loop whatever it is handed. The ids of the last step
-    stay here, as they stay on the device in ``runtime/kvcache.py``."""
+class _Adapted:
+    """``join`` / ``launch`` / ``fetch`` / ``first_ids`` over a backend that
+    lacks some of them, so the engine has one loop whatever it is handed. A
+    backend with only ``prefill_into`` (sync, or async as the race tests'
+    fakes) joins by running it: the first id it returned waits here and is
+    fed to the slot at the next launch, as it waits on the device in
+    ``runtime/kvcache.py``. One with only a blocking ``step(tokens,
+    positions, active) -> ids`` launches by running the whole step and
+    holding its result; the ids of the last step stay here."""
 
     def __init__(self, backend):
         self.backend = backend
+        self._own_steps = not hasattr(backend, "launch")
         self._ids = [0] * backend.slots
-        if inspect.iscoroutinefunction(backend.step):
+        self._first = {}   # slot -> a join's first id, not yet fed
+        if inspect.iscoroutinefunction(backend.prefill_into):
+            self.join = self._join_async
+        if inspect.iscoroutinefunction(
+                backend.step if self._own_steps else backend.launch):
             self.launch = self._launch_async
 
-    def _tokens(self, fresh, active) -> list:
-        return [0 if not live else self._ids[slot] if token is None else token
-                for slot, (token, live) in enumerate(zip(fresh, active))]
+    def join(self, slot, tokens) -> None:
+        self._first[slot] = int(self.backend.prefill_into(slot, tokens))
 
-    def _record(self, ids, active) -> LaunchedStep:
-        backend = self.backend
-        self._ids = [int(t) for t in ids]
-        return LaunchedStep(
-            bound=getattr(backend, "step_bound", backend.max_len),
-            attended=getattr(backend, "step_attended", None),
-            cache_bytes=dict(getattr(backend, "step_cache_bytes", {})),
-            state_bytes=dict(getattr(backend, "step_state_bytes", {})),
-            active=active, ids=self._ids,
-            report=dict(getattr(backend, "step_report", {})))
+    async def _join_async(self, slot, tokens) -> None:
+        self._first[slot] = int(await self.backend.prefill_into(slot, tokens))
+
+    def first_ids(self) -> list:
+        return [self._first.pop(slot, None)
+                for slot in range(self.backend.slots)]
+
+    def _start(self, fresh, positions, active):
+        """The backend's call of one launch, and what it feeds each slot:
+        the host's token, else a joined prompt's first id, else — where the
+        last step's ids stay here — that step's."""
+        fed = [self._first.pop(slot, None) if token is None else token
+               for slot, token in enumerate(fresh)]
+        if not self._own_steps:
+            return self.backend.launch(fed, positions, active), fed
+        fed = [0 if not live else self._ids[slot] if token is None else token
+               for slot, (token, live) in enumerate(zip(fed, active))]
+        return self.backend.step(fed, positions, active), fed
+
+    def _record(self, step, fed, active) -> LaunchedStep:
+        if self._own_steps:
+            backend = self.backend
+            self._ids = [int(t) for t in step]
+            step = LaunchedStep(
+                bound=getattr(backend, "step_bound", backend.max_len),
+                attended=getattr(backend, "step_attended", None),
+                cache_bytes=dict(getattr(backend, "step_cache_bytes", {})),
+                state_bytes=dict(getattr(backend, "step_state_bytes", {})),
+                active=active, ids=self._ids,
+                report=dict(getattr(backend, "step_report", {})))
+        step.fed = fed
+        return step
 
     def launch(self, fresh, positions, active) -> LaunchedStep:
-        return self._record(self.backend.step(
-            self._tokens(fresh, active), positions, active), active)
+        step, fed = self._start(fresh, positions, active)
+        return self._record(step, fed, active)
 
     async def _launch_async(self, fresh, positions, active) -> LaunchedStep:
-        return self._record(await self.backend.step(
-            self._tokens(fresh, active), positions, active), active)
+        step, fed = self._start(fresh, positions, active)
+        return self._record(await step, fed, active)
 
-    def fetch(self, step: LaunchedStep) -> LaunchedStep:
-        return step
+    def fetch(self, step: LaunchedStep):
+        return step if self._own_steps else self.backend.fetch(step)
 
 
 class _CallClock:
@@ -226,7 +268,7 @@ class _CallClock:
     ``resumed`` when the awaiting coroutine runs again. ``wait`` is what the
     backend's ``phase_hook`` reported as blocked on the device (None from a
     backend without the hook); ``ledger`` is the request the call serves,
-    for the hook's ``compile`` stamp; ``join`` is None, or of a prefill the
+    for the hook's ``compile`` stamp; ``join`` is None, or of a join the
     seconds the hook reported by part (``behind_step``, ``run``)."""
 
     __slots__ = ("submit", "entered", "left", "resumed", "wait", "ledger",
@@ -250,12 +292,14 @@ class _Flight:
     """A launched step the host has not read: who rides it. ``step`` is the
     backend's record once the launching call has returned."""
 
-    snapshot: list                # (slot, sequence, position) of its launch
+    # (slot, sequence, position, first) of its launch; ``first``: the slot
+    # was fed a joined prompt's first id, which the host has not seen.
+    snapshot: list
     tick: int                     # the tick that launched it
     step: LaunchedStep | None = None
 
     def __post_init__(self):
-        self._slots = frozenset(slot for slot, _, _ in self.snapshot)
+        self._slots = frozenset(entry[0] for entry in self.snapshot)
 
     def holds(self, slot: int) -> bool:
         """Whether this step has ``slot`` live."""
@@ -294,25 +338,37 @@ class DecodeEngine:
       cache key, checked every tick;
     - ``reset_cache()``: drop + reallocate the pooled cache (reload
       invalidation);
-    - ``prefill_into(slot, tokens) -> first generated token id``;
+    - ``prefill_into(slot, tokens) -> first generated token id``: the join
+      that blocks and reads (a reload's re-prefill);
+    - ``join(slot, tokens)``: dispatch the same prefill and return without
+      reading it. The first id stays with the backend: the next launch
+      feeds the slot from it where ``fresh[slot]`` is None, and that step's
+      fetch hands it over as ``fed[slot]``. The backend keeps at most two
+      joins in flight on the device;
+    - ``first_ids() -> ids``: block until every dispatched join has run and
+      return, a slot, the id the next launch would feed it — for a pass
+      whose requests all want one token, which no step carries;
     - ``launch(fresh, positions, active) -> LaunchedStep``: start one decode
       step and return without reading it. Plain lists, one entry a slot:
-      ``fresh[slot]`` is the token the host feeds (a slot admitted or
-      re-prefilled since the last launch) or None — the slot's token is the
-      id the LAST LAUNCHED step gave it, which the backend kept. The record
+      ``fresh[slot]`` is the token the host feeds (a slot re-prefilled since
+      the last launch) or None — the slot's token is the id the LAST
+      LAUNCHED step, or the prefill joined since, gave it, which the backend
+      kept. The record
       carries what the backend worked out at the launch: ``bound`` (observed
       as ``ai4e_decode_step_bound``), ``attended`` (counted as attended K/V
       positions), ``cache_bytes`` (``ai4e_decode_cache_bytes_total{kind}``),
       ``state_bytes`` (``ai4e_decode_state_bytes_total{kind}``);
     - ``fetch(step) -> step``: block until that step has run and fill in
-      ``ids`` (next token id per slot) and ``report`` (``{name: value}``,
-      observed as ``ai4e_decode_<name>``). Steps are fetched in the order
-      they were launched; a failure surfaces here;
-    - or, in place of the two, only a blocking ``step(tokens, positions,
-      active) -> ids`` with its figures left in ``step_bound`` /
-      ``step_attended`` / ``step_cache_bytes`` / ``step_state_bytes`` /
-      ``step_report``
-      attributes (the tests' fakes): ``_BlockingSteps`` adapts it;
+      ``ids`` (next token id per slot), ``fed`` (the token each slot was
+      fed) and ``report`` (``{name: value}``, observed as
+      ``ai4e_decode_<name>``). Steps are fetched in the order they were
+      launched; a failure — a step's or a joined prefill's — surfaces here;
+    - or, in place of ``launch`` and ``fetch``, only a blocking
+      ``step(tokens, positions, active) -> ids`` with its figures left in
+      ``step_bound`` / ``step_attended`` / ``step_cache_bytes`` /
+      ``step_state_bytes`` / ``step_report`` attributes, and in place of
+      ``join`` and ``first_ids`` only ``prefill_into`` (the tests' fakes):
+      ``_Adapted`` supplies what is missing;
     - optionally ``step_report_series`` (attribute, ``{name: (help,
       buckets)}``): what a model that reports on its step declares;
     - optionally ``bound_for(longest)``: the bound a step whose largest
@@ -325,7 +381,7 @@ class DecodeEngine:
       grew a program's dispatch cache). Without it the whole in-thread time
       of a step is booked as ``device_wait``. The same hook feeds the device
       thread's ledger — ``enqueue`` (0 seconds: the line before a prefill or
-      a step is dispatched), ``behind_step`` and ``run`` (a prefill's two
+      a step is dispatched), ``behind_step`` and ``run`` (a join's two
       waits), ``readback`` (of a fetch's ``device_wait``, the ids' copy) —
       and a backend without it registers none of that ledger's series.
 
@@ -338,10 +394,10 @@ class DecodeEngine:
     def __init__(self, backend, max_pending: int = 64,
                  metrics: MetricsRegistry | None = None):
         self.backend = backend
-        # The one step surface: a backend with only a blocking ``step`` is
-        # adapted here and nowhere else.
-        self._steps = (backend if hasattr(backend, "launch")
-                       else _BlockingSteps(backend))
+        # The one surface of joins and steps: a backend with only a blocking
+        # ``prefill_into`` or ``step`` is adapted here and nowhere else.
+        self._steps = (backend if hasattr(backend, "join")
+                       and hasattr(backend, "launch") else _Adapted(backend))
         self._advance = (
             self._advance_async
             if inspect.iscoroutinefunction(self._steps.launch)
@@ -452,14 +508,20 @@ class DecodeEngine:
             "Decode steps launched, by kind: all, and ahead (launched while "
             "the step before it was unread, so the device had it queued "
             "before the host read the last ids)")
+        self._joins_total = self.metrics.counter(
+            "ai4e_decode_joins_total",
+            "Prefills joined into a slot, by kind: all, and ahead (the host "
+            "did not wait for its first id: read with the fetch of the first "
+            "step that carried the slot)")
         self._discarded = self.metrics.counter(
             "ai4e_decode_discarded_slot_steps_total",
             "Slot-steps computed for a sequence that had ended (EOS, "
             "cancel, expiry, drain) while a launched step held it")
         # The device thread's ledger. ``_drained``: the instant that thread
-        # saw the device's queue empty (a prefill's wait returned, or a fetch
-        # with nothing launched after it) and whether a prefill's wait it
-        # was; None while the device has work queued, or nobody knows.
+        # saw the device's queue empty (a blocking prefill's wait returned,
+        # or a fetch with nothing launched after it) and whether a prefill's
+        # wait it was; None while the device has work queued — as after a
+        # join, which returns with its prefill queued — or nobody knows.
         self._books = hasattr(backend, "phase_hook")
         self._drained: tuple[float, bool] | None = None
         self._idled = self._idling = False   # the idle wait: passed, inside
@@ -471,17 +533,26 @@ class DecodeEngine:
                 "ai4e_decode_device_unqueued_seconds_total",
                 "Seconds the device had nothing queued by the decode thread, "
                 "by cause: from that thread seeing the queue drained (a "
-                "prefill's wait returned, only its insert trailing; or a "
-                "fetch with no later step launched) to its next dispatch. "
-                "empty: the engine passed through its idle wait (the load's); "
-                "join: begins at a prefill's wait or ends at a prefill's "
-                "dispatch; loop: the rest (a settle followed by a step)")
+                "blocking prefill's wait returned, only its insert trailing; "
+                "or a fetch with no later step launched) to its next "
+                "dispatch. empty: the engine passed through its idle wait "
+                "(the load's); join: begins at a blocking prefill's wait or "
+                "ends at a prefill's dispatch; loop: the rest (a settle "
+                "followed by a step)")
+            # Every cause reads a number from the start: a join that returns
+            # with its prefill queued books none, and a worker that is never
+            # idle would otherwise have no series at all.
+            for cause in UNQUEUED_CAUSES:
+                self._unqueued.inc(0.0, model=self._model, cause=cause)
             self._join_hist = self.metrics.histogram(
                 "ai4e_decode_join_seconds",
-                "One prefill's seconds by what it waited for "
-                "(hops/dispatch/behind_step/run add up to its "
-                "ai4e_decode_step_seconds{phase=prefill}), and turnaround: "
-                "the join-caused unqueued interval that follows its wait",
+                "One join's seconds by what the device thread waited for "
+                "(hops/dispatch/behind_step/run add up to its call, "
+                "ai4e_decode_step_seconds{phase=prefill}; run: a prefill's "
+                "run — the join two before it, at most two being in flight, "
+                "and its own where the call reads the id), and turnaround: "
+                "the join-caused unqueued interval that follows its wait, 0 "
+                "where the call returned with its prefill queued",
                 buckets=_TICK_BUCKETS)
             self._readback = self.metrics.histogram(
                 "ai4e_decode_fetch_readback_seconds",
@@ -736,8 +807,11 @@ class DecodeEngine:
                                                     seq.deadline_at))
 
     async def _admit(self) -> None:
-        """Prefill queued requests into free KV-cache slots — BETWEEN
-        decode steps, the continuous-batching join."""
+        """Join queued requests into free KV-cache slots — BETWEEN decode
+        steps, the continuous-batching join: each prompt's prefill is
+        dispatched and the pass goes on to the next; the step launched
+        after the pass carries every slot joined here, and its fetch brings
+        their first ids."""
         if self._draining:
             # Anything that raced past the submit-side refusal is retired
             # here rather than prefilled onto a leaving worker.
@@ -783,28 +857,34 @@ class DecodeEngine:
                 seq.ledger.stamp("slot", "decode", ms=wait * 1e3,
                                  reason=f"slot {slot} tick {self._tick_no}")
             self._joins += 1
-            token = await self._prefill(seq, seq.prompt)
-            if token is None or seq.done:
-                continue  # failed, or re-check after the await: retired
             seq.position = len(seq.prompt)
-            self._note_token(seq, token)
+            await self._prefill(seq, seq.prompt, ahead=True)
 
-    async def _prefill(self, seq: _Sequence, tokens) -> int | None:
+    async def _prefill(self, seq: _Sequence, tokens,
+                       ahead: bool = False) -> int | None:
         """``tokens`` (the prompt or, after a reload, the history) through
-        the backend's prefill into the sequence's slot. Returns the first
-        generated token; a backend failure retires the sequence and
-        returns None."""
+        the backend's prefill into the sequence's slot. ``ahead``: a join —
+        dispatched, its first id left with the backend for the next launch;
+        else the call blocks and returns that id. A backend failure retires
+        the sequence and returns None."""
         try:
             token, clock = await self._call(
-                self.backend.prefill_into, seq.slot, list(tokens),
-                ledger=seq.ledger, join={})
+                self._steps.join if ahead else self.backend.prefill_into,
+                seq.slot, list(tokens), ledger=seq.ledger, join={})
         except Exception as exc:  # noqa: BLE001; ai4e: noqa[AIL005] — delivered to the sequence's waiter as its failure
             self._retire(seq, "failed", error=exc)
             return None
         seconds = clock.resumed - clock.submit
         self._step_hist.observe(seconds, phase="prefill", model=self._model)
+        if ahead:
+            self._joins_total.inc(model=self._model, kind="all")
         if self._books:
-            self._drained = (clock.left, True)
+            if ahead and self._steps is self.backend:
+                # It returned with its prefill queued: nothing drained.
+                self._join_hist.observe(0.0, part="turnaround",
+                                        model=self._model)
+            else:
+                self._drained = (clock.left, True)
             behind = clock.join.get("behind_step", 0.0)
             run = clock.join.get("run", 0.0)
             in_thread = clock.left - clock.entered
@@ -821,7 +901,7 @@ class DecodeEngine:
                         else f"{len(tokens)} tokens") + (
                     f" behind {behind * 1e3:.3f} run {run * 1e3:.3f}"
                     if self._books else ""))
-        return int(token)
+        return None if ahead else int(token)
 
     async def _step(self, launch: bool = True) -> None:
         """Launch the next decode step over the whole slot pool (every
@@ -831,21 +911,32 @@ class DecodeEngine:
         while the host fetches and notes the last one's ids."""
         entered = time.perf_counter()
         unread = self._launched[-1] if self._launched else None
-        snapshot = []
+        snapshot, alone = [], []
         if launch:
             for slot, seq in sorted(self._active.items()):
                 if seq.done:
                     continue
                 # A sequence the unread step carries has one token the host
-                # has not seen: count it. One that ends by count when that
-                # token is read is never in this launch.
+                # has not seen, and one joined since its last read a first
+                # id besides: count them. One that ends by count when the
+                # unread step is read is never in this launch.
                 ahead = unread is not None and unread.holds(slot)
                 position = seq.position + ahead
-                if ahead and (len(seq.tokens) + 1 >= seq.max_new_tokens
+                unseen = ahead + (not seq.tokens)
+                if ahead and (len(seq.tokens) + unseen >= seq.max_new_tokens
                               or position >= self.backend.max_len):
                     continue
-                snapshot.append((slot, seq, position, ahead))
-        if not snapshot and unread is None:
+                # ``first``: this launch feeds the slot its prompt's first
+                # id, which stayed with the backend.
+                snapshot.append((slot, seq, position, ahead,
+                                 not ahead and not seq.tokens))
+            # A joined request for one token needs no step, only its first
+            # id read: it rides a step others need, and where none does,
+            # nothing is launched and the ids are read alone.
+            if all(first and seq.max_new_tokens == 1
+                   for _, seq, _, _, first in snapshot):
+                snapshot, alone = [], snapshot
+        if not snapshot and not alone and unread is None:
             self._last_submit = None
             return
         # One region a launched step, as numbered; a call that only reads
@@ -855,7 +946,7 @@ class DecodeEngine:
             bound_for = getattr(self.backend, "bound_for", None)
             region = device_trace(
                 "ai4e.decode.tick", tick=self._tick_no, active=len(snapshot),
-                bound=(bound_for(max(p for _, _, p, _ in snapshot))
+                bound=(bound_for(max(entry[2] for entry in snapshot))
                        if bound_for else self.backend.max_len))
         with region:
             args = flight = None
@@ -864,13 +955,14 @@ class DecodeEngine:
                     fresh = [None] * self.pool.slots
                     positions = [0] * self.pool.slots
                     active = [False] * self.pool.slots
-                    for slot, seq, position, ahead in snapshot:
-                        if not ahead:   # the host has its last token
+                    for slot, seq, position, ahead, first in snapshot:
+                        if not ahead and not first:   # the host has its last
                             fresh[slot] = seq.tokens[-1]
                         positions[slot] = position
                         active[slot] = True
                     args = (fresh, positions, active)
-                flight = _Flight([entry[:3] for entry in snapshot],
+                flight = _Flight([(slot, seq, position, first) for
+                                  slot, seq, position, _, first in snapshot],
                                  self._tick_no)
                 self._launches.inc(model=self._model, kind="all")
                 if unread is not None:
@@ -878,8 +970,8 @@ class DecodeEngine:
                 # Registered before the call: a retire that runs while it
                 # is awaited must see that this step holds the slot.
                 self._launched.append(flight)
-            step, clock = await self._call(
-                self._advance, args, unread and unread.step)
+            (step, firsts), clock = await self._call(
+                self._advance, args, unread and unread.step, bool(alone))
             phase = self._phase
             if flight is not None:
                 flight.step = step
@@ -905,22 +997,26 @@ class DecodeEngine:
                 with device_trace("ai4e.decode.bookkeeping"):
                     self._note_step(unread)
                 self._release_parked()
+            for slot, seq, *_ in alone:
+                if not seq.done and seq.slot == slot:
+                    self._note_token(seq, int(firsts[slot]))
             phase["bookkeeping"] += time.perf_counter() - clock.resumed
 
-    def _advance_in_thread(self, args, unread):
-        """On the device thread: launch, then fetch — in that order."""
+    def _advance_in_thread(self, args, unread, alone):
+        """On the device thread: launch, then fetch — in that order — or,
+        with nothing to launch, the joined slots' first ids read alone."""
         step = self._steps.launch(*args) if args is not None else None
         if unread is not None:
             self._steps.fetch(unread)
-        return step
+        return step, self._steps.first_ids() if alone else None
 
-    async def _advance_async(self, args, unread):
+    async def _advance_async(self, args, unread, alone):
         step = await self._steps.launch(*args) if args is not None else None
         if unread is not None:
             fetched = self._steps.fetch(unread)
             if inspect.isawaitable(fetched):
                 await fetched
-        return step
+        return step, self._steps.first_ids() if alone else None
 
     async def _settle(self) -> None:
         """Read every launched step, launching nothing: what ``reset_cache``
@@ -945,13 +1041,15 @@ class DecodeEngine:
 
     def _note_step(self, flight: _Flight) -> None:
         """Account one step whose ids were just fetched — single segment:
-        its counters, then each rider's token. A rider retired since the
-        launch (or whose slot has a new tenant) is a discarded slot-step."""
+        its counters, then each rider's token, and before it the first id of
+        a rider joined under this step. A rider retired since the launch
+        (or whose slot has a new tenant, or that its first id ended) is a
+        discarded slot-step."""
         step, snapshot = flight.step, flight.snapshot
         self._step_active.observe(len(snapshot), model=self._model)
         self._step_bound.observe(step.bound, model=self._model)
         self._kv_positions.inc(
-            sum(position + 1 for _, _, position in snapshot),
+            sum(entry[2] + 1 for entry in snapshot),
             model=self._model, kind="live")
         self._kv_positions.inc(
             self.pool.slots * step.bound if step.attended is None
@@ -962,7 +1060,12 @@ class DecodeEngine:
             self._state_bytes.inc(nbytes, model=self._model, kind=kind)
         for name, value in step.report.items():
             self._step_report[name].observe(value, model=self._model)
-        for slot, seq, position in snapshot:
+        for slot, seq, position, first in snapshot:
+            if first and not (seq.done or seq.slot != slot):
+                # What the slot was fed is its prompt's first id, which the
+                # host did not wait for: noted here, before the step's own.
+                self._joins_total.inc(model=self._model, kind="ahead")
+                self._note_token(seq, int(step.fed[slot]), flight.tick)
             if seq.done or seq.slot != slot:
                 self._discarded.inc(model=self._model)
                 continue

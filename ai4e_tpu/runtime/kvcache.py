@@ -23,19 +23,22 @@ discipline ``ModelRuntime.warmup`` applies to batch buckets):
   prompt bucket (``ladder.DECODE_PROMPT_BUCKETS``: prompts pad to the
   smallest fitting bucket, so XLA compiles ``len(buckets)`` prefill
   programs, not one per prompt length);
-- **insert** — a prefill's KV block written into a slot's rows, and the
+- **insert** — a prefill's KV block written into a slot's rows, the
   state it reached after the prompt's ``length`` tokens into the slot's
-  index of every state tensor (slot index is a traced scalar: one program
-  per bucket, any slot);
+  index of every state tensor, and its first generated id into the slot's
+  entry of the ids the next step feeds on (slot index is a traced scalar:
+  one program per bucket, any slot);
 - **step** — one decode step over the WHOLE pool: every slot advances
   one token (inactive slots ride along masked; their rows are garbage a
   later prefill overwrites; of the recurrent state only the live slots'
   moves — ``state_pool.update_live`` — and a dead slot's stays what it
-  was). A slot's token is the id the step before
-  gave it, which never left the device, unless the host feeds one (a
-  slot prefilled since): ``launch`` dispatches the step and returns,
-  ``fetch`` reads its ids, so the engine launches step N+1 before it
-  reads step N. The layers read the pool as it came in and
+  was). A slot's token is the id the step before —
+  or the prefill joined since — gave it, which never left the device,
+  unless the host feeds one (a slot re-prefilled since): ``launch``
+  dispatches the step and returns, ``fetch`` reads its ids and what each
+  slot was fed, so the engine launches step N+1 before it reads step N
+  and dispatches a pass's prefills back to back (``join``) without
+  reading their first ids at all. The layers read the pool as it came in and
   the new token's K/V are stored afterwards as ONE row per slot (all
   layers at once), in place: the step produces nothing else of the
   pool's shape. Attention reads each slot only as far as it has written
@@ -56,6 +59,7 @@ from __future__ import annotations
 
 import logging
 import time
+from collections import deque
 from dataclasses import dataclass
 from typing import Any
 
@@ -126,7 +130,7 @@ class PagedDecodeRuntime:
     """The ``DecodeEngine`` backend over a real JAX model. The engine
     runs every method on its single device-executor thread (the device is
     the serial resource, batcher discipline); all block until the device
-    has answered but ``launch``, which only dispatches."""
+    has answered but ``launch`` and ``join``, which only dispatch."""
 
     def __init__(self, servable: LMServable, slots: int = 8,
                  prompt_buckets=None, donate: bool | None = None):
@@ -161,23 +165,28 @@ class PagedDecodeRuntime:
         # Bytes of state a slot holds: in the tensors a step advances at its
         # live slots only, and in those it moves at every slot.
         self._state_slot_bytes = (0, 0)
-        # The ids of the last launched step, on the device: what the next
-        # launch feeds every slot the host does not. None: there is no such
-        # step (start, reset, a failure) and every live slot is fed.
+        # The ids of the last launched step, on the device, with the first
+        # id of every prefill joined since in its slot: what the next launch
+        # feeds every slot the host does not. None: there is neither (start,
+        # reset, a failure).
         self._ids = None
+        # The first ids of the joins dispatched last, on the device, newest
+        # last: at most two are in flight (``_dispatch_join``).
+        self._joined = deque()
         # The last launched step: unread for as long as it has its ``out``.
         self._newest = None
         self._donate = donate
         self._programs = None
         # ``hook(phase, seconds)``, installed by the DecodeEngine: told the
         # seconds a fetch spent blocked on the device (``device_wait``) and
-        # of those its read-back alone (``readback``), the seconds a prefill
-        # waited for the step launched before it (``behind_step``) and then
-        # for its own run (``run``), the instant before a prefill or a step
-        # is enqueued (``enqueue``, 0 seconds: the device thread's ledger
-        # closes there) and the seconds of any call that had to build its
-        # program (``compile``). None (and during ``warm()``): nothing is
-        # reported.
+        # of those its read-back alone (``readback``), the seconds a join
+        # waited for the step launched before it (``behind_step``: none, it
+        # waits for no step) and for a prefill's run (``run``: the join two
+        # before it and, in ``prefill_into``, its own), the instant before a
+        # prefill or a step is enqueued (``enqueue``, 0 seconds: the device
+        # thread's ledger closes there) and the seconds of any call that had
+        # to build its program (``compile``). None (and during ``warm()``):
+        # nothing is reported.
         self.phase_hook = None
 
     # -- cache lifecycle ---------------------------------------------------
@@ -213,6 +222,7 @@ class PagedDecodeRuntime:
         # holds three pool tensors on the device at once, which would be
         # the allocator's peak of the whole worker.
         self._k = self._v = self._state = self._ids = None
+        self._joined.clear()
         self._k = kv_pool.allocate(shape, dtype)
         self._v = kv_pool.allocate(shape, dtype)
         self._state = state_pool.allocate(self.state_spec(), self.slots)
@@ -225,6 +235,14 @@ class PagedDecodeRuntime:
         if self._programs is None:
             self._build_programs()
 
+    def _ensure_ids(self) -> None:
+        """The device-resident ids, where there are none yet (start, reset,
+        a failure): zeros, which nothing reads before a join or a step has
+        written its slot."""
+        if self._ids is None:
+            import jax.numpy as jnp
+            self._ids = jnp.zeros((self.slots,), jnp.int32)
+
     def _build_programs(self) -> None:
         import jax
         import jax.numpy as jnp
@@ -234,7 +252,7 @@ class PagedDecodeRuntime:
             # backends donation keeps the pool resident exactly once.
             self._donate = jax.default_backend() != "cpu"
         donate_step = (3, 4, 5) if self._donate else ()
-        donate_insert = (0, 1, 2) if self._donate else ()
+        donate_insert = (0, 1, 2, 3) if self._donate else ()
         slots = self.slots
 
         def prefill(params, tokens, length):
@@ -244,18 +262,27 @@ class PagedDecodeRuntime:
         # whether the host feeds the slot, the token it feeds, the position.
         # Every other slot feeds on ``previous``, the last step's ids, which
         # stayed on the device. Returns the step's output (ids, then what the
-        # model appends) and the ids alone, for the next launch.
+        # model appends, then the token each slot was fed: a joined prompt's
+        # first id reaches the host here) and the ids alone, for the next
+        # launch.
         def step(params, host, previous, k, v, state, bound):
             tokens = jnp.where(host[0] != 0, host[1], previous)
             out, k, v, state = model.apply(params, tokens, k, v, state,
                                            host[2], bound,
                                            method="decode_step")
-            return out, out[:slots], k, v, state
+            # Behind a barrier: the model's own program stays as compiled
+            # without the wrapper's concatenation (XLA fused it into a sparse
+            # family's per-layer producers otherwise).
+            out = jax.lax.optimization_barrier(out)
+            return jnp.concatenate([out, tokens]), out[:slots], k, v, state
 
         # A wrapper for its name: the trace's module stays ``jit_insert``.
-        def insert(k, v, state, k_block, v_block, state_block, slot):
+        # ``token`` is the prefill's (1,) ids: the slot feeds on it next.
+        def insert(k, v, state, ids, k_block, v_block, state_block, token,
+                   slot):
             return (*kv_pool.insert_block(k, v, k_block, v_block, slot),
-                    state_pool.insert(state, state_block, slot))
+                    state_pool.insert(state, state_block, slot),
+                    ids.at[slot].set(token[0]))
 
         self._programs = {
             "prefill": jax.jit(prefill),
@@ -301,10 +328,15 @@ class PagedDecodeRuntime:
                 return b
         return self.prompt_buckets[-1]
 
-    def prefill_into(self, slot: int, tokens) -> int:
-        """Run the prompt through the prefill program (padded to its
-        bucket), write its KV block into ``slot``, return the first
-        generated token id."""
+    def _dispatch_join(self, slot: int, tokens) -> tuple:
+        """Dispatch the prefill of ``tokens`` (padded to its bucket) and the
+        insert of what it returns into ``slot`` — K/V block, state, and its
+        first generated id into the ids the next launch feeds on. Returns
+        that id, still on the device, and the seconds waited first: at most
+        two joins are in flight, one running and one queued behind it, so
+        the next is not dispatched before the one two back has run. That
+        hides a dispatch under a run and holds what the joins add to the
+        allocator's peak to one more prefill's outputs and temporaries."""
         self._ensure()
         n = len(tokens)
         if not 0 < n < self.max_len:
@@ -313,28 +345,60 @@ class PagedDecodeRuntime:
         bucket = self.bucket_for(n)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :n] = tokens
+        t0 = time.perf_counter()
+        if len(self._joined) == 2:
+            with device_trace("ai4e.decode.join.run"):
+                self._joined.popleft().block_until_ready()
+        waited = time.perf_counter() - t0
+        self._ensure_ids()
         self._tell("enqueue")
         with device_trace("ai4e.decode.prefill", bucket=bucket, slot=slot):
             token, k_block, v_block, state_block = self._run(
                 "prefill", self.servable.params, padded,
                 np.asarray([n], np.int32))
         with device_trace("ai4e.decode.insert", slot=slot):
-            self._k, self._v, self._state = self._run(
-                "insert", self._k, self._v, self._state, k_block, v_block,
-                state_block, np.int32(slot))
-        # The device runs in order: first whatever step was launched before
-        # this prefill (its ids are the last thing it writes), then the
-        # prefill, whose one id the host reads; the insert trails.
+            self._k, self._v, self._state, self._ids = self._run(
+                "insert", self._k, self._v, self._state, self._ids, k_block,
+                v_block, state_block, token, np.int32(slot))
+        self._joined.append(token)
+        return token, waited
+
+    def join(self, slot: int, tokens) -> None:
+        """Dispatch a prompt's prefill into ``slot`` and return without
+        reading it. Its first generated id stays on the device: the next
+        ``launch`` feeds the slot from it where ``fresh[slot]`` is None, and
+        that step's ``fetch`` hands it to the host as ``fed[slot]``. A
+        failure on the device surfaces at that fetch."""
+        _, waited = self._dispatch_join(slot, tokens)
+        self._tell("behind_step", 0.0)
+        self._tell("run", waited)
+
+    def prefill_into(self, slot: int, tokens) -> int:
+        """``join``, then read: run the prompt through the prefill program,
+        write its KV block into ``slot``, return the first generated token
+        id."""
+        token, waited = self._dispatch_join(slot, tokens)
         t0 = time.perf_counter()
-        if self._ids is not None:
-            with device_trace("ai4e.decode.join.behind_step"):
-                self._ids.block_until_ready()
-        t1 = time.perf_counter()
         with device_trace("ai4e.decode.join.run"):
             first = int(token[0])   # waits for the prefill program's run
-        self._tell("behind_step", t1 - t0)
-        self._tell("run", time.perf_counter() - t1)
+        self._tell("behind_step", 0.0)
+        self._tell("run", waited + time.perf_counter() - t0)
         return first
+
+    def first_ids(self) -> list:
+        """Wait for everything dispatched and read the id the next launch
+        would feed each slot: a joined prompt's first id, for the engine
+        that has no step to carry it to the host."""
+        t0 = time.perf_counter()
+        try:
+            with device_trace("ai4e.decode.device_wait"):
+                ids = np.asarray(self._ids).tolist()
+        except Exception:
+            self._ids = None
+            self._joined.clear()
+            raise
+        self._tell("device_wait", time.perf_counter() - t0)
+        return ids
 
     def bound_for(self, longest: int) -> int:
         """The smallest rung of ``step_bounds`` that holds every key a step
@@ -349,9 +413,9 @@ class PagedDecodeRuntime:
     def launch(self, fresh, positions, active) -> LaunchedStep:
         """Dispatch one decode step over the pool and return without
         waiting for it. ``fresh[slot]`` is the token the host feeds that
-        slot, or None: the slot feeds on the id the last launched step gave
-        it. The program computes every slot; inactive rows are garbage the
-        engine never reads. ``positions`` and ``active`` choose the
+        slot, or None: the slot feeds on the id the last launched step — or
+        the prefill joined into it since — gave it. The program computes
+        every slot; inactive rows are garbage the engine never reads. ``positions`` and ``active`` choose the
         program: the one compiled for ``bound_for`` the largest position
         among the ACTIVE slots (an inactive slot's stale position does not
         count), whose attention covers that many positions and is otherwise
@@ -384,14 +448,13 @@ class PagedDecodeRuntime:
             if token is not None:
                 host[0, slot], host[1, slot] = 1, token
         host[2] = positions
-        # A step still unread that has already finished: the device has sat
+        # A step still unread, and everything dispatched since (a join's
+        # insert writes ``_ids`` last), already finished: the device has sat
         # idle since, and goes on until this launch lands. For how long the
         # host cannot know (``is_ready`` does not block, and says no more).
         starved = (self._ids is not None and self._newest is not None
                    and self._newest.out is not None and self._ids.is_ready())
-        if self._ids is None:
-            import jax.numpy as jnp
-            self._ids = jnp.zeros((self.slots,), jnp.int32)
+        self._ensure_ids()
         self._tell("enqueue")
         try:
             with device_trace("ai4e.decode.dispatch", bound=bound,
@@ -415,10 +478,11 @@ class PagedDecodeRuntime:
         return self._newest
 
     def fetch(self, step: LaunchedStep) -> LaunchedStep:
-        """Wait for a launched step and read what it returned: ``ids``, and
-        from a model that reports on its step (``step_report``, over the
-        launch's own ``active``) its ``report`` — what the model appended
-        to its ids came with the same fetch."""
+        """Wait for a launched step and read what it returned: ``ids``,
+        ``fed`` (the token each slot was fed: from the host, the step before
+        or a prefill joined since) and, from a model that reports on its
+        step (``step_report``, over the launch's own ``active``), its
+        ``report`` — all of it came with the same fetch."""
         t0 = time.perf_counter()
         try:
             with device_trace("ai4e.decode.device_wait"):
@@ -428,6 +492,7 @@ class PagedDecodeRuntime:
                     out = np.asarray(step.out)   # the d2h alone
         except Exception:
             self._ids = None   # a step launched after this one is void too
+            self._joined.clear()
             raise
         finally:
             step.out = None
@@ -435,9 +500,10 @@ class PagedDecodeRuntime:
         self._tell("device_wait", now - t0)
         self._tell("readback", now - t1)
         step.ids = out[:self.slots].tolist()
-        if out.shape[0] > self.slots:
+        step.fed = out[-self.slots:].tolist()
+        if out.shape[0] > 2 * self.slots:
             step.report = self.servable.model.step_report(
-                out[self.slots:], step.active)
+                out[self.slots:-self.slots], step.active)
         return step
 
     def step(self, tokens, positions, active) -> list[int]:

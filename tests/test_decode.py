@@ -316,7 +316,7 @@ class TestEngineScheduling:
             "ai4e_decode_kv_positions_total",
             "ai4e_decode_cache_bytes_total",
             "ai4e_decode_state_bytes_total", "ai4e_decode_tick_joins",
-            "ai4e_decode_step_launches_total",
+            "ai4e_decode_step_launches_total", "ai4e_decode_joins_total",
             "ai4e_decode_discarded_slot_steps_total"}
 
     @pytest.mark.parametrize("declared", [

@@ -13,7 +13,14 @@
   step, one that ends by ``eos_id`` costs exactly one discarded slot-step,
   tokens reach ``on_token`` in order and as soon as their step is fetched;
 - a slot is not handed to a join while a launched step still has its
-  previous tenant live.
+  previous tenant live;
+- a join does not stop the loop either (``join`` / ``first_ids``): a clump's
+  prefills are dispatched back to back, the first id of each stays with the
+  backend, rides the next step and is read with its fetch, before the
+  second — the plain loop's ids for clumps of 1, 2, 4 and 7 on three slots;
+  a request for one token, a first id that is ``eos_id``, a cancel, an
+  expiry, a failure, a reload and a drain between a join and its read; and
+  the runtime never has more than two joins in flight.
 """
 
 import asyncio
@@ -168,6 +175,38 @@ class TestTheEngineGivesThePlainLoopsIds:
         # D's step after its EOS had been launched: computed, discarded.
         assert counted(reg, "ai4e_decode_discarded_slot_steps_total") >= 1
 
+    @pytest.mark.parametrize("clump", [1, 2, 4, 7])
+    def test_a_clump_joined_ahead_gives_the_plain_loops_ids(self, family,
+                                                            clump):
+        """``clump`` requests at once on three slots: every join of a pass
+        is dispatched before anything is read, the first ids stay on the
+        device, and each stream is the plain loop's — index 0 first."""
+        wanted = [("abcde"[i % 5], (9, 1, 6, 2, 12, 5, 3)[i])
+                  for i in range(clump)]
+        seen = []
+
+        async def main():
+            engine, reg = family.engine()
+            await engine.start()
+            out = await asyncio.gather(*[
+                engine.submit(PROMPTS[name], budget,
+                              on_token=lambda i, t, k=k: seen.append(
+                                  (k, i, t)))
+                for k, (name, budget) in enumerate(wanted)])
+            await wait_until(lambda: settled(engine))
+            await engine.stop()
+            return out, reg
+
+        out, reg = asyncio.run(main())
+        assert out == [family.want(name, budget) for name, budget in wanted]
+        for k, tokens in enumerate(out):
+            assert [(i, t) for j, i, t in seen if j == k] == list(
+                enumerate(tokens))
+        joins = "ai4e_decode_joins_total"
+        assert counted(reg, joins, kind="all") == clump
+        # All but a request for one token that found nothing else to ride.
+        assert clump - 1 <= counted(reg, joins, kind="ahead") <= clump
+
     def test_a_cancel_and_an_expiry_with_a_step_in_flight(self, family):
         async def main():
             engine, reg = family.engine()
@@ -278,10 +317,13 @@ class Scripted:
         self.log.append("reset")
         assert not self.unread, "cache reset under a launched step"
 
-    def prefill_into(self, slot, tokens):
+    def _free_of_steps(self, slot):
         assert not any(step.active[slot] for step in self.unread), (
             f"join written into slot {slot} under a step launched for its "
             f"previous tenant")
+
+    def prefill_into(self, slot, tokens):
+        self._free_of_steps(slot)
         self.log.append(("prefill", slot))
         return int(tokens[-1]) + 1
 
@@ -290,7 +332,7 @@ class Scripted:
                   for slot, token in enumerate(fresh)]
         self._ids = [t + 1 for t in tokens]
         step = LaunchedStep(bound=self.max_len, active=list(active),
-                            out=list(self._ids))
+                            out=list(self._ids), fed=tokens)
         self.launches.append(
             ([slot for slot, live in enumerate(active) if live],
              bool(self.unread)))
@@ -353,9 +395,10 @@ class TestTheOrderOfLaunchAndFetch:
         serve_scripted(backend, [([1], 5)])
         events = [ev for ev in backend.log if ev[0] != "prefill"]
         assert events == [
-            ("token", (1,), 0, 2),                      # the prefill's
             ("launch", 1),
-            ("launch", 2), ("fetch", 1), ("token", (1,), 1, 3),
+            ("launch", 2), ("fetch", 1),
+            ("token", (1,), 0, 2),       # the prefill's: read with step 1
+            ("token", (1,), 1, 3),
             ("launch", 3), ("fetch", 2), ("token", (1,), 2, 4),
             ("launch", 4), ("fetch", 3), ("token", (1,), 3, 5),
             ("fetch", 4), ("token", (1,), 4, 6)]
@@ -448,6 +491,326 @@ class TestASlotWaitsForTheStepsThatHoldIt:
         assert out == list(range(2, 14))
         assert "reset" in backend.log   # which asserts nothing was unread
         assert counted(reg, "ai4e_decode_reprefills_total") == 1
+
+
+class Ahead(Scripted):
+    """``Scripted`` with the join that does not block: the first id stays
+    in ``_ids``, where the next launch finds it."""
+
+    def join(self, slot, tokens):
+        self._free_of_steps(slot)
+        self.log.append(("join", slot))
+        self._ids[slot] = int(tokens[-1]) + 1
+
+    def first_ids(self):
+        self.log.append("first_ids")
+        return list(self._ids)
+
+
+def by_hand(backend, script):
+    """``script(engine, tick)`` on an engine whose ticks the script drives;
+    afterwards nothing is in flight and the pool is whole. Returns
+    ``(what the script returned, registry)``."""
+    reg = MetricsRegistry()
+
+    async def main():
+        engine = DecodeEngine(backend, metrics=reg)
+
+        async def tick(n=1):
+            for _ in range(n):
+                await asyncio.sleep(0)      # submits reach the queue
+                await engine._tick()
+
+        out = await script(engine, tick)
+        for _ in range(200):
+            if not (engine._active or engine._queue or engine._launched):
+                break
+            await tick()
+        await engine.stop()
+        assert settled(engine)
+        return out
+
+    return asyncio.run(main()), reg
+
+
+def submit(engine, backend, prompt, budget, **kw):
+    """A submitted request whose tokens land in ``backend.log``."""
+    return asyncio.ensure_future(engine.submit(
+        prompt, budget, on_token=lambda i, t: backend.log.append(
+            ("token", tuple(prompt), i, t)), **kw))
+
+
+def calls(backend):
+    return [ev if isinstance(ev, str) else ev[0] for ev in backend.log
+            if ev == "first_ids" or ev[0] in ("join", "launch", "fetch")]
+
+
+class TestAJoinDoesNotStopTheLoop:
+    @pytest.mark.parametrize("clump,slots", [(1, 2), (2, 2), (7, 8), (5, 3)])
+    def test_a_pass_dispatches_its_joins_then_launches_then_fetches(
+            self, clump, slots):
+        """join, join, ..., launch — and the first fetch only after the
+        launch that carries the joins; a clump larger than the pool joins
+        as slots come free."""
+        backend = Ahead(slots=slots)
+        out, reg = serve_scripted(
+            backend, [([10 * (i + 1)], 3) for i in range(clump)])
+        assert out == [[10 * (i + 1) + k for k in (1, 2, 3)]
+                       for i in range(clump)]
+        first = min(clump, slots)
+        assert calls(backend)[:first + 3] == (
+            ["join"] * first + ["launch", "launch", "fetch"])
+        assert "first_ids" not in backend.log
+        assert not any(ev[0] == "prefill" for ev in backend.log
+                       if ev != "first_ids")
+        assert counted(reg, "ai4e_decode_joins_total", kind="all") == clump
+        assert counted(reg, "ai4e_decode_joins_total", kind="ahead") == clump
+        assert counted(reg, "ai4e_decode_discarded_slot_steps_total") == 0
+
+    def test_on_token_sees_index_0_before_index_1_with_the_same_ids(self):
+        backend = Ahead(slots=2)
+        (a, b), _ = serve_scripted(backend, [([1], 4), ([20], 2)])
+        assert (a, b) == ([2, 3, 4, 5], [21, 22])
+        tokens = [ev for ev in backend.log if ev[0] == "token"]
+        assert tokens[:4] == [("token", (1,), 0, 2), ("token", (1,), 1, 3),
+                              ("token", (20,), 0, 21), ("token", (20,), 1, 22)]
+        # Both first ids came with the fetch of step 1, after launch 2.
+        assert backend.log.index(("fetch", 1)) < backend.log.index(tokens[0])
+        assert backend.log.index(("launch", 2)) < backend.log.index(
+            ("fetch", 1))
+
+    def test_a_request_for_one_token_alone_is_read_without_a_step(self):
+        backend = Ahead(slots=2)
+        (a, b), reg = serve_scripted(backend, [([1], 1), ([20], 1)])
+        assert (a, b) == ([2], [21])
+        assert calls(backend) == ["join", "join", "first_ids"]
+        assert counted(reg, "ai4e_decode_step_launches_total", kind="all") == 0
+        assert counted(reg, "ai4e_decode_joins_total", kind="all") == 2
+        assert counted(reg, "ai4e_decode_joins_total", kind="ahead") == 0
+        assert counted(reg, "ai4e_decode_discarded_slot_steps_total") == 0
+
+    def test_a_request_for_one_token_among_others_rides_their_step(self):
+        """It is not known finished when the step is launched: one
+        discarded slot-step, no launch of its own, no read of its own."""
+        backend = Ahead(slots=2)
+        (a, b), reg = serve_scripted(backend, [([1], 1), ([20], 3)])
+        assert (a, b) == ([2], [21, 22, 23])
+        assert [slots for slots, _ in backend.launches] == [[0, 1], [1]]
+        assert "first_ids" not in backend.log
+        assert counted(reg, "ai4e_decode_discarded_slot_steps_total") == 1
+        assert counted(reg, "ai4e_decode_joins_total", kind="ahead") == 2
+
+    def test_a_first_id_that_is_eos_costs_one_discarded_slot_step(self):
+        backend = Ahead(slots=2, eos_id=2)
+        (a, b), reg = serve_scripted(backend, [([1], 64), ([20], 3)])
+        assert (a, b) == ([2], [21, 22, 23])
+        assert [slots for slots, _ in backend.launches] == [[0, 1], [0, 1]]
+        # Step 1's id for it, and step 2's, launched before its EOS was read.
+        assert counted(reg, "ai4e_decode_discarded_slot_steps_total") == 2
+
+    @pytest.mark.parametrize("ended_by", ["cancel", "expiry"])
+    def test_retired_between_its_join_and_the_read(self, ended_by):
+        """Neither id reaches it, its slot stays parked while the step that
+        carries it is unread, and the request queued behind it joins that
+        slot only after the fetch (``Ahead.join`` asserts it)."""
+        backend = Ahead(slots=1)
+
+        async def script(engine, tick):
+            first = submit(engine, backend, [1], 9)
+            second = submit(engine, backend, [20], 2)
+            await tick()                     # join + launch 1
+            seq = engine._active[0]
+            assert not seq.tokens and engine._launched
+            if ended_by == "cancel":
+                engine.cancel(seq.future)
+            else:
+                seq.deadline_at = 1.0
+                engine._sweep()
+            assert seq.done and engine._parked == {0}
+            engine.pool.check_conservation()
+            await tick(6)
+            return await asyncio.gather(first, second,
+                                        return_exceptions=True)
+
+        (first, second), reg = by_hand(backend, script)
+        assert first == [] if ended_by == "cancel" else isinstance(
+            first, DeadlineExceeded)
+        assert second == [21, 22]
+        assert not any(ev[:2] == ("token", (1,)) for ev in backend.log)
+        joins = [i for i, ev in enumerate(backend.log) if ev == ("join", 0)]
+        before = backend.log[:joins[1]]
+        assert (sum(ev[0] == "launch" for ev in before)
+                == sum(ev[0] == "fetch" for ev in before))
+        assert counted(reg, "ai4e_decode_discarded_slot_steps_total") >= 1
+        assert counted(reg, "ai4e_decode_joins_total", kind="all") == 2
+        assert counted(reg, "ai4e_decode_joins_total", kind="ahead") == 1
+
+    def test_a_join_that_fails_surfaces_at_the_read(self):
+        """The device's failure of a joined prefill comes up at the fetch of
+        the step that carried it: its sequence fails, what was launched
+        after is void, and the engine goes on."""
+        backend = Ahead(slots=2)
+        fetch = backend.fetch
+
+        def poisoned(step):
+            backend.fetch = fetch
+            backend.unread.clear()
+            raise RuntimeError("the prefill fell over")
+
+        async def between(engine, futures):
+            await asyncio.gather(*futures, return_exceptions=True)
+            futures.append(asyncio.ensure_future(engine.submit([30], 3)))
+
+        backend.fetch = poisoned
+        (a, b, c), reg = serve_scripted(backend, [([1], 5), ([20], 5)],
+                                        between=between)
+        assert all(isinstance(exc, RuntimeError)
+                   and "decode step failed" in str(exc) for exc in (a, b))
+        assert c == [31, 32, 33]
+        assert not any(ev[0] == "token" and ev[1] != (30,)
+                       for ev in backend.log)
+        assert counted(reg, "ai4e_decode_sequences_total",
+                       outcome="failed") == 2
+
+    def test_a_reload_with_a_join_unread_settles_it_with_its_step(self):
+        backend = Ahead(slots=1)
+
+        async def script(engine, tick):
+            answer = submit(engine, backend, [1], 8)
+            await tick()                     # join + launch 1
+            assert not engine._active[0].tokens
+            backend.params_version += 1
+            await tick()                     # reads step 1, then resets
+            assert engine._active[0].tokens == [2, 3, 4]
+            await tick(8)
+            return await answer
+
+        out, reg = by_hand(backend, script)
+        assert out == list(range(2, 10))
+        reset = backend.log.index("reset")      # asserted nothing unread
+        assert backend.log[reset - 3:reset] == [
+            ("fetch", 1), ("token", (1,), 0, 2), ("token", (1,), 1, 3)]
+        assert backend.log[reset + 1] == ("prefill", 0)
+        assert counted(reg, "ai4e_decode_reprefills_total") == 1
+        assert counted(reg, "ai4e_decode_joins_total", kind="ahead") == 1
+
+    def test_a_drain_with_a_join_unread_reads_it_and_frees_the_slot(self):
+        backend = Ahead(slots=2)
+
+        async def script(engine, tick):
+            kept = submit(engine, backend, [1], 3)
+            forced = submit(engine, backend, [20], 50)
+            await tick()                     # two joins + launch 1
+            queued = submit(engine, backend, [30], 2)
+            await asyncio.sleep(0)
+            assert engine.begin_drain() == 1 and not engine.drain_complete
+            await tick(4)                    # the first ids are read, A ends
+            assert engine.force_drain() == 1
+            await tick(3)
+            assert engine.drain_complete
+            return await asyncio.gather(kept, forced, queued,
+                                        return_exceptions=True)
+
+        (kept, forced, queued), _ = by_hand(backend, script)
+        assert kept == [2, 3, 4]
+        assert isinstance(forced, DrainingError)
+        assert isinstance(queued, DrainingError)
+        assert ("token", (20,), 0, 21) in backend.log
+
+
+class FakeArray:
+    """What a scripted program returns: knows whether it was waited for."""
+
+    def __init__(self, waited):
+        self.waited = waited
+
+    def block_until_ready(self):
+        self.waited.add(id(self))
+        return self
+
+    def __getitem__(self, index):
+        self.waited.add(id(self))   # a read waits too
+        return 7
+
+
+class FakePrograms(dict):
+    """``prefill`` / ``insert`` of a runtime over a scripted device: at
+    every prefill's dispatch, how many earlier joins nobody has waited
+    for."""
+
+    def __init__(self):
+        self.waited, self.tokens, self.unwaited = set(), [], []
+        self["prefill"] = self._program(self._prefill)
+        self["insert"] = self._program(
+            lambda *args: tuple(FakeArray(self.waited) for _ in range(4)))
+
+    @staticmethod
+    def _program(fn):
+        def program(*args):
+            return fn(*args)
+        program._cache_size = lambda: 1
+        return program
+
+    def _prefill(self, params, padded, length):
+        self.unwaited.append(sum(id(t) not in self.waited
+                                 for t in self.tokens))
+        self.tokens.append(FakeArray(self.waited))
+        return (self.tokens[-1], *(FakeArray(self.waited) for _ in range(3)))
+
+
+@pytest.mark.parametrize("joins", [1, 2, 3, 9])
+def test_the_runtime_keeps_at_most_two_joins_in_flight(joins):
+    """Before join ``i`` is dispatched the runtime has waited for join
+    ``i - 2``: one running, one queued, whatever the clump — a rule of the
+    code, not a setting."""
+    from ai4e_tpu.runtime.kvcache import PagedDecodeRuntime, build_lm_servable
+    runtime = PagedDecodeRuntime(build_lm_servable(
+        family="seqformer-lm", name="lm", max_len=MAX_LEN,
+        **FAMILIES["seqformer-lm"]), slots=4, prompt_buckets=(8,))
+    runtime._k = runtime._v = runtime._state = object()
+    runtime._ids = object()
+    programs = runtime._programs = FakePrograms()
+    for i in range(joins):
+        assert runtime.join(i % 4, [1, 2, 3]) is None
+    assert programs.unwaited == [0, 1, 1, 1, 1, 1, 1, 1, 1][:joins]
+    assert runtime.prefill_into(0, [1, 2, 3]) == 7   # a join, then the read
+    assert programs.unwaited[-1] == min(joins, 1)
+    assert len(programs.tokens) == joins + 1
+
+
+def test_insert_leaves_the_first_id_where_the_next_launch_finds_it(family):
+    """On each family's runtime: a join writes its prefill's id into the
+    device-resident ids at its slot — also under a step launched before it —
+    and a launch that leaves the slot to the device (``fresh[slot] = None``)
+    gives the ids of one that feeds that id from the host; nothing compiles
+    that ``warm()`` did not build."""
+    ahead, fed = family.served, family.plain
+    ahead.reset_cache()
+    fed.reset_cache()
+    told = []
+    ahead.phase_hook = lambda phase, seconds: told.append(phase)
+    try:
+        first = fed.prefill_into(1, list(PROMPTS["a"]))
+        assert ahead.join(1, list(PROMPTS["a"])) is None
+        assert ahead.first_ids()[1] == first
+        positions, active = [0, 3, 0], [False, True, False]
+        one = ahead.launch([None] * SLOTS, positions, active)
+        # A second prompt joins under step 1, unread: slot 1 goes on feeding
+        # on step 1's id, slot 2 on its own first id.
+        ahead.join(2, list(PROMPTS["d"]))
+        two = ahead.launch([None] * SLOTS, [0, 4, 4], [False, True, True])
+        got = [ahead.fetch(one), ahead.fetch(two)]
+    finally:
+        ahead.phase_hook = None
+    assert "compile" not in told and told.count("enqueue") == 4
+    want = [fed.fetch(fed.launch([None, first, None], positions, active))]
+    other = fed.prefill_into(2, list(PROMPTS["d"]))
+    want.append(fed.fetch(fed.launch(
+        [None, want[0].ids[1], other], [0, 4, 4], [False, True, True])))
+    assert [step.ids[1] for step in got] == [step.ids[1] for step in want]
+    assert got[1].ids[2] == want[1].ids[2]
+    assert got[0].fed[1] == first and got[1].fed[1:] == [got[0].ids[1], other]
+    assert want[1].fed[1:] == [want[0].ids[1], other]
 
 
 def test_a_blocking_step_backend_rides_the_same_loop():
