@@ -5,7 +5,9 @@ held here give.
 A MoE layer, for a row ``h`` (after its norm)::
 
     p = softmax(h W_r)                      over ALL ``total`` experts, float32
-    the K largest p and their experts       (a tie to the lower index)
+        (or sigmoid(h W_r), an expert's own score, where the family says)
+    the K largest p and their experts       (a tie to the lower index; of
+        p + b where the family keeps a bias b for the choice alone)
     p <- p / sum of the K                   where the family renormalises
     y = sum over the row's K experts e of p_e * W_down,e(silu(W_gate,e h) * W_up,e h)
 
@@ -44,18 +46,32 @@ def _dot(eq, a, b):
     return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
 
 
-def route(h, router, k: int, renormalise: bool = False):
+def route(h, router, k: int, renormalise: bool = False,
+          scoring: str = "softmax", bias=None, scale: float = 1.0):
     """``h (..., D)`` (after the layer's norm), ``router (D, total)`` → the
     chosen experts ``(..., K)``, ids over ``total``, and their weights
-    ``(..., K)`` in float32: the softmax probabilities, divided by their sum
-    where ``renormalise``. ``top_k`` breaks a tie toward the lower expert
-    index and returns exactly K."""
+    ``(..., K)`` in float32: the scores — ``scoring``: the softmax
+    probabilities, or each expert's own ``sigmoid`` — of the K largest,
+    divided by their sum where ``renormalise`` and multiplied by ``scale``.
+    ``bias (total,)``, where given, is added to the scores for the CHOICE
+    alone: the weights are the scores without it. ``top_k`` breaks a tie
+    toward the lower expert index and returns exactly K."""
     with jax.named_scope("router"):
-        p = jax.nn.softmax(_dot("...d,de->...e", h, router), axis=-1)
-        top_p, top_e = jax.lax.top_k(p, k)
+        logits = _dot("...d,de->...e", h, router)
+        if scoring == "softmax":
+            p = jax.nn.softmax(logits, axis=-1)
+        elif scoring == "sigmoid":
+            p = jax.nn.sigmoid(logits)
+        else:
+            raise ValueError(f"unknown scoring {scoring!r}")
+        if bias is None:
+            top_p, top_e = jax.lax.top_k(p, k)
+        else:
+            _, top_e = jax.lax.top_k(p + bias.astype(jnp.float32), k)
+            top_p = jnp.take_along_axis(p, top_e, axis=-1)
         if renormalise:
             top_p = top_p / top_p.sum(axis=-1, keepdims=True)
-        return top_e, top_p
+        return top_e, top_p * scale if scale != 1.0 else top_p
 
 
 def gate_matrix(top_e, top_p, held: int, first_held: int = 0):
@@ -112,15 +128,41 @@ def routed(h, top_e, top_p, w_gate, w_up, w_down, first_held: int = 0):
 
 
 def shared(h, gate_w, w_gate, w_up, w_down):
-    """A shared expert every row passes through, behind a sigmoid gate of its
-    own: ``sigmoid(h w_s) * W_down(silu(W_gate h) * W_up h)``. ``gate_w (D,
-    1)``, weights ``(D, F)`` and ``(F, D)``."""
+    """A shared expert every row passes through: ``W_down(silu(W_gate h) *
+    W_up h)``, weights ``(D, F)`` and ``(F, D)`` — behind a sigmoid gate of
+    its own, ``sigmoid(h w_s)``, where the family has one (``gate_w (D,
+    1)``; None: ungated)."""
     with jax.named_scope("shared_expert"):
         a = (jax.nn.silu(_dot("...d,df->...f", h, w_gate))
              * _dot("...d,df->...f", h, w_up)).astype(h.dtype)
         y = _dot("...f,fd->...d", a, w_down)
+        if gate_w is None:
+            return y.astype(h.dtype)
         return (jax.nn.sigmoid(_dot("...d,do->...o", h, gate_w))
                 * y).astype(h.dtype)
+
+
+# What ``load_report`` returns, as the decode engine exposes it (a model's
+# ``step_report_series``): each name a histogram ``ai4e_decode_<name>``, with
+# its help and buckets. One declaration for every routed family.
+step_report_series = {
+    "experts_touched": (
+        "Experts with at least one LIVE token, a MoE layer a decode "
+        "step (mean over the step's layers)",
+        (*(2 ** i for i in range(11)), float("inf"))),
+    "expert_peak_load": (
+        "The fullest expert's live tokens over the mean load (live "
+        "slots x experts a token / experts), a MoE layer a decode "
+        "step: the straggler measure",
+        (1.0, 1.25, 1.5, 1.75, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0,
+         float("inf"))),
+    "held_picks_share": (
+        "Live tokens' picks that land on an expert held here over all "
+        "their picks, a decode step (held / total experts where the "
+        "router spreads evenly)",
+        (0.0, 0.05, 0.1, 0.15, 0.2, 0.25, 0.3, 0.35, 0.4, 0.5, 0.75,
+         1.0, float("inf"))),
+}
 
 
 def load_report(picks: np.ndarray, total: int, held: int,

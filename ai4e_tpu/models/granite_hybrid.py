@@ -484,9 +484,9 @@ class GraniteHybridLM(nn.Module):
             state += [(f"ssm{j}", (self.ssm_state, inner), jnp.float32),
                       (f"conv{j}", (self.conv - 1, inner + 2 * self.ssm_state),
                        self.dtype)]
-        return kv_pool.SlotSpec(
-            (len(self.attention_layers), self.kv_heads, self.head_dim),
-            self.dtype, tuple(state), tuple(f"ssm{j}" for j in mamba))
+        return kv_pool.kv_slot(
+            len(self.attention_layers), self.kv_heads, self.head_dim,
+            self.dtype, state, tuple(f"ssm{j}" for j in mamba))
 
     def _embed(self, tokens):
         with jax.named_scope("embedding"):
@@ -527,8 +527,8 @@ class GraniteHybridLM(nn.Module):
                 h, cache = layer.step(
                     h, (state[f"ssm{j}"], state[f"conv{j}"]), position, bound)
                 new_state[f"ssm{j}"], new_state[f"conv{j}"] = cache
-        k_cache, v_cache = kv_pool.write_rows(k_cache, v_cache, k_rows,
-                                              v_rows, position)
+        k_cache, v_cache = kv_pool.write_rows(
+            (k_cache, v_cache), (k_rows, v_rows), position)
         return h, k_cache, v_cache, new_state
 
     def prefill(self, tokens, length):

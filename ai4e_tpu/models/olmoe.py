@@ -235,8 +235,8 @@ class OlmoeLM(nn.Module):
     @nn.nowrap
     def cache_spec(self):
         """What a slot holds (``kv_pool.SlotSpec``): K/V of every layer."""
-        return kv_pool.SlotSpec(
-            (self.depth, self.heads, self.dim // self.heads), self.dtype)
+        return kv_pool.kv_slot(
+            self.depth, self.heads, self.dim // self.heads, self.dtype)
 
     def _logits(self, h):
         with jax.named_scope("head"):
@@ -263,8 +263,8 @@ class OlmoeLM(nn.Module):
             k_rows.append(k)
             v_rows.append(v)
             experts.append(e)
-        k_cache, v_cache = kv_pool.write_rows(k_cache, v_cache, k_rows,
-                                              v_rows, position)
+        k_cache, v_cache = kv_pool.write_rows(
+            (k_cache, v_cache), (k_rows, v_rows), position)
         return h, k_cache, v_cache, jnp.stack(experts)
 
     def prefill(self, tokens, length):
@@ -297,20 +297,11 @@ class OlmoeLM(nn.Module):
                                             position, bound)
         return self._logits(h), k_cache, v_cache
 
-    # What ``step_report`` returns, as the decode engine exposes it: each
-    # name a histogram ``ai4e_decode_<name>``, with its help and buckets.
+    # What ``step_report`` returns (``experts.step_report_series``): every
+    # expert is held here, so no share of the picks.
     step_report_series = {
-        "experts_touched": (
-            "Experts with at least one LIVE token, a MoE layer a decode "
-            "step (mean over the step's layers)",
-            (*(2 ** i for i in range(11)), float("inf"))),
-        "expert_peak_load": (
-            "The fullest expert's live tokens over the mean load (live "
-            "slots x experts a token / experts), a MoE layer a decode "
-            "step: the straggler measure",
-            (1.0, 1.25, 1.5, 1.75, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0,
-             float("inf"))),
-    }
+        name: expert_layer.step_report_series[name]
+        for name in ("experts_touched", "expert_peak_load")}
 
     @nn.nowrap
     def step_report(self, extra: np.ndarray, active) -> dict[str, float]:

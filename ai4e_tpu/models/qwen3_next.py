@@ -527,8 +527,8 @@ class Qwen3NextLM(nn.Module):
             state += [(f"delta{j}", (self.lin_v_heads, self.lin_dim,
                                      self.lin_dim), jnp.float32),
                       (f"conv{j}", (self.conv - 1, channels), self.dtype)]
-        return kv_pool.SlotSpec(
-            (full, self.kv_heads, self.head_dim), self.dtype, tuple(state),
+        return kv_pool.kv_slot(
+            full, self.kv_heads, self.head_dim, self.dtype, state,
             tuple(f"delta{j}" for j in range(self.depth - full)))
 
     def _logits(self, h):
@@ -568,8 +568,8 @@ class Qwen3NextLM(nn.Module):
                     bound)
                 new_state[f"delta{j}"], new_state[f"conv{j}"] = cache
             experts.append(e)
-        k_cache, v_cache = kv_pool.write_rows(k_cache, v_cache, k_rows,
-                                              v_rows, position)
+        k_cache, v_cache = kv_pool.write_rows(
+            (k_cache, v_cache), (k_rows, v_rows), position)
         return h, k_cache, v_cache, new_state, jnp.stack(experts)
 
     def prefill(self, tokens, length):
@@ -602,26 +602,8 @@ class Qwen3NextLM(nn.Module):
             tokens, k_cache, v_cache, state, position, bound)
         return self._logits(h), k_cache, v_cache, state
 
-    # What ``step_report`` returns, as the decode engine exposes it: each
-    # name a histogram ``ai4e_decode_<name>``, with its help and buckets.
-    step_report_series = {
-        "experts_touched": (
-            "Experts with at least one LIVE token, a MoE layer a decode "
-            "step (mean over the step's layers)",
-            (*(2 ** i for i in range(11)), float("inf"))),
-        "expert_peak_load": (
-            "The fullest expert's live tokens over the mean load (live "
-            "slots x experts a token / experts), a MoE layer a decode "
-            "step: the straggler measure",
-            (1.0, 1.25, 1.5, 1.75, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0,
-             float("inf"))),
-        "held_picks_share": (
-            "Live tokens' picks that land on an expert held here over all "
-            "their picks, a decode step (held / total experts where the "
-            "router spreads evenly)",
-            (0.0, 0.05, 0.1, 0.15, 0.2, 0.25, 0.3, 0.35, 0.4, 0.5, 0.75,
-             1.0, float("inf"))),
-    }
+    # What ``step_report`` returns, as the decode engine exposes it.
+    step_report_series = expert_layer.step_report_series
 
     @nn.nowrap
     def step_report(self, extra: np.ndarray, active) -> dict[str, float]:

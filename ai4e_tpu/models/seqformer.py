@@ -202,8 +202,8 @@ class SeqFormerLM(nn.Module):
     @nn.nowrap
     def cache_spec(self):
         """What a slot holds (``kv_pool.SlotSpec``): K/V of every layer."""
-        return kv_pool.SlotSpec(
-            (self.depth, self.heads, self.dim // self.heads), jnp.float32)
+        return kv_pool.kv_slot(
+            self.depth, self.heads, self.dim // self.heads, jnp.float32)
 
     def _logits(self, h):
         # Tied embedding head: attend() reuses the embedding matrix, so
@@ -240,8 +240,8 @@ class SeqFormerLM(nn.Module):
             h, k, v = blk.step(h, k_cache, v_cache, i, position, bound)
             k_rows.append(k)
             v_rows.append(v)
-        k_cache, v_cache = kv_pool.write_rows(k_cache, v_cache, k_rows,
-                                              v_rows, position)
+        k_cache, v_cache = kv_pool.write_rows(
+            (k_cache, v_cache), (k_rows, v_rows), position)
         return h, k_cache, v_cache
 
     def decode_step(self, tokens, k_cache, v_cache, state, position,

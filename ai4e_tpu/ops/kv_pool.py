@@ -1,8 +1,14 @@
-"""The decode path's K/V pool — its layout and the operations on it.
+"""The decode path's pool of cached rows — its layout and the operations on
+it.
 
-One preallocated buffer per tensor::
+One preallocated buffer per tensor of rows a family declares
+(``SlotSpec.rows`` of ``Rows``); for a family that keeps K and V::
 
     k, v : (layers, slots, max_len, kv_heads * head_dim)
+
+and in general ``(layers, slots, length or max_len, width)``: a latent row
+every head shares, an indexer's key, a window's ring of ``length`` rows are
+declared in the same words (``models/dots3.py``).
 
 A slot is a row of it (``runtime/decode.SlotPool`` hands slots out; the
 device never reallocates per request), and one position of a slot is one
@@ -27,13 +33,17 @@ the insert and what a step read. A change of layout or of the read (a block
 table, another block rule) is a change to these two files.
 
 The decode read is a Pallas kernel (Mosaic on the chip, the interpreter
-elsewhere: ``ops/pallas/lowering.resolve_interpret``); the rest is
-``jax.numpy``. Scopes name the device side for the trace's readers
+elsewhere: ``ops/pallas/lowering.resolve_interpret``), and so are a long
+prompt's attention under a mask or a window and its index scores
+(``prompt_attention``, ``prompt_index_scores``:
+``ops/pallas/flash_attention.py``); the rest is ``jax.numpy``. Scopes name the device side for the trace's readers
 (``benchmark/lib/xplane_spans.py``) — they are metadata and change no
 program.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -41,7 +51,10 @@ import numpy as np
 
 from typing import Any, NamedTuple
 
-from .pallas.decode_attention import pooled_attention
+from .pallas.decode_attention import latent_attention, pooled_attention
+from .pallas.flash_attention import (
+    index_scores as flash_index_scores,
+    prompt_attention as flash_prompt_attention)
 
 # What one grid step of the decode read fetches of each tensor: 256
 # positions of a 4 KB row, 1,024 of a 1 KB one. Smaller, and the grid's
@@ -50,27 +63,64 @@ from .pallas.decode_attention import pooled_attention
 READ_BLOCK_BYTES = 1 << 20
 
 
+class Rows(NamedTuple):
+    """One tensor of rows a slot keeps, a row a position: ``layers`` layers
+    of rows ``width`` wide in ``dtype``. ``length``: how many a slot holds —
+    None, the cache's whole length (a row a position of the sequence), or a
+    ring of that many: position ``p`` lies at row ``p % length`` and a step
+    reads the ``min(p, length)`` last ones, in any order. ``kind``: the label
+    its bytes are counted under (``ai4e_decode_cache_bytes_total``).
+    ``select``: the family's read keeps at most that many of the positions
+    it scores (a learned selection); None: it keeps all it reads. ``whole``:
+    the step reads every slot's first ``bound`` positions in ``jax.numpy``,
+    not the blocks a slot has written through the kernel."""
+
+    name: str
+    layers: int
+    width: int
+    dtype: Any
+    length: int | None = None
+    kind: str = "kv"
+    select: int | None = None
+    whole: bool = False
+
+
 class SlotSpec(NamedTuple):
     """Everything one slot of a family's cache holds — what its
-    ``cache_spec()`` declares and ``runtime/kvcache.py`` allocates: the K/V
-    of ``kv`` = (layers that keep K/V, K/V heads, head_dim) in ``dtype``,
-    and ``state``: fixed-size tensors, each ``(name, shape a slot, dtype)``
-    (``ops/state_pool.py``); none for a family whose every layer keeps
-    K/V. ``live``: the names of the state tensors its step advances at the
-    live slots only (``state_pool.update_live``); a tensor not named there
-    the step reads and writes at every slot."""
+    ``cache_spec()`` declares and ``runtime/kvcache.py`` allocates. ``rows``:
+    the tensors that keep a row a position (``Rows``), in the order the
+    family's ``prefill`` returns their blocks and its ``decode_step`` takes
+    and returns them; K and V of every layer that keeps them are the
+    two-tensor case (``kv_slot``). ``state``: fixed-size tensors, each
+    ``(name, shape a slot, dtype)`` (``ops/state_pool.py``); none for a
+    family whose every layer keeps rows. ``live``: the names of the state
+    tensors its step advances at the live slots only
+    (``state_pool.update_live``); a tensor not named there the step reads
+    and writes at every slot."""
 
-    kv: tuple
-    dtype: Any
+    rows: tuple
     state: tuple = ()
     live: tuple = ()
 
 
-def pool_shape(spec: tuple, slots: int, max_len: int) -> tuple:
-    """Shape of each pool tensor for a model whose ``cache_spec().kv`` is
-    ``spec = (layers, kv_heads, head_dim)``."""
-    layers, heads, head_dim = spec
-    return layers, slots, max_len, heads * head_dim
+def kv_slot(layers: int, kv_heads: int, head_dim: int, dtype,
+            state: tuple = (), live: tuple = ()) -> SlotSpec:
+    """The declaration of a family that keeps K and V of ``layers`` layers,
+    ``kv_heads x head_dim`` a row each."""
+    return SlotSpec((Rows("k", layers, kv_heads * head_dim, dtype),
+                     Rows("v", layers, kv_heads * head_dim, dtype)),
+                    tuple(state), tuple(live))
+
+
+def pool_shape(rows: Rows, slots: int, max_len: int) -> tuple:
+    """Shape of the pool tensor of one ``Rows`` declaration."""
+    return rows.layers, slots, rows.length or max_len, rows.width
+
+
+def rows_nbytes(spec: tuple, slots: int, max_len: int) -> int:
+    """Resident bytes of the pool tensors of ``SlotSpec.rows``."""
+    return sum(int(np.prod(pool_shape(rows, slots, max_len)))
+               * np.dtype(rows.dtype).itemsize for rows in spec)
 
 
 def allocate(shape: tuple, dtype):
@@ -86,7 +136,9 @@ def read_block(shape: tuple, dtype) -> int:
     ``shape``: a block of one tensor within ``READ_BLOCK_BYTES``, on whole
     sublane tiles of any dtype, or the pool's whole length."""
     fit = max(READ_BLOCK_BYTES // (shape[-1] * np.dtype(dtype).itemsize), 1)
-    return min(fit - fit % 32 or fit, shape[2])
+    # whole lane tiles of positions where a row allows (a mask over the
+    # positions lies on the lanes), else whole sublane tiles
+    return min(fit - fit % 128 or fit - fit % 32 or fit, shape[2])
 
 
 def positions_read(shape: tuple, dtype, position, active, bound: int) -> int:
@@ -96,8 +148,56 @@ def positions_read(shape: tuple, dtype, position, active, bound: int) -> int:
     and each active slot's own new token. ``position``, ``active``: per
     slot, host values."""
     block = read_block(shape, dtype)
+    bound = min(bound, shape[2])
     return sum(-(-min(p, bound) // block) * block for p in position) + sum(
         map(bool, active))
+
+
+def step_reads(spec: tuple, slots: int, max_len: int, position, active,
+               bound: int) -> tuple:
+    """What one step reads and writes of the tensors of ``SlotSpec.rows``,
+    from the host's positions: ``(attended, bytes by kind, selected)``.
+    ``attended``: the positions its attention reads of the first tensor, a
+    layer (``positions_read``). Bytes: of each tensor the rows read — the
+    blocks the kernel fetches (``positions_read``), or every slot's first
+    ``bound`` where it is read ``whole`` — and the one row a live slot
+    writes, every layer, summed under the tensor's ``kind``. ``selected``:
+    the positions the softmax kept, a layer, where a tensor declares a
+    selection (a live slot at ``p`` keeps ``min(p + 1, select)``); else
+    None."""
+    live = sum(map(bool, active))
+    attended, selected, nbytes = None, None, {}
+    for rows in spec:
+        shape = pool_shape(rows, slots, max_len)
+        read = (slots * min(bound, shape[2]) + live if rows.whole
+                else positions_read(shape, rows.dtype, position, active,
+                                    bound))
+        attended = read if attended is None else attended
+        row = rows.layers * rows.width * np.dtype(rows.dtype).itemsize
+        nbytes[rows.kind] = nbytes.get(rows.kind, 0) + row * (read + live)
+        if rows.select:
+            selected = sum(min(p + 1, rows.select)
+                           for p, on in zip(position, active) if on)
+    return attended, nbytes, selected
+
+
+def prefill_pairs(spec: tuple, n: int) -> dict:
+    """(query, key) pairs the attention of a prompt of ``n`` tokens is
+    over, a layer, by kind: causal pairs ``n (n + 1) / 2`` under a tensor's
+    ``kind``; a ring of ``length`` keeps the ``length + 1`` last keys of a
+    query (the query's own among them), and a selection at most ``select``
+    of them, counted as ``selected`` (what it scored is another tensor's)."""
+    def pairs(cap):
+        full = min(n, cap)
+        return full * (full + 1) // 2 + (n - full) * cap
+
+    out = {}
+    for rows in spec:
+        if rows.select:
+            out["selected"] = pairs(rows.select)
+        else:
+            out[rows.kind] = pairs(rows.length + 1 if rows.length else n)
+    return out
 
 
 def _dot(eq, a, b):
@@ -133,6 +233,105 @@ def prefill_attention(q, k, v, mask, scale: float | None = None):
         return _dot("bhqk,bkhd->bqhd", w.astype(v.dtype), v).astype(q.dtype)
 
 
+# Queries a block of ``query_blocks``: what a block keeps of its work against
+# every key of the prompt is ``QUERY_BLOCK x keys`` — 12.8 MB of float32 index
+# scores at 12.5k keys.
+QUERY_BLOCK = 256
+
+
+def select_top(scores, valid, k: int):
+    """The exact top-``k`` of ``scores (..., N)`` (float32) among the
+    positions ``valid (..., N)`` marks, as a mask ``(..., N)``: the ``k``
+    largest, a tie to the lower index; every valid position where fewer than
+    ``k`` are. No sort and no gather: the ``k``-th largest value is found
+    bit by bit on the scores' order-preserving integer image (32 counts),
+    then the ties at it are ranked by a running count."""
+    if k >= scores.shape[-1]:
+        return valid
+    bits = jax.lax.bitcast_convert_type(scores.astype(jnp.float32),
+                                        jnp.uint32)
+    sign = jnp.uint32(1 << 31)
+    bits = jnp.where(bits == sign, jnp.uint32(0), bits)      # -0.0 is 0.0
+    key = jnp.where(bits >= sign, ~bits, bits | sign)
+    key = jnp.where(valid, key, jnp.uint32(0))   # under every valid key
+
+    def bit(i, found):
+        trial = found | (sign >> i.astype(jnp.uint32))
+        count = (key >= trial).sum(axis=-1, keepdims=True)
+        return jnp.where(count >= k, trial, found)
+
+    kth = jax.lax.fori_loop(
+        0, 32, bit, jnp.zeros((*scores.shape[:-1], 1), jnp.uint32))
+    above = key > kth
+    ties = (key == kth) & valid
+    room = k - above.sum(axis=-1, keepdims=True)
+    return valid & (above | (ties & (jnp.cumsum(ties, axis=-1) <= room)))
+
+
+def query_blocks(fn, p: int, block: int = QUERY_BLOCK):
+    """``fn(at, q_pos (B,), k_pos (P,)) -> (B, ...)`` over the ``p`` queries
+    of a prompt in blocks of ``block``, against all ``p`` keys: ``(p, ...)``."""
+    block = math.gcd(p, block)
+    out = jax.lax.map(
+        lambda i: fn(i * block, i * block + jnp.arange(block), jnp.arange(p)),
+        jnp.arange(p // block))
+    return out.reshape(p, *out.shape[2:])
+
+
+def prompt_index_scores(iq, ik, w, first):
+    """The index scores of a block of a prompt's queries, from position
+    ``first`` (traced or not), against every key of the prompt: ``I[t, s] =
+    sum_j w[t, j] * relu(iq[j, t] . ik[s])`` in float32 (``pallas
+    .flash_attention.index_scores``). iq: (J, B, d); ik: (P, d); w: (B, J).
+    Returns (B, P); keys in blocks wholly after the last query read 0 (they
+    are not scored: the caller's causal mask leaves them out)."""
+    with jax.named_scope("indexer"):
+        return flash_index_scores(iq, ik, w, first)
+
+
+def prompt_attention(q, k, v, scale: float, mask=None,
+                     window: int | None = None,
+                     interpret: bool | None = None):
+    """Causal attention of one padded prompt whose scores never leave the
+    chip's fast memory, at any length (``pallas.flash_attention
+    .prompt_attention``: one kernel, blocks of queries against the blocks of
+    keys under the diagonal). q, k: (P, H, dqk) — a key's and a value's
+    widths may differ —, v: (P, H, dv). A query at ``t`` reads the keys ``s
+    <= t``: of them, where ``window`` is given, the ``window`` last ones
+    (itself among them) — a banded read —, and, where ``mask (P, P)`` is,
+    those it marks nonzero — a selection, one byte a pair for every head.
+    Float32 scores and softmax, the weights cast to ``v``'s dtype for the
+    value product. Returns (P, H, dv) in ``v``'s dtype."""
+    with jax.named_scope("attention"):
+        heads_first = [jnp.swapaxes(a, 0, 1) for a in (q, k, v)]
+        return jnp.swapaxes(flash_prompt_attention(
+            *heads_first, scale=scale, mask=mask, window=window,
+            interpret=interpret), 0, 1)
+
+
+def latent_decode_attention(q, new, pool, layer: int, position, *,
+                            value: int, bound: int, scale: float,
+                            keep=None, own=None,
+                            interpret: bool | None = None):
+    """One layer's attention of one decode step over rows every head shares
+    (latent attention in its absorbed form). q: (S, H, row); new: (S, row),
+    the new token's own row; pool: (layers, slots, length, row), whole and
+    never rewritten; a position's value is the first ``value`` lanes of its
+    row. A slot reads its ``min(position, bound)`` first rows — of them,
+    where ``keep (S, bound)`` (bool) is given, those it marks — and, unless
+    ``own (S,)`` says no, the new token's own term. Returns (S, H, value) in
+    ``q``'s dtype. All of it is ``pallas.decode_attention.latent_attention``."""
+    block = read_block(pool.shape, pool.dtype)
+    if keep is not None:
+        # whole blocks of positions: what lies past ``bound`` is left out
+        keep = jnp.pad(keep, ((0, 0), (0, -keep.shape[1] % block)))
+    with jax.named_scope("attention"):
+        return latent_attention(
+            q.astype(pool.dtype), new.astype(pool.dtype), pool, layer,
+            position, value=value, bound=bound, block=block, scale=scale,
+            keep=keep, own=own, interpret=interpret).astype(q.dtype)
+
+
 def prompt_block(rows):
     """A prefill's K (or V) as the block ``insert_block`` takes: ``rows`` —
     per-layer (B, P, H, hd), as ``prefill_attention`` reads them — become
@@ -142,15 +341,16 @@ def prompt_block(rows):
     return rows.reshape(*rows.shape[:3], -1)
 
 
-def insert_block(k_pool, v_pool, k_block, v_block, slot):
-    """Land one prompt's blocks (``prompt_block`` with B = 1) at the start
-    of ``slot``'s rows — ``slot`` may be traced: one program a block length,
-    any slot. Blocks are rank-matched to the pool, so one
-    dynamic_update_slice a tensor lands the whole prompt."""
+def insert_block(pools: tuple, blocks: tuple, slot) -> tuple:
+    """Land one prompt's blocks (``prompt_block`` with B = 1), a tensor of
+    the declaration each, at the start of ``slot``'s rows — ``slot`` may be
+    traced: one program a block length, any slot. Blocks are rank-matched to
+    the pool, so one dynamic_update_slice a tensor lands the whole prompt. A
+    ring's block is the whole ring, each position at its row already."""
     zero = (0, slot, 0, 0)
     with jax.named_scope("cache_insert"):
-        return (jax.lax.dynamic_update_slice(k_pool, k_block, zero),
-                jax.lax.dynamic_update_slice(v_pool, v_block, zero))
+        return tuple(jax.lax.dynamic_update_slice(pool, block, zero)
+                     for pool, block in zip(pools, blocks))
 
 
 def decode_attention(q, k_new, v_new, k_pool, v_pool, layer: int, position,
@@ -191,9 +391,11 @@ def decode_attention(q, k_new, v_new, k_pool, v_pool, layer: int, position,
             interpret=interpret).reshape(q.shape).astype(q.dtype)
 
 
-def write_rows(k_pool, v_pool, k_rows, v_rows, position):
-    """Store one decode step's K/V: ``k_rows``/``v_rows`` are per-layer lists
-    of (S, H, hd), ``position`` (S,).
+def write_rows(pools: tuple, rows: tuple, position) -> tuple:
+    """Store one decode step's new rows: ``rows[i]`` is the per-layer list
+    of (S, ...) the step made for the tensor ``pools[i]``, ``position``
+    (S,) — the row of each slot the new ones land at (a ring's caller
+    hands ``position % length``).
 
     One row per slot, all layers at once, written where the pool already
     lives: ``layers`` contiguous rows of whole lane tiles a slot and a
@@ -204,13 +406,13 @@ def write_rows(k_pool, v_pool, k_rows, v_rows, position):
     clamped onto it, not dropped: the engine retires a sequence before it
     gets there."""
     with jax.named_scope("cache_update"):
-        rows = (len(k_rows), position.shape[0], 1, k_pool.shape[-1])
-        k_rows = jnp.stack(k_rows).reshape(rows)
-        v_rows = jnp.stack(v_rows).reshape(rows)
+        pools = list(pools)
+        rows = [jnp.stack(new).reshape(len(new), position.shape[0], 1,
+                                       pool.shape[-1])
+                for pool, new in zip(pools, rows)]
         for slot in range(position.shape[0]):
-            at = (0, slot, position[slot], 0)
-            k_pool = jax.lax.dynamic_update_slice(
-                k_pool, k_rows[:, slot:slot + 1], at)
-            v_pool = jax.lax.dynamic_update_slice(
-                v_pool, v_rows[:, slot:slot + 1], at)
-    return k_pool, v_pool
+            for i, (pool, new) in enumerate(zip(pools, rows)):
+                pools[i] = jax.lax.dynamic_update_slice(
+                    pool, new[:, slot:slot + 1],
+                    (0, slot, position[slot], 0))
+    return tuple(pools)

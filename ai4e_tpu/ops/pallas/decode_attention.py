@@ -57,6 +57,16 @@ joined in the slot's last grid step, which normalises and writes the slot's
 row. (Joined outside, on ``(slots, heads)``-sized values in ``jax.numpy``,
 the same arithmetic was ~17 small XLA operations a layer at head width 64 —
 lane spreads and relayouts — a seventh of the step program's operations.)
+
+A second body, ``latent_attention``, reads a pool whose row is shared by
+EVERY head (latent attention in its absorbed form): ``q`` is a plain
+``(heads, row)`` matrix — no head owns lanes — the scores contract the whole
+row, and the value is the row's first ``value`` lanes, taken from the block
+the key came in (no second tensor, no second DMA). It walks the same plan,
+takes an optional ``keep`` mask over the positions (a learned selection
+reaches it as data: a block is fetched whole and the rows the selection
+left out are masked) and a flag a slot that says whether the new token's
+own term joins its softmax.
 """
 
 from __future__ import annotations
@@ -75,6 +85,7 @@ NEG_INF = -1e30  # finite: a slot with nothing cached keeps exp() defined
 # Rows of the plan, (3, slots): with the layer, (1,), the kernel's
 # scalar-prefetch operands.
 LIMIT, SOURCE, HOLD = range(3)
+OWN = 3        # a fourth row, of the latent read's plan alone
 
 SUBLANES = 8   # rows of a float32 tile: what Mosaic stacks and slices whole
 
@@ -264,6 +275,145 @@ def _pooled(q, k_new, v_new, k_pool, v_pool, layer, position, *, heads: int,
     if group > 1:
         out = out.reshape(slots, group, kv_heads, head_dim).swapaxes(1, 2)
     return out.reshape(slots, heads * head_dim)
+
+
+def _latent_kernel(plan_ref, layer_ref, q_ref, new_ref, *rest, block: int,
+                   value: int, scale: float, masked: bool):
+    # q_ref: (heads, row); new_ref: (1, row) — the slot's new token;
+    # keep_ref (``masked``): (1, block) float32, 0 where the position is
+    # kept and NEG_INF where it is left out (added to the scores);
+    # pool_ref: (block, row); out_ref: (heads, value). Scratch, carried
+    # across a slot's blocks: acc (heads, value); m, l (heads, 1).
+    if masked:
+        keep_ref, pool_ref, out_ref, acc, m, l = rest
+    else:
+        (pool_ref, out_ref, acc, m, l), keep_ref = rest, None
+    s, b = pl.program_id(0), pl.program_id(1)
+    limit = plan_ref[LIMIT, s]
+
+    @pl.when(b == 0)
+    def _init():
+        acc[...] = jnp.zeros_like(acc)
+        m[...] = jnp.full_like(m, NEG_INF)
+        l[...] = jnp.zeros_like(l)
+
+    def accumulate(ragged: bool):
+        rows = pool_ref[...]
+        precision = (jax.lax.Precision.HIGHEST if rows.dtype == jnp.float32
+                     else None)
+        scores = jax.lax.dot_general(
+            q_ref[...], rows, (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32) * scale    # (heads, block)
+        v = rows[:, :value]
+        if masked:
+            scores = scores + keep_ref[...]    # 0 kept, NEG_INF left out
+        if ragged:
+            start = b * block
+            cols = start + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+            at = start + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+            scores = jnp.where(cols < limit, scores, NEG_INF)
+            v = jnp.where(at < limit, v, 0)    # 0 x NaN is NaN
+        m_prev = m[...]
+        m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
+        if masked:
+            # a head whose every position so far was left out would have
+            # m_new at NEG_INF and exp(0) = 1 on positions it must not
+            # read: held above NEG_INF, they underflow to 0
+            m_new = jnp.maximum(m_new, 0.1 * NEG_INF)
+        p = jnp.exp(scores - m_new)
+        shrink = jnp.exp(m_prev - m_new)
+        l[...] = l[...] * shrink + p.sum(axis=-1, keepdims=True)
+        m[...] = m_new
+        weighted = jnp.zeros(acc.shape, jnp.float32)
+        for _ in range(1 if v.dtype == jnp.float32 else 3):
+            term = p.astype(v.dtype)
+            weighted += jax.lax.dot_general(
+                term, v, (((1,), (0,)), ((), ())), precision=precision,
+                preferred_element_type=jnp.float32)
+            p = p - term.astype(jnp.float32)
+        acc[...] = acc[...] * shrink + weighted
+
+    pl.when((b + 1) * block <= limit)(partial(accumulate, False))
+    pl.when((b * block < limit) & (limit < (b + 1) * block))(
+        partial(accumulate, True))
+
+    @pl.when(b == pl.num_programs(1) - 1)
+    def _finish():
+        new = new_ref[...].astype(jnp.float32)              # (1, row)
+        own = (q_ref[...].astype(jnp.float32) * new).sum(
+            axis=-1, keepdims=True) * scale                 # (heads, 1)
+        own = jnp.where(plan_ref[OWN, s] > 0, own, NEG_INF)
+        top = jnp.maximum(m[...], own)
+        w_pool, w_own = jnp.exp(m[...] - top), jnp.exp(own - top)
+        out_ref[...] = ((acc[...] * w_pool + w_own * new[:, :value])
+                        / (l[...] * w_pool + w_own)).astype(out_ref.dtype)
+
+
+def _keep_index(s, b, plan, layer):
+    return s, 0, _pool_index(s, b, plan, layer)[2]
+
+
+@partial(jax.jit, static_argnames=("value", "bound", "block", "scale",
+                                   "interpret"))
+def _latent(q, new, pool, layer, position, keep, own, *, value: int,
+            bound: int, block: int, scale: float, interpret: bool):
+    slots, heads, row = q.shape
+    plan = jnp.concatenate([block_plan(position, bound, block),
+                            own.astype(jnp.int32)[None]])
+    masked = keep is not None
+    per_slot = pl.BlockSpec((None, 1, row), _slot_index)
+    in_specs = [pl.BlockSpec((None, heads, row), _slot_index), per_slot]
+    operands = [q, new[:, None]]
+    if masked:
+        in_specs.append(pl.BlockSpec((None, 1, block), _keep_index))
+        operands.append(jnp.where(keep != 0, 0.0, NEG_INF).astype(
+            jnp.float32)[:, None])
+    in_specs.append(pl.BlockSpec((None, None, block, row), _pool_index))
+    return pl.pallas_call(
+        partial(_latent_kernel, block=block, value=value, scale=scale,
+                masked=masked),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(slots, -(-bound // block)),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, heads, value), _slot_index),
+            scratch_shapes=[pltpu.VMEM((heads, value), jnp.float32),
+                            pltpu.VMEM((heads, 1), jnp.float32),
+                            pltpu.VMEM((heads, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((slots, heads, value), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="latent_attention",
+    )(plan, layer.reshape(1), *operands, pool)
+
+
+def latent_attention(q, new, pool, layer, position, *, value: int,
+                     bound: int, block: int, scale: float, keep=None,
+                     own=None, interpret: bool | None = None):
+    """Attention of one new token a slot over rows every head shares.
+
+    q: (slots, heads, row) in the pool's dtype — a head's query against the
+    whole row; new: (slots, row), the new token's own row; pool: (layers,
+    slots, length, row), whole; the value of a position is the first
+    ``value`` lanes of its row. A slot reads ``layer``'s rows ``<
+    min(position[slot], bound)`` — of those, where ``keep (slots, length)``
+    is given, the ones it marks nonzero — and, unless ``own (slots,)`` says
+    no, the new token's own term. ``scale`` multiplies the scores. Returns
+    (slots, heads, value) in q's dtype. The order of the rows does not
+    matter (a ring may be read as it lies)."""
+    if not 0 < bound <= pool.shape[2] or not 0 < block <= pool.shape[2]:
+        raise ValueError(f"bound {bound} and block {block} must lie within "
+                         f"the pool's {pool.shape[2]} positions")
+    if keep is not None and block % 128 and block != pool.shape[2]:
+        raise ValueError(f"a masked read takes blocks of whole lane tiles of "
+                         f"positions, not {block}")
+    if own is None:
+        own = jnp.ones(position.shape, jnp.int32)
+    return _latent(q, new, pool, jnp.asarray(layer, jnp.int32),
+                   position.astype(jnp.int32), keep, own, value=value,
+                   bound=bound, block=block, scale=float(scale),
+                   interpret=resolve_interpret("decode_attention", interpret))
 
 
 def pooled_attention(q, k_new, v_new, k_pool, v_pool, layer, position, *,

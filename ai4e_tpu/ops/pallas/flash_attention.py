@@ -32,6 +32,13 @@ TPU grids execute sequentially with the rightmost axis fastest, so the
 accumulator/max/denominator live in VMEM scratch carried across the k-axis
 steps; the output block is written on the last k step. D rides the 128-lane
 axis; block_q rides sublanes.
+
+Two more bodies serve the decode engine's long prefills (``ops/kv_pool.py``
+``prompt_attention`` / ``prompt_index_scores``; inference only): a prompt's
+causal attention whose keys and values differ in width, under a one-byte
+mask (a learned selection) or a window, in the operands' own dtype on the
+MXU — ``prompt_attention`` — and the index scores such a selection is made
+from — ``index_scores``.
 """
 
 from __future__ import annotations
@@ -387,3 +394,220 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int | None = None,
         return out.reshape(n, h, s_q, d)
 
     return shard_over_batch(call, mesh, interpret)((q, k, v))
+
+
+# -- a prompt's attention under a mask: the decode engine's long prefill ----
+
+# Queries and keys a grid step of ``prompt_attention``: a (512, 512) float32
+# block of scores is 1 MB of VMEM beside ~0.8 MB of double-buffered operands.
+PROMPT_BLOCK = 512
+
+
+def _prompt_block(p: int) -> int:
+    """The largest divisor of ``p`` up to ``PROMPT_BLOCK`` on whole lane
+    tiles (a block of the mask has its keys on the lanes); ``p`` itself for
+    a prompt no longer than one block."""
+    if p <= PROMPT_BLOCK:
+        return p
+    for block in range(PROMPT_BLOCK, 0, -LANES):
+        if p % block == 0:
+            return block
+    raise ValueError(f"a prompt of {p} positions has no block of whole lane "
+                     f"tiles: pad it to a multiple of {LANES}")
+
+
+def _key_blocks(iq, block: int, window: int | None):
+    """First and last block of keys the queries of block ``iq`` read: up to
+    the diagonal, and from the window's far edge where there is one."""
+    lo = 0 if window is None else jnp.maximum(
+        iq * block - (window - 1), 0) // block
+    return lo, iq
+
+
+def _prompt_kernel(q_ref, k_ref, v_ref, *rest, block: int, n_k: int,
+                   window: int | None, scale: float, masked: bool):
+    # q_ref: (block, dqk); k_ref: (block, dqk); v_ref: (block, dv);
+    # mask_ref (``masked``): (block, block) int8, nonzero where the query
+    # reads the key; out_ref: (block, dv). Scratch, carried across a query
+    # block's key blocks: acc (block, dv); m, l (block, 1).
+    if masked:
+        mask_ref, out_ref, acc, m, l = rest
+    else:
+        (out_ref, acc, m, l), mask_ref = rest, None
+    iq, j = pl.program_id(1), pl.program_id(2)
+    lo, hi = _key_blocks(iq, block, window)
+    ik = lo + j
+
+    @pl.when(j == 0)
+    def _init():
+        acc[...] = jnp.zeros_like(acc)
+        m[...] = jnp.full_like(m, NEG_INF)
+        l[...] = jnp.zeros_like(l)
+
+    @pl.when(ik <= hi)
+    def _accumulate():
+        v = v_ref[...]
+        precision = (jax.lax.Precision.HIGHEST if v.dtype == jnp.float32
+                     else None)
+        scores = jax.lax.dot_general(
+            q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+            precision=precision,
+            preferred_element_type=jnp.float32) * scale
+        q_pos = iq * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, 1), 0)
+        k_pos = ik * block + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block), 1)
+        allowed = k_pos <= q_pos
+        if window is not None:
+            allowed &= k_pos > q_pos - window
+        if masked:
+            allowed &= mask_ref[...].astype(jnp.int32) != 0
+        scores = jnp.where(allowed, scores, NEG_INF)
+        m_prev = m[...]
+        # held above NEG_INF: a query none of whose keys so far are allowed
+        # would weigh them exp(0) = 1 otherwise
+        m_new = jnp.maximum(
+            jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True)),
+            0.1 * NEG_INF)
+        p = jnp.exp(scores - m_new)
+        shrink = jnp.exp(m_prev - m_new)
+        l[...] = l[...] * shrink + p.sum(axis=-1, keepdims=True)
+        m[...] = m_new
+        acc[...] = acc[...] * shrink + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            precision=precision, preferred_element_type=jnp.float32)
+
+    @pl.when(j == n_k - 1)
+    def _finish():
+        out_ref[...] = (acc[...] / jnp.maximum(l[...], 1e-30)).astype(
+            out_ref.dtype)
+
+
+@partial(jax.jit, static_argnames=("scale", "window", "interpret"))
+def _prompt(q, k, v, mask, *, scale: float, window: int | None,
+            interpret: bool):
+    heads, p, dqk = q.shape
+    dv = v.shape[-1]
+    block = _prompt_block(p)
+    n_k = p // block
+    if window is not None:
+        # the keys of a query block span block + window - 1 positions
+        n_k = min(n_k, -(-(block + window - 1) // block) + 1)
+
+    def keys(h, iq, j):
+        lo, hi = _key_blocks(iq, block, window)
+        # a block past the diagonal is the diagonal's again: not fetched
+        return h, jnp.minimum(lo + j, hi), 0
+
+    def queries(h, iq, j):
+        return h, iq, 0
+
+    in_specs = [pl.BlockSpec((None, block, dqk), queries),
+                pl.BlockSpec((None, block, dqk), keys),
+                pl.BlockSpec((None, block, dv), keys)]
+    operands = [q, k, v]
+    if mask is not None:
+        in_specs.append(pl.BlockSpec(
+            (block, block), lambda h, iq, j: (iq, keys(h, iq, j)[1])))
+        operands.append(mask.astype(jnp.int8))
+    return pl.pallas_call(
+        partial(_prompt_kernel, block=block, n_k=n_k, window=window,
+                scale=scale, masked=mask is not None),
+        grid=(heads, p // block, n_k),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, block, dv), queries),
+        out_shape=jax.ShapeDtypeStruct((heads, p, dv), v.dtype),
+        scratch_shapes=[pltpu.VMEM((block, dv), jnp.float32),
+                        pltpu.VMEM((block, 1), jnp.float32),
+                        pltpu.VMEM((block, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="prompt_attention",
+    )(*operands)
+
+
+def prompt_attention(q, k, v, *, scale: float, mask=None,
+                     window: int | None = None,
+                     interpret: bool | None = None):
+    """Causal attention of one prompt whose keys and values differ in width,
+    under a mask and a window: q, k: (H, P, dqk); v: (H, P, dv) → (H, P, dv)
+    in ``v``'s dtype. A query at ``t`` reads the keys ``s <= t`` — of them,
+    where ``window`` is given, those with ``s > t - window``, and, where
+    ``mask (P, P)`` is, those it marks nonzero (a learned selection reaches
+    the kernel as data, one byte a pair, the same for every head). No score
+    leaves VMEM: float32 scores and online softmax a block, the weights cast
+    to ``v``'s dtype for the value product. A block of keys above the
+    diagonal or behind the window is neither fetched nor computed; with a
+    window the grid's key axis is as long as the band, not the prompt. ``P``
+    is one block or a multiple of 128 (``_prompt_block``)."""
+    if mask is not None and mask.shape != (q.shape[1], q.shape[1]):
+        raise ValueError(f"mask {mask.shape} for {q.shape[1]} positions")
+    return _prompt(q, k, v, mask, scale=float(scale), window=window,
+                   interpret=resolve_interpret("prompt_attention", interpret))
+
+
+def _index_kernel(first_ref, iq_ref, ik_ref, w_ref, out_ref, *, block: int):
+    # iq_ref: (J, B, d), a block of queries' index heads; ik_ref: (block, d),
+    # a block of keys; w_ref: (B, J) float32; out_ref: (B, block) float32.
+    ik = pl.program_id(0)
+    queries = out_ref.shape[0]
+    below = ik * block <= first_ref[0] + queries - 1
+
+    @pl.when(below)
+    def _score():
+        keys, w = ik_ref[...], w_ref[...]
+        precision = (jax.lax.Precision.HIGHEST if keys.dtype == jnp.float32
+                     else None)
+        total = jnp.zeros(out_ref.shape, jnp.float32)
+        for j in range(iq_ref.shape[0]):
+            s = jax.lax.dot_general(
+                iq_ref[j], keys, (((1,), (1,)), ((), ())),
+                precision=precision, preferred_element_type=jnp.float32)
+            total += jnp.maximum(s, 0.0) * w[:, j:j + 1]
+        out_ref[...] = total
+
+    @pl.when(jnp.logical_not(below))
+    def _above():   # every key of the block lies after every query
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def _index(iq, ik, w, first, *, interpret: bool):
+    heads, queries, d = iq.shape
+    p = ik.shape[0]
+    block = _prompt_block(p)
+
+    def keys(k, first):
+        # a block above the diagonal is the diagonal's again: not fetched
+        return jnp.minimum(k, (first[0] + queries - 1) // block), 0
+
+    return pl.pallas_call(
+        partial(_index_kernel, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(p // block,),
+            in_specs=[pl.BlockSpec((heads, queries, d),
+                                   lambda k, first: (0, 0, 0)),
+                      pl.BlockSpec((block, d), keys),
+                      pl.BlockSpec((queries, heads),
+                                   lambda k, first: (0, 0))],
+            out_specs=pl.BlockSpec((queries, block),
+                                   lambda k, first: (0, k))),
+        out_shape=jax.ShapeDtypeStruct((queries, p), jnp.float32),
+        interpret=interpret,
+        name="index_scores",
+    )(first.reshape(1).astype(jnp.int32), iq, ik, w.astype(jnp.float32))
+
+
+def index_scores(iq, ik, w, first, *, interpret: bool | None = None):
+    """The index scores of a block of a prompt's queries against its keys,
+    ``I[t, s] = sum_j w[t, j] * relu(iq[j, t] . ik[s])`` in float32: iq (J,
+    B, d), the ``J`` index heads of the ``B`` queries from position
+    ``first`` (an int32 scalar, traced or not); ik (P, d), one key a
+    position; w (B, J). Returns (B, P); a block of keys that lies wholly
+    after the last query is neither fetched nor scored and reads 0 — the
+    caller's causal mask leaves it out. The ``(B, J, P)`` products never
+    leave VMEM. ``P`` is one block or a multiple of 128."""
+    return _index(iq, ik, w, jnp.asarray(first, jnp.int32),
+                  interpret=resolve_interpret("index_scores", interpret))

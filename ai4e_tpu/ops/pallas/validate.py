@@ -1,7 +1,8 @@
 """On-device Pallas kernel validation (VERDICT r1 next-round #5).
 
 The serving kernels (``flash_attention``, ``segmentation_argmax``,
-``normalize_image``, ``decode_attention``, ``state_update``) default to
+``normalize_image``, ``decode_attention``, ``latent_attention``,
+``prompt_attention``, ``index_scores``, ``state_update``) default to
 interpret mode off-TPU, so CPU CI never
 proves they compile to Mosaic and fit VMEM on real hardware. This module is
 that proof: ``validate_kernels()`` runs each kernel with ``interpret=False``
@@ -150,8 +151,9 @@ def validate_kernels(interpret: bool = False) -> dict:
         edge = kv_pool.read_block((1, 1, length, kv_heads * head_dim), dtype)
         position = np.asarray([0, 1, edge - 1, edge, edge + 1, 600,
                                length - 1, length], np.int32)
-        shape = kv_pool.pool_shape((1, kv_heads, head_dim), len(position),
-                                   length)
+        shape = kv_pool.pool_shape(
+            kv_pool.Rows("k", 1, kv_heads * head_dim, dtype), len(position),
+            length)
         k_pool, v_pool = (jax.numpy.asarray(rng.standard_normal(shape), dtype)
                           for _ in range(2))
         q, k_new, v_new = (
@@ -186,6 +188,162 @@ def validate_kernels(interpret: bool = False) -> dict:
         results[f"decode_attention_{name}"] = {
             "ok": bool(err < tol), "max_err": round(err, 6),
             "vmem_bytes": vmem}
+
+    # the latent read (rows every head shares, the value a row's own first
+    # lanes) vs a plain float32 softmax, at the ``dots3`` cell's shapes: 128
+    # heads on a 640-lane row (576 published + padding) of which 512 are the
+    # value, a cache of 12,544 in blocks of 768 — the last one cut —, with
+    # and without a selection's mask (whole blocks left out in one slot, the
+    # new token's own term in another); and a window's ring, 64 heads on
+    # 1,152 lanes, 512 rows. Timed where compiled: the bytes are the rows of
+    # the blocks fetched.
+    import time
+    for name, heads, row, value, length, masked in (
+            ("latent", 128, 640, 512, 12544, False),
+            ("latent_masked", 128, 640, 512, 12544, True),
+            ("window", 64, 1152, 1024, 512, False)):
+        dtype = "bfloat16"
+        shape = (2, 8, length, row)
+        edge = kv_pool.read_block(shape, dtype)
+        position = np.asarray([0, 1, edge - 1, edge, edge + 1, 600 % length
+                               or 7, length - 1, length], np.int32)
+        pool = jax.numpy.asarray(rng.standard_normal(shape) * 0.3, dtype)
+        q = jax.numpy.asarray(rng.standard_normal((8, heads, row)) * 0.2,
+                              dtype)
+        new = jax.numpy.asarray(rng.standard_normal((8, row)) * 0.3, dtype)
+        keep = own = None
+        if masked:
+            keep = rng.random((8, length)) < 0.3
+            keep[6, :4 * edge] = False      # whole blocks with nothing kept
+            keep[7, 2 * edge:] = False
+            own = np.asarray([1, 1, 1, 0, 1, 1, 1, 1], np.int32)
+        run = jax.jit(lambda q, new, pool, position, keep, own:
+                      kv_pool.latent_decode_attention(
+                          q, new, pool, 1, position, value=value,
+                          bound=length, scale=0.07, keep=keep, own=own,
+                          interpret=interpret))
+        args = (q, new, pool, jax.numpy.asarray(position), keep, own)
+        got = np.asarray(run(*args), np.float32)
+        rows = np.asarray(pool, np.float32)[1]
+        f_q, f_new = np.asarray(q, np.float32), np.asarray(new, np.float32)
+        err = 0.0
+        for slot, p in enumerate(position):
+            kept = (np.ones(p, bool) if keep is None else keep[slot, :p])
+            keys = np.concatenate(
+                [rows[slot, :p][kept], f_new[slot:slot + 1]
+                 if own is None or own[slot] else f_new[:0]])
+            if not len(keys) or not p:
+                continue
+            scores = f_q[slot] @ keys.T * 0.07
+            w = np.exp(scores - scores.max(-1, keepdims=True))
+            want = (w / w.sum(-1, keepdims=True)) @ keys[:, :value]
+            err = max(err, float(np.max(np.abs(got[slot] - want))))
+        entry = {"ok": bool(err < 0.04), "max_err": round(err, 6),
+                 "vmem_bytes": 2 * (edge + heads + 1) * row * 2
+                 + 2 * heads * value * 2 + heads * (value + 256) * 4}
+        assert entry["vmem_bytes"] <= VMEM_BUDGET_BYTES, entry
+        if not interpret:
+            full = (q, new, pool, jax.numpy.full((8,), length, np.int32),
+                    keep, own)
+            run(*full).block_until_ready()
+            t0 = time.perf_counter()
+            for _ in range(10):
+                out = run(*full)
+            out.block_until_ready()
+            seconds = (time.perf_counter() - t0) / 10
+            fetched = 8 * length * row * 2
+            entry.update(ms=round(seconds * 1e3, 4),
+                         gb_per_s=round(fetched / seconds / 1e9, 1))
+        results[f"latent_attention_{name}"] = entry
+
+    # a prompt's attention and its index scores vs plain float32 ``jax.numpy``
+    # at ``highest``, at the ``dots3`` cell's longest bucket (12,288
+    # positions; 1,536 under the interpreter, three blocks of 512): the full
+    # layers' form — keys of 192 lanes, values of 128, a one-byte (P, P) mask
+    # that keeps about a sixth of the pairs and leaves whole blocks of keys
+    # out for some queries —, the sliding layers' — keys of 256, a window of
+    # 513 —, and one block of 256 queries' 64 index heads against every key.
+    # Four heads where the model hands over 32 a call: the grid's head axis
+    # is parallel, a head's program is the same. The oracle holds (heads,
+    # 1024, P) scores at a time.
+    from .flash_attention import index_scores, prompt_attention
+    p = 1536 if interpret else 12288
+    heads = 4
+    hi = jax.lax.Precision.HIGHEST
+
+    def plain_prompt(q, k, v, allowed, scale):
+        out = []
+        for at in range(0, p, 1024):
+            s = jax.numpy.einsum(
+                "hqd,hkd->hqk", q[:, at:at + 1024].astype("float32"),
+                k.astype("float32"), precision=hi) * scale
+            w = jax.nn.softmax(jax.numpy.where(
+                allowed[None, at:at + 1024], s, -1e30), axis=-1)
+            out.append(jax.numpy.einsum("hqk,hkd->hqd", w,
+                                        v.astype("float32"), precision=hi))
+        return np.asarray(jax.numpy.concatenate(out, axis=1))
+
+    t_pos, s_pos = np.arange(p)[:, None], np.arange(p)[None, :]
+    causal = s_pos <= t_pos
+    selected = rng.random((p, p)) < 0.17
+    selected[-700:, :1024] = False     # whole blocks of keys with none kept
+    selected |= s_pos == t_pos         # every query keeps a key: its own
+    for name, dqk, dv, mask, window in (
+            ("selected", 192, 128, selected, None),
+            ("window", 256, 128, None, 513)):
+        q, k = (jax.numpy.asarray(rng.standard_normal((heads, p, dqk)),
+                                  "bfloat16") for _ in range(2))
+        v = jax.numpy.asarray(rng.standard_normal((heads, p, dv)),
+                              "bfloat16")
+        scale = dqk ** -0.5
+        allowed = causal & (mask if mask is not None
+                            else s_pos > t_pos - window)
+        run = jax.jit(lambda q, k, v, mask: prompt_attention(
+            q, k, v, scale=scale, mask=mask, window=window,
+            interpret=interpret))
+        args = (q, k, v, None if mask is None
+                else jax.numpy.asarray(mask, "int8"))
+        got = np.asarray(run(*args), np.float32)
+        err = float(np.max(np.abs(got - plain_prompt(
+            q, k, v, jax.numpy.asarray(allowed), scale))))
+        block = 512
+        entry = {"ok": bool(err < 0.04), "max_err": round(err, 6),
+                 "vmem_bytes": 2 * block * (2 * dqk + 2 * dv) * 2
+                 + 2 * block * block + block * block * 4
+                 + block * (dv + 2) * 4}
+        assert entry["vmem_bytes"] <= VMEM_BUDGET_BYTES, entry
+        if not interpret:
+            run(*args).block_until_ready()
+            t0 = time.perf_counter()
+            for _ in range(5):
+                out = run(*args)
+            out.block_until_ready()
+            entry["ms"] = round((time.perf_counter() - t0) / 5 * 1e3, 3)
+        results[f"prompt_attention_{name}"] = entry
+
+    j_heads, d, queries = 64, 128, 256
+    first = p - 2 * queries - 128 if not interpret else p - queries
+    first -= first % queries
+    iq = jax.numpy.asarray(rng.standard_normal((j_heads, queries, d)),
+                           "bfloat16")
+    ik = jax.numpy.asarray(rng.standard_normal((p, d)), "bfloat16")
+    w = jax.numpy.asarray(rng.standard_normal((queries, j_heads)) * 0.1,
+                          "float32")
+    got = np.asarray(jax.jit(lambda iq, ik, w, first: index_scores(
+        iq, ik, w, first, interpret=interpret))(iq, ik, w,
+                                                jax.numpy.int32(first)))
+    want = np.asarray(jax.numpy.einsum(
+        "jts,tj->ts", jax.nn.relu(jax.numpy.einsum(
+            "jtd,sd->jts", iq.astype("float32"), ik.astype("float32"),
+            precision=hi)), w, precision=hi))
+    # keys in blocks wholly after the last query are not scored: read 0
+    scored = np.arange(p) < -(-(first + queries) // 512) * 512
+    err = float(np.max(np.abs(got - want)[:, scored]))
+    results["index_scores"] = {
+        "ok": bool(err < 0.02 and not got[:, ~scored].any()),
+        "max_err": round(err, 6),
+        "vmem_bytes": 2 * (j_heads * queries * d + 512 * d) * 2
+        + 2 * queries * 512 * 4 + queries * j_heads * 4}
 
     # the state update at the live slots of the pool vs the families' own
     # jax.numpy recurrences — the two cells' blocks (Mamba-2: 64 heads of 64

@@ -173,6 +173,9 @@ class LaunchedStep:
     bound: int                    # positions of every slot the step covers
     # K/V positions its attention reads; None: ``slots x bound``.
     attended: int | None = None
+    # Positions its softmax kept, a layer, where the read selects; None:
+    # it keeps all it reads.
+    selected: int | None = None
     # Bytes of each kind of cache it reads and writes, ``{kind: bytes}``.
     cache_bytes: dict = field(default_factory=dict)
     # Of the fixed-size state: ``moved`` (what it reads and writes) and
@@ -356,7 +359,8 @@ class DecodeEngine:
       kept. The record
       carries what the backend worked out at the launch: ``bound`` (observed
       as ``ai4e_decode_step_bound``), ``attended`` (counted as attended K/V
-      positions), ``cache_bytes`` (``ai4e_decode_cache_bytes_total{kind}``),
+      positions), ``selected`` (counted beside them where the read selects),
+      ``cache_bytes`` (``ai4e_decode_cache_bytes_total{kind}``),
       ``state_bytes`` (``ai4e_decode_state_bytes_total{kind}``);
     - ``fetch(step) -> step``: block until that step has run and fill in
       ``ids`` (next token id per slot), ``fed`` (the token each slot was
@@ -371,6 +375,12 @@ class DecodeEngine:
       ``_Adapted`` supplies what is missing;
     - optionally ``step_report_series`` (attribute, ``{name: (help,
       buckets)}``): what a model that reports on its step declares;
+    - optionally ``prefill_report(n) -> {series: {kind: count}}``: what the
+      prefill of a prompt of ``n`` tokens computes, from the host's length
+      alone, counted after every join as
+      ``ai4e_decode_prefill_<series>_total{kind}`` (``tokens``: real,
+      padded; ``pairs``: by the kinds the backend's cache declares). A
+      backend without it registers neither series;
     - optionally ``bound_for(longest)``: the bound a step whose largest
       live position is ``longest`` will run — the ``bound=`` of the
       ``ai4e.decode.tick`` region, which opens before the launch;
@@ -465,8 +475,22 @@ class DecodeEngine:
         self._kv_positions = self.metrics.counter(
             "ai4e_decode_kv_positions_total",
             "K/V positions per decode step: live (sum of position + 1 over "
-            "active slots) and attended (what the step's attention read: "
-            "the backend's count, else slots x the step's bound)")
+            "active slots), attended (what the step's attention read: "
+            "the backend's count, else slots x the step's bound) and, from a "
+            "backend whose read selects, selected (what its softmax kept)")
+        # What a backend that reports on its prefills (``prefill_report``)
+        # counts; one that does not registers nothing.
+        self._prefill_work = {} if not hasattr(
+            backend, "prefill_report") else {
+            series: self.metrics.counter(
+                f"ai4e_decode_prefill_{series}_total", help_text)
+            for series, help_text in (
+                ("tokens", "Tokens the joined prefills computed, by kind: "
+                 "real (the prompt's) and padded (the bucket's), as the "
+                 "backend counts them"),
+                ("pairs", "(query, key) pairs of the joined prefills' "
+                 "attention, a layer, by the kinds the backend's cache "
+                 "declares"))}
         self._cache_bytes = self.metrics.counter(
             "ai4e_decode_cache_bytes_total",
             "Bytes of the slots' cache a decode step read and wrote, by "
@@ -878,6 +902,12 @@ class DecodeEngine:
         self._step_hist.observe(seconds, phase="prefill", model=self._model)
         if ahead:
             self._joins_total.inc(model=self._model, kind="all")
+        if self._prefill_work:
+            for series, kinds in self.backend.prefill_report(
+                    len(tokens)).items():
+                for kind, n in kinds.items():
+                    self._prefill_work[series].inc(n, model=self._model,
+                                                   kind=kind)
         if self._books:
             if ahead and self._steps is self.backend:
                 # It returned with its prefill queued: nothing drained.
@@ -1054,6 +1084,9 @@ class DecodeEngine:
         self._kv_positions.inc(
             self.pool.slots * step.bound if step.attended is None
             else step.attended, model=self._model, kind="attended")
+        if step.selected is not None:
+            self._kv_positions.inc(step.selected, model=self._model,
+                                   kind="selected")
         for kind, nbytes in step.cache_bytes.items():
             self._cache_bytes.inc(nbytes, model=self._model, kind=kind)
         for kind, nbytes in step.state_bytes.items():

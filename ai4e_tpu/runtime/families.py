@@ -666,6 +666,27 @@ def build_granite_hybrid_lm(name: str = "lm", vocab_size: int = 512,
                       vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
 
 
+def build_dots3_lm(name: str = "lm", vocab_size: int = 512,
+                   max_len: int = 256, eos_id: int | None = None,
+                   rng=None, dtype: str = "bfloat16", **dims):
+    """The latent-attention decoder (``models/dots3.py`` ``Dots3LM``): a
+    latent row a position that every head shares, on the ``full`` layers of
+    ``layer_types`` behind an indexer's exact top-``index_topk`` selection
+    (its key cached beside the row), on the ``sliding`` ones a ring of the
+    window with ranks and heads of its own (``swa_*``); a head-wise gate;
+    ``dense_layers`` leading dense MLPs, then sigmoid-routed experts of which
+    this process holds ``experts_held`` from ``first_expert``, with an
+    ungated shared expert; untied head, bfloat16 weights and cache.
+    ``dims``: the model's fields; a key the family does not know is an
+    error, not a default."""
+    from ..models.dots3 import create_dots3_lm
+    from .kvcache import LMServable
+    model, params = create_dots3_lm(rng=rng, vocab_size=vocab_size,
+                                    dtype=dtype, **dims)
+    return LMServable(name=name, model=model, params=params,
+                      vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
+
+
 # LM families ride the decode engine (``runtime/decode.py``), never the
 # MicroBatcher: ``cli`` tells them from the batch families by this table.
 LM_FAMILIES = {
@@ -673,6 +694,7 @@ LM_FAMILIES = {
     "olmoe": build_olmoe_lm,
     "qwen3-next": build_qwen3_next_lm,
     "granite-hybrid": build_granite_hybrid_lm,
+    "dots3": build_dots3_lm,
 }
 
 

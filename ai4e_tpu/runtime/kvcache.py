@@ -1,13 +1,15 @@
 """Pooled KV-cache decode runtime — the device side of continuous
 batching (``runtime/decode.py`` owns the scheduling).
 
-The cache is ONE preallocated slot-pool buffer per tensor: K and V of
-the layers that keep them (``ops/kv_pool.py`` owns that layout and every
-operation on it) and, for a family whose other layers keep a fixed-size
-state a sequence, one tensor a state it declares (``ops/state_pool.py``).
-What a slot holds is the family's to say (``cache_spec()`` →
-``kv_pool.SlotSpec``) and this runtime's to hold: it allocates, inserts,
-donates, resets and counts both kinds alike and knows no family. Keyed by
+The cache is ONE preallocated slot-pool buffer per tensor: each tensor of
+rows a family declares — K and V of the layers that keep them, or a latent
+row, an indexer's key, a window's ring (``ops/kv_pool.py`` owns that layout
+and every operation on it) — and, for a family whose other layers keep a
+fixed-size state a sequence, one tensor a state it declares
+(``ops/state_pool.py``). What a slot holds is the family's to say
+(``cache_spec()`` → ``kv_pool.SlotSpec``) and this runtime's to hold: it
+allocates, inserts, donates, resets and counts every declared tensor alike
+and knows no family. Keyed by
 ``(model, params_version)`` — a hot weight reload bumps the version and
 the engine invalidates (``reset_cache``) then re-prefills, the same key
 contract as rescache (a KV block computed under old weights is a stale
@@ -92,13 +94,14 @@ class LMServable:
 
     name: str
     # A flax module with the LM entry points, called by name:
-    # ``prefill(tokens (B, P), length (B,))`` → ids, K block, V block
-    # (``kv_pool.prompt_block``), state (``{name: (B, *shape)}`` after
-    # ``length`` tokens; ``{}`` from a family that keeps K/V only);
-    # ``decode_step(tokens (S,), k, v, state, position (S,), bound)`` → ids
+    # ``prefill(tokens (B, P), length (B,))`` → ids, one block a tensor of
+    # rows it declares (``kv_pool.prompt_block``: K then V for a family
+    # that keeps those), state (``{name: (B, *shape)}`` after
+    # ``length`` tokens; ``{}`` from a family that keeps rows only);
+    # ``decode_step(tokens (S,), *rows, state, position (S,), bound)`` → ids
     # (S,) then, optionally, more int32s the model's own
     # ``step_report(extra, active)`` turns into per-step figures (declared
-    # by its ``step_report_series``); k, v, state — attending cached
+    # by its ``step_report_series``); *rows, state — attending cached
     # positions ``< bound`` only (a Python int: one program a value); and
     # ``cache_spec()`` → ``kv_pool.SlotSpec``: everything a slot holds.
     # ``runtime/families.py`` (``LM_FAMILIES``) builds them.
@@ -159,8 +162,9 @@ class PagedDecodeRuntime:
         self.step_bounds = tuple(sorted(
             {min(-(-rung // 128) * 128, self.max_len)
              for rung in (3 * self.max_len // 4, self.max_len)}))
-        self._k = None
-        self._v = None
+        # The pool tensors of the declaration's rows, in its order; None
+        # before the first use.
+        self._rows = None
         self._state = None
         # Bytes of state a slot holds: in the tensors a step advances at its
         # live slots only, and in those it moves at every slot.
@@ -195,12 +199,17 @@ class PagedDecodeRuntime:
     def params_version(self) -> int:
         return self.servable.params_version
 
+    def rows_spec(self) -> tuple:
+        """The tensors of rows a slot holds, as the model declares them
+        (``kv_pool.Rows``)."""
+        return tuple(self.servable.model.cache_spec().rows)
+
     def cache_spec(self) -> tuple:
-        """``(shape, dtype)`` of each pool tensor: the model's layers, heads
-        and head size and its cache dtype, this runtime's slots and
-        length."""
-        spec = self.servable.model.cache_spec()
-        return kv_pool.pool_shape(spec.kv, self.slots, self.max_len), spec.dtype
+        """``(shape, dtype)`` of each pool tensor of rows, in the
+        declaration's order: the model's layers and row, this runtime's
+        slots and length."""
+        return tuple((kv_pool.pool_shape(rows, self.slots, self.max_len),
+                      rows.dtype) for rows in self.rows_spec())
 
     def state_spec(self) -> tuple:
         """``((name, shape a slot, dtype), ...)`` of what a slot holds
@@ -208,29 +217,29 @@ class PagedDecodeRuntime:
         return tuple(self.servable.model.cache_spec().state)
 
     def cache_nbytes(self) -> int:
-        """Resident bytes of the pooled cache (K, V and every state
-        tensor) — the number the memory math in docs/streaming.md bounds."""
-        shape, dtype = self.cache_spec()
-        return (2 * int(np.prod(shape)) * np.dtype(dtype).itemsize
+        """Resident bytes of the pooled cache (every tensor of rows and
+        every state tensor of the declaration) — the number the memory math
+        in docs/streaming.md bounds."""
+        return (kv_pool.rows_nbytes(self.rows_spec(), self.slots,
+                                    self.max_len)
                 + state_pool.nbytes(self.state_spec(), self.slots))
 
     def reset_cache(self) -> None:
         """Drop + reallocate the pooled cache (hot-reload invalidation:
         blocks computed under the old weights must never serve)."""
-        shape, dtype = self.cache_spec()
         # The old pool goes first: while it lives, building the new one
         # holds three pool tensors on the device at once, which would be
         # the allocator's peak of the whole worker.
-        self._k = self._v = self._state = self._ids = None
+        self._rows = self._state = self._ids = None
         self._joined.clear()
-        self._k = kv_pool.allocate(shape, dtype)
-        self._v = kv_pool.allocate(shape, dtype)
+        self._rows = tuple(kv_pool.allocate(shape, dtype)
+                           for shape, dtype in self.cache_spec())
         self._state = state_pool.allocate(self.state_spec(), self.slots)
         self._state_slot_bytes = state_pool.slot_bytes(
             self.state_spec(), self.servable.model.cache_spec().live)
 
     def _ensure(self) -> None:
-        if self._k is None:
+        if self._rows is None:
             self.reset_cache()
         if self._programs is None:
             self._build_programs()
@@ -251,8 +260,8 @@ class PagedDecodeRuntime:
             # CPU XLA cannot donate (every run would warn); on device
             # backends donation keeps the pool resident exactly once.
             self._donate = jax.default_backend() != "cpu"
-        donate_step = (3, 4, 5) if self._donate else ()
-        donate_insert = (0, 1, 2, 3) if self._donate else ()
+        donate_step = (3, 4) if self._donate else ()
+        donate_insert = (0, 1, 2) if self._donate else ()
         slots = self.slots
 
         def prefill(params, tokens, length):
@@ -265,22 +274,22 @@ class PagedDecodeRuntime:
         # model appends, then the token each slot was fed: a joined prompt's
         # first id reaches the host here) and the ids alone, for the next
         # launch.
-        def step(params, host, previous, k, v, state, bound):
+        def step(params, host, previous, rows, state, bound):
             tokens = jnp.where(host[0] != 0, host[1], previous)
-            out, k, v, state = model.apply(params, tokens, k, v, state,
-                                           host[2], bound,
-                                           method="decode_step")
+            out, *rows, state = model.apply(params, tokens, *rows, state,
+                                            host[2], bound,
+                                            method="decode_step")
             # Behind a barrier: the model's own program stays as compiled
             # without the wrapper's concatenation (XLA fused it into a sparse
             # family's per-layer producers otherwise).
             out = jax.lax.optimization_barrier(out)
-            return jnp.concatenate([out, tokens]), out[:slots], k, v, state
+            return (jnp.concatenate([out, tokens]), out[:slots], tuple(rows),
+                    state)
 
         # A wrapper for its name: the trace's module stays ``jit_insert``.
         # ``token`` is the prefill's (1,) ids: the slot feeds on it next.
-        def insert(k, v, state, ids, k_block, v_block, state_block, token,
-                   slot):
-            return (*kv_pool.insert_block(k, v, k_block, v_block, slot),
+        def insert(rows, state, ids, blocks, state_block, token, slot):
+            return (kv_pool.insert_block(rows, blocks, slot),
                     state_pool.insert(state, state_block, slot),
                     ids.at[slot].set(token[0]))
 
@@ -289,7 +298,7 @@ class PagedDecodeRuntime:
             # ``bound`` is static: one entry of this jit's cache per rung,
             # so ``_run`` sees a rung that was not warmed as a compile.
             "step": jax.jit(step, donate_argnums=donate_step,
-                            static_argnums=(6,),
+                            static_argnums=(5,),
                             compiler_options=STEP_COMPILER_OPTIONS.get(
                                 jax.default_backend())),
             "insert": jax.jit(insert, donate_argnums=donate_insert),
@@ -353,15 +362,23 @@ class PagedDecodeRuntime:
         self._ensure_ids()
         self._tell("enqueue")
         with device_trace("ai4e.decode.prefill", bucket=bucket, slot=slot):
-            token, k_block, v_block, state_block = self._run(
+            token, *blocks, state_block = self._run(
                 "prefill", self.servable.params, padded,
                 np.asarray([n], np.int32))
         with device_trace("ai4e.decode.insert", slot=slot):
-            self._k, self._v, self._state, self._ids = self._run(
-                "insert", self._k, self._v, self._state, self._ids, k_block,
-                v_block, state_block, token, np.int32(slot))
+            self._rows, self._state, self._ids = self._run(
+                "insert", self._rows, self._state, self._ids, tuple(blocks),
+                state_block, token, np.int32(slot))
         self._joined.append(token)
         return token, waited
+
+    def prefill_report(self, n: int) -> dict:
+        """What the prefill of a prompt of ``n`` tokens computes, from the
+        host's length and the bucket it pads to: ``tokens`` — ``real`` and
+        ``padded`` — and ``pairs``, the (query, key) pairs of its attention a
+        layer by the declaration's kinds (``kv_pool.prefill_pairs``)."""
+        return {"tokens": {"real": n, "padded": self.bucket_for(n)},
+                "pairs": kv_pool.prefill_pairs(self.rows_spec(), n)}
 
     def join(self, slot: int, tokens) -> None:
         """Dispatch a prompt's prefill into ``slot`` and return without
@@ -421,10 +438,13 @@ class PagedDecodeRuntime:
         count), whose attention covers that many positions and is otherwise
         the same step. What the step will read is worked out here, from the
         host's positions, and travels with it: ``bound``, ``attended`` (the
-        K/V positions its attention reads, a layer —
-        ``kv_pool.positions_read``) and ``cache_bytes`` — ``kv``: those rows
-        of K and of V, every K/V layer, and the one row a live slot writes;
-        ``state``: what the step reads and writes of the state pool, once
+        positions its attention reads of the declaration's first tensor, a
+        layer), ``selected`` (where a tensor declares a selection, the
+        positions the softmax kept) and ``cache_bytes`` by the tensors'
+        ``kind`` (``kv_pool.step_reads``: the rows the step reads of each
+        tensor of rows, every layer, and the one row a live slot writes —
+        ``kv`` for a family that keeps K and V); ``state``: what the step
+        reads and writes of the state pool, once
         in and once out (``state_pool.slot_bytes``: the live slots' blocks
         of a tensor the family steps through ``update_live``, every slot's
         of one it does not) — and, where a slot holds state,
@@ -439,10 +459,9 @@ class PagedDecodeRuntime:
                 f"active {list(active)}): the step would skip it")
         bound = self.bound_for(max(
             (p for p, live in zip(positions, active) if live), default=0))
-        shape, dtype = self.cache_spec()
-        attended = kv_pool.positions_read(shape, dtype, positions, active,
-                                          bound)
-        row_bytes = 2 * shape[0] * shape[-1] * np.dtype(dtype).itemsize
+        attended, cache_bytes, selected = kv_pool.step_reads(
+            self.rows_spec(), self.slots, self.max_len, positions, active,
+            bound)
         host = np.zeros((3, self.slots), np.int32)
         for slot, token in enumerate(fresh):
             if token is not None:
@@ -459,9 +478,9 @@ class PagedDecodeRuntime:
         try:
             with device_trace("ai4e.decode.dispatch", bound=bound,
                               starved=int(starved)):
-                out, self._ids, self._k, self._v, self._state = self._run(
-                    "step", self.servable.params, host, self._ids, self._k,
-                    self._v, self._state, bound)
+                out, self._ids, self._rows, self._state = self._run(
+                    "step", self.servable.params, host, self._ids,
+                    self._rows, self._state, bound)
         except Exception:
             self._ids = None   # nothing launched: the next launch feeds all
             raise
@@ -470,8 +489,8 @@ class PagedDecodeRuntime:
         moved = 2 * (live * sparse + self.slots * dense)
         self._newest = LaunchedStep(
             bound=bound, attended=attended, active=list(active), out=out,
-            starved=starved,
-            cache_bytes={"kv": row_bytes * (attended + live), "state": moved},
+            starved=starved, selected=selected,
+            cache_bytes={**cache_bytes, "state": moved},
             state_bytes=({"moved": moved,
                           "live": 2 * live * (sparse + dense)}
                          if sparse + dense else {}))

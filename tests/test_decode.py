@@ -432,15 +432,14 @@ class TestPagedDecodeRuntime:
         real_zeros = jnp.zeros
 
         def zeros(*args, **kwargs):
-            held.append((lm_runtime._k is not None,
-                         lm_runtime._v is not None))
+            held.append(lm_runtime._rows is not None)
             return real_zeros(*args, **kwargs)
 
         lm_runtime._ensure()
         monkeypatch.setattr(jnp, "zeros", zeros)
         lm_runtime.reset_cache()
-        assert held == [(False, False), (True, False)]
-        assert lm_runtime._k.shape == lm_runtime._v.shape
+        assert held == [False, False]
+        assert lm_runtime._rows[0].shape == lm_runtime._rows[1].shape
 
     def test_reload_params_bumps_version_and_checks_tree(self, lm_runtime):
         import jax
@@ -487,8 +486,10 @@ def tiny_lm(request):
         dim=_POOL["heads"] * _POOL["head_dim"], depth=_POOL["depth"],
         heads=_POOL["heads"], **_LM_FAMILIES[request.param])
     model, params = servable.model, servable.params
-    spec, dtype, state = model.cache_spec()[:3]
-    assert spec == (_POOL["depth"], _POOL["heads"], _POOL["head_dim"])
+    rows, state = model.cache_spec()[:2]
+    dtype = rows[0].dtype
+    assert [(r.layers, r.width) for r in rows] == 2 * [
+        (_POOL["depth"], _POOL["heads"] * _POOL["head_dim"])]
     assert state == ()   # these families keep K/V only
 
     def step(params, tokens, k, v, position):

@@ -742,7 +742,7 @@ class FakePrograms(dict):
         self.waited, self.tokens, self.unwaited = set(), [], []
         self["prefill"] = self._program(self._prefill)
         self["insert"] = self._program(
-            lambda *args: tuple(FakeArray(self.waited) for _ in range(4)))
+            lambda *args: tuple(FakeArray(self.waited) for _ in range(3)))
 
     @staticmethod
     def _program(fn):
@@ -767,7 +767,7 @@ def test_the_runtime_keeps_at_most_two_joins_in_flight(joins):
     runtime = PagedDecodeRuntime(build_lm_servable(
         family="seqformer-lm", name="lm", max_len=MAX_LEN,
         **FAMILIES["seqformer-lm"]), slots=4, prompt_buckets=(8,))
-    runtime._k = runtime._v = runtime._state = object()
+    runtime._rows = runtime._state = object()
     runtime._ids = object()
     programs = runtime._programs = FakePrograms()
     for i in range(joins):

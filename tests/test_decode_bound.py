@@ -56,7 +56,7 @@ def lm(request):
     import jax
     runtime = build_runtime(request.param)
     model, params = runtime.servable.model, runtime.servable.params
-    shape, dtype = runtime.cache_spec()
+    shape, dtype = runtime.cache_spec()[0]
     rng = np.random.default_rng(7)
     k = rng.standard_normal(shape).astype(dtype)
     v = rng.standard_normal(shape).astype(dtype)

@@ -315,7 +315,7 @@ class TestNamedScopes:
         def lowered(runtime, debug_info):
             return runtime._programs["step"].lower(
                 runtime.servable.params, np.zeros((3, 3), np.int32),
-                np.zeros(3, np.int32), runtime._k, runtime._v,
+                np.zeros(3, np.int32), runtime._rows,
                 runtime._state, runtime.max_len).as_text(
                     debug_info=debug_info)
 

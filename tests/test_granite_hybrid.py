@@ -67,8 +67,8 @@ def _garbage_cache(model, slots, seed):
     """A pool and a state pool of garbage: whatever a slot held before."""
     spec = model.cache_spec()
     rng = np.random.default_rng(seed)
-    shape = kv_pool.pool_shape(spec.kv, slots, CACHE)
-    k, v = (jnp.asarray(rng.standard_normal(shape), spec.dtype)
+    shape = kv_pool.pool_shape(spec.rows[0], slots, CACHE)
+    k, v = (jnp.asarray(rng.standard_normal(shape), spec.rows[0].dtype)
             for _ in range(2))
     state = {name: jnp.asarray(rng.standard_normal((slots, *shape)), dtype)
              for name, shape, dtype in spec.state}
@@ -89,7 +89,7 @@ def _served_logits(lm, seq, prompt_len, slot=1, slots=3):
         lm.params, padded, np.asarray([prompt_len], np.int32),
         method="prefill_logits")
     out = [np.asarray(logits[0, :prompt_len], np.float32)]
-    k, v = kv_pool.insert_block(k, v, k_block, v_block, slot)
+    k, v = kv_pool.insert_block((k, v), (k_block, v_block), slot)
     state = state_pool.insert(state, state_block, slot)
     step = jax.jit(lambda *a: apply(lm.params, *a, method="decode_logits"))
     for position in range(prompt_len, len(seq)):
@@ -273,7 +273,8 @@ def test_the_embedding_and_the_head_are_one_array(lm32):
 def test_cache_spec_declares_kv_of_attention_layers_and_state_of_the_rest():
     model, _ = create_granite_hybrid_lm(dtype="bfloat16", **SPEC)
     spec = model.cache_spec()
-    assert spec.kv == (1, 2, 16) and spec.dtype == jnp.bfloat16
+    assert spec.rows == tuple(kv_pool.Rows(n, 1, 2 * 16, jnp.bfloat16)
+                              for n in "kv")
     assert [s[0] for s in spec.state] == [
         name for j in range(7) for name in (f"ssm{j}", f"conv{j}")]
     assert spec.state[0][1:] == ((16, 4 * 16), jnp.float32)
@@ -350,8 +351,8 @@ def test_the_worker_serves_the_family_through_the_same_wiring():
     assert type(engine) is DecodeEngine
     assert type(backend) is PagedDecodeRuntime
     assert backend.max_len == 64 and backend.prompt_buckets == (8, 64)
-    assert backend._k.shape == (1, 4, 64, 32)
-    assert backend._k.dtype == jnp.bfloat16
+    assert backend._rows[0].shape == (1, 4, 64, 32)
+    assert backend._rows[0].dtype == jnp.bfloat16
     assert backend._state["ssm0"].shape == (4, 16, 4 * 16)
     assert backend._state["ssm0"].dtype == jnp.float32
     assert "/lm-stream-async" in worker.service.endpoints

@@ -35,8 +35,9 @@ class Pool:
         self.tol = TOLERANCE[dtype]
         self.layers, self.slots, self.heads = layers, slots, heads
         self.max_len, self.head_dim = max_len, head_dim
-        self.shape = kv_pool.pool_shape((layers, heads, head_dim), slots,
-                                        max_len)
+        self.shape = kv_pool.pool_shape(
+            kv_pool.Rows("k", layers, heads * head_dim, self.dtype), slots,
+            max_len)
         rng = np.random.default_rng(29)
         self.k = jnp.asarray(rng.standard_normal(self.shape), self.dtype)
         self.v = jnp.asarray(rng.standard_normal(self.shape), self.dtype)
@@ -131,7 +132,7 @@ def the_row_write_changes_one_row_a_slot_and_a_layer(pool):
               for _ in range(LAYERS)]
     v_rows = [jnp.asarray(pool.rng.standard_normal(row), pool.dtype)
               for _ in range(LAYERS)]
-    k, v = jax.jit(kv_pool.write_rows)(pool.k, pool.v, k_rows, v_rows,
+    k, v = jax.jit(kv_pool.write_rows)((pool.k, pool.v), (k_rows, v_rows),
                                        jnp.asarray(position, jnp.int32))
     for got, before, rows in ((k, pool.k, k_rows), (v, pool.v, v_rows)):
         assert got.shape == pool.shape and got.dtype == pool.dtype
@@ -157,7 +158,7 @@ def the_insert_lands_a_block_in_its_slot_and_touches_no_other(pool):
           for _ in range(LAYERS)]
     k_block, v_block = kv_pool.prompt_block(ks), kv_pool.prompt_block(vs)
     assert k_block.shape == (LAYERS, 1, prompt, HEADS * HEAD_DIM)
-    k, v = jax.jit(kv_pool.insert_block)(pool.k, pool.v, k_block, v_block,
+    k, v = jax.jit(kv_pool.insert_block)((pool.k, pool.v), (k_block, v_block),
                                          jnp.int32(slot))
     for got, before, per_layer in ((k, pool.k, ks), (v, pool.v, vs)):
         want = pool.f32(before).copy()
@@ -202,8 +203,9 @@ def a_prompt_inserted_then_attended_is_the_prefills_own_attention(pool):
                   for x in (k, v)]
         blocks[0][layer], blocks[1][layer] = k[:, :-1], v[:, :-1]
         k_pool, v_pool = kv_pool.insert_block(
-            pool.k, pool.v, kv_pool.prompt_block(blocks[0]),
-            kv_pool.prompt_block(blocks[1]), jnp.int32(slot))
+            (pool.k, pool.v), (kv_pool.prompt_block(blocks[0]),
+                               kv_pool.prompt_block(blocks[1])),
+            jnp.int32(slot))
         new = [jnp.zeros((SLOTS, HEADS, HEAD_DIM), pool.dtype)
                .at[slot].set(x[0, -1]) for x in (q, k, v)]
         got = kv_pool.decode_attention(
@@ -400,7 +402,8 @@ def _grouped(dtype, heads, kv_heads, head_dim, slots, max_len, seed=31):
     """A one-layer pool of ``kv_heads`` heads a row and one step's new rows
     for ``heads`` query heads, as float32 numpy beside the device values."""
     rng = np.random.default_rng(seed)
-    shape = kv_pool.pool_shape((1, kv_heads, head_dim), slots, max_len)
+    shape = kv_pool.pool_shape(kv_pool.Rows("k", 1, kv_heads * head_dim, dtype),
+                               slots, max_len)
     k, v = (jnp.asarray(rng.standard_normal(shape), dtype) for _ in range(2))
     q, k_new, v_new = (
         jnp.asarray(rng.standard_normal((slots, n, head_dim)), dtype)

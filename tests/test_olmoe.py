@@ -90,7 +90,8 @@ def _served_logits(lm, seq, prompt_len, slot, slots=3):
     pool of garbage, then one decode step a token, teacher-forced, the other
     slots riding along at position 0."""
     apply = lm.model.apply
-    spec, dtype, _ = lm.model.cache_spec()[:3]
+    spec = lm.model.cache_spec().rows[0]
+    dtype = spec.dtype
     rng = np.random.default_rng(slot)
     shape = kv_pool.pool_shape(spec, slots, CACHE)
     k = jnp.asarray(rng.standard_normal(shape), dtype)
@@ -102,7 +103,7 @@ def _served_logits(lm, seq, prompt_len, slot, slots=3):
         lm.params, padded, np.asarray([prompt_len], np.int32),
         method="prefill_logits")
     out = [np.asarray(logits[0, :prompt_len], np.float32)]
-    k, v = kv_pool.insert_block(k, v, k_block, v_block, slot)
+    k, v = kv_pool.insert_block((k, v), (k_block, v_block), slot)
     step = jax.jit(lambda *a: apply(lm.params, *a, method="decode_logits"))
     for position in range(prompt_len, len(seq)):
         tokens = np.zeros((slots,), np.int32)
@@ -271,7 +272,7 @@ def test_the_worker_serves_the_family_through_the_same_wiring():
     assert type(engine) is DecodeEngine
     assert type(backend) is PagedDecodeRuntime
     assert backend.max_len == CACHE and backend.prompt_buckets == (8, CACHE)
-    assert backend._k.dtype == jnp.bfloat16
+    assert backend._rows[0].dtype == jnp.bfloat16
     assert backend.cache_nbytes() == 2 * 2 * 3 * 4 * CACHE * 16 * 2
     assert "/lm-stream-async" in worker.service.endpoints
 

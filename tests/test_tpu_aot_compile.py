@@ -177,8 +177,7 @@ def _compile_step(runtime, sharding, bound):
     so the test answers for it."""
     from ai4e_tpu.ops.pallas import decode_attention, state_update
     from ai4e_tpu.runtime import kvcache
-    pool_shape, pool_dtype = runtime.cache_spec()
-    pool = _on(sharding, (pool_shape, pool_dtype))
+    pools = tuple(_on(sharding, spec) for spec in runtime.cache_spec())
     # What a launch hands it: the host's three rows a slot, and the last
     # step's ids, which stayed on the device.
     host = _on(sharding, ((3, runtime.slots), jnp.int32))
@@ -191,8 +190,8 @@ def _compile_step(runtime, sharding, bound):
                  for name, shape, dtype in runtime.state_spec()}
         # The default backend here is the CPU: the chip's options by hand.
         return runtime._programs["step"].lower(
-            _on(sharding, runtime.servable.params), host, previous, pool,
-            pool, state, bound).compile(
+            _on(sharding, runtime.servable.params), host, previous, pools,
+            state, bound).compile(
                 compiler_options=kvcache.STEP_COMPILER_OPTIONS["tpu"])
 
 
@@ -296,7 +295,7 @@ def _assert_step_reads_in_place(runtime, compiled, bound, temp_limit,
     fusion's result, holds one layer's K or V, whole or cut (a slice that
     reached the kernel would be such a copy, every layer, every step)."""
     import re
-    pool_shape, pool_dtype = runtime.cache_spec()
+    pool_shape, pool_dtype = runtime.cache_spec()[0]
     layers, slots, max_len, row = pool_shape
     pool_type = _hlo_type(pool_shape, pool_dtype)
     results = _entry_results(compiled)
@@ -359,7 +358,7 @@ def test_decode_step_at_the_benchmark_cell_writes_rows_in_place(
     positions): ``_assert_step_reads_in_place``. About 6 s each."""
     runtime, spec = gpt2m_cell
     assert runtime.step_bounds == (768, 1024)
-    assert runtime.cache_spec() == ((24, 32, 1024, 1024), jnp.float32)
+    assert runtime.cache_spec() == 2 * (((24, 32, 1024, 1024), jnp.float32),)
     bound = runtime.step_bounds[rung]
     _assert_step_reads_in_place(
         runtime, _compile_step(runtime, v5e_sharding, bound), bound, 0.5e9,
@@ -379,7 +378,7 @@ def test_olmoe_step_at_the_benchmark_cell_writes_rows_in_place(
     configuration states. About 4 s and 10 s."""
     runtime, spec = olmoe_cell
     assert runtime.step_bounds == (1536, 2048)
-    assert runtime.cache_spec() == ((8, 32, 2048, 2048), jnp.bfloat16)
+    assert runtime.cache_spec() == 2 * (((8, 32, 2048, 2048), jnp.bfloat16),)
     bound = runtime.step_bounds[rung]
     memory = _assert_step_reads_in_place(
         runtime, _compile_step(runtime, v5e_sharding, bound), bound, 0.1e9,
@@ -427,7 +426,7 @@ def test_qnext_step_at_the_benchmark_cell_moves_no_pool(
     from ai4e_tpu.ops import state_pool
     runtime, spec = qnext_cell
     assert runtime.step_bounds == (2304, 3072)
-    assert runtime.cache_spec() == ((3, 32, 3072, 512), jnp.bfloat16)
+    assert runtime.cache_spec() == 2 * (((3, 32, 3072, 512), jnp.bfloat16),)
     state = runtime.state_spec()
     assert len(state) == 18
     assert state[0] == ("delta0", (32, 128, 128), jnp.float32)
@@ -496,7 +495,7 @@ def test_granite_step_at_the_benchmark_cell_moves_no_pool(
     from ai4e_tpu.ops import state_pool
     runtime, spec = granite_cell
     assert runtime.step_bounds == (768, 1024)
-    assert runtime.cache_spec() == ((4, 64, 1024, 512), jnp.bfloat16)
+    assert runtime.cache_spec() == 2 * (((4, 64, 1024, 512), jnp.bfloat16),)
     state = runtime.state_spec()
     assert len(state) == 72
     assert state[0] == ("ssm0", (128, 64 * 64), jnp.float32)
@@ -565,3 +564,153 @@ def test_decode_kernel_with_grouped_heads_compiles(v5e_sharding):
             q, k_new, v_new, k, v, 1, position, 2304, interpret=False),
         q, new, new, pool, pool, ints)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("pool,heads,value,bound", [
+    ((3, 16, 12544, 640), 128, 512, 9472),
+    ((3, 16, 12544, 640), 128, 512, 12544),
+    ((3, 16, 512, 1152), 64, 1024, 512)])
+def test_latent_kernel_compiles(v5e_sharding, pool, heads, value, bound,
+                                masked):
+    """The latent read at the ``dots3.longdoc`` cell's shapes alone, by
+    Mosaic: 128 heads on one 640-lane row whose first 512 lanes are the
+    value, blocks of 768 positions (the last one of the cache cut), with and
+    without the selection's mask; and the window's ring, 64 heads on 1,152
+    lanes."""
+    from ai4e_tpu.ops import kv_pool
+    slots = pool[1]
+    rows = _on(v5e_sharding, (pool, jnp.bfloat16))
+    q = _on(v5e_sharding, ((slots, heads, pool[3]), jnp.bfloat16))
+    new = _on(v5e_sharding, ((slots, pool[3]), jnp.bfloat16))
+    ints = _on(v5e_sharding, ((slots,), jnp.int32))
+    keep = _on(v5e_sharding, ((slots, bound), jnp.bool_))
+
+    def read(q, new, rows, position, keep, own):
+        return kv_pool.latent_decode_attention(
+            q, new, rows, 1, position, value=value, bound=bound, scale=0.07,
+            keep=keep if masked else None, own=own if masked else None,
+            interpret=False)
+
+    compiled = _compile(read, q, new, rows, ints, keep, ints)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("p", [3072, 12288, 12544])
+@pytest.mark.parametrize("heads,dqk,window", [(32, 192, None), (32, 256, 513)])
+def test_prompt_kernels_compile(v5e_sharding, p, heads, dqk, window):
+    """The prefill's two kernels at the ``dots3.longdoc`` cell's shapes
+    alone, by Mosaic: a group of 32 heads, keys 192 (256 on the sliding
+    layers) wide against values of 128, under the selection's one-byte mask
+    or over the window's band, at the shortest and the longest buckets
+    (blocks of 512) and the cache's own length (blocks of 256); and the
+    indexer's 64 heads of 128 for a block of 256 queries."""
+    from ai4e_tpu.ops import kv_pool
+    q, k = (_on(v5e_sharding, ((p, heads, dqk), jnp.bfloat16))
+            for _ in range(2))
+    v = _on(v5e_sharding, ((p, heads, 128), jnp.bfloat16))
+    mask = _on(v5e_sharding, ((p, p), jnp.int8))
+
+    def attend(q, k, v, mask):
+        return kv_pool.prompt_attention(
+            q, k, v, 0.07, mask=None if window else mask, window=window,
+            interpret=False)
+
+    assert "tpu_custom_call" in _compile(attend, q, k, v, mask).as_text()
+    if window:
+        return
+    import importlib
+    flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
+    scores = _compile(
+        lambda iq, ik, w, first: flash.index_scores(iq, ik, w, first,
+                                                    interpret=False),
+        _on(v5e_sharding, ((64, 256, 128), jnp.bfloat16)),
+        _on(v5e_sharding, ((p, 128), jnp.bfloat16)),
+        _on(v5e_sharding, ((256, 64), jnp.float32)),
+        _on(v5e_sharding, ((), jnp.int32)))
+    assert "tpu_custom_call" in scores.as_text()
+
+
+@pytest.fixture(scope="module")
+def dots3_cell():
+    from ai4e_tpu.models.dots3 import Dots3LM, create_dots3_lm
+    from benchmark.references.dots3 import NOT_MODEL_KEYS
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "dots3-note-prev.json")) as f:
+        spec = json.load(f)["models"]["models"][0]
+
+    def model(**dims):   # the spec's JSON list as the module's tuple
+        return Dots3LM(**dict(dims, layer_types=tuple(dims["layer_types"])))
+
+    return _benchmark_cell(
+        "dots3-note-prev.json", create_dots3_lm, model,
+        [key for key in spec if key not in NOT_MODEL_KEYS])
+
+
+@pytest.mark.parametrize("rung", [0, 1])
+def test_dots3_step_at_the_benchmark_cell_moves_no_pool(
+        v5e_sharding, dots3_cell, rung):
+    """The ``dots3.longdoc`` cell (``benchmark/configs/dots3-note-prev.json``):
+    three tensors of rows — latent ``(3, 16, 12544, 640)``, index ``(3, 16,
+    12544, 128)`` and the window's ring ``(3, 16, 512, 1152)`` — each made
+    only by row writes on its donated parameter (no copy, no fusion that
+    rewrites one), one ``latent_attention`` Mosaic call a layer whose pool
+    operand is the parameter itself, every tensor aliased input to output. At
+    the top rung, the whole worker's memory: weights + pools + the widest
+    prefill's (12,288, and the cache length 12,544 the runtime adds)
+    temporaries and outputs stay under 15.5 GB."""
+    import re
+    runtime, spec = dots3_cell
+    assert runtime.step_bounds == (9472, 12544)
+    shapes = ((3, 16, 12544, 640), (3, 16, 12544, 128), (3, 16, 512, 1152))
+    assert runtime.cache_spec() == tuple((s, jnp.bfloat16) for s in shapes)
+    bound = runtime.step_bounds[rung]
+    compiled = _compile_step(runtime, v5e_sharding, bound)
+    results, entry = _entry_results(compiled), _entry(compiled)
+    kernels = _mosaic_calls(compiled, "latent_attention")
+    assert len(kernels) == 6, len(kernels)
+    for shape in shapes:
+        pool_type = _hlo_type(shape, jnp.bfloat16)
+        makers = [op for kind, op in results if kind.startswith(pool_type)]
+        assert sorted(set(makers)) == ["dynamic-update-slice",
+                                       "parameter"], (shape, set(makers))
+        assert makers.count("dynamic-update-slice") == 16
+        (pool,) = re.findall(r"(%\S+) = " + re.escape(pool_type)
+                             + r"\S* parameter\(", entry)
+        if shape[-1] != 128:   # the index keys are read by XLA's product
+            assert sum(pool in ops for _, ops, _ in kernels) == 3
+    assert len(kernels) == compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"')
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= runtime.cache_nbytes()
+    assert runtime.cache_nbytes() == 2 * 3 * 16 * (
+        12544 * 640 + 12544 * 128 + 512 * 1152)
+    assert memory.temp_size_in_bytes < 1.0e9, memory.temp_size_in_bytes
+    if bound < runtime.max_len:
+        return
+
+    resident = memory.argument_size_in_bytes   # weights + pools (+ ints)
+    assert 10.9e9 < resident < 11.2e9, resident
+    assert runtime.max_len == spec["max_len"] == 12544
+    import importlib
+    flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
+    for top in (12288, runtime.max_len):
+        with pytest.MonkeyPatch.context() as patch:
+            # the prefill's kernels by Mosaic, as on the chip (the default
+            # backend here is the CPU)
+            patch.setattr(flash, "resolve_interpret",
+                          lambda kernel, interpret: False)
+            prefill = runtime._programs["prefill"].lower(
+                _on(v5e_sharding, runtime.servable.params),
+                _on(v5e_sharding, ((1, top), jnp.int32)),
+                _on(v5e_sharding, ((1,), jnp.int32))).compile()
+        assert len(_mosaic_calls(prefill, "prompt_attention")) == 3 * 4 + 3 * 2
+        prefill = prefill.memory_analysis()
+        peak = resident + max(memory.temp_size_in_bytes,
+                              prefill.temp_size_in_bytes
+                              + prefill.output_size_in_bytes)
+        print(f"dots3 cell: resident {resident}, step temporaries "
+              f"{memory.temp_size_in_bytes}, prefill {top}: temporaries "
+              f"{prefill.temp_size_in_bytes} + outputs "
+              f"{prefill.output_size_in_bytes}, peak {peak}")
+        assert peak < 15.5e9, (top, peak, prefill.temp_size_in_bytes)
