@@ -69,10 +69,6 @@ from .olmoe import norm_scale, rms_norm, rope, seeded
 
 LANES = 128
 LN_EPS = 1e-6   # the indexer key's LayerNorm
-# Rows a prefill's FFN takes at once: the routed product's (rows x K, D)
-# float32 result is 0.34 GB at 2,048 rows of 5,120 with 8 experts a token,
-# and 2 GB at a whole prompt of 12,544.
-FFN_ROWS = 2048
 # Heads whose un-absorbed queries, keys and values a prefill holds at once
 # (a prompt of 12,288: 0.15 GB each of q and k, 0.1 each of v and the output;
 # all 128 heads at once were 2 GB).
@@ -221,6 +217,7 @@ class _Layer(nn.Module):
         weights = (self.w_gate, self.w_up, self.w_down)
         if routed:
             y = expert_layer.routed(h, top_e, top_p, *weights,
+                                    total=self.experts,
                                     first_held=self.first_expert)
         else:
             gate = expert_layer.gate_matrix(top_e, top_p, self.experts_held,
@@ -294,7 +291,9 @@ class _Layer(nn.Module):
         """``x (P, D)``, one prompt of ``length`` tokens padded to its
         bucket → the block's output ``(P, D)``, what it caches — a full
         layer its latent rows ``(P, row)`` and index keys ``(P, d)``, a
-        sliding one its ring ``(window − 1, row)``."""
+        sliding one its ring ``(window − 1, row)`` — and the passes its
+        expert product took (``experts.window_passes``; None from a dense
+        layer)."""
         p = x.shape[0]
         position = jnp.arange(p)
         h = rms_norm(x, self.norm_in, self.eps)
@@ -345,11 +344,9 @@ class _Layer(nn.Module):
                 q, k, v, self.scale, mask=allowed, window=window))
         o = jnp.concatenate(out, axis=1)
         x = self._out(x, h, o)
-        parts = -(-p // FFN_ROWS)
-        size = padded(-(-p // parts))
-        x = jnp.concatenate([self._ffn(x[at:at + size], routed=True)[0]
-                             for at in range(0, p, size)])
-        return x, cache
+        x, top_e = self._ffn(x, routed=True)
+        return x, cache, None if top_e is None else expert_layer.window_passes(
+            top_e, self.experts_held, self.experts, self.first_expert)
 
     def step(self, x, pools, layer: int, position, bound: int):
         """One token a slot: ``x (S, D)`` at ``position (S,)``; ``pools`` —
@@ -492,16 +489,19 @@ class Dots3LM(nn.Module):
         """One prompt: ``tokens (1, P)``, ``length (1,)``."""
         with jax.named_scope("embedding"):
             h = self.embed[tokens[0]]
-        latent, index, ring = [], [], []
+        latent, index, ring, passes = [], [], [], []
         for layer in self.layers:
-            h, cache = layer.prefill(h, length[0])
+            h, cache, taken = layer.prefill(h, length[0])
             if layer.full:
                 latent.append(cache[0])
                 index.append(cache[1])
             else:
                 ring.append(cache[0])
-        return h[None], tuple(jnp.stack(rows)[:, None]
-                              for rows in (latent, index, ring))
+            if taken is not None:
+                passes.append(taken)
+        return (h[None], tuple(jnp.stack(rows)[:, None]
+                               for rows in (latent, index, ring)),
+                expert_layer.pass_report(passes))
 
     def _step(self, tokens, latent, index, ring, position, bound):
         with jax.named_scope("embedding"):
@@ -527,11 +527,11 @@ class Dots3LM(nn.Module):
         return h, latent, index, ring, jnp.stack(picks)
 
     def prefill(self, tokens, length):
-        h, blocks = self._prefill(tokens, length)
+        h, blocks, passes = self._prefill(tokens, length)
         last = jnp.take_along_axis(
             h, (length - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-        return (jnp.argmax(self._logits(last), axis=-1).astype(jnp.int32),
-                *blocks, {})
+        ids = jnp.argmax(self._logits(last), axis=-1).astype(jnp.int32)
+        return jnp.concatenate([ids, passes]), *blocks, {}
 
     def decode_step(self, tokens, latent, index, ring, state, position,
                     bound=None):
@@ -547,7 +547,7 @@ class Dots3LM(nn.Module):
     # Logits, for tests only: the serving programs ship ids.
 
     def prefill_logits(self, tokens, length):
-        h, blocks = self._prefill(tokens, length)
+        h, blocks, _ = self._prefill(tokens, length)
         return (self._logits(h), *blocks, {})
 
     def decode_logits(self, tokens, latent, index, ring, state, position,
@@ -559,6 +559,8 @@ class Dots3LM(nn.Module):
     # What ``step_report`` returns: the routing series of the sparse-expert
     # families, under the same names.
     step_report_series = expert_layer.step_report_series
+    # What ``prefill`` appends to its first id (``experts.pass_report``).
+    prefill_report_kinds = expert_layer.prefill_report_kinds
 
     @nn.nowrap
     def step_report(self, extra: np.ndarray, active) -> dict[str, float]:

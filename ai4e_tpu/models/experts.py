@@ -30,7 +30,18 @@ Two forms of the same sum, chosen by the caller for the program it builds:
   by expert and each expert multiplies its own rows only
   (``jax.lax.ragged_dot``), so the multiplies are the published ``K`` a
   row — what a prefill of thousands of rows over 128 held experts needs:
-  dense there is ``held / (K * held / total)`` times the work.
+  dense there is ``held / (K * held / total)`` times the work. Its work is
+  in proportion to the pairs held HERE, not to ``T x K``: all pairs are
+  ranked (one sort of int32 keys), and a pass gathers, multiplies and
+  combines a WINDOW of ranked pairs — ``window_rows``, a static size from
+  the operands' shapes: one and a half times the ``T x K x held / total``
+  an even router sends here, on whole tiles of 512 rows (1.5 T of 8 T
+  where an eighth is held, 3.75 T of 10 T where a quarter is), and every
+  pair where all experts are held or the pairs are few. Beyond the window
+  nothing is dropped: the product takes as many passes as the held pairs
+  need (``window_passes``), each reading the held experts' weights once —
+  one on any router near even, none where nothing is held, ``T x K /
+  window`` where every pick is. A prompt goes through in one call.
 
 No capacity, no drop, in either form.
 """
@@ -40,6 +51,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# ``routed``'s window is whole tiles of this many rows.
+TILE = 512
+# Columns ``routed`` adds into its result at once: XLA's scatter on the chip
+# adds a row of 5,120 float32 in 1.45 us and one of at most 2,048 in 0.1 us
+# (PERF.md section 6, PR 40).
+COMBINE_COLUMNS = 1024
 
 
 def _dot(eq, a, b):
@@ -96,35 +115,100 @@ def dense(h, gate, w_gate, w_up, w_down):
         return _dot("...ef,efd->...d", a, w_down).astype(h.dtype)
 
 
-def routed(h, top_e, top_p, w_gate, w_up, w_down, first_held: int = 0):
+def window_rows(rows: int, k: int, held: int, total: int) -> int:
+    """The (row, pick) pairs ``routed`` multiplies in one pass, from shapes
+    alone: one and a half times the ``rows x k x held / total`` an even
+    router sends to the experts held here, on whole tiles of ``TILE`` — and
+    every pair where that would be no fewer (all experts held) or where the
+    pairs are few: the product over one tile of rows that 128 experts share
+    is dearer on the chip than the pairs it leaves out (PERF.md section 6,
+    PR 40)."""
+    pairs = rows * k
+    if pairs <= 8 * TILE:
+        return pairs
+    tiles = -(-3 * pairs * held // (2 * total * TILE))
+    return min(tiles * TILE, pairs)
+
+
+def window_passes(top_e, held: int, total: int, first_held: int = 0):
+    """The passes ``routed`` takes over ``top_e (T, K)``: the pairs that
+    land on an expert held here over its window, rounded up — an int32
+    scalar, 0 where none does."""
+    local = top_e - first_held
+    count = ((local >= 0) & (local < held)).sum().astype(jnp.int32)
+    return -(-count // window_rows(*top_e.shape, held, total))
+
+
+# What the prefill of a family whose expert layers are ``routed`` appends to
+# its first id (``pass_report``), as the decode engine counts it:
+# ``ai4e_decode_prefill_expert_passes_total{kind}``.
+prefill_report_kinds = ("first", "extra")
+
+
+def pass_report(passes):
+    """Each expert layer's ``window_passes`` of one prefill → int32 ``(2,)``
+    in ``prefill_report_kinds``' order: the layers that took a pass at all,
+    and the passes beyond it — 0 while every layer's held pairs fit its
+    window."""
+    passes = jnp.stack(passes)
+    first = jnp.minimum(passes, 1).sum()
+    return jnp.stack([first, passes.sum() - first])
+
+
+def routed(h, top_e, top_p, w_gate, w_up, w_down, total: int,
+           first_held: int = 0):
     """The same sum with each held expert multiplying only the rows that
-    chose it. ``h (T, D)``, ``top_e``, ``top_p (T, K)``. The ``T x K`` (row,
-    pick) pairs are sorted by held expert, pairs of experts held elsewhere
-    last and outside every group: what ``ragged_dot`` leaves in their rows
-    is not defined on every backend, so they are zeroed by hand. Each
-    pair's output is weighted, brought back to its row's place and the
-    row's ``K`` summed. Returns ``(T, D)`` in ``h``'s dtype."""
+    chose it. ``h (T, D)``, ``top_e``, ``top_p (T, K)``, ``total`` the
+    router's width. The ``T x K`` (row, pick) pairs are ranked by held
+    expert, the pairs of experts held elsewhere last; a pass gathers,
+    multiplies and combines a window of ``window_rows`` ranked pairs, each
+    expert's group cut to the window, and there are as many passes as the
+    held pairs need: one where the router spreads anywhere near evenly,
+    none where nothing is held, ``T x K / window`` where every pick is.
+    What ``ragged_dot`` leaves in the rows past the last group is not
+    defined on every backend, so they are zeroed by hand. Each pair's
+    output is weighted and added into its row of a ``(T, D)`` float32
+    result, ``COMBINE_COLUMNS`` columns a scatter. Returns ``(T, D)`` in
+    ``h``'s dtype."""
     held = w_gate.shape[0]
     t, k = top_e.shape
+    window = window_rows(t, k, held, total)
     with jax.named_scope("experts"):
         local = (top_e - first_held).reshape(-1)
-        here = (local >= 0) & (local < held)
-        group = jnp.where(here, local, held)
-        order = jnp.argsort(group, stable=True)
+        group = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.pad(jnp.argsort(group, stable=True).astype(jnp.int32),
+                        (0, -(t * k) % window))
         sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
-        x = h[order // k]
-        grouped = here[order][:, None]
-        weight = top_p.reshape(-1)[order][:, None]
-        g = jax.lax.ragged_dot(x, w_gate, sizes,
-                               preferred_element_type=jnp.float32)
-        u = jax.lax.ragged_dot(x, w_up, sizes,
-                               preferred_element_type=jnp.float32)
-        a = jnp.where(grouped, jax.nn.silu(g) * u * weight,
-                      0.0).astype(h.dtype)
-        y = jnp.where(grouped, jax.lax.ragged_dot(
-            a, w_down, sizes, preferred_element_type=jnp.float32), 0.0)
-        back = jnp.argsort(order)
-        return y[back].reshape(t, k, -1).sum(axis=1).astype(h.dtype)
+        ends = jnp.cumsum(sizes)
+        begins = ends - sizes
+        weight = top_p.reshape(-1)
+        columns = range(0, w_down.shape[-1], COMBINE_COLUMNS)
+
+        def one_pass(i, out):
+            lo = i * window
+            pairs = jax.lax.dynamic_slice_in_dim(order, lo, window)
+            cut = (jnp.clip(ends, lo, lo + window)
+                   - jnp.clip(begins, lo, lo + window))
+            grouped = (lo + jnp.arange(window) < ends[-1])[:, None]
+            row = pairs // k
+            x = h[row]
+            g = jax.lax.ragged_dot(x, w_gate, cut,
+                                   preferred_element_type=jnp.float32)
+            u = jax.lax.ragged_dot(x, w_up, cut,
+                                   preferred_element_type=jnp.float32)
+            a = jnp.where(grouped, jax.nn.silu(g) * u
+                          * weight[pairs][:, None], 0.0).astype(h.dtype)
+            y = jnp.where(grouped, jax.lax.ragged_dot(
+                a, w_down, cut, preferred_element_type=jnp.float32), 0.0)
+            return tuple(
+                block.at[row].add(y[:, at:at + COMBINE_COLUMNS])
+                for at, block in zip(columns, out))
+
+        out = jax.lax.fori_loop(
+            0, window_passes(top_e, held, total, first_held), one_pass,
+            tuple(jnp.zeros((t, min(COMBINE_COLUMNS, w_down.shape[-1] - at)),
+                            jnp.float32) for at in columns))
+        return jnp.concatenate(out, axis=1).astype(h.dtype)
 
 
 def shared(h, gate_w, w_gate, w_up, w_down):

@@ -318,6 +318,7 @@ class _Layer(nn.Module):
         weights = (self.w_gate, self.w_up, self.w_down)
         if routed:
             y = expert_layer.routed(h, top_e, top_p, *weights,
+                                    total=self.experts,
                                     first_held=self.first_expert)
         else:
             gate = expert_layer.gate_matrix(top_e, top_p, self.experts_held,
@@ -399,9 +400,10 @@ class _Layer(nn.Module):
 
     def prefill(self, x, mask, length):
         """x: (B, P, D); mask: (B, P) valid tokens; length: (B,). Returns
-        ``(y, cache)``: a full layer's cache is ``(k, v)`` of (B, P, KVH,
-        hd), a linear layer's ``(state (B, Hv, dk, dv), tail (B, conv − 1,
-        C))`` after ``length`` tokens."""
+        ``(y, cache, passes)``: a full layer's cache is ``(k, v)`` of (B, P,
+        KVH, hd), a linear layer's ``(state (B, Hv, dk, dv), tail (B, conv −
+        1, C))`` after ``length`` tokens; ``passes``: what the expert
+        product took (``experts.window_passes``)."""
         b, p, _ = x.shape
         if self.full:
             q, gate, k, v = self._qkv(
@@ -430,8 +432,9 @@ class _Layer(nn.Module):
                         q, k, v, jnp.where(mask[..., None], g, 0.0),
                         jnp.where(mask[..., None], beta, 0.0))
                 x, cache = self._lin_out(x, o, z), (state, tail)
-        y, _ = self._moe(x.reshape(b * p, -1), routed=True)
-        return y.reshape(x.shape), cache
+        y, top_e = self._moe(x.reshape(b * p, -1), routed=True)
+        return y.reshape(x.shape), cache, expert_layer.window_passes(
+            top_e, self.experts_held, self.experts, self.first_expert)
 
     def step(self, x, cache, position, bound):
         """One token per slot: x (S, D). A full layer's ``cache`` is ``(k
@@ -540,16 +543,18 @@ class Qwen3NextLM(nn.Module):
         with jax.named_scope("embedding"):
             h = self.embed[tokens]
         mask = jnp.arange(tokens.shape[1])[None, :] < length[:, None]
-        ks, vs, state = [], [], {}
+        ks, vs, state, passes = [], [], {}, []
         for layer in self.layers:
-            h, cache = layer.prefill(h, mask, length)
+            h, cache, taken = layer.prefill(h, mask, length)
+            passes.append(taken)
             if layer.full:
                 ks.append(cache[0])
                 vs.append(cache[1])
             else:
                 j = len(state) // 2
                 state[f"delta{j}"], state[f"conv{j}"] = cache
-        return h, kv_pool.prompt_block(ks), kv_pool.prompt_block(vs), state
+        return (h, kv_pool.prompt_block(ks), kv_pool.prompt_block(vs), state,
+                expert_layer.pass_report(passes))
 
     def _step(self, tokens, k_cache, v_cache, state, position, bound):
         with jax.named_scope("embedding"):
@@ -573,11 +578,11 @@ class Qwen3NextLM(nn.Module):
         return h, k_cache, v_cache, new_state, jnp.stack(experts)
 
     def prefill(self, tokens, length):
-        h, k, v, state = self._prefill(tokens, length)
+        h, k, v, state, passes = self._prefill(tokens, length)
         last = jnp.take_along_axis(
             h, (length - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-        return (jnp.argmax(self._logits(last), axis=-1).astype(jnp.int32),
-                k, v, state)
+        ids = jnp.argmax(self._logits(last), axis=-1).astype(jnp.int32)
+        return jnp.concatenate([ids, passes]), k, v, state
 
     def decode_step(self, tokens, k_cache, v_cache, state, position,
                     bound=None):
@@ -593,7 +598,7 @@ class Qwen3NextLM(nn.Module):
     # Logits, for tests only: the serving programs ship ids.
 
     def prefill_logits(self, tokens, length):
-        h, k, v, state = self._prefill(tokens, length)
+        h, k, v, state, _ = self._prefill(tokens, length)
         return self._logits(h), k, v, state
 
     def decode_logits(self, tokens, k_cache, v_cache, state, position,
@@ -604,6 +609,8 @@ class Qwen3NextLM(nn.Module):
 
     # What ``step_report`` returns, as the decode engine exposes it.
     step_report_series = expert_layer.step_report_series
+    # What ``prefill`` appends to its first id (``experts.pass_report``).
+    prefill_report_kinds = expert_layer.prefill_report_kinds
 
     @nn.nowrap
     def step_report(self, extra: np.ndarray, active) -> dict[str, float]:

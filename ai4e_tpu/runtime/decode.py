@@ -381,6 +381,14 @@ class DecodeEngine:
       ``ai4e_decode_prefill_<series>_total{kind}`` (``tokens``: real,
       padded; ``pairs``: by the kinds the backend's cache declares). A
       backend without it registers neither series;
+    - optionally ``report_kinds`` (attribute, names) and ``join_report(slot)
+      -> {kind: count}``: what the prefill last joined into ``slot`` counted
+      on the device, handed over once, after the read that brought its first
+      id to the host (a ``fetch``, ``first_ids``, ``prefill_into``) brought
+      it along — counted as
+      ``ai4e_decode_prefill_expert_passes_total{kind}`` (``first``,
+      ``extra``). A backend whose ``report_kinds`` is empty registers
+      nothing;
     - optionally ``bound_for(longest)``: the bound a step whose largest
       live position is ``longest`` will run — the ``bound=`` of the
       ``ai4e.decode.tick`` region, which opens before the launch;
@@ -491,6 +499,18 @@ class DecodeEngine:
                 ("pairs", "(query, key) pairs of the joined prefills' "
                  "attention, a layer, by the kinds the backend's cache "
                  "declares"))}
+        # What a backend's prefills report from the device beside their first
+        # id (``join_report``): the passes their expert layers took. None
+        # from a backend that reports nothing.
+        self._expert_passes = None
+        if getattr(backend, "report_kinds", ()):
+            self._expert_passes = self.metrics.counter(
+                "ai4e_decode_prefill_expert_passes_total",
+                "Passes the joined prefills' expert layers took over their "
+                "window of held (row, pick) pairs, by kind: first (a layer's "
+                "one pass) and extra (those beyond it: more pairs landed on "
+                "the experts held here than one and a half times an even "
+                "router's share)")
         self._cache_bytes = self.metrics.counter(
             "ai4e_decode_cache_bytes_total",
             "Bytes of the slots' cache a decode step read and wrote, by "
@@ -884,6 +904,14 @@ class DecodeEngine:
             seq.position = len(seq.prompt)
             await self._prefill(seq, seq.prompt, ahead=True)
 
+    def _count_join(self, slot: int) -> None:
+        """Count what the prefill joined into ``slot`` reported from the
+        device (``join_report``), now that the read of its first id has
+        brought it to the host."""
+        if self._expert_passes is not None:
+            for kind, n in self.backend.join_report(slot).items():
+                self._expert_passes.inc(n, model=self._model, kind=kind)
+
     async def _prefill(self, seq: _Sequence, tokens,
                        ahead: bool = False) -> int | None:
         """``tokens`` (the prompt or, after a reload, the history) through
@@ -902,6 +930,8 @@ class DecodeEngine:
         self._step_hist.observe(seconds, phase="prefill", model=self._model)
         if ahead:
             self._joins_total.inc(model=self._model, kind="all")
+        else:
+            self._count_join(seq.slot)
         if self._prefill_work:
             for series, kinds in self.backend.prefill_report(
                     len(tokens)).items():
@@ -1028,6 +1058,7 @@ class DecodeEngine:
                     self._note_step(unread)
                 self._release_parked()
             for slot, seq, *_ in alone:
+                self._count_join(slot)
                 if not seq.done and seq.slot == slot:
                     self._note_token(seq, int(firsts[slot]))
             phase["bookkeeping"] += time.perf_counter() - clock.resumed
@@ -1094,6 +1125,8 @@ class DecodeEngine:
         for name, value in step.report.items():
             self._step_report[name].observe(value, model=self._model)
         for slot, seq, position, first in snapshot:
+            if first:
+                self._count_join(slot)
             if first and not (seq.done or seq.slot != slot):
                 # What the slot was fed is its prompt's first id, which the
                 # host did not wait for: noted here, before the step's own.

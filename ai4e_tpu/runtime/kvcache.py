@@ -169,11 +169,20 @@ class PagedDecodeRuntime:
         # Bytes of state a slot holds: in the tensors a step advances at its
         # live slots only, and in those it moves at every slot.
         self._state_slot_bytes = (0, 0)
-        # The ids of the last launched step, on the device, with the first
-        # id of every prefill joined since in its slot: what the next launch
-        # feeds every slot the host does not. None: there is neither (start,
-        # reset, a failure).
+        # One int32 vector on the device. Its first ``slots`` entries: the
+        # ids of the last launched step, with the first id of every prefill
+        # joined since in its slot — what the next launch feeds every slot
+        # the host does not. After them, ``slots`` entries a kind: what a
+        # model's prefill appends to its first id (its
+        # ``prefill_report_kinds``; none from most), at the joined slot
+        # until the next step carries it to the host. None: there is
+        # neither (start, reset, a failure).
         self._ids = None
+        # By slot, the report of the prefill last joined into it: None from
+        # its dispatch until a read brings it to the host (a column that
+        # comes again with a later read is not taken twice), then ``{kind:
+        # count}`` until ``join_report`` hands it over.
+        self._reports = {}
         # The first ids of the joins dispatched last, on the device, newest
         # last: at most two are in flight (``_dispatch_join``).
         self._joined = deque()
@@ -232,6 +241,7 @@ class PagedDecodeRuntime:
         # the allocator's peak of the whole worker.
         self._rows = self._state = self._ids = None
         self._joined.clear()
+        self._reports.clear()
         self._rows = tuple(kv_pool.allocate(shape, dtype)
                            for shape, dtype in self.cache_spec())
         self._state = state_pool.allocate(self.state_spec(), self.slots)
@@ -250,7 +260,8 @@ class PagedDecodeRuntime:
         written its slot."""
         if self._ids is None:
             import jax.numpy as jnp
-            self._ids = jnp.zeros((self.slots,), jnp.int32)
+            self._ids = jnp.zeros(
+                ((1 + len(self.report_kinds)) * self.slots,), jnp.int32)
 
     def _build_programs(self) -> None:
         import jax
@@ -271,11 +282,12 @@ class PagedDecodeRuntime:
         # whether the host feeds the slot, the token it feeds, the position.
         # Every other slot feeds on ``previous``, the last step's ids, which
         # stayed on the device. Returns the step's output (ids, then what the
-        # model appends, then the token each slot was fed: a joined prompt's
-        # first id reaches the host here) and the ids alone, for the next
-        # launch.
+        # model appends, then what the prefills joined since the last step
+        # reported, then the token each slot was fed: a joined prompt's first
+        # id reaches the host here) and the ids alone, zeros after them, for
+        # the next launch.
         def step(params, host, previous, rows, state, bound):
-            tokens = jnp.where(host[0] != 0, host[1], previous)
+            tokens = jnp.where(host[0] != 0, host[1], previous[:slots])
             out, *rows, state = model.apply(params, tokens, *rows, state,
                                             host[2], bound,
                                             method="decode_step")
@@ -283,15 +295,20 @@ class PagedDecodeRuntime:
             # without the wrapper's concatenation (XLA fused it into a sparse
             # family's per-layer producers otherwise).
             out = jax.lax.optimization_barrier(out)
-            return (jnp.concatenate([out, tokens]), out[:slots], tuple(rows),
-                    state)
+            joined = previous[slots:]
+            return (jnp.concatenate([out, joined, tokens]),
+                    jnp.concatenate([out[:slots], jnp.zeros_like(joined)]),
+                    tuple(rows), state)
 
         # A wrapper for its name: the trace's module stays ``jit_insert``.
-        # ``token`` is the prefill's (1,) ids: the slot feeds on it next.
+        # ``token`` is the prefill's first id, which the slot feeds on next,
+        # and what the model appends to it: the slot's entry of each of
+        # ``ids``' parts.
         def insert(rows, state, ids, blocks, state_block, token, slot):
             return (kv_pool.insert_block(rows, blocks, slot),
                     state_pool.insert(state, state_block, slot),
-                    ids.at[slot].set(token[0]))
+                    ids.at[slot + slots * jnp.arange(token.shape[0])].set(
+                        token))
 
         self._programs = {
             "prefill": jax.jit(prefill),
@@ -330,6 +347,35 @@ class PagedDecodeRuntime:
         ``{name: (help, buckets)}``; empty from a model that reports
         nothing."""
         return getattr(self.servable.model, "step_report_series", {})
+
+    @property
+    def report_kinds(self) -> tuple:
+        """What the model's prefill appends to its first id, by name (its
+        ``prefill_report_kinds``); empty from a model that appends
+        nothing."""
+        return tuple(getattr(self.servable.model, "prefill_report_kinds", ()))
+
+    def _note_reports(self, reports) -> None:
+        """``reports``, ``slots`` entries a kind, came to the host with a
+        read: the column of a slot whose join still waits for its report is
+        that report, unless it is all zeros (a step launched before the
+        join's insert carries those)."""
+        if not reports.size:
+            return
+        reports = reports.reshape(-1, self.slots)
+        for slot in np.flatnonzero(reports.any(axis=0)).tolist():
+            if self._reports.get(slot, ()) is None:
+                self._reports[slot] = dict(zip(self.report_kinds,
+                                               reports[:, slot].tolist()))
+
+    def join_report(self, slot: int) -> dict:
+        """What the prefill last joined into ``slot`` reported, ``{kind:
+        count}``, once: from the read that brought its first id to the host
+        on (a step's ``fetch``, ``first_ids``, ``prefill_into``); ``{}``
+        before, after, and from a model that reports nothing."""
+        if self._reports.get(slot) is None:
+            return {}
+        return self._reports.pop(slot)
 
     def bucket_for(self, n: int) -> int:
         for b in self.prompt_buckets:
@@ -370,6 +416,7 @@ class PagedDecodeRuntime:
                 "insert", self._rows, self._state, self._ids, tuple(blocks),
                 state_block, token, np.int32(slot))
         self._joined.append(token)
+        self._reports[slot] = None
         return token, waited
 
     def prefill_report(self, n: int) -> dict:
@@ -397,10 +444,11 @@ class PagedDecodeRuntime:
         token, waited = self._dispatch_join(slot, tokens)
         t0 = time.perf_counter()
         with device_trace("ai4e.decode.join.run"):
-            first = int(token[0])   # waits for the prefill program's run
+            token = np.asarray(token)   # waits for the prefill program's run
         self._tell("behind_step", 0.0)
         self._tell("run", waited + time.perf_counter() - t0)
-        return first
+        self._reports[slot] = dict(zip(self.report_kinds, token[1:].tolist()))
+        return int(token[0])
 
     def first_ids(self) -> list:
         """Wait for everything dispatched and read the id the next launch
@@ -409,13 +457,14 @@ class PagedDecodeRuntime:
         t0 = time.perf_counter()
         try:
             with device_trace("ai4e.decode.device_wait"):
-                ids = np.asarray(self._ids).tolist()
+                ids = np.asarray(self._ids)
         except Exception:
             self._ids = None
             self._joined.clear()
             raise
         self._tell("device_wait", time.perf_counter() - t0)
-        return ids
+        self._note_reports(ids[self.slots:])
+        return ids[:self.slots].tolist()
 
     def bound_for(self, longest: int) -> int:
         """The smallest rung of ``step_bounds`` that holds every key a step
@@ -520,9 +569,11 @@ class PagedDecodeRuntime:
         self._tell("readback", now - t1)
         step.ids = out[:self.slots].tolist()
         step.fed = out[-self.slots:].tolist()
-        if out.shape[0] > 2 * self.slots:
+        joined = out.shape[0] - (1 + len(self.report_kinds)) * self.slots
+        self._note_reports(out[joined:-self.slots])
+        if joined > self.slots:
             step.report = self.servable.model.step_report(
-                out[self.slots:-self.slots], step.active)
+                out[self.slots:joined], step.active)
         return step
 
     def step(self, tokens, positions, active) -> list[int]:

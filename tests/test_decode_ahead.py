@@ -25,6 +25,7 @@
 
 import asyncio
 
+import numpy as np
 import pytest
 
 from ai4e_tpu.admission.deadline import DeadlineExceeded
@@ -731,6 +732,10 @@ class FakeArray:
     def __getitem__(self, index):
         self.waited.add(id(self))   # a read waits too
         return 7
+
+    def __array__(self, dtype=None, copy=None):
+        self.waited.add(id(self))   # the whole vector's read (PR 40) as well
+        return np.asarray([7], dtype)
 
 
 class FakePrograms(dict):

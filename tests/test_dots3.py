@@ -384,7 +384,7 @@ def test_the_eight_shares_add_up_to_the_uncut_expert_layer():
                                                     2 * share), *held))
                 else:
                     got = got + np.asarray(expert_layer.routed(
-                        h, top_e, top_p, *held, first_held=2 * share))
+                        h, top_e, top_p, *held, total=16, first_held=2 * share))
             assert np.abs(got - want).max() < 2e-5, form
     # and one share alone is the reference's share
     part = np.asarray(reference.moe(h, layer, spec, w, held=(6, 2)))
@@ -463,7 +463,9 @@ def test_the_runtime_serves_the_family_and_counts_its_cache():
     assert runtime._rows[2].shape == (5, 3, 4, 128)   # a ring, not max_len
 
 
-def test_the_engine_counts_selected_positions_and_prefill_work():
+def _served(requests: int, new_tokens: int):
+    """The registry of a ``DecodeEngine`` over ``_runtime()`` after
+    ``requests`` requests of one 11-token prompt, one after another."""
     import asyncio
 
     from ai4e_tpu.metrics.registry import MetricsRegistry
@@ -474,12 +476,69 @@ def test_the_engine_counts_selected_positions_and_prefill_work():
         engine = DecodeEngine(_runtime(), metrics=reg)
         await engine.start()
         try:
-            await engine.submit([5, 9, 12, 4, 4, 8, 1, 2, 3, 6, 7], 6)
+            for _ in range(requests):
+                await engine.submit([5, 9, 12, 4, 4, 8, 1, 2, 3, 6, 7],
+                                    new_tokens)
         finally:
             await engine.stop()
         return reg
 
-    reg = asyncio.run(main())
+    return asyncio.run(main())
+
+
+def test_a_join_report_comes_once_with_the_read_of_its_first_id():
+    """What a prefill appends to its first id (the passes of its seven
+    expert layers) stays on the device beside it and reaches the host with
+    the read that brings the id — the first step's fetch, ``first_ids``,
+    ``prefill_into`` — once: a later read that carries the same column
+    again is not taken twice."""
+    runtime = _runtime()
+    runtime.warm()
+    assert runtime.report_kinds == ("first", "extra")
+    prompt = np.random.default_rng(12).integers(0, 97, size=13).tolist()
+    token = runtime.servable.model.apply(
+        runtime.servable.params, np.asarray([prompt + [0] * 3], np.int32),
+        np.asarray([13], np.int32), method="prefill")[0]
+    want = dict(zip(runtime.report_kinds, token[1:].tolist()))
+    assert 5 <= want["first"] <= 7 and want["extra"] == 0
+
+    def step(positions):
+        return runtime.fetch(runtime.launch(
+            [None] * 3, positions, [p > 0 for p in positions]))
+
+    runtime.join(1, prompt)
+    assert runtime.join_report(1) == {}      # on the device still
+    step([0, 13, 0])
+    assert runtime.join_report(1) == want
+    assert runtime.join_report(1) == {}      # handed over once
+    step([0, 14, 0])
+    assert runtime.join_report(1) == {}      # the step zeroed its rows
+    runtime.join(0, prompt)                  # read alone, no step to carry it
+    runtime.first_ids()
+    assert runtime.join_report(0) == want
+    step([13, 15, 0])                        # carries slot 0's column again
+    assert runtime.join_report(0) == {}
+    runtime.prefill_into(2, prompt)          # the blocking join reads it
+    assert runtime.join_report(2) == want
+    step([14, 16, 13])
+    assert all(runtime.join_report(slot) == {} for slot in range(3))
+    runtime.reset_cache()
+    assert runtime._reports == {}
+
+
+@pytest.mark.parametrize("new_tokens", [1, 6])
+def test_the_engine_counts_the_passes_of_a_prefills_expert_layers(new_tokens):
+    """``ai4e_decode_prefill_expert_passes_total``: seven expert layers, one
+    pass each, whether a step carried the report (6 tokens) or the first id
+    was read alone (a request for one token)."""
+    passes = _served(2, new_tokens).counter(
+        "ai4e_decode_prefill_expert_passes_total")
+    assert passes.value(model="lm", kind="first") == 2 * 7
+    assert passes.value(model="lm", kind="extra") == 0
+
+
+def test_the_engine_counts_selected_positions_and_prefill_work():
+    reg = _served(1, 6)
     positions = reg.counter("ai4e_decode_kv_positions_total")
     assert positions.value(model="lm", kind="selected") == 5 * 8
     assert positions.value(model="lm", kind="live") == sum(range(12, 17))

@@ -303,6 +303,7 @@ def test_four_shares_and_one_shared_expert_sum_to_the_uncut_layer(form):
                 parts.append(expert_layer.dense(h, gate, *weights))
             else:
                 parts.append(expert_layer.routed(h, top_e, top_p, *weights,
+                                                 total=total,
                                                  first_held=first))
             # a share is what the reference gives for the same share
             share = dict(layer, w_gate=weights[0], w_up=weights[1],

@@ -179,9 +179,11 @@ def _compile_step(runtime, sharding, bound):
     from ai4e_tpu.runtime import kvcache
     pools = tuple(_on(sharding, spec) for spec in runtime.cache_spec())
     # What a launch hands it: the host's three rows a slot, and the last
-    # step's ids, which stayed on the device.
+    # step's ids, which stayed on the device (and after them, a slot a kind,
+    # what the model's prefill reports beside its first id).
     host = _on(sharding, ((3, runtime.slots), jnp.int32))
-    previous = _on(sharding, ((runtime.slots,), jnp.int32))
+    previous = _on(sharding, (
+        ((1 + len(runtime.report_kinds)) * runtime.slots,), jnp.int32))
     with pytest.MonkeyPatch.context() as patch:
         for kernel in (decode_attention, state_update):
             patch.setattr(kernel, "resolve_interpret",
