@@ -18,8 +18,12 @@ clump, the sizes ``k`` and the lengths the mid-quantiles of their log-normals
 window's epochs, each with its clump: every seed offers the same requests in
 the same clumps with the same neighbours, starting at another point of the
 cycle (PR 23's finding: a free shuffle per seed is the seed changing the
-work). A phase offers the whole clumps nearest its share of the rate, so the
-mean rate is ``rate_per_s`` to within half a clump a phase.
+work). A mix whose file gives ``rotation`` starts every seed at THAT point of
+the cycle, and ``--seed`` makes the bodies alone: where a step's time follows
+the live count, the starting point itself changes the work (``burstchat``,
+PR 42: two runs of one rotation read the gen p95 0.004 % apart, three
+rotations 6.2 % apart). A phase offers the whole clumps nearest its share of
+the rate, so the mean rate is ``rate_per_s`` to within half a clump a phase.
 """
 
 from __future__ import annotations
@@ -69,7 +73,7 @@ def schedule(traffic: dict, seconds: float, seed: int) -> list[dict]:
                  else 1.0)
         order = list(range(epochs))
         if phase == "window" and epochs:
-            k = seed % epochs
+            k = traffic.get("rotation", seed) % epochs
             order = order[k:] + order[:k]
         arrivals = []
         for i, at in zip(order, stats.due_times(

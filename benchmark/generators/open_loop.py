@@ -5,7 +5,10 @@ Traffic parameters: ``path``, ``mode`` (``async`` = submit → long-poll →
 result, ``sync`` = one call), ``rate_per_s``, ``ramp_s`` (arrivals at the
 cell's rate before the window, until occupancy has settled), ``drain_cap_s``
 (how long after the window requests due inside it are followed; what has not
-ended by then has failed), ``payload``, ``schedule_seed``, and for prompts
+ended by then has failed), ``payload``, ``schedule_seed``, ``rotation``
+(optional: the point of the cycle at which EVERY seed's window starts, for a
+mix where the starting point itself changes the work; without it ``--seed``
+chooses the point), and for prompts
 ``prompt_len`` / ``max_new_tokens`` (log-normal ``median``, ``sigma``,
 ``lo``, ``hi``).
 
@@ -54,7 +57,7 @@ def schedule(traffic: dict, seconds: float, seed: int) -> list[dict]:
         scale = span / sum(gaps) * (n / (n + 0.5)) if n else 1.0
         order = list(range(n))
         if phase == "window" and n:
-            k = seed % n
+            k = traffic.get("rotation", seed) % n
             order = order[k:] + order[:k]
         for i, due in zip(order, stats.due_times(
                 [gaps[i] * scale for i in order], start)):
@@ -102,7 +105,9 @@ async def run(ctx) -> dict:
                 due_abs = origin + a["due"]
                 if a["in_window"] and not opened:
                     await asyncio.sleep(max(0.0, t0 - ctx.now()))
-                    await ctx.window_start()
+                    await ctx.window_start([
+                        b["due"] - t["ramp_s"] for b in arrivals
+                        if b["in_window"]])
                     opened = True
                 await asyncio.sleep(max(0.0, due_abs - ctx.now()))
                 tasks[a["counter"]] = asyncio.ensure_future(

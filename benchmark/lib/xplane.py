@@ -1,6 +1,7 @@
 """Reduce a JAX profiler trace (``*.xplane.pb``) to what the benchmark
 reports: device busy time, the traced window, time per XLA module, the
-device operations that took most time, and the longest idle gaps.
+device operations that took most time, and the longest idle gaps (with an
+interval, the idle at its two edges among them).
 
 Run as a child pinned to the host CPU (``ProfileData`` lives in JAX):
 ``python benchmark/lib/xplane.py <trace dir or file> <out.json> [<from_s> <to_s>]``.
@@ -105,8 +106,14 @@ def reduce_planes(planes: list[dict],
         first, last = interval_ns
     gaps = []
     merged = devices[0]["merged"]
+    if interval_ns is not None:
+        # The interval's own edges bound the idle before its first event and
+        # after its last (an interval that opens on an arrival ends in the
+        # schedule's lull: its longest gap).
+        merged = [(first, first), *merged, (last, last)]
     for (_, end), (start, _) in zip(merged, merged[1:]):
-        gaps.append((start - end, end))
+        if start > end:
+            gaps.append((start - end, end))
     gaps.sort(reverse=True)
     return {
         "devices": n,

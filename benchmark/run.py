@@ -45,8 +45,8 @@ sys.path.insert(0, ROOT)
 from benchmark.lib import e2e, prom, stats  # noqa: E402
 from benchmark.lib.payloads import KINDS as PAYLOAD_KINDS  # noqa: E402
 from benchmark.lib.stack import LIB, Stack, StartError  # noqa: E402
+from benchmark.lib.tracing import TRACE_SECONDS, trace_instant  # noqa: E402
 
-TRACE_SECONDS = 4.0      # of the steady window, under --trace 1
 HELPER_TIMEOUT_S = 240.0
 
 
@@ -109,6 +109,7 @@ class RunContext:
         self.setup_s = None
         self.prom_before = self.prom_after = None
         self.gauge_samples: list[dict] = []
+        self.trace_instant_s = self.trace_written_epoch = None
         self._sampler = None
         self._payloads = None
 
@@ -132,16 +133,22 @@ class RunContext:
             await asyncio.sleep(1.0)
             self.gauge_samples.append(await self._scrape())
 
-    async def _trigger_trace(self) -> None:
-        await asyncio.sleep(max(0.0, (self.seconds - TRACE_SECONDS) / 2))
+    async def _trigger_trace(self, dues) -> None:
+        self.trace_instant_s = trace_instant(self.seconds, dues)
+        await asyncio.sleep(self.trace_instant_s)
         open(os.path.join(self.work, "trace.start"), "w").close()
+        self.trace_written_epoch = time.time()
 
-    async def window_start(self) -> float:
-        """The first request of the window is about to be offered."""
+    async def window_start(self, dues=None) -> float:
+        """The first request of the window is about to be offered. ``dues``:
+        the instants, in seconds from now, at which the generator's schedule
+        has the window's arrivals due (``lib/tracing.py`` places the traced
+        seconds on one of them); a generator that has no schedule gives
+        none."""
         if self.trace:
             self.prom_before = await self._scrape()
             self._sampler = [asyncio.ensure_future(self._sample_gauges()),
-                             asyncio.ensure_future(self._trigger_trace())]
+                             asyncio.ensure_future(self._trigger_trace(dues))]
         t0 = self.now()
         self.setup_s = t0 - T_START
         log(f"window open after {self.setup_s:.1f}s of set-up")
@@ -208,6 +215,26 @@ def label_gaps(trace: dict, trace_done: dict, ledgers) -> list[list]:
             if abs(near[0] - at) < 1.0:
                 label += f" near {near[1]}"
         out.append([label, gap["seconds"]])
+    return out
+
+
+# (number, its limit) as the references' verdicts name them. A family whose
+# verdict counts the tokens beyond the share margin is held to that count,
+# not to the share it also reports.
+COMPARED = (("worst_margin", "limit_margin"), ("beyond", "allowed_beyond"),
+            ("share_beyond", "limit_share"),
+            ("worst_pixels_moved", "limit_pixels"))
+
+
+def compared(verdict: dict, gen: dict, compiles) -> dict:
+    """Every number ``correct`` rests on, beside its limit."""
+    out = {"failed": [gen["failed"], 0]}
+    if compiles is not None:
+        out["compiles_in_window"] = [compiles, 0]
+    for value, limit in COMPARED:
+        if value in verdict and limit in verdict and not (
+                value == "share_beyond" and "beyond" in out):
+            out[value] = [verdict[value], verdict[limit]]
     return out
 
 
@@ -349,6 +376,7 @@ def main() -> int:
 
     metrics: dict[str, dict] = {}
     notes: dict = {}
+    compiles = None
     if not trace:
         for m in cell.metrics("end_to_end"):
             if m["name"] == "setup_s":
@@ -408,6 +436,13 @@ def main() -> int:
             "device_ops": trace_summary["device_ops"],
             "idle_gaps": label_gaps(trace_summary, trace_done,
                                     gen["ledgers"])}
+        if ctx.trace_written_epoch is not None:
+            # How long the launcher took from ``trace.start`` to the interval
+            # it keeps: what ``TRACE_LEAD_S`` has to cover.
+            notes["trace_placement"] = {
+                "instant_s": ctx.trace_instant_s,
+                "lead_measured_s": (trace_done["interval_epoch"][0]
+                                    - ctx.trace_written_epoch)}
         result["notes"] = notes
     if args.set:
         result["overridden"] = args.set
@@ -417,7 +452,12 @@ def main() -> int:
         result["rehearsal"] = True
         result["rehearsal_metrics"] = result.pop("metrics")
         result["metrics"] = {}
+    # Last in the line and last on standard error: what was compared.
+    result["compared"] = compared(verdict, gen, compiles)
     write_json_atomic(os.path.join(work, "result.json"), result)
+    for name, (value, limit) in result["compared"].items():
+        print(f"compared: {name} {value} (limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

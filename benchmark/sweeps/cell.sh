@@ -14,6 +14,9 @@
 #   ... cell.sh pairs <workload> <parent dir> <seed>...
 #       untraced 51 s runs, each seed on a parent checkout (a directory of
 #       this tree) and on this tree: parent, change, change, parent, ...
+#       (TRACE=1 in the environment: traced runs, every per-layer metric of
+#       each side in the short line - how PR 42 held the folded names to the
+#       parent's series)
 cd "$(dirname "$0")/../.."
 mode=$1; w=$2; shift 2
 mkdir -p chiprun_out/cell
@@ -78,9 +81,9 @@ pairs)
   for seed in "$@"; do
     for side in $order; do
       [ $side = parent ] && dir=$parent || dir=$here
-      out=$here/chiprun_out/cell/${w}_${side}_${seed}.txt
-      (cd $dir && python3 benchmark/run.py --workload $w --seed $seed --seconds 51 --trace 0 > $out 2>&1)
-      echo "== [$side] $w seed $seed rc=$?"; line $out
+      out=$here/chiprun_out/cell/${w}_${side}_t${TRACE:-0}_${seed}.txt
+      (cd $dir && python3 benchmark/run.py --workload $w --seed $seed --seconds 51 --trace ${TRACE:-0} > $out 2>&1)
+      echo "== [$side] $w seed $seed trace ${TRACE:-0} rc=$?"; line $out
     done
     [ "$order" = "parent change" ] && order="change parent" || order="parent change"
   done;;

@@ -24,11 +24,16 @@ With seeds it prints each; with ``--scan`` the eight steadiest of the range.
 It chooses nothing on its own: ``sweeps/<workload>.md`` says which seed was
 taken and what the chip read. ``qnext.docqa`` (PR 32): ``--tick 0.0215 --join
 0.006 --join-token 0.000068``.
+
+``traced_instant`` (PR 42) is where a traced run of a cell opens its traced
+seconds under one rotation: the cell's own schedule through
+``lib/tracing.trace_instant``, the rule ``run.py`` applies.
 """
 
 from __future__ import annotations
 
 import argparse
+import importlib
 import json
 import os
 import sys
@@ -38,14 +43,37 @@ sys.path.insert(0, HERE)
 
 import schedule_model as model  # noqa: E402
 from benchmark.generators.open_loop import schedule  # noqa: E402
+from benchmark.lib.tracing import trace_instant  # noqa: E402
+
+
+def load(*parts):
+    with open(os.path.join(model.ROOT, *parts)) as f:
+        return json.load(f)
+
+
+def traced_instant(workload: str, rotation: int,
+                   seconds: float = model.WINDOW_S,
+                   manifest: str = "BENCHMARK.json"):
+    """``(instant, dues)``: the seconds of the window at which a traced run of
+    the cell at ``--seed rotation`` writes ``trace.start``, and the due
+    instants of the window's arrivals it chose among (None for a generator
+    without a schedule, which keeps the window's middle)."""
+    cell = next(w for w in load(manifest)["workloads"]
+                if w["name"] == workload)
+    traffic = load("benchmark", "traffic", cell["traffic"] + ".json")
+    generator = importlib.import_module(
+        "benchmark.generators." + traffic["generator"])
+    dues = None
+    if hasattr(generator, "schedule"):
+        dues = [a["due"] - traffic["ramp_s"]
+                for a in generator.schedule(traffic, seconds, rotation)
+                if a["in_window"]]
+    return trace_instant(seconds, dues), dues
 
 
 def configure(workload: str, overrides: list[str]) -> dict:
     """The cell's mix; ``schedule_model``'s slots and buckets set to the
     cell's."""
-    def load(*parts):
-        with open(os.path.join(model.ROOT, *parts)) as f:
-            return json.load(f)
     manifest = load("BENCHMARK.json")
     cell = next(w for w in manifest["workloads"] if w["name"] == workload)
     config = load(next(c["file"] for c in manifest["configs"]

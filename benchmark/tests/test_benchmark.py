@@ -79,6 +79,11 @@ def test_every_seed_offers_the_same_work_in_another_order():
     gaps = np.diff([2.0] + [x["due"] for x in win])
     assert math.isclose(gaps.mean(), 0.2, rel_tol=0.02)   # the fixed rate
     assert all(x["prompt_len"] + x["max_new_tokens"] <= 1024 for x in a)
+    # a mix that gives ``rotation`` starts every seed at that point
+    fixed = dict(traffic, rotation=7)
+    assert open_loop.schedule(fixed, 20.0, seed=1) == open_loop.schedule(
+        fixed, 20.0, seed=2 ** 31 + 5) == open_loop.schedule(
+        traffic, 20.0, seed=7)
 
 
 def test_prom_parse_and_delta():
@@ -327,6 +332,47 @@ def _dump(obj, path: str) -> None:
         json.dump(obj, f, indent=1)
 
 
+# The two bounds PR 42's re-check loosened after the check refused them as
+# too tight (``PERF.md`` section 2): an end-to-end entry of an older parent is
+# held to the manifest with the bound it has now.
+LOOSENED = {"token_latency_p95_ms": (0.045, 0.1),
+            "gen_latency_p95_ms": (0.02, 0.06)}
+
+
+def _as_it_stands(was: dict) -> dict:
+    before, now = LOOSENED.get(was["name"], (None, None))
+    return dict(was, bound=now) if was.get("bound") == before else was
+
+
+def _manifest_since(parent: str) -> tuple[dict, dict]:
+    """``BENCHMARK.json`` at ``parent`` and now, after holding what no PR
+    since may have touched — a ``benchmark`` PR's harness, metric files and
+    ``per_layer`` list apart (PR 42 folded those): every configuration, mix,
+    reference and end-to-end definition the parent had has the same bytes,
+    and the manifest's ``command``, ``paths``, ``run_seconds``, ``configs``
+    and ``workloads`` differ only by entries appended. Skips where the
+    parent commit is not in reach."""
+    def git(*args):
+        return subprocess.run(["git", "-C", ROOT, *args], capture_output=True,
+                              text=True)
+    if git("cat-file", "-e", parent).returncode:
+        pytest.skip("the parent commit is not in this checkout")
+    kept = [os.path.join("benchmark", d)
+            for d in ("configs", "traffic", "references", "end_to_end")]
+    changed = git("diff", "--name-status", parent, "--",
+                  *kept).stdout.split("\n")
+    edited = [line for line in changed if line and not line.startswith("A")]
+    assert edited == [], edited
+    old = json.loads(git("show", parent + ":BENCHMARK.json").stdout)
+    new = _load(os.path.join(ROOT, "BENCHMARK.json"))
+    for key in ("command", "paths", "run_seconds"):
+        assert new[key] == old[key]
+    for key in ("configs", "workloads"):
+        assert new[key][:len(old[key])] == old[key], key
+    old["end_to_end"] = [_as_it_stands(m) for m in old["end_to_end"]]
+    return old, new
+
+
 def _copy_of_the_tree(root: str) -> None:
     shutil.copytree(os.path.join(ROOT, "benchmark"),
                     os.path.join(root, "benchmark"),
@@ -398,6 +444,11 @@ def test_rehearsal(cpu_root, workload, trace):
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(line)
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
+    # what was compared comes last, each number beside its limit, and ends
+    # standard error too
+    assert list(line)[-1] == "compared" and line["compared"]["failed"] == [0, 0]
+    assert all(len(pair) == 2 for pair in line["compared"].values())
+    assert proc.stderr.strip().splitlines()[-1].startswith("compared: ")
     assert set(line["device"]) >= {"platform", "kind", "count",
                                    "memory_peak_bytes"}
     # never a device result: no number under a device metric's name

@@ -28,12 +28,16 @@ from test_benchmark import _load, _run, cpu_root, test_rehearsal  # noqa: E402,F
 
 CELL = "dots3.longdoc"
 CONFIG = "dots3-note-prev"
-# Eighteen of the thirty-two ISSUE 39 names: the manifest holds 128 per-layer
+# Eighteen of the thirty-two ISSUE 39 names: the manifest held 128 per-layer
 # metrics at most and had 110 (PERF.md section 3 names the fourteen left out:
-# the window's share of the cache bytes is 100 less the other two).
-TICK_SET = tuple(name + ".longdoc" for name in (
-    "engine_itl_ms", "engine_ttft_ms", "tick_device_wait_ms", "tick_host_ms",
-    "step_ms", "queue_wait_ms", "queue_wait_joins_ms", "queue_wait_tick_ms",
+# the window's share of the cache bytes is 100 less the other two). One entry
+# a metric since PR 42: the four that move the token p95 in every cell are
+# folded (no mix in the name), the six that move the gen p95 elsewhere keep
+# their ``.longdoc`` entry, ``step_ms`` is retired (the tick's phases summed).
+FOLDED = ("engine_ttft_ms", "queue_wait_ms", "queue_wait_joins_ms",
+          "queue_wait_tick_ms")
+TICK_SET = FOLDED + tuple(name + ".longdoc" for name in (
+    "engine_itl_ms", "tick_device_wait_ms", "tick_host_ms",
     "step_active_slots", "device_idle_queued_share", "kv_useful_share"))
 NEW_COUNTERS = tuple(name + ".longdoc" for name in (
     "latent_bytes_share", "index_bytes_share", "selected_share",
@@ -59,7 +63,7 @@ def test_rehearsal_reports_the_cache_and_prefill_metrics(cpu_root):  # noqa: F81
         "latent_bytes_share", "index_bytes_share")]
     assert all(0 < s < 100 for s in shares)
     assert 0 < 100.0 - sum(shares) < 100      # the rest: the window's rings
-    assert got["step_ms.longdoc"]["value"] > 0
+    assert got["engine_itl_ms.longdoc"]["value"] > 0
     # prompts of 20-60 under a selection of 8: most of a context is left out
     assert 5 < got["selected_share.longdoc"]["value"] < 50
     assert 30 < got["prefill_real_share.longdoc"]["value"] <= 100
@@ -68,8 +72,9 @@ def test_rehearsal_reports_the_cache_and_prefill_metrics(cpu_root):  # noqa: F81
 
 def test_the_entries_exist_and_agree_with_the_files():
     """The manifest has the configuration, the cell and its metrics, each
-    naming only this cell and each with its file; where they stand in their
-    lists is a later PR's to change."""
+    listing this cell (alone where no other cell shares the entry) and each
+    with its file; where they stand in their lists is a later PR's to
+    change."""
     manifest = _load(os.path.join(ROOT, "BENCHMARK.json"))
     config, = (c for c in manifest["configs"] if c["name"] == CONFIG)
     body = _load(os.path.join(ROOT, config["file"]))
@@ -83,7 +88,9 @@ def test_the_entries_exist_and_agree_with_the_files():
     assert len(cell["why"]) <= 200 and len(config["why"]) <= 200
     by_name = {m["name"]: m for m in manifest["per_layer"]}
     for name in METRICS:
-        assert by_name[name]["workloads"] == [CELL]
+        assert CELL in by_name[name]["workloads"]
+        if name not in FOLDED:
+            assert by_name[name]["workloads"] == [CELL]
         definition = _load(os.path.join(ROOT, "benchmark", "layer_metrics",
                                         name + ".json"))
         importlib.import_module("benchmark.readers." + definition["reader"])

@@ -12,7 +12,6 @@ from __future__ import annotations
 import importlib
 import json
 import os
-import subprocess
 import sys
 
 import numpy as np
@@ -24,23 +23,23 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, HERE)
 
 from benchmark.lib import prom  # noqa: E402
-from test_benchmark import _load, _run, cpu_root, test_rehearsal  # noqa: E402,F401 — cpu_root is a fixture
+from test_benchmark import (  # noqa: E402,F401 — cpu_root is a fixture
+    _load, _manifest_since, _run, cpu_root, test_rehearsal)
 
 CELL = "qnext.docqa"
 PARENT = "d516040db90cd05f785cbb75498e3ea295f4ba51"
+# One entry a metric since PR 42 (PR 32's names carried the mix where another
+# cell has the metric too; ``step_ms`` is retired: the tick's phases summed).
 METRICS = (
-    "engine_itl_ms.docqa", "tick_device_wait_ms.docqa", "tick_host_ms.docqa",
-    "tick_admit_ms.docqa", "step_ms.docqa", "prefill_ms.docqa",
-    "queue_wait_ms.docqa", "step_active_slots.docqa", "kv_useful_share.docqa",
-    "experts_touched.docqa", "expert_peak_load.docqa",
-    "held_picks_share.docqa", "state_bytes_share.docqa",
-    "qnext_step_roofline",
+    "engine_itl_ms", "tick_device_wait_ms", "tick_host_ms", "tick_admit_ms",
+    "prefill_ms", "queue_wait_ms", "step_active_slots", "kv_useful_share",
+    "experts_touched", "expert_peak_load", "held_picks_share.docqa",
+    "state_bytes_share", "qnext_step_roofline",
     # the layers above the engine, which run here as in ``gpt2m.chat``
-    "fabric_ms.docqa", "fabric_queue_ms.docqa", "fabric_deliver_ms.docqa",
-    "shell_in_ms.docqa", "shell_out_ms.docqa", "engine_ttft_ms.docqa",
-    "slot_occupancy.docqa")
-# what the parent's worker already exposes: these read on its program too
-OLD_SERIES = {"step_ms.docqa": 50.0}
+    "fabric_ms", "fabric_queue_ms", "fabric_deliver_ms", "shell_in_ms",
+    "shell_out_ms", "engine_ttft_ms", "slot_occupancy")
+# the metrics of the block that only this cell has
+OWN = ("held_picks_share.docqa", "qnext_step_roofline")
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -55,55 +54,43 @@ def test_rehearsal_reports_the_state_and_routing_metrics(cpu_root):  # noqa: F81
     got = line["rehearsal_metrics"]
     # every counter-borne metric; the trace-borne roofline needs a chip
     assert set(METRICS) - {"qnext_step_roofline"} <= set(got)
-    assert 0 < got["experts_touched.docqa"]["value"] <= 4
+    assert 0 < got["experts_touched"]["value"] <= 4
     assert 0 < got["held_picks_share.docqa"]["value"] < 100
-    assert 0 < got["state_bytes_share.docqa"]["value"] < 100
+    assert 0 < got["state_bytes_share"]["value"] < 100
     assert "compile phases inside the window: 0" in proc.stdout
 
 
 def test_entries_are_appended_and_name_only_the_new_cell():
-    """PR 32's entries follow everything the parent had, in one block, and
-    name only the new cell (a later PR appends after them: the block is
-    found by its first name, not by being last)."""
+    """Membership, not position: every metric of PR 32's block lists the
+    cell, and the ones no other cell has list it alone (PR 42 folded the
+    per-cell copies into one entry a metric)."""
     manifest = _load(os.path.join(ROOT, "BENCHMARK.json"))
-    names = [m["name"] for m in manifest["per_layer"]]
-    start = names.index(METRICS[0])
-    assert tuple(names[start:start + len(METRICS)]) == METRICS
-    assert "moe_step_roofline" in names[:start]     # the parent's last
+    entries = {m["name"]: m for m in manifest["per_layer"]}
+    assert set(METRICS) <= set(entries)
     cell, = (w for w in manifest["workloads"] if w["name"] == CELL)
     assert cell == {"name": CELL, "config": "qwen3-next-80b-a3b",
                     "traffic": "docqa", "chips": 1, "why": cell["why"]}
     assert "qwen3-next-80b-a3b" in [c["name"] for c in manifest["configs"]]
-    for m in manifest["per_layer"][start:start + len(METRICS)]:
-        assert m["workloads"] == [CELL]
+    for name in METRICS:
+        assert CELL in entries[name]["workloads"]
+    for name in OWN:
+        assert entries[name]["workloads"] == [CELL]
     for m in manifest["end_to_end"]:
         if m["name"] != "setup_s":
             assert CELL in m["workloads"]
 
 
 def test_nothing_the_benchmark_had_is_edited():
-    """Against the parent commit: every file it has under ``benchmark/`` has
-    the same bytes, and ``BENCHMARK.json`` differs only by entries appended
-    to its lists (the cell's name at the end of the two latency metrics'
-    ``workloads``). Skipped where the parent commit is not in reach."""
-    def git(*args):
-        return subprocess.run(["git", "-C", ROOT, *args], capture_output=True,
-                              text=True)
-    if git("cat-file", "-e", PARENT).returncode:
-        pytest.skip("the parent commit is not in this checkout")
-    changed = git("diff", "--name-status", PARENT, "--", "benchmark",
-                  "BENCHMARK.json").stdout.split("\n")
-    edited = [line for line in changed if line and not line.startswith("A")]
-    assert edited == ["M\tBENCHMARK.json"], edited
-    old = json.loads(git("show", PARENT + ":BENCHMARK.json").stdout)
-    new = _load(os.path.join(ROOT, "BENCHMARK.json"))
-    for key in ("command", "paths", "run_seconds"):
-        assert new[key] == old[key]
-    for key in ("configs", "workloads", "per_layer"):
-        assert new[key][:len(old[key])] == old[key], key
+    """Against PR 32's parent commit (``test_benchmark._manifest_since``):
+    the cell's name follows the parent's at the end of the two latency
+    metrics' ``workloads``, and nothing else of an end-to-end entry
+    differs."""
+    old, new = _manifest_since(PARENT)
     for was, now in zip(old["end_to_end"], new["end_to_end"], strict=True):
         if "workloads" in was:
-            assert now == dict(was, workloads=was["workloads"] + [CELL])
+            given = was["workloads"] + [CELL]
+            assert now == dict(was, workloads=now["workloads"])
+            assert now["workloads"][:len(given)] == given
         else:
             assert now == was
 
@@ -122,16 +109,12 @@ def test_metric_is_silent_on_the_parents_program(name):
     ctx = {"prom_before": {}, "prom_after": old, "ledgers": [],
            "config": {"derived": {}}, "gauge_samples": [], "notes": {},
            "trace": None}
-    value = reader.read(definition, ctx)
-    if name in OLD_SERIES:
-        assert value == pytest.approx(OLD_SERIES[name])
-    else:
-        assert value is None
+    assert reader.read(definition, ctx) is None
 
 
 def test_state_bytes_share_reads_the_two_kinds():
     definition = _load(os.path.join(
-        ROOT, "benchmark", "layer_metrics", "state_bytes_share.docqa.json"))
+        ROOT, "benchmark", "layer_metrics", "state_bytes_share.json"))
     reader = importlib.import_module(
         "benchmark.readers." + definition["reader"])
     after = prom.parse(
@@ -202,7 +185,9 @@ def test_configuration_holds_every_published_number():
                        "max_position_embeddings"}
     assert set(config["reduced"]) == differs | {"weights"}
     manifest = _load(os.path.join(ROOT, "BENCHMARK.json"))
-    assert set(manifest["configs"][-1]["reduced"]) == set(config["reduced"])
+    entry, = (c for c in manifest["configs"]
+              if c["name"] == "qwen3-next-80b-a3b")
+    assert set(entry["reduced"]) == set(config["reduced"])
     spec = config["models"]["models"][0]
     assert (spec["dim"], spec["heads"], spec["kv_heads"], spec["head_dim"],
             spec["lin_k_heads"], spec["lin_v_heads"], spec["lin_dim"],

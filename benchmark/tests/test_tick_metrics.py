@@ -23,11 +23,11 @@ from benchmark.lib import prom, xplane, xplane_spans  # noqa: E402
 from benchmark.readers import counter_ratio, prom_mean_sum  # noqa: E402
 from test_benchmark import _load, _run, cpu_root  # noqa: E402,F401 — cpu_root is a fixture
 
+# One entry a metric since PR 42: the names PR 24 gave carried the mix.
 NEW_METRICS = (
-    "tick_device_wait_ms.chat", "tick_host_ms.chat", "tick_admit_ms.chat",
-    "queue_wait_ms.chat", "step_active_slots.chat", "kv_useful_share.chat",
-    "shell_in_ms.chat", "shell_out_ms.chat", "fabric_queue_ms.chat",
-    "fabric_deliver_ms.chat")
+    "tick_device_wait_ms", "tick_host_ms", "tick_admit_ms", "queue_wait_ms",
+    "step_active_slots", "kv_useful_share", "shell_in_ms", "shell_out_ms",
+    "fabric_queue_ms", "fabric_deliver_ms")
 
 
 def _tick_scrape(ticks: int, seconds: dict) -> dict:
@@ -83,14 +83,12 @@ def test_counter_ratio():
 @pytest.mark.parametrize("name", NEW_METRICS)
 def test_new_metric_is_declared_and_silent_on_the_parents_program(name):
     """Each new metric has its definition file, a reader that exists, an
-    entry in ``BENCHMARK.json`` at the end of ``per_layer`` — and on a
-    program without the new series and stamps it returns nothing."""
+    entry in ``BENCHMARK.json``'s ``per_layer`` that lists the chat cell —
+    and on a program without the new series and stamps it returns nothing."""
     manifest = _load(os.path.join(ROOT, "BENCHMARK.json"))
     entry = next(m for m in manifest["per_layer"] if m["name"] == name)
-    assert entry["workloads"] == ["gpt2m.chat"]
+    assert "gpt2m.chat" in entry["workloads"]
     assert entry["moves"] in {m["name"] for m in manifest["end_to_end"]}
-    names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-len(NEW_METRICS):] == list(NEW_METRICS)
     definition = dict(_load(os.path.join(
         ROOT, "benchmark", "layer_metrics", name + ".json")), name=name)
     reader = importlib.import_module(
@@ -221,13 +219,13 @@ def test_rehearsal_reports_every_new_metric(cpu_root):  # noqa: F811
     assert line["correct"] is True and line["rehearsal"] is True
     reported = line["rehearsal_metrics"]
     assert set(NEW_METRICS) <= set(reported), sorted(reported)
-    tick = (reported["tick_device_wait_ms.chat"]["value"]
-            + reported["tick_host_ms.chat"]["value"]
-            + reported["tick_admit_ms.chat"]["value"])
+    tick = (reported["tick_device_wait_ms"]["value"]
+            + reported["tick_host_ms"]["value"]
+            + reported["tick_admit_ms"]["value"])
     # The eight phases partition the step-to-step interval: their means
     # add up to the mean gap between a stream's tokens.
-    assert tick == pytest.approx(reported["engine_itl_ms.chat"]["value"],
+    assert tick == pytest.approx(reported["engine_itl_ms"]["value"],
                                  rel=0.15)
-    assert 0 < reported["kv_useful_share.chat"]["value"] <= 100
-    assert 1 <= reported["step_active_slots.chat"]["value"] <= 8
+    assert 0 < reported["kv_useful_share"]["value"] <= 100
+    assert 1 <= reported["step_active_slots"]["value"] <= 8
     assert "compile phases inside the window: 0" in proc.stdout
