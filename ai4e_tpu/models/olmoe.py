@@ -98,11 +98,38 @@ def rms_norm(x, scale, eps):
     return (h * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x, position, theta):
+def yarn_inv_freq(dim: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's rotary frequencies of a rotated width ``dim``, ``(dim / 2,)``
+    float32: ``f_i = θ^(−2i/dim)`` kept where a pair turns more than
+    ``beta_fast`` times over the ``original`` positions, divided by
+    ``factor`` where it turns fewer than ``beta_slow`` times, blended
+    linearly between (``r_i`` from 0 at pair ``low`` to 1 at ``high``)."""
+    def pair(turns):   # the pair that turns ``turns`` times over ``original``
+        return (dim * np.log(original / (2 * np.pi * turns))
+                / (2 * np.log(theta)))
+
+    low = max(int(np.floor(pair(beta_fast))), 0)
+    high = min(int(np.ceil(pair(beta_slow))), dim - 1)
+    i = np.arange(dim // 2, dtype=np.float64)
+    f = theta ** (-2.0 * i / dim)
+    r = np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (f * (1.0 - r) + f / factor * r).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's ``m(a) = 0.1 a ln(factor) + 1`` (1 where nothing is scaled)."""
+    return 0.1 * mscale * float(np.log(factor)) + 1.0 if factor > 1 else 1.0
+
+
+def rope(x, position, theta, inv_freq=None):
     """Rotate-half rotary embedding of ``x (..., heads, head_dim)`` at
-    ``position (...)``, in float32, cast back."""
+    ``position (...)``, in float32, cast back. ``inv_freq (head_dim / 2,)``,
+    where a family scales its frequencies (``yarn_inv_freq``), stands in for
+    ``theta``'s own."""
     half = x.shape[-1] // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = position.astype(jnp.float32)[..., None, None] * inv_freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     h = x.astype(jnp.float32)

@@ -687,6 +687,25 @@ def build_dots3_lm(name: str = "lm", vocab_size: int = 512,
                       vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
 
 
+def build_xing4_lm(name: str = "lm", vocab_size: int = 512,
+                   max_len: int = 256, eos_id: int | None = None,
+                   rng=None, dtype: str = "bfloat16", **dims):
+    """The hyper-connected latent-attention decoder (``models/xing4.py``
+    ``Xing4LM``): ``streams`` residual streams a token, mixed around every
+    sublayer through a Sinkhorn-normalised matrix (``ops/mhc.py``); dense
+    latent attention under YaRN, one latent row a position; ``dense_layers``
+    leading dense MLPs, then sigmoid-routed experts, all held, with an
+    ungated shared expert; untied head, bfloat16 weights and cache.
+    ``dims``: the model's fields; a key the family does not know is an
+    error, not a default."""
+    from ..models.xing4 import create_xing4_lm
+    from .kvcache import LMServable
+    model, params = create_xing4_lm(rng=rng, vocab_size=vocab_size,
+                                    dtype=dtype, **dims)
+    return LMServable(name=name, model=model, params=params,
+                      vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
+
+
 # LM families ride the decode engine (``runtime/decode.py``), never the
 # MicroBatcher: ``cli`` tells them from the batch families by this table.
 LM_FAMILIES = {
@@ -695,6 +714,7 @@ LM_FAMILIES = {
     "qwen3-next": build_qwen3_next_lm,
     "granite-hybrid": build_granite_hybrid_lm,
     "dots3": build_dots3_lm,
+    "xing4": build_xing4_lm,
 }
 
 

@@ -244,6 +244,33 @@ def test_the_latent_kernel_is_the_softmax_over_shared_rows(
     assert np.abs(np.asarray(got, np.float64)[live] - want[live]).max() < tol
 
 
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 3e-2)])
+def test_a_global_latent_pool_is_read_whole_without_a_selection(dtype, tol):
+    """``keep=None`` on a GLOBAL pool (no ring, no indexer: the ``xing4``
+    family's read), through ``kv_pool.latent_decode_attention`` with the
+    block ``read_block`` gives a 640-lane row, at a bound above one block:
+    every block under a slot's position is read unmasked, a slot past the
+    bound reads the bound, and what lies above a position is never seen."""
+    rng = np.random.default_rng(32)
+    slots, length, heads, row, value = 4, 2048, 32, 640, 512
+    pool = jnp.asarray(rng.standard_normal((2, slots, length, row)) * 0.3,
+                       dtype)
+    block = kv_pool.read_block(pool.shape, pool.dtype)
+    bound = 1536
+    assert block < bound < length          # 768 (bfloat16) / 384 (float32)
+    position = np.asarray([0, block - 1, block + 5, 1990], np.int32)
+    q = jnp.asarray(rng.standard_normal((slots, heads, row)) * 0.2, dtype)
+    new = jnp.asarray(rng.standard_normal((slots, row)) * 0.3, dtype)
+    got = kv_pool.latent_decode_attention(
+        q, new, pool, 1, jnp.asarray(position), value=value, bound=bound,
+        scale=0.07, interpret=True)
+    assert got.shape == (slots, heads, value) and got.dtype == q.dtype
+    want = _latent_by_numpy(q, new, pool, 1, np.minimum(position, bound),
+                            value, 0.07, None, np.ones(slots))
+    live = position > 0
+    assert np.abs(np.asarray(got, np.float64)[live] - want[live]).max() < tol
+
+
 def _prompt_by_numpy(q, k, v, scale, mask, window):
     f = np.float64
     q, k, v = (np.asarray(a, f) for a in (q, k, v))   # (P, H, d)

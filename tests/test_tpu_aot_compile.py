@@ -716,3 +716,75 @@ def test_dots3_step_at_the_benchmark_cell_moves_no_pool(
               f"{prefill.temp_size_in_bytes} + outputs "
               f"{prefill.output_size_in_bytes}, peak {peak}")
         assert peak < 15.5e9, (top, peak, prefill.temp_size_in_bytes)
+
+
+@pytest.fixture(scope="module")
+def xing4_cell():
+    from ai4e_tpu.models.xing4 import Xing4LM, create_xing4_lm
+    from benchmark.references.xing4 import NOT_MODEL_KEYS
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "xing4.0-29b-a4b.json")) as f:
+        spec = json.load(f)["models"]["models"][0]
+    return _benchmark_cell(
+        "xing4.0-29b-a4b.json", create_xing4_lm, Xing4LM,
+        [key for key in spec if key not in NOT_MODEL_KEYS])
+
+
+@pytest.mark.parametrize("rung", [0, 1])
+def test_xing4_step_at_the_benchmark_cell_moves_no_pool(
+        v5e_sharding, xing4_cell, rung):
+    """The ``xing4.reason`` cell (``benchmark/configs/xing4.0-29b-a4b.json``):
+    one tensor of latent rows ``(7, 32, 4096, 640)`` made only by row writes
+    on its donated parameter, one ``latent_attention`` Mosaic call a layer
+    whose pool operand is the parameter itself, aliased input to output. At
+    the top rung, the whole worker's memory: weights + pool + the widest
+    prefill's (2,048, and the cache length 4,096 the runtime adds)
+    temporaries and outputs stay under 15 GB."""
+    import importlib
+    import re
+    runtime, spec = xing4_cell
+    assert runtime.step_bounds == (3072, 4096)
+    shape = (7, 32, 4096, 640)
+    assert runtime.cache_spec() == ((shape, jnp.bfloat16),)
+    bound = runtime.step_bounds[rung]
+    compiled = _compile_step(runtime, v5e_sharding, bound)
+    results, entry = _entry_results(compiled), _entry(compiled)
+    kernels = _mosaic_calls(compiled, "latent_attention")
+    assert len(kernels) == 7, len(kernels)
+    pool_type = _hlo_type(shape, jnp.bfloat16)
+    makers = [op for kind, op in results if kind.startswith(pool_type)]
+    assert sorted(set(makers)) == ["dynamic-update-slice", "parameter"]
+    assert makers.count("dynamic-update-slice") == 32
+    (pool,) = re.findall(r"(%\S+) = " + re.escape(pool_type)
+                         + r"\S* parameter\(", entry)
+    assert sum(pool in ops for _, ops, _ in kernels) == 7
+    assert len(kernels) == compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"')
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= runtime.cache_nbytes()
+    assert runtime.cache_nbytes() == 2 * 7 * 32 * 4096 * 640
+    assert memory.temp_size_in_bytes < 0.5e9, memory.temp_size_in_bytes
+    if bound < runtime.max_len:
+        return
+
+    resident = memory.argument_size_in_bytes   # weights + pool (+ ints)
+    assert 12.2e9 < resident < 12.3e9, resident
+    flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
+    for top in (2048, runtime.max_len):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(flash, "resolve_interpret",
+                          lambda kernel, interpret: False)
+            prefill = runtime._programs["prefill"].lower(
+                _on(v5e_sharding, runtime.servable.params),
+                _on(v5e_sharding, ((1, top), jnp.int32)),
+                _on(v5e_sharding, ((1,), jnp.int32))).compile()
+        assert len(_mosaic_calls(prefill, "prompt_attention")) == 7
+        prefill = prefill.memory_analysis()
+        peak = resident + max(memory.temp_size_in_bytes,
+                              prefill.temp_size_in_bytes
+                              + prefill.output_size_in_bytes)
+        print(f"xing4 cell: resident {resident}, step temporaries "
+              f"{memory.temp_size_in_bytes}, prefill {top}: temporaries "
+              f"{prefill.temp_size_in_bytes} + outputs "
+              f"{prefill.output_size_in_bytes}, peak {peak}")
+        assert peak < 15.0e9, (top, peak, prefill.temp_size_in_bytes)
