@@ -301,7 +301,12 @@ def prompt_attention(q, k, v, scale: float, mask=None,
     (itself among them) — a banded read —, and, where ``mask (P, P)`` is,
     those it marks nonzero — a selection, one byte a pair for every head.
     Float32 scores and softmax, the weights cast to ``v``'s dtype for the
-    value product. Returns (P, H, dv) in ``v``'s dtype."""
+    value product. A grid step carries a few of the ``H`` heads (up to four
+    that the kernel's fast memory holds at these widths) against one block
+    of the mask, which is fetched and unpacked once for them; the grid is as long as
+    the blocks the queries read — under the diagonal, inside the band — and
+    a head's output is the same whatever group it ran in. Returns (P, H, dv)
+    in ``v``'s dtype."""
     with jax.named_scope("attention"):
         heads_first = [jnp.swapaxes(a, 0, 1) for a in (q, k, v)]
         return jnp.swapaxes(flash_prompt_attention(

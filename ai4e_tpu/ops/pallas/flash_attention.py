@@ -47,6 +47,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -399,8 +400,18 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int | None = None,
 # -- a prompt's attention under a mask: the decode engine's long prefill ----
 
 # Queries and keys a grid step of ``prompt_attention``: a (512, 512) float32
-# block of scores is 1 MB of VMEM beside ~0.8 MB of double-buffered operands.
+# block of scores is 1 MB of VMEM.
 PROMPT_BLOCK = 512
+# What a call may hold in VMEM, and the limit it asks Mosaic for.
+PROMPT_VMEM_BYTES = 32 * 1024 * 1024
+# Heads a grid step at most (``_head_group``). The kernel is a straight line
+# of them, which every start of a worker traces and lowers anew, compile
+# cache or not: 8 heads a step cost ``dots3.longdoc`` 10.5 s of a warm 87 s
+# set-up where 1 cost none, for 5 % of the kernel's time over 4 (PR 44).
+PROMPT_HEADS = 4
+
+# Rows of the list of pairs (``_prompt_pairs``).
+IQ, IK, FIRST, LAST = range(4)
 
 
 def _prompt_block(p: int) -> int:
@@ -416,71 +427,120 @@ def _prompt_block(p: int) -> int:
                      f"tiles: pad it to a multiple of {LANES}")
 
 
-def _key_blocks(iq, block: int, window: int | None):
-    """First and last block of keys the queries of block ``iq`` read: up to
-    the diagonal, and from the window's far edge where there is one."""
-    lo = 0 if window is None else jnp.maximum(
-        iq * block - (window - 1), 0) // block
-    return lo, iq
+def _prompt_pairs(blocks: int, block: int, window: int | None) -> np.ndarray:
+    """The blocks of scores a prompt of ``blocks`` blocks computes, in the
+    order the grid walks them: (4, pairs) int32 — the block of queries (IQ),
+    the block of keys (IK), and whether the pair is its query block's FIRST
+    and LAST. A query block reads the key blocks from the window's far edge
+    (block 0 with no window) up to the diagonal, one after the other."""
+    pairs = []
+    for iq in range(blocks):
+        lo = 0 if window is None else max(iq * block - (window - 1),
+                                          0) // block
+        pairs += [(iq, ik, ik == lo, ik == iq) for ik in range(lo, iq + 1)]
+    return np.asarray(pairs, np.int32).T
 
 
-def _prompt_kernel(q_ref, k_ref, v_ref, *rest, block: int, n_k: int,
+def prompt_vmem_bytes(group: int, block: int, dqk: int, dv: int,
+                      itemsize: int, masked: bool) -> int:
+    """What a call that carries ``group`` heads a grid step holds in VMEM:
+    the q, k, v and out blocks double-buffered, the accumulator, max and sum
+    (a lane tile a row each), and what the heads share — the mask's block
+    double-buffered, its float32 bias, and one head's scores, weights and
+    their cast. A block's last dimension lies on whole lane tiles."""
+    def lanes(d):
+        return -(-d // LANES) * LANES
+
+    operands = group * block * (2 * lanes(dqk) + 2 * lanes(dv)) * itemsize
+    scratch = group * block * (lanes(dv) + 2 * LANES) * 4
+    shared = block * lanes(block) * (2 * masked + 4 + 3 * 4)
+    return 2 * operands + scratch + shared
+
+
+def _head_group(heads: int, block: int, dqk: int, dv: int, itemsize: int,
+                masked: bool) -> int:
+    """Heads a grid step: the largest divisor of ``heads`` up to
+    ``PROMPT_HEADS`` whose blocks fit ``PROMPT_VMEM_BYTES`` (one head where
+    nothing larger divides or fits)."""
+    return max(g for g in range(1, min(heads, PROMPT_HEADS) + 1)
+               if heads % g == 0 and (
+        g == 1 or prompt_vmem_bytes(g, block, dqk, dv, itemsize, masked)
+        <= PROMPT_VMEM_BYTES))
+
+
+def _across(column, width: int):
+    """A row's value held on every lane, ``(rows, LANES)``, as ``(rows,
+    width)``: whole lane tiles repeated — no lane moves —, or one lane
+    spread where ``width`` is no multiple of a tile."""
+    if width % LANES == 0:
+        return pltpu.repeat(column, width // LANES, axis=1)
+    return jnp.broadcast_to(column[:, :1], (column.shape[0], width))
+
+
+def _prompt_kernel(pairs_ref, q_ref, k_ref, v_ref, *rest, block: int,
                    window: int | None, scale: float, masked: bool):
-    # q_ref: (block, dqk); k_ref: (block, dqk); v_ref: (block, dv);
-    # mask_ref (``masked``): (block, block) int8, nonzero where the query
-    # reads the key; out_ref: (block, dv). Scratch, carried across a query
-    # block's key blocks: acc (block, dv); m, l (block, 1).
+    # pairs_ref: (4, pairs) int32, the grid's second axis (``_prompt_pairs``);
+    # q_ref, k_ref: (G, block, dqk); v_ref: (G, block, dv); mask_ref
+    # (``masked``): (block, block) int8, nonzero where the query reads the
+    # key; out_ref: (G, block, dv). Scratch: bias (block, block), what the
+    # pair permits, the same for the G heads; carried across a query block's
+    # pairs: acc (G, block, dv); m, l (G, block, LANES), a row's running max
+    # and sum on every lane (the layout the vector unit subtracts and
+    # multiplies by without moving a lane).
     if masked:
-        mask_ref, out_ref, acc, m, l = rest
+        mask_ref, out_ref, bias, acc, m, l = rest
     else:
-        (out_ref, acc, m, l), mask_ref = rest, None
-    iq, j = pl.program_id(1), pl.program_id(2)
-    lo, hi = _key_blocks(iq, block, window)
-    ik = lo + j
+        (out_ref, bias, acc, m, l), mask_ref = rest, None
+    # program_id is read at the top level (``_flash_kernel``)
+    t = pl.program_id(1)
+    iq, ik = pairs_ref[IQ, t], pairs_ref[IK, t]
 
-    @pl.when(j == 0)
+    @pl.when(pairs_ref[FIRST, t] == 1)
     def _init():
         acc[...] = jnp.zeros_like(acc)
         m[...] = jnp.full_like(m, NEG_INF)
         l[...] = jnp.zeros_like(l)
 
-    @pl.when(ik <= hi)
-    def _accumulate():
-        v = v_ref[...]
-        precision = (jax.lax.Precision.HIGHEST if v.dtype == jnp.float32
-                     else None)
+    q_pos = iq * block + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+    k_pos = ik * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    allowed = k_pos <= q_pos
+    if window is not None:
+        allowed &= k_pos > q_pos - window
+    if masked:
+        allowed &= mask_ref[...].astype(jnp.int32) != 0
+    # added to a head's float32 scores: s + 0.0 is s and s - 1e30 is -1e30
+    # for any score a model makes, so a head reads what ``where`` would give
+    bias[...] = jnp.where(allowed, 0.0, NEG_INF)
+
+    # a straight line of G heads, not a loop: the scheduler runs one head's
+    # products under another's softmax
+    precision = (jax.lax.Precision.HIGHEST if v_ref.dtype == jnp.float32
+                 else None)
+    for g in range(q_ref.shape[0]):
+        v = v_ref[g]
         scores = jax.lax.dot_general(
-            q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+            q_ref[g], k_ref[g], (((1,), (1,)), ((), ())),
             precision=precision,
-            preferred_element_type=jnp.float32) * scale
-        q_pos = iq * block + jax.lax.broadcasted_iota(
-            jnp.int32, (block, 1), 0)
-        k_pos = ik * block + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block), 1)
-        allowed = k_pos <= q_pos
-        if window is not None:
-            allowed &= k_pos > q_pos - window
-        if masked:
-            allowed &= mask_ref[...].astype(jnp.int32) != 0
-        scores = jnp.where(allowed, scores, NEG_INF)
-        m_prev = m[...]
+            preferred_element_type=jnp.float32) * scale + bias[...]
+        m_prev = m[g]
         # held above NEG_INF: a query none of whose keys so far are allowed
         # would weigh them exp(0) = 1 otherwise
         m_new = jnp.maximum(
             jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True)),
             0.1 * NEG_INF)
-        p = jnp.exp(scores - m_new)
+        p = jnp.exp(scores - _across(m_new, block))
         shrink = jnp.exp(m_prev - m_new)
-        l[...] = l[...] * shrink + p.sum(axis=-1, keepdims=True)
-        m[...] = m_new
-        acc[...] = acc[...] * shrink + jax.lax.dot_general(
+        l[g] = l[g] * shrink + p.sum(axis=-1, keepdims=True)
+        m[g] = m_new
+        acc[g] = acc[g] * _across(shrink, acc.shape[-1]) + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             precision=precision, preferred_element_type=jnp.float32)
 
-    @pl.when(j == n_k - 1)
+    @pl.when(pairs_ref[LAST, t] == 1)
     def _finish():
-        out_ref[...] = (acc[...] / jnp.maximum(l[...], 1e-30)).astype(
-            out_ref.dtype)
+        for g in range(q_ref.shape[0]):
+            total = _across(jnp.maximum(l[g], 1e-30), acc.shape[-1])
+            out_ref[g] = (acc[g] / total).astype(out_ref.dtype)
 
 
 @partial(jax.jit, static_argnames=("scale", "window", "interpret"))
@@ -489,42 +549,43 @@ def _prompt(q, k, v, mask, *, scale: float, window: int | None,
     heads, p, dqk = q.shape
     dv = v.shape[-1]
     block = _prompt_block(p)
-    n_k = p // block
-    if window is not None:
-        # the keys of a query block span block + window - 1 positions
-        n_k = min(n_k, -(-(block + window - 1) // block) + 1)
+    group = _head_group(heads, block, dqk, dv, v.dtype.itemsize,
+                        mask is not None)
+    pairs = _prompt_pairs(p // block, block, window)
 
-    def keys(h, iq, j):
-        lo, hi = _key_blocks(iq, block, window)
-        # a block past the diagonal is the diagonal's again: not fetched
-        return h, jnp.minimum(lo + j, hi), 0
+    def queries(h, t, pairs):
+        return h, pairs[IQ, t], 0
 
-    def queries(h, iq, j):
-        return h, iq, 0
+    def keys(h, t, pairs):
+        return h, pairs[IK, t], 0
 
-    in_specs = [pl.BlockSpec((None, block, dqk), queries),
-                pl.BlockSpec((None, block, dqk), keys),
-                pl.BlockSpec((None, block, dv), keys)]
+    in_specs = [pl.BlockSpec((group, block, dqk), queries),
+                pl.BlockSpec((group, block, dqk), keys),
+                pl.BlockSpec((group, block, dv), keys)]
     operands = [q, k, v]
     if mask is not None:
         in_specs.append(pl.BlockSpec(
-            (block, block), lambda h, iq, j: (iq, keys(h, iq, j)[1])))
+            (block, block), lambda h, t, pairs: (pairs[IQ, t], pairs[IK, t])))
         operands.append(mask.astype(jnp.int8))
     return pl.pallas_call(
-        partial(_prompt_kernel, block=block, n_k=n_k, window=window,
-                scale=scale, masked=mask is not None),
-        grid=(heads, p // block, n_k),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, block, dv), queries),
+        partial(_prompt_kernel, block=block, window=window, scale=scale,
+                masked=mask is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(heads // group, pairs.shape[1]),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((group, block, dv), queries),
+            scratch_shapes=[pltpu.VMEM((block, block), jnp.float32),
+                            pltpu.VMEM((group, block, dv), jnp.float32),
+                            pltpu.VMEM((group, block, LANES), jnp.float32),
+                            pltpu.VMEM((group, block, LANES), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((heads, p, dv), v.dtype),
-        scratch_shapes=[pltpu.VMEM((block, dv), jnp.float32),
-                        pltpu.VMEM((block, 1), jnp.float32),
-                        pltpu.VMEM((block, 1), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=PROMPT_VMEM_BYTES),
         interpret=interpret,
         name="prompt_attention",
-    )(*operands)
+    )(jnp.asarray(pairs), *operands)
 
 
 def prompt_attention(q, k, v, *, scale: float, mask=None,
@@ -537,10 +598,23 @@ def prompt_attention(q, k, v, *, scale: float, mask=None,
     ``mask (P, P)`` is, those it marks nonzero (a learned selection reaches
     the kernel as data, one byte a pair, the same for every head). No score
     leaves VMEM: float32 scores and online softmax a block, the weights cast
-    to ``v``'s dtype for the value product. A block of keys above the
-    diagonal or behind the window is neither fetched nor computed; with a
-    window the grid's key axis is as long as the band, not the prompt. ``P``
-    is one block or a multiple of 128 (``_prompt_block``)."""
+    to ``v``'s dtype for the value product.
+
+    A grid step carries a group of heads against ONE block of the mask: what
+    the block permits — the diagonal, the window, the mask's bytes — is
+    folded once into a float32 bias, and each head of the group adds it to
+    its own scores; the mask's block is fetched once a group, not once a
+    head. The group is a divisor of ``H`` up to ``PROMPT_HEADS`` that fits
+    the kernel's VMEM at these widths (``_head_group``), laid out as a
+    straight line so that one head's products run under another's softmax,
+    and a head's output does not depend on the group it ran in. A row's running max and
+    sum lie on every lane of a tile: the scores subtract them and the
+    accumulator scales by them with no lane moved. The grid is as long as
+    the blocks read: a static list of (query block, key block) pairs
+    (``_prompt_pairs``), every block on or under the diagonal and, with a
+    window, inside the band — none above or behind is fetched, computed or
+    stepped over. ``P`` is one block or a multiple of 128
+    (``_prompt_block``)."""
     if mask is not None and mask.shape != (q.shape[1], q.shape[1]):
         raise ValueError(f"mask {mask.shape} for {q.shape[1]} positions")
     return _prompt(q, k, v, mask, scale=float(scale), window=window,

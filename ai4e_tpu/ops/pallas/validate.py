@@ -263,10 +263,11 @@ def validate_kernels(interpret: bool = False) -> dict:
     # that keeps about a sixth of the pairs and leaves whole blocks of keys
     # out for some queries —, the sliding layers' — keys of 256, a window of
     # 513 —, and one block of 256 queries' 64 index heads against every key.
-    # Four heads where the model hands over 32 a call: the grid's head axis
-    # is parallel, a head's program is the same. The oracle holds (heads,
-    # 1024, P) scores at a time.
-    from .flash_attention import index_scores, prompt_attention
+    # Four heads, one grid step's group, where the model hands over 32 a
+    # call (eight groups of 4). The oracle holds (heads, 1024, P) scores at
+    # a time.
+    from .flash_attention import (PROMPT_VMEM_BYTES, _head_group,
+                                  index_scores, prompt_attention)
     p = 1536 if interpret else 12288
     heads = 4
     hi = jax.lax.Precision.HIGHEST
@@ -306,12 +307,20 @@ def validate_kernels(interpret: bool = False) -> dict:
         got = np.asarray(run(*args), np.float32)
         err = float(np.max(np.abs(got - plain_prompt(
             q, k, v, jax.numpy.asarray(allowed), scale))))
-        block = 512
+        # the four heads are one grid step's group: their q, k (on whole
+        # lane tiles: 192 lies on 256), v and out blocks double-buffered,
+        # acc, m and l (a lane tile a row) a head; once for the group the
+        # mask's block double-buffered, its float32 bias and one head's
+        # scores, weights and their cast. The call asks Mosaic for
+        # ``PROMPT_VMEM_BYTES`` (``vmem_limit_bytes``).
+        block, lanes = 512, -(-dqk // 128) * 128
+        assert _head_group(heads, block, dqk, dv, 2, mask is not None) == heads
         entry = {"ok": bool(err < 0.04), "max_err": round(err, 6),
-                 "vmem_bytes": 2 * block * (2 * dqk + 2 * dv) * 2
-                 + 2 * block * block + block * block * 4
-                 + block * (dv + 2) * 4}
-        assert entry["vmem_bytes"] <= VMEM_BUDGET_BYTES, entry
+                 "vmem_bytes": 2 * heads * block * (2 * lanes + 2 * dv) * 2
+                 + heads * block * (dv + 2 * 128) * 4
+                 + 2 * block * block * (mask is not None)
+                 + 4 * block * block * 4}
+        assert entry["vmem_bytes"] <= PROMPT_VMEM_BYTES, entry
         if not interpret:
             run(*args).block_until_ready()
             t0 = time.perf_counter()

@@ -8,6 +8,7 @@ eight shares of the expert layer against the uncut one, the routing rules of
 through the worker's own runtime.
 """
 
+import importlib
 import os
 import sys
 from types import SimpleNamespace
@@ -286,21 +287,10 @@ def _prompt_by_numpy(q, k, v, scale, mask, window):
     return np.einsum("hqk,khd->qhd", w / w.sum(axis=-1, keepdims=True), v)
 
 
-@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 3e-2)])
-@pytest.mark.parametrize("p,window,masked", [
-    (16, None, True),       # one block: a tiny prompt
-    (640, None, True),      # five blocks of 128, a selection
-    (640, 130, False),      # a band that spans three blocks of keys
-    (1024, 513, False),     # the published window over two blocks of 512
-    (1280, None, False)])   # causal alone, blocks of 256
-def test_prompt_attention_is_the_softmax_under_a_mask_and_a_window(
-        dtype, tol, p, window, masked):
-    """The prefill's kernel under the interpreter against ``numpy``: keys 24
-    wide against values 16 wide, blocks above the diagonal and behind the
-    window never read, a selection that leaves whole blocks of a query's
-    keys out (the query's own key among them)."""
-    rng = np.random.default_rng(p)
-    heads, dqk, dv = 3, 24, 16
+def _prompt_operands(rng, p, heads, dtype, masked, dqk=24, dv=16):
+    """q, k (P, H, dqk), v (P, H, dv) and, where ``masked``, a selection
+    that leaves whole blocks of a query's keys out (its own key among
+    them)."""
     q, k = (jnp.asarray(rng.standard_normal((p, heads, dqk)) * 0.5, dtype)
             for _ in range(2))
     v = jnp.asarray(rng.standard_normal((p, heads, dv)), dtype)
@@ -310,11 +300,118 @@ def test_prompt_attention_is_the_softmax_under_a_mask_and_a_window(
         mask[:, 0] = True                    # every query keeps a key
         mask[p // 2:, p // 4:] = False       # whole blocks left out
         mask = jnp.asarray(mask)
+    return q, k, v, mask
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 3e-2)])
+@pytest.mark.parametrize("p,window,masked,heads", [
+    (16, None, True, 3),        # one block: a tiny prompt
+    (640, None, True, 3),       # five blocks of 128, a selection
+    (640, 130, False, 3),       # a band that spans three blocks of keys
+    (1024, 513, False, 3),      # the published window over two blocks of 512
+    (1280, None, False, 3),     # causal alone, blocks of 256
+    (200, None, False, 1),      # one head, one block that is no lane tile's
+    (200, 7, False, 5),         # ... and five heads under a narrow window
+    (1024, None, True, 16),     # four groups of 4 heads, two blocks of 512
+    (1024, 513, False, 17),     # a count no group divides: a head a step
+    (2560, None, True, 2),      # five blocks of 512, a selection
+    (2560, 513, False, 2)])     # ... and the band: two blocks a query block
+def test_prompt_attention_is_the_softmax_under_a_mask_and_a_window(
+        dtype, tol, p, window, masked, heads):
+    """The prefill's kernel under the interpreter against ``numpy``: keys 24
+    wide against values 16 wide, a group of heads a grid step, blocks above
+    the diagonal and behind the window never walked, a selection that leaves
+    whole blocks of a query's keys out (the query's own key among them)."""
+    q, k, v, mask = _prompt_operands(np.random.default_rng(p), p, heads,
+                                     dtype, masked)
     got = kv_pool.prompt_attention(q, k, v, 0.2, mask=mask, window=window,
                                    interpret=True)
-    assert got.shape == (p, heads, dv) and got.dtype == v.dtype
+    assert got.shape == (p, heads, 16) and got.dtype == v.dtype
     want = _prompt_by_numpy(q, k, v, 0.2, mask, window)
     assert np.abs(np.asarray(got, np.float64) - want).max() < tol
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("window,masked", [
+    (None, True), (513, False), (None, False)])
+def test_a_heads_output_does_not_depend_on_its_group(dtype, window, masked):
+    """Sixteen heads in groups of four (keys 200 wide: two lane tiles)
+    against the same heads a call each (a group of one): bit for bit the
+    same output — the block's bias is shared, the scores, the softmax and
+    the value product are the head's own."""
+    flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
+    p, heads = 1024, 16
+    assert 1 < flash._head_group(heads, 512, 200, 16,
+                                 jnp.dtype(dtype).itemsize, masked) < heads
+    *qkv, mask = _prompt_operands(np.random.default_rng(44), p, heads, dtype,
+                                  masked, dqk=200)
+    q, k, v = (jnp.swapaxes(a, 0, 1) for a in qkv)   # heads first
+    together = flash.prompt_attention(q, k, v, scale=0.2, mask=mask,
+                                      window=window, interpret=True)
+    alone = jnp.concatenate([flash.prompt_attention(
+        q[h:h + 1], k[h:h + 1], v[h:h + 1], scale=0.2, mask=mask,
+        window=window, interpret=True) for h in range(heads)])
+    assert together.shape == (heads, p, 16)
+    assert np.array_equal(np.asarray(together, np.float32),
+                          np.asarray(alone, np.float32))
+
+
+@pytest.mark.parametrize("block", [128, 256, 512])
+@pytest.mark.parametrize("window", [None, 5, 513])
+def test_the_list_of_pairs_holds_each_block_a_query_block_reads_once(
+        block, window):
+    """``_prompt_pairs`` for 1-24 blocks: exactly the blocks in which some
+    query may read some key, each once, none above the diagonal or behind
+    the window; a query block's pairs adjacent and in the keys' order, the
+    first and the last marked."""
+    flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
+    for blocks in range(1, 25):
+        pairs = flash._prompt_pairs(blocks, block, window)
+        assert pairs.dtype == np.int32 and pairs.shape[0] == 4
+        iq, ik = pairs[flash.IQ], pairs[flash.IK]
+        # a block is read iff its nearest pair is permitted: the query
+        # block's last query (first, behind the window) against the key
+        # block's first key (last)
+        want = [(a, b) for a in range(blocks) for b in range(a + 1)
+                if window is None
+                or (b + 1) * block - 1 > a * block - window]
+        assert list(zip(iq.tolist(), ik.tolist())) == want
+        assert (ik <= iq).all() and (np.diff(iq) >= 0).all()
+        first = np.r_[True, np.diff(iq) != 0]
+        last = np.r_[np.diff(iq) != 0, True]
+        assert np.array_equal(pairs[flash.FIRST], first)
+        assert np.array_equal(pairs[flash.LAST], last)
+        assert (ik[last] == iq[last]).all()       # the diagonal closes it
+        if window is not None and window <= block + 1:
+            assert (np.bincount(iq) <= 2).all()   # the band, not the prompt
+
+
+@pytest.mark.parametrize("heads,block,dqk,dv,itemsize,masked,group", [
+    (32, 512, 192, 128, 2, True, 4),      # dots3's full layers, 6,144
+    (32, 512, 256, 128, 2, False, 4),     # ... its sliding layers
+    (32, 256, 192, 128, 2, True, 4),      # the cache's own length, 12,544
+    (32, 128, 192, 128, 2, False, 4),     # xing4's 128 bucket
+    (4, 512, 192, 128, 2, True, 4),       # validate.py's four heads
+    (1, 512, 192, 128, 2, True, 1),
+    (17, 512, 24, 16, 4, False, 1),       # no group divides: a head a step
+    (3, 128, 24, 16, 4, True, 3),
+    (6, 128, 24, 16, 4, True, 3),         # the largest divisor up to four
+    (32, 512, 512, 512, 4, True, 2),      # wide heads: what VMEM holds
+    (32, 512, 2048, 2048, 4, False, 1)])
+def test_the_group_of_heads_follows_from_the_shapes(
+        heads, block, dqk, dv, itemsize, masked, group):
+    """``_head_group``: the largest divisor of the heads, up to
+    ``PROMPT_HEADS``, whose blocks, with the block of bias and one head's
+    scores, fit the VMEM the call asks for."""
+    flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
+    assert flash._head_group(heads, block, dqk, dv, itemsize,
+                             masked) == group <= flash.PROMPT_HEADS
+    assert group == 1 or flash.prompt_vmem_bytes(
+        group, block, dqk, dv, itemsize, masked) <= flash.PROMPT_VMEM_BYTES
+    wider = [g for g in range(group + 1, flash.PROMPT_HEADS + 1)
+             if heads % g == 0]
+    assert all(flash.prompt_vmem_bytes(g, block, dqk, dv, itemsize, masked)
+               > flash.PROMPT_VMEM_BYTES for g in wider)
 
 
 @pytest.mark.parametrize("p,queries,first", [

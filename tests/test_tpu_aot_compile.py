@@ -599,15 +599,22 @@ def test_latent_kernel_compiles(v5e_sharding, pool, heads, value, bound,
 
 
 @pytest.mark.parametrize("p", [3072, 12288, 12544])
-@pytest.mark.parametrize("heads,dqk,window", [(32, 192, None), (32, 256, 513)])
-def test_prompt_kernels_compile(v5e_sharding, p, heads, dqk, window):
+@pytest.mark.parametrize("heads,dqk,window,masked", [
+    (32, 192, None, True), (32, 256, 513, False), (32, 192, None, False)])
+def test_prompt_kernels_compile(v5e_sharding, p, heads, dqk, window, masked):
     """The prefill's two kernels at the ``dots3.longdoc`` cell's shapes
     alone, by Mosaic: a group of 32 heads, keys 192 (256 on the sliding
     layers) wide against values of 128, under the selection's one-byte mask
     or over the window's band, at the shortest and the longest buckets
-    (blocks of 512) and the cache's own length (blocks of 256); and the
-    indexer's 64 heads of 128 for a block of 256 queries."""
+    (blocks of 512) and the cache's own length (blocks of 256), 4 heads a
+    grid step; ``xing4``'s form of the same call, 32 heads with
+    neither mask nor window; and the indexer's 64 heads of 128 for a block
+    of 256 queries. A call's blocks fit the VMEM it asks Mosaic for, by the
+    kernel's own count and by the compile."""
+    import importlib
     from ai4e_tpu.ops import kv_pool
+    from ai4e_tpu.ops.pallas.validate import VMEM_PHYSICAL_BYTES
+    flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
     q, k = (_on(v5e_sharding, ((p, heads, dqk), jnp.bfloat16))
             for _ in range(2))
     v = _on(v5e_sharding, ((p, heads, 128), jnp.bfloat16))
@@ -615,14 +622,17 @@ def test_prompt_kernels_compile(v5e_sharding, p, heads, dqk, window):
 
     def attend(q, k, v, mask):
         return kv_pool.prompt_attention(
-            q, k, v, 0.07, mask=None if window else mask, window=window,
+            q, k, v, 0.07, mask=mask if masked else None, window=window,
             interpret=False)
 
     assert "tpu_custom_call" in _compile(attend, q, k, v, mask).as_text()
-    if window:
+    block = flash._prompt_block(p)
+    group = flash._head_group(heads, block, dqk, 128, 2, masked)
+    assert group == 4
+    assert (flash.prompt_vmem_bytes(group, block, dqk, 128, 2, masked)
+            <= flash.PROMPT_VMEM_BYTES <= VMEM_PHYSICAL_BYTES // 2)
+    if not masked:
         return
-    import importlib
-    flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
     scores = _compile(
         lambda iq, ik, w, first: flash.index_scores(iq, ik, w, first,
                                                     interpret=False),
