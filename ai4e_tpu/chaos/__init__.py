@@ -20,9 +20,6 @@ assemblies carry no chaos code path. Three parts:
   store at every record boundary and seeded mid-record offsets, assert
   0 acknowledged-task loss / no conflicting state / replica
   convergence per reboot (docs/durability.md).
-
-``bench.py --fault-rate R [--resilience]`` drives the same injector over
-the full platform for the goodput-under-failure A/B.
 """
 
 from .crashpoint import check_reboot, crash_offsets, drive_workload, sweep

@@ -7,10 +7,9 @@ interpret mode off-TPU, so CPU CI never
 proves they compile to Mosaic and fit VMEM on real hardware. This module is
 that proof: ``validate_kernels()`` runs each kernel with ``interpret=False``
 (on TPU) against a pure-XLA oracle and asserts its working set fits the
-per-core scoped-VMEM budget under double buffering. ``bench.py`` embeds the
-result in its JSON (``"pallas_tpu"``) on the chip, and ``chip_smoke.py`` runs
+per-core scoped-VMEM budget under double buffering. ``chip_smoke.py`` runs
 ``python -m ai4e_tpu.ops.pallas.validate`` as its kernel phase — a failed
-kernel fails either run.
+kernel fails the run.
 
 VMEM accounting mirrors each kernel's BlockSpecs (pallas_guide.md: Mosaic
 double-buffers every in/out block; scratch is single-buffered).

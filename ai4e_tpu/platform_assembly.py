@@ -27,12 +27,6 @@ class PlatformConfig:
     max_delivery_count: int = 1440  # broker patience (setup_env.sh:65)
     dispatcher_concurrency: int = 1  # serial per queue (host.json:5-9)
     journal_path: str | None = None  # None → pure in-memory store
-    # Journal fsync policy (docs/durability.md): "never" (default —
-    # write+flush, today's behavior: survives SIGKILL, loses the unsynced
-    # tail on a machine crash), "always" (fsync per append), or
-    # "group:<ms>" (batched group commit, crash window bounded by the
-    # window). None resolves the AI4E_TASKSTORE_FSYNC env knob.
-    taskstore_fsync: str | None = None
     lease_seconds: float = 300.0
     native_broker: bool = False      # C++ broker core (native/broker_core.cpp)
     native_store: bool = False       # C++ task-store core (native/taskstore_core.cpp)
@@ -273,12 +267,11 @@ class LocalPlatform:
             result_backend=result_backend,
             result_offload_threshold=(self.config.result_offload_threshold
                                       if result_backend else None))
-        # Journal-bearing stores additionally get the fsync policy and the
-        # assembly registry (ai4e_journal_* metrics must land beside the
-        # platform's own /metrics, not in the process default — AIL002).
-        journal_kwargs = dict(result_kwargs,
-                              fsync=self.config.taskstore_fsync,
-                              metrics=self.metrics)
+        # Journal-bearing stores additionally get the assembly registry
+        # (ai4e_journal_* metrics must land beside the platform's own
+        # /metrics, not in the process default — AIL002); their fsync
+        # policy is AI4E_TASKSTORE_FSYNC's (docs/durability.md).
+        journal_kwargs = dict(result_kwargs, metrics=self.metrics)
         if self.config.task_shards > 1:
             if self.config.native_store or self.config.native_broker:
                 raise ValueError(
@@ -609,8 +602,8 @@ class LocalPlatform:
         # Terminal-history retention: None = AUTO — 15 min on the Python
         # store, sized to the soak evidence (unevicted terminal history
         # grows ~12 MB/min at 200 req/s → AUTO bounds steady-state at
-        # ~180 MB, the level the retention-on soak measured flat;
-        # bench_results/r5-cpu/). 0 keeps its pre-r5 meaning (evict
+        # ~180 MB, the level the retention-on soak measured flat:
+        # scripts/soak.sh). 0 keeps its pre-r5 meaning (evict
         # terminal tasks immediately); NEGATIVE opts out of eviction
         # entirely. Nothing on the native store (no eviction support).
         # Redis expiry played this role for the reference.

@@ -6,8 +6,7 @@ seeded chaos timeline at rate, and records the whole run — topology,
 per-loadgen windows (offered vs achieved + error taxonomy), the chaos
 events with their actual fire times, the per-shard + global invariant
 verdict, and the merged per-role metrics — as ONE JSON artifact
-(``bench_results/r12-*`` acceptance shape: the scale claim is a file,
-not a README paragraph).
+(the scale claim is a file, not a README paragraph).
 
 Boot order is dependency order: stores first (primaries, then replicas,
 each health-gated), then workers, dispatchers, gateways, the balancer,

@@ -168,7 +168,7 @@ class InMemoryTaskStore(StoreSideEffects):
         # eviction must be O(victim's results), not O(all results) — the
         # 40-min soak wedged the store for minutes when each of ~6k
         # victims scanned ~190k result keys under the lock
-        # (bench_results/r5-cpu/).
+        # (scripts/soak.sh; tests/test_taskstore.py TestEvictionScales).
         self._result_keys: dict[str, set[str]] = {}
         self._result_backend = result_backend
         self._result_offload_threshold = result_offload_threshold

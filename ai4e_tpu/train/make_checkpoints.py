@@ -641,8 +641,8 @@ def make_checkpoint(name: str, out_dir: str, min_eval: float = MIN_EVAL,
 
 # Production training sizes = the serving sizes in deploy/specs/models.json.
 # Accuracy does not transfer across input sizes (species measured 1.0@64 →
-# 0.12@224 with 64-trained weights), so every full (non --fast) training —
-# the CLI's and the bench's train-on-the-spot path — goes through these.
+# 0.12@224 with 64-trained weights), so every full (non --fast) training
+# goes through these.
 FULL_OVERRIDES = {
     # 300 steps at 512: the 150-step default converged to the gate's edge
     # (0.83-0.87 depending on backend numerics); doubling the schedule puts
@@ -650,23 +650,6 @@ FULL_OVERRIDES = {
     "megadetector": {"image_size": 512, "steps": 300},
     "species": {"image_size": 224, "steps": 120},
 }
-
-
-def train_full(name: str, out_dir: str) -> dict:
-    """Train ``name`` at production size and RECORD it in the manifest —
-    the single entry point for producing a servable checkpoint outside CI
-    (serving reads image_size from the manifest; a checkpoint without a
-    manifest entry would be served at the wrong resolution)."""
-    entry = make_checkpoint(name, out_dir, **FULL_OVERRIDES.get(name, {}))
-    manifest_path = os.path.join(out_dir, "MANIFEST.json")
-    manifest = {}
-    if os.path.exists(manifest_path):
-        with open(manifest_path) as f:
-            manifest = json.load(f)
-    manifest[name] = entry
-    with open(manifest_path, "w") as f:
-        json.dump(manifest, f, indent=2)
-    return entry
 
 
 def main(argv=None) -> None:

@@ -1,10 +1,9 @@
 """Shared closed-loop load-measurement client.
 
-Used by ``bench.py`` (in-proc platform) and ``examples/loadgen.py`` (any
-live deployment): N clients each keep exactly one request in flight against
-an async task route (POST → long-poll ``/task/{id}``) or a sync route
-(POST → response), with an untimed steady-state ramp before the measured
-window opens.
+Used by ``examples/loadgen.py`` against any live deployment: N clients each
+keep exactly one request in flight against an async task route (POST →
+long-poll ``/task/{id}``) or a sync route (POST → response), with an untimed
+steady-state ramp before the measured window opens.
 
 Error tolerance is the point of sharing this: a non-503 error response, an
 undecodable body, a vanished task (404 after the reaper), or a transport
@@ -16,7 +15,6 @@ creates.
 from __future__ import annotations
 
 import asyncio
-import json
 import time
 
 
@@ -66,46 +64,16 @@ async def run_closed_loop(
     ramp: float = 5.0,
     task_timeout: float = 120.0,
     poll_wait: float = 30.0,
-    post_url_for=None,
-    headers_for=None,
-    deadline_s: float | None = None,
-    events_url_for=None,
-    tenant_names: dict | None = None,
 ) -> dict:
     """Drive ``post_url`` closed-loop; returns window stats.
 
     ``status_url_for(task_id) -> url`` is required in async mode.
-    ``post_url_for() -> url`` (optional) picks the POST target per request —
-    the bench's duplicate-request mix rides this (identical requests POST
-    the bare route, unique ones carry a never-repeating query param).
-    ``headers_for() -> dict`` (optional) adds per-request headers on top of
-    ``headers`` — the bench's deadline/priority mix rides this
-    (admission control).
-    ``deadline_s`` (optional): the per-request latency budget the traffic
-    carries; completions are additionally bucketed into goodput (finished
-    within the budget) vs ``late``, and tasks the platform shed on their
-    deadline (terminal ``expired`` status / 504) count as ``expired``,
-    not failed.
-    ``tenant_names`` (optional): subscription key → tenant name. When
-    set, every outcome is additionally bucketed by the tenant whose key
-    the request carried (``Ocp-Apim-Subscription-Key``, set via
-    ``headers``/``headers_for``) and the window JSON gains a
-    ``by_tenant`` block — completions, goodput, and the tenant-quota
-    429s (``quota_shed``) the gateway's per-tenant bucket refused
-    (docs/tenancy.md). Keys absent from the map bucket under ``""``.
-    ``events_url_for(task_id) -> url`` (optional, async mode): follow the
-    task's SSE event stream (``GET /task/{id}/events``, pipeline
-    platforms — docs/pipelines.md) instead of long-polling, recording
-    **time-to-first-partial** — POST to the first stage partial (a
-    ``stage`` event reaching completed/cached, or any ``chunk``) — and
-    scoring the terminal event; the window JSON then carries
-    ``time_to_first_partial_ms_p50``/``_p95`` and ``first_partials``. A
-    failed/closed stream falls back to the ordinary status poll.
+    Tasks the platform shed on their deadline (terminal ``expired``
+    status / 504) count as ``expired``, not failed.
     Returns ``{"value", "p50_latency_ms", "p95_latency_ms", "completed",
     "failed", "expired", "duration_s", ...}`` where value is
     completions/second inside the measurement window that opens after
-    ``ramp`` seconds; with ``deadline_s`` set the dict gains
-    ``goodput`` (within-deadline completions/second) and ``late``.
+    ``ramp`` seconds.
     """
     import aiohttp
 
@@ -113,11 +81,9 @@ async def run_closed_loop(
         raise ValueError("async mode needs status_url_for")
 
     latencies: list[float] = []
-    ttfps: list[float] = []  # time-to-first-partial samples (events mode)
     completed = 0
     failed = 0
     expired = 0
-    good = 0  # completions within deadline_s (== completed when unset)
     # Loadgen honesty (ISSUE 11): every POST the client actually attempted
     # (backpressure re-entries included) and a client-side error taxonomy,
     # so the window JSON records OFFERED vs ACHIEVED rate — a CPU-bound
@@ -127,197 +93,70 @@ async def run_closed_loop(
 
     def _err(kind: str) -> None:
         errors[kind] = errors.get(kind, 0) + 1
-    # Per-priority-class accounting, keyed by the X-Priority header each
-    # request carried ("" = unlabeled). Only populated when headers_for
-    # labels traffic — the bench's --mix profiles report per-class
-    # goodput and deadline-miss rate off these buckets.
-    by_class: dict[str, dict] = {}
 
-    def _bucket(cls: str) -> dict:
-        b = by_class.get(cls)
-        if b is None:
-            b = by_class[cls] = {"completed": 0, "good": 0, "failed": 0,
-                                 "expired": 0}
-        return b
-    # Per-tenant accounting (docs/tenancy.md), keyed by the tenant whose
-    # subscription key each request carried — only populated when the
-    # caller supplies the key → name map.
-    by_tenant: dict[str, dict] = {}
-
-    def _tbucket(name: str) -> dict:
-        b = by_tenant.get(name)
-        if b is None:
-            b = by_tenant[name] = {"offered": 0, "completed": 0, "good": 0,
-                                   "failed": 0, "expired": 0,
-                                   "quota_shed": 0}
-        return b
-
-    def _tenant_of(hdrs: dict) -> str | None:
-        if tenant_names is None:
-            return None
-        return tenant_names.get(
-            hdrs.get("Ocp-Apim-Subscription-Key", ""), "")
-
-    def _headers() -> dict:
-        if headers_for is None:
-            return headers
-        return {**headers, **headers_for()}
-
-    def _score_completion(elapsed: float, cls: str, tname=None) -> None:
-        nonlocal completed, good
+    def _score_completion(elapsed: float) -> None:
+        nonlocal completed
         latencies.append(elapsed)
         completed += 1
-        _bucket(cls)["completed"] += 1
-        in_deadline = deadline_s is None or elapsed <= deadline_s
-        if in_deadline:
-            good += 1
-            _bucket(cls)["good"] += 1
-        if tname is not None:
-            _tbucket(tname)["completed"] += 1
-            if in_deadline:
-                _tbucket(tname)["good"] += 1
 
-    def _score_failed(cls: str, tname=None) -> None:
+    def _score_failed() -> None:
         nonlocal failed
         failed += 1
-        _bucket(cls)["failed"] += 1
-        if tname is not None:
-            _tbucket(tname)["failed"] += 1
 
-    def _score_expired(cls: str, tname=None) -> None:
+    def _score_expired() -> None:
         nonlocal expired
         expired += 1
-        _bucket(cls)["expired"] += 1
-        if tname is not None:
-            _tbucket(tname)["expired"] += 1
 
-    def _score_backpressure(resp, tname=None) -> None:
+    def _score_backpressure(resp) -> None:
         # A tenant-quota 429 is the tenant's OWN contract (shed, carries
-        # Retry-After) — bucket it to the tenant so the noisy-neighbor
-        # A/B can show who paid; other 429/503s are platform pressure.
+        # Retry-After); other 429/503s are platform pressure.
         reason = resp.headers.get("X-Shed-Reason", "")
         if "tenant-quota" in reason:
             _err("tenant_quota_429")
-            if tname is not None:
-                _tbucket(tname)["quota_shed"] += 1
         else:
             _err(f"backpressure_{resp.status}")
-
-    def _score_terminal(status: str, elapsed: float, cls: str,
-                        tname=None) -> None:
-        # "failed" FIRST — the platform's canonical bucketing
-        # (TaskStatus.canonical) tests it first.
-        if "failed" in status:
-            _score_failed(cls, tname)
-        elif "completed" in status:
-            _score_completion(elapsed, cls, tname)
-        elif "expired" in status:
-            _score_expired(cls, tname)
-        else:
-            _score_failed(cls, tname)  # stream ended on a non-terminal status
-
-    async def _follow_events(task_id: str, t0: float, cls: str,
-                             deadline: float, tname=None) -> bool:
-        """Consume the task's SSE stream: record the first partial, score
-        the terminal event. True when the request was scored; False →
-        the caller falls back to status polling."""
-        saw_partial = False
-        try:
-            budget = max(1.0, deadline - time.perf_counter())
-            async with session.get(
-                    events_url_for(task_id),
-                    params={"wait": str(round(budget, 1))},
-                    headers=headers) as resp:
-                if resp.status != 200:
-                    return False
-                current: dict = {}
-                async for raw in resp.content:
-                    if time.perf_counter() > deadline:
-                        # stuck task: don't hang the run
-                        _score_failed(cls, tname)
-                        return True
-                    line = raw.decode("utf-8").rstrip("\r\n")
-                    if line.startswith(":"):
-                        continue  # keep-alive
-                    if line:
-                        if line.startswith("event: "):
-                            current["event"] = line[len("event: "):]
-                        elif line.startswith("data: "):
-                            try:
-                                current["data"] = json.loads(
-                                    line[len("data: "):])
-                            except ValueError:
-                                pass
-                        continue
-                    etype = current.get("event")
-                    data = current.get("data") or {}
-                    current = {}
-                    if etype in ("stage", "chunk") and not saw_partial:
-                        state = data.get("state", "")
-                        if etype == "chunk" or state in ("completed",
-                                                         "cached"):
-                            saw_partial = True
-                            ttfps.append(time.perf_counter() - t0)
-                    elif etype == "terminal":
-                        _score_terminal(data.get("Status", ""),
-                                        time.perf_counter() - t0, cls,
-                                        tname)
-                        return True
-        except (aiohttp.ClientError, asyncio.TimeoutError):
-            return False
-        return False  # stream closed without a terminal event
 
     async def one_async() -> None:
         nonlocal offered
         t0 = time.perf_counter()
-        url = post_url if post_url_for is None else post_url_for()
-        hdrs = _headers()
-        cls = hdrs.get("X-Priority", "")
-        tname = _tenant_of(hdrs)
         offered += 1
-        if tname is not None:
-            _tbucket(tname)["offered"] += 1
         try:
-            async with session.post(url, data=payload,
-                                    headers=hdrs) as resp:
+            async with session.post(post_url, data=payload,
+                                    headers=headers) as resp:
                 if resp.status in (503, 429):
                     # Backpressure (admission 503 / per-key throttle 429 /
                     # tenant quota 429): not a failure — yield briefly and
                     # re-enter. The client honors Retry-After when present,
                     # capped so one long hint can't idle the closed loop
                     # past the window.
-                    _score_backpressure(resp, tname)
+                    _score_backpressure(resp)
                     await asyncio.sleep(_backoff(resp))
                     return
                 if resp.status == 504:  # shed: budget spent at the edge
                     _err("shed_504")
-                    _score_expired(cls, tname)
+                    _score_expired()
                     return
                 if resp.status >= 400:
                     _err(f"http_{resp.status}")
-                    _score_failed(cls, tname)
+                    _score_failed()
                     return
                 task = await resp.json()
             task_id = task["TaskId"]
         except asyncio.TimeoutError:
             _err("timeout")
-            _score_failed(cls, tname)
+            _score_failed()
             return
         except aiohttp.ClientError as exc:
             _err("connect_error"
                  if isinstance(exc, aiohttp.ClientConnectorError)
                  else "transport_error")
-            _score_failed(cls, tname)
+            _score_failed()
             return
         except (ValueError, KeyError, TypeError):
             _err("bad_response")
-            _score_failed(cls, tname)
+            _score_failed()
             return
         deadline = t0 + task_timeout
-        if events_url_for is not None:
-            if await _follow_events(task_id, t0, cls, deadline, tname):
-                return
-            # Stream unavailable/interrupted: poll like everyone else.
         while True:
             try:
                 async with session.get(status_url_for(task_id),
@@ -325,32 +164,32 @@ async def run_closed_loop(
                                        headers=headers) as resp:
                     if resp.status == 404:  # reaped/evicted task
                         _err("task_poll_404")
-                        _score_failed(cls, tname)
+                        _score_failed()
                         return
                     record = await resp.json()
                 status = record["Status"]
             except (aiohttp.ClientError, asyncio.TimeoutError, ValueError,
                     KeyError, TypeError):
                 _err("poll_transport")
-                _score_failed(cls, tname)
+                _score_failed()
                 return
             # "failed" FIRST — the platform's canonical bucketing
             # (TaskStatus.canonical) tests it first, so a status carrying
             # both words counts the same here as in the store's sets.
             if "failed" in status:
-                _score_failed(cls, tname)
+                _score_failed()
                 return
             if "completed" in status:
-                _score_completion(time.perf_counter() - t0, cls, tname)
+                _score_completion(time.perf_counter() - t0)
                 return
             if "expired" in status:
                 # Admission shed the task on its deadline (terminal) —
                 # shed work, not a platform failure.
-                _score_expired(cls, tname)
+                _score_expired()
                 return
             if time.perf_counter() > deadline:  # stuck task: don't hang the run
                 _err("stuck_timeout")
-                _score_failed(cls, tname)
+                _score_failed()
                 return
 
     async def one_sync() -> None:
@@ -359,23 +198,17 @@ async def run_closed_loop(
         # one_async, so sustained backpressure can never outlive the run.
         nonlocal offered
         t0 = time.perf_counter()
-        url = post_url if post_url_for is None else post_url_for()
-        hdrs = _headers()
-        cls = hdrs.get("X-Priority", "")
-        tname = _tenant_of(hdrs)
         offered += 1
-        if tname is not None:
-            _tbucket(tname)["offered"] += 1
         try:
-            async with session.post(url, data=payload,
-                                    headers=hdrs) as resp:
+            async with session.post(post_url, data=payload,
+                                    headers=headers) as resp:
                 if resp.status in (503, 429):
-                    _score_backpressure(resp, tname)
+                    _score_backpressure(resp)
                     await asyncio.sleep(_backoff(resp))
                     return
                 if resp.status == 504:  # admission shed on deadline
                     _err("shed_504")
-                    _score_expired(cls, tname)
+                    _score_expired()
                     return
                 await resp.read()
                 ok = resp.status == 200
@@ -390,9 +223,9 @@ async def run_closed_loop(
                  else "transport_error")
             ok = False
         if ok:
-            _score_completion(time.perf_counter() - t0, cls, tname)
+            _score_completion(time.perf_counter() - t0)
         else:
-            _score_failed(cls, tname)
+            _score_failed()
 
     one = one_sync if mode == "sync" else one_async
 
@@ -407,20 +240,14 @@ async def run_closed_loop(
     mark: dict = {}
     close: dict = {}
 
-    def _class_snapshot() -> dict:
-        return {cls: dict(b) for cls, b in by_class.items()}
-
-    def _tenant_snapshot() -> dict:
-        return {name: dict(b) for name, b in by_tenant.items()}
+    def _snapshot() -> dict:
+        return dict(t=time.perf_counter(), completed=completed,
+                    failed=failed, expired=expired, offered=offered,
+                    errors=dict(errors), n_lat=len(latencies))
 
     async def open_window() -> None:
         await asyncio.sleep(ramp)
-        mark.update(t=time.perf_counter(), completed=completed,
-                    failed=failed, expired=expired, good=good,
-                    offered=offered, errors=dict(errors),
-                    n_lat=len(latencies), n_ttfp=len(ttfps),
-                    by_class=_class_snapshot(),
-                    by_tenant=_tenant_snapshot())
+        mark.update(_snapshot())
 
     async def close_window() -> None:
         # Snapshot AT stop_at, not after the drain: gather() returns only
@@ -428,12 +255,7 @@ async def run_closed_loop(
         # would stretch the denominator by up to task_timeout with no
         # completions — deflating throughput several-fold.
         await asyncio.sleep(ramp + duration)
-        close.update(t=time.perf_counter(), completed=completed,
-                     failed=failed, expired=expired, good=good,
-                     offered=offered, errors=dict(errors),
-                     n_lat=len(latencies), n_ttfp=len(ttfps),
-                     by_class=_class_snapshot(),
-                     by_tenant=_tenant_snapshot())
+        close.update(_snapshot())
 
     stop_at = time.perf_counter() + ramp + duration
     await asyncio.gather(open_window(), close_window(),
@@ -444,8 +266,7 @@ async def run_closed_loop(
     n = close["completed"] - mark["completed"]
 
     n_offered = close["offered"] - mark["offered"]
-    window_errors = _window_error_delta(close, mark)
-    out = {
+    return {
         "value": round(n / elapsed, 2),
         **_latency_percentiles(window_lat),
         "completed": n,
@@ -459,76 +280,8 @@ async def run_closed_loop(
         "offered": n_offered,
         "offered_rate": round(n_offered / elapsed, 2),
         "achieved_rate": round(n / elapsed, 2),
-        "client_errors": window_errors,
+        "client_errors": _window_error_delta(close, mark),
     }
-    if events_url_for is not None:
-        # Time-to-first-partial (docs/pipelines.md): POST → first stage
-        # partial on the event stream, window-sliced like the latencies.
-        window_ttfp = sorted(ttfps[mark["n_ttfp"]:close["n_ttfp"]])
-        out["first_partials"] = len(window_ttfp)
-        if window_ttfp:
-            def tp(q: float) -> float:
-                idx = max(0, int(len(window_ttfp) * q) - 1)
-                return round(window_ttfp[idx] * 1000, 1)
-            out["time_to_first_partial_ms_p50"] = round(
-                window_ttfp[len(window_ttfp) // 2] * 1000, 1)
-            out["time_to_first_partial_ms_p95"] = tp(0.95)
-    if deadline_s is not None:
-        n_good = close["good"] - mark["good"]
-        # Goodput — THE saturation metric (PAPERS.md): completions that
-        # landed inside the caller's budget, per second of the window.
-        out["goodput"] = round(n_good / elapsed, 2)
-        out["late"] = n - n_good
-        # Deadline-miss rate: late + platform-shed (expired) work over
-        # everything that asked for a deadline and resolved in-window.
-        n_expired = close["expired"] - mark["expired"]
-        resolved = n + n_expired
-        if resolved:
-            out["deadline_miss_rate"] = round(
-                (out["late"] + n_expired) / resolved, 3)
-    labeled = {cls for cls in close["by_class"] if cls}
-    if labeled:
-        # Per-priority window deltas (the --mix profiles' report): the
-        # class label is the X-Priority value each request carried.
-        per = {}
-        for cls in sorted(labeled):
-            at_close = close["by_class"].get(cls, {})
-            at_open = mark["by_class"].get(
-                cls, {"completed": 0, "good": 0, "failed": 0, "expired": 0})
-            c = at_close.get("completed", 0) - at_open["completed"]
-            g = at_close.get("good", 0) - at_open["good"]
-            e = at_close.get("expired", 0) - at_open["expired"]
-            entry = {
-                "completed": c,
-                "failed": at_close.get("failed", 0) - at_open["failed"],
-                "expired": e,
-            }
-            if deadline_s is not None:
-                entry["goodput"] = round(g / elapsed, 2)
-                entry["late"] = c - g
-                if c + e:
-                    entry["deadline_miss_rate"] = round(
-                        (entry["late"] + e) / (c + e), 3)
-            per[cls] = entry
-        out["by_priority"] = per
-    if tenant_names is not None:
-        # Per-tenant window deltas (docs/tenancy.md): who completed, who
-        # ran late, and who paid the tenant-quota 429s — the bench's
-        # --tenant-mix noisy-neighbor A/B reads its verdict off this.
-        zero = {"offered": 0, "completed": 0, "good": 0, "failed": 0,
-                "expired": 0, "quota_shed": 0}
-        per_tenant = {}
-        for name in sorted(close["by_tenant"]):
-            at_close = close["by_tenant"][name]
-            at_open = mark["by_tenant"].get(name, zero)
-            entry = {k: at_close.get(k, 0) - at_open[k] for k in zero}
-            g = entry.pop("good")
-            if deadline_s is not None:
-                entry["goodput"] = round(g / elapsed, 2)
-                entry["late"] = entry["completed"] - g
-            per_tenant[name] = entry
-        out["by_tenant"] = per_tenant
-    return out
 
 
 async def run_open_loop(

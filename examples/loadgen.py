@@ -1,15 +1,15 @@
 """Closed-loop load generator for a deployed platform.
 
 Drives a live gateway (any deployment: the `python -m ai4e_tpu
-control-plane` + `worker` process topology, a k8s ingress, or the bench's
-in-proc assembly) and prints one JSON summary line, bench.py-style. Unlike
-bench.py — which builds its own single-process platform — this measures
-whatever is already running, so it is the tool for the production topology.
+control-plane` + `worker` process topology, a k8s ingress, or an in-proc
+assembly) and prints one JSON summary line. It builds no platform of its
+own: it measures whatever is already running, so it is the tool for the
+production topology.
 
 Async mode POSTs the task route and long-polls `/v1/taskmanagement/task/{id}`
 to completion; sync mode measures request/response on the given path. The
-client loop (ramp window, error tolerance, percentile summary) is shared
-with bench.py: ``ai4e_tpu/utils/loadclient.py``.
+client loop (ramp window, error tolerance, percentile summary) is
+``ai4e_tpu/utils/loadclient.py``.
 
     python examples/loadgen.py --gateway http://localhost:8080 \
         --path /v1/landcover/classify-async --payload tile.npy \

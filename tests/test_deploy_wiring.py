@@ -273,7 +273,7 @@ class TestCheckpointServingSizeWiring:
             trained = manifest[name]["kwargs"].get("image_size")
             assert trained is not None, (
                 f"{name} manifest predates the image_size record — retrain "
-                "with the current factory (train_full)")
+                "with the current factory (ai4e_tpu.train.make_checkpoints)")
             served = by_ckpt[name].get("image_size")
             assert served == trained, (
                 f"{name}: models.json serves at {served}, trained at "

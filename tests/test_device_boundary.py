@@ -4,23 +4,16 @@ environment says, and a measurement or smoke run that is not on the chip
 fails instead of producing a result. The start-up cases run a fresh
 interpreter each — ``jax.config`` is process-global."""
 
-import importlib.util
 import json
 import os
 import socket
 import subprocess
 import sys
 import time
-from types import SimpleNamespace
 
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-_spec = importlib.util.spec_from_file_location(
-    "bench", os.path.join(REPO, "bench.py"))
-bench = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(bench)
 
 
 def _env(**overrides) -> dict:
@@ -120,50 +113,15 @@ class TestWorkerRefusesADeviceItWasNotAskedFor:
         assert "AI4E_RUNTIME_PLATFORM=cpu" in output
 
 
-def test_bench_without_cpu_flag_measures_nothing_on_a_chipless_box():
-    res = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                         env=_env(JAX_PLATFORMS="cpu"), capture_output=True,
-                         text=True, timeout=120)
-    assert res.returncode != 0
-    assert res.stdout.strip() == ""  # no metric line
-    assert "platform 'cpu'" in res.stderr
+# -- benchmark/peaks.json: a peak is keyed by the exact device_kind ----------
 
-
-# -- bench.py: the measurement path names its device or measures nothing ---
-
-def _fake_devices(monkeypatch, platform: str, kind: str, count: int = 1):
-    import jax
-    monkeypatch.setattr(jax, "devices", lambda: [
-        SimpleNamespace(platform=platform, device_kind=kind)] * count)
-
-
-class TestBenchPeaksTable:
-    def test_v5e_peak_is_keyed_by_exact_device_kind(self, monkeypatch):
-        _fake_devices(monkeypatch, "tpu", "TPU v5 lite", count=4)
-        assert bench._peak_flops_per_chip() == 197e12
-        assert bench._device_fields() == {
-            "platform": "tpu", "device_kind": "TPU v5 lite",
-            "device_count": 4}
-
-    def test_unknown_tpu_is_an_error_not_a_default(self, monkeypatch):
-        # "TPU v5" used to prefix-match a guessed 197e12.
-        _fake_devices(monkeypatch, "tpu", "TPU v5")
-        with pytest.raises(SystemExit, match="TPU v5"):
-            bench._peak_flops_per_chip()
-
-    def test_cpu_test_mode_claims_no_peak(self, monkeypatch):
-        _fake_devices(monkeypatch, "cpu", "cpu")
-        assert bench._peak_flops_per_chip() is None
-        with pytest.raises(SystemExit, match="platform 'cpu'"):
-            bench._require_tpu()
-
-
-def test_bench_kernel_validation_failure_fails_the_run(monkeypatch):
-    from ai4e_tpu.ops.pallas import validate
-    monkeypatch.setattr(validate, "validate_kernels", lambda interpret: {
-        "flash_attention": {"ok": False, "max_err": 1.0}, "all_ok": False})
-    with pytest.raises(SystemExit, match="flash_attention"):
-        bench._validated_kernels()
+def test_peaks_are_keyed_by_exact_device_kind_with_a_source():
+    with open(os.path.join(REPO, "benchmark", "peaks.json")) as f:
+        peaks = json.load(f)
+    assert peaks["TPU v5 lite"]["source"]
+    # "TPU v5" once prefix-matched a guessed peak: no key may shadow another.
+    assert not [(a, b) for a in peaks for b in peaks
+                if a != b and b.startswith(a)]
 
 
 # -- kernels: which lowering, said once -------------------------------------

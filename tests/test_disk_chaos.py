@@ -106,12 +106,14 @@ class TestCrashPointSweep:
 @pytest.mark.chaos
 @pytest.mark.durability
 class TestDegradedEdge:
-    def test_enospc_and_eio_degrade_then_recovery_readmits(self, tmp_path):
+    def test_enospc_and_eio_degrade_then_recovery_readmits(
+            self, tmp_path, monkeypatch):
+        monkeypatch.setenv("AI4E_TASKSTORE_FSYNC", "always")
+
         async def main():
             metrics = MetricsRegistry()
             platform = LocalPlatform(PlatformConfig(
                 journal_path=str(tmp_path / "journal"),
-                taskstore_fsync="always",
                 retry_delay=0.01), metrics=metrics)
             checker = InvariantChecker().attach(platform.store)
             be = await serve(_completing_backend(platform))

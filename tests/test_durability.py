@@ -916,9 +916,9 @@ class TestAssemblyDefaults:
     def test_platform_default_policy_is_never_and_env_resolves(
             self, tmp_path, monkeypatch):
         from ai4e_tpu.platform_assembly import LocalPlatform, PlatformConfig
-        cfg = PlatformConfig(journal_path=str(tmp_path / "j"))
-        assert cfg.taskstore_fsync is None
-        platform = LocalPlatform(cfg, metrics=MetricsRegistry())
+        platform = LocalPlatform(
+            PlatformConfig(journal_path=str(tmp_path / "j")),
+            metrics=MetricsRegistry())
         assert platform.store._fsync_kind == "never"
         platform.store.close()
         monkeypatch.setenv("AI4E_TASKSTORE_FSYNC", "always")

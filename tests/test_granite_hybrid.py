@@ -328,12 +328,17 @@ def test_roofline_counts_at_the_cell():
 
 # -- the family through the deployed wiring ------------------------------------
 
-def test_the_worker_serves_the_family_through_the_same_wiring():
+def test_the_worker_serves_the_family_through_the_same_wiring(monkeypatch):
     """``"family": "granite-hybrid"`` in a models spec: the same ``cli``
     worker, ``DecodeEngine`` and ``PagedDecodeRuntime`` as the other LM
     families; a state pool beside the K/V pool; the state's bytes counted as
     moved and as live, and the prefills each tick admitted."""
     from ai4e_tpu.cli import build_worker
+    from ai4e_tpu.metrics import MetricsRegistry
+    # A registry of its own: the series below are counted from zero whatever
+    # ran earlier in this process.
+    monkeypatch.setattr("ai4e_tpu.service.app.DEFAULT_REGISTRY",
+                        MetricsRegistry())
     from ai4e_tpu.config import FrameworkConfig
     from ai4e_tpu.runtime.decode import DecodeEngine
     from ai4e_tpu.runtime.kvcache import PagedDecodeRuntime

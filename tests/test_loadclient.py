@@ -1,5 +1,5 @@
-"""The shared closed-loop measurement client (utils/loadclient.py — used by
-bench.py and examples/loadgen.py) against a live aiohttp app that exhibits
+"""The closed-loop measurement client (utils/loadclient.py — used by
+examples/loadgen.py) against a live aiohttp app that exhibits
 the production failure modes it must survive: 503 backpressure, error
 responses, non-JSON bodies, vanished (404) tasks, and tasks stuck
 non-terminal. A load tool pointed at a deployment must record these as

@@ -2,9 +2,8 @@
 worker/batcher/device, and the priority classes keep interactive latency
 flat while a background batch stack saturates the queue — the isolation the
 reference only gets from separate container pools
-(``APIs/Charts/camera-trap/`` side-by-side deployments). The bench-level
-artifact is ``bench.py --model mixed``; this test pins the serving-level
-isolation property on CPU."""
+(``APIs/Charts/camera-trap/`` side-by-side deployments). This test pins
+the serving-level isolation property on CPU."""
 
 import asyncio
 import io

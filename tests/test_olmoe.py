@@ -250,12 +250,17 @@ def test_reference_counts_by_hand():
                       + kv_token * (10_000.0 + 32))
 
 
-def test_the_worker_serves_the_family_through_the_same_wiring():
+def test_the_worker_serves_the_family_through_the_same_wiring(monkeypatch):
     """``"family": "olmoe"`` in a models spec: the same ``cli`` worker,
     ``DecodeEngine`` and ``PagedDecodeRuntime`` as ``seqformer-lm``; a
     bfloat16 pool; the two routing series observed from the step's own
     fetch, over live slots."""
     from ai4e_tpu.cli import build_worker
+    from ai4e_tpu.metrics import MetricsRegistry
+    # A registry of its own: the series below are counted from zero whatever
+    # ran earlier in this process.
+    monkeypatch.setattr("ai4e_tpu.service.app.DEFAULT_REGISTRY",
+                        MetricsRegistry())
     from ai4e_tpu.config import FrameworkConfig
     from ai4e_tpu.runtime.decode import DecodeEngine
     from ai4e_tpu.runtime.kvcache import PagedDecodeRuntime

@@ -242,7 +242,7 @@ class TestFlashAttention:
 class TestValidationHarness:
     def test_validate_kernels_in_interpreter(self):
         """The on-device validation harness (ops/pallas/validate.py — run by
-        bench.py on real TPU) must itself be correct: same checks under the
+        chip_smoke.py on real TPU) must itself be correct: same checks under the
         pallas interpreter pass, and the VMEM accounting stays in budget."""
         from ai4e_tpu.ops.pallas.validate import (
             VMEM_BUDGET_BYTES,
@@ -260,6 +260,24 @@ class TestValidationHarness:
         # dim — never sequence length (the k-axis is a grid axis) — so even
         # the largest serving config (d=128) fits comfortably.
         assert flash_attention_vmem_bytes(128, 128, 128) <= VMEM_BUDGET_BYTES
+
+    def test_main_fails_and_names_the_kernel_that_is_not_ok(
+            self, monkeypatch, capsys):
+        """``chip_smoke.py``'s kernel phase is ``validate.main``: a kernel
+        that is not ok is a non-zero exit and is named on the result line."""
+        import json
+
+        from ai4e_tpu.ops.pallas import validate
+        from ai4e_tpu.runtime import registry
+        monkeypatch.setattr(registry, "enable_compilation_cache",
+                            lambda: None)  # jax.config is process-global
+        monkeypatch.setattr(validate, "validate_kernels", lambda interpret: {
+            "flash_attention": {"ok": False, "max_err": 1.0},
+            "all_ok": False})
+        assert validate.main(["--interpret"]) != 0
+        result = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert result["flash_attention"]["ok"] is False
+        assert result["device"]["platform"] == "cpu"
 
 
 class TestShardOverBatch:

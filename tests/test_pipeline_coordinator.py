@@ -543,45 +543,6 @@ class TestStreamingClients:
 
         asyncio.run(main())
 
-    def test_loadclient_reports_time_to_first_partial(self):
-        async def main():
-            platform, host, spec, gw = await build(
-                PlatformConfig(retry_delay=0.05, pipeline=True),
-                ["a", "b"],
-                lambda h: PipelineSpec("load", "/v1/pipe/load", [
-                    StageSpec("a", h.endpoint("a")),
-                    StageSpec("b", h.endpoint("b"), after=("a",)),
-                ]))
-            host.delays["b"] = 0.15  # the gap TTFP must beat
-            from ai4e_tpu.utils.loadclient import run_closed_loop
-            base = str(gw.make_url("")).rstrip("/")
-            try:
-                window = await run_closed_loop(
-                    gw.session,
-                    post_url=f"{base}/v1/pipe/load",
-                    payload=b'{"w": 1}',
-                    headers={"Content-Type": "application/json"},
-                    mode="async",
-                    status_url_for=(
-                        lambda tid: f"{base}/v1/taskmanagement/task/{tid}"),
-                    events_url_for=(
-                        lambda tid:
-                        f"{base}/v1/taskmanagement/task/{tid}/events"),
-                    concurrency=4, duration=1.5, ramp=0.4,
-                    task_timeout=30.0)
-                assert window["completed"] > 0
-                assert window["first_partials"] > 0
-                # The point of streaming: the first partial lands well
-                # before the end-to-end answer.
-                assert window["time_to_first_partial_ms_p50"] \
-                    < window["p50_latency_ms"]
-            finally:
-                await platform.stop()
-                await gw.close()
-                await host.close()
-
-        asyncio.run(main())
-
 
 class TestAssemblyWiring:
     def test_off_by_default_byte_identical(self):

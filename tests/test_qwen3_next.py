@@ -368,12 +368,17 @@ def test_roofline_counts_at_the_cell():
 
 # -- the family through the deployed wiring ------------------------------------
 
-def test_the_worker_serves_the_family_through_the_same_wiring():
+def test_the_worker_serves_the_family_through_the_same_wiring(monkeypatch):
     """``"family": "qwen3-next"`` in a models spec: the same ``cli`` worker,
     ``DecodeEngine`` and ``PagedDecodeRuntime`` as the other LM families; a
     state pool beside the K/V pool; the routing series and both kinds of
     cache bytes observed from the step's own fetch."""
     from ai4e_tpu.cli import build_worker
+    from ai4e_tpu.metrics import MetricsRegistry
+    # A registry of its own: the series below are counted from zero whatever
+    # ran earlier in this process.
+    monkeypatch.setattr("ai4e_tpu.service.app.DEFAULT_REGISTRY",
+                        MetricsRegistry())
     from ai4e_tpu.config import FrameworkConfig
     from ai4e_tpu.runtime.decode import DecodeEngine
     from ai4e_tpu.runtime.kvcache import PagedDecodeRuntime

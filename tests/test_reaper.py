@@ -186,8 +186,7 @@ class TestNoResurrection:
 
 class TestAutoRetentionDefault:
     """Terminal-history retention defaults (the 20-min soak finding: an
-    unevicted control plane grows ~12 MB/min at 200 req/s — scripts/soak.sh,
-    bench_results/r5-cpu/). None = AUTO (15 min on the Python store), 0
+    unevicted control plane grows ~12 MB/min at 200 req/s — scripts/soak.sh). None = AUTO (15 min on the Python store), 0
     keeps its pre-AUTO evict-immediately meaning, negative opts out,
     native store = no eviction support."""
 

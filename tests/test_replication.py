@@ -484,8 +484,7 @@ class TestStoreClientFailoverPatience:
         (~1.5 s) expired inside the promotion window; patience must cover
         the DEFAULT watchdog's detection (failover_down_after ×
         failover_interval = 6 s) with margin. Lowering these defaults is
-        a deliberate act, not a drive-by (scripts/ha_failover_drive.py,
-        bench_results/r5-cpu/ha_failover_drive.json)."""
+        a deliberate act, not a drive-by (scripts/ha_failover_drive.py)."""
         from ai4e_tpu.config import PlatformSection
         from ai4e_tpu.service.task_manager import HttpTaskManager
 

@@ -668,7 +668,7 @@ class TestEvictionScales:
         """Eviction must be O(victims' results), not O(victims × all
         results): the 40-min soak wedged the control plane for minutes when
         ~6k victims each scanned ~190k result keys under the store lock
-        (bench_results/r5-cpu/). 20k tasks-with-results evicted here in
+        (scripts/soak.sh). 20k tasks-with-results evicted here in
         well under the old quadratic path's ~40 s."""
         import time as _time
 
