@@ -7,7 +7,8 @@ A MoE layer, for a row ``h`` (after its norm)::
     p = softmax(h W_r)                      over ALL ``total`` experts, float32
         (or sigmoid(h W_r), an expert's own score, where the family says)
     the K largest p and their experts       (a tie to the lower index; of
-        p + b where the family keeps a bias b for the choice alone)
+        p + b where the family keeps a bias b for the choice alone; inside
+        the best few of the experts' groups where the family limits them)
     p <- p / sum of the K                   where the family renormalises
     y = sum over the row's K experts e of p_e * W_down,e(silu(W_gate,e h) * W_up,e h)
 
@@ -65,16 +66,34 @@ def _dot(eq, a, b):
     return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
 
 
+def kept_groups(choice, groups: tuple):
+    """The group limit of a router: ``choice (..., total)`` — what the
+    experts are chosen by — in ``groups = (n, keep)``: ``n`` groups of
+    ``total / n`` neighbours, a group scored by the sum of its two largest,
+    the ``keep`` best groups kept (a tie to the lower group). Returns
+    ``choice`` with the experts of the other groups at ``-inf``."""
+    n, keep = groups
+    per_group = choice.reshape(*choice.shape[:-1], n, -1)
+    best_two, _ = jax.lax.top_k(per_group, 2)
+    _, kept = jax.lax.top_k(best_two.sum(axis=-1), keep)
+    allowed = jax.nn.one_hot(kept, n, dtype=jnp.bool_).any(axis=-2)
+    return jnp.where(allowed[..., None], per_group,
+                     -jnp.inf).reshape(choice.shape)
+
+
 def route(h, router, k: int, renormalise: bool = False,
-          scoring: str = "softmax", bias=None, scale: float = 1.0):
+          scoring: str = "softmax", bias=None, scale: float = 1.0,
+          groups: tuple | None = None):
     """``h (..., D)`` (after the layer's norm), ``router (D, total)`` → the
     chosen experts ``(..., K)``, ids over ``total``, and their weights
     ``(..., K)`` in float32: the scores — ``scoring``: the softmax
     probabilities, or each expert's own ``sigmoid`` — of the K largest,
     divided by their sum where ``renormalise`` and multiplied by ``scale``.
     ``bias (total,)``, where given, is added to the scores for the CHOICE
-    alone: the weights are the scores without it. ``top_k`` breaks a tie
-    toward the lower expert index and returns exactly K."""
+    alone: the weights are the scores without it. ``groups = (n, keep)``,
+    where given, limits the choice to the ``keep`` best of ``n`` groups of
+    experts (``kept_groups``). ``top_k`` breaks a tie toward the lower
+    expert index and returns exactly K."""
     with jax.named_scope("router"):
         logits = _dot("...d,de->...e", h, router)
         if scoring == "softmax":
@@ -83,10 +102,13 @@ def route(h, router, k: int, renormalise: bool = False,
             p = jax.nn.sigmoid(logits)
         else:
             raise ValueError(f"unknown scoring {scoring!r}")
-        if bias is None:
+        if bias is None and groups is None:
             top_p, top_e = jax.lax.top_k(p, k)
         else:
-            _, top_e = jax.lax.top_k(p + bias.astype(jnp.float32), k)
+            choice = p if bias is None else p + bias.astype(jnp.float32)
+            if groups is not None:
+                choice = kept_groups(choice, groups)
+            _, top_e = jax.lax.top_k(choice, k)
             top_p = jnp.take_along_axis(p, top_e, axis=-1)
         if renormalise:
             top_p = top_p / top_p.sum(axis=-1, keepdims=True)

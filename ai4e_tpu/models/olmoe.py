@@ -122,17 +122,22 @@ def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
     return 0.1 * mscale * float(np.log(factor)) + 1.0 if factor > 1 else 1.0
 
 
-def rope(x, position, theta, inv_freq=None):
-    """Rotate-half rotary embedding of ``x (..., heads, head_dim)`` at
-    ``position (...)``, in float32, cast back. ``inv_freq (head_dim / 2,)``,
-    where a family scales its frequencies (``yarn_inv_freq``), stands in for
-    ``theta``'s own."""
+def rope(x, position, theta, inv_freq=None, interleave: bool = False):
+    """Rotary embedding of ``x (..., heads, head_dim)`` at ``position
+    (...)``, in float32, cast back: pair ``i`` is the lanes ``(i, i + half)``
+    (rotate-half) or, where ``interleave``, the neighbours ``(2i, 2i + 1)``.
+    ``inv_freq (head_dim / 2,)``, where a family scales its frequencies
+    (``yarn_inv_freq``), stands in for ``theta``'s own."""
     half = x.shape[-1] // 2
     if inv_freq is None:
         inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = position.astype(jnp.float32)[..., None, None] * inv_freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     h = x.astype(jnp.float32)
+    if interleave:
+        a, b = h[..., 0::2], h[..., 1::2]
+        return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
     a, b = h[..., :half], h[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
                            axis=-1).astype(x.dtype)

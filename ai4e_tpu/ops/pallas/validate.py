@@ -355,11 +355,12 @@ def validate_kernels(interpret: bool = False) -> dict:
 
     # the state update at the live slots of the pool vs the families' own
     # jax.numpy recurrences — the two cells' blocks (Mamba-2: 64 heads of 64
-    # by a state of 128, the pool's (128, 4096) a slot; the delta rule: 32
-    # heads of 128 x 128; 2 MB a slot both), eight slots of which five are
-    # live: the first, the last and a dead one between; a dead slot's state
-    # must come back bit for bit.
-    from ...models import granite_hybrid, qwen3_next
+    # by a state of 128, the pool's (128, 4096) a slot; the delta rule and
+    # Kimi Delta Attention, whose decay is a column of the block and no
+    # scalar: 32 heads of 128 x 128; 2 MB a slot all), eight slots of which
+    # five are live: the first, the last and a dead one between; a dead
+    # slot's state must come back bit for bit.
+    from ...models import granite_hybrid, ling3, qwen3_next
     position = np.asarray([3, 0, 9, 1, 0, 0, 700, 12], np.int32)
     live = position > 0
     slots = len(position)
@@ -390,11 +391,19 @@ def validate_kernels(interpret: bool = False) -> dict:
         return qwen3_next.delta_rule_update(*args, position,
                                             interpret=interpret)
 
+    # a head's channels from the gate's bound (-5) to nearly 0
+    g_channel = -5.0 * jax.nn.sigmoid(normal(slots, hv, dk, scale=3.0))
+
+    def kda(*args):
+        return ling3.kda_update(*args, position, interpret=interpret)
+
     for name, run, oracle, args, flat in (
             ("ssd", ssd, granite_hybrid.ssd_step,
              (state, x, dt, a, b, c), (slots, h * p)),
             ("delta_rule", delta_rule, qwen3_next.delta_rule_step,
-             (delta, q, k, v, g, beta), (slots, hv, dk))):
+             (delta, q, k, v, g, beta), (slots, hv, dk)),
+            ("kda", kda, ling3.kda_step,
+             (delta, q, k, v, g_channel, beta), (slots, hv, dk))):
         out, new = (np.asarray(r) for r in jax.jit(run)(*args))
         want_out, want_new = (np.asarray(r) for r in oracle(*args))
         err = max(

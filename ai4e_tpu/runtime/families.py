@@ -706,6 +706,28 @@ def build_xing4_lm(name: str = "lm", vocab_size: int = 512,
                       vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
 
 
+def build_ling3_lm(name: str = "lm", vocab_size: int = 512,
+                   max_len: int = 256, eos_id: int | None = None,
+                   rng=None, dtype: str = "bfloat16", **dims):
+    """The KDA / latent-attention hybrid (``models/ling3.py`` ``Ling3LM``):
+    Kimi Delta Attention layers — a recurrent state a slot that decays by a
+    bounded gate a key channel — with a latent-attention layer behind a
+    head-wise gate every ``group``-th, one latent row a position;
+    ``dense_layers`` leading dense MLPs, then sigmoid-routed experts chosen
+    inside the best ``route_groups[1]`` of ``route_groups[0]`` groups, of
+    which this process holds ``experts_held`` from ``first_expert``, with an
+    ungated shared expert; untied head, bfloat16 weights and cache.
+    ``dims``: the model's fields and the published SwiGLU limits of the held
+    layers (all 0, or an error); a key the family does not know is an
+    error, not a default."""
+    from ..models.ling3 import create_ling3_lm
+    from .kvcache import LMServable
+    model, params = create_ling3_lm(rng=rng, vocab_size=vocab_size,
+                                    dtype=dtype, **dims)
+    return LMServable(name=name, model=model, params=params,
+                      vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
+
+
 # LM families ride the decode engine (``runtime/decode.py``), never the
 # MicroBatcher: ``cli`` tells them from the batch families by this table.
 LM_FAMILIES = {
@@ -715,6 +737,7 @@ LM_FAMILIES = {
     "granite-hybrid": build_granite_hybrid_lm,
     "dots3": build_dots3_lm,
     "xing4": build_xing4_lm,
+    "ling3": build_ling3_lm,
 }
 
 

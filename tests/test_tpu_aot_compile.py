@@ -798,3 +798,77 @@ def test_xing4_step_at_the_benchmark_cell_moves_no_pool(
               f"{prefill.temp_size_in_bytes} + outputs "
               f"{prefill.output_size_in_bytes}, peak {peak}")
         assert peak < 15.0e9, (top, peak, prefill.temp_size_in_bytes)
+
+
+@pytest.fixture(scope="module")
+def ling3_cell():
+    from ai4e_tpu.models.ling3 import Ling3LM, create_ling3_lm
+    from benchmark.references.ling3 import NOT_MODEL_KEYS
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "ling-3.0-flash.json")) as f:
+        spec = json.load(f)["models"]["models"][0]
+    limits = ("expert_swiglu_limits", "shared_swiglu_limits")
+    keys = [key for key in spec if key not in NOT_MODEL_KEYS + limits]
+    spec["route_groups"] = tuple(spec["route_groups"])
+    return _benchmark_cell("ling-3.0-flash.json", create_ling3_lm, Ling3LM,
+                           keys)
+
+
+@pytest.mark.parametrize("rung", [0, 1])
+def test_ling3_step_at_the_benchmark_cell_moves_no_pool(
+        v5e_sharding, ling3_cell, rung):
+    """The ``ling3.toolctx`` cell (``benchmark/configs/ling-3.0-flash.json``):
+    one tensor of latent rows ``(1, 96, 9216, 640)`` made only by row writes
+    on its donated parameter, read by ONE ``latent_attention`` Mosaic call;
+    six ``f32[96,32,128,128]`` KDA states, each advanced by one
+    ``state_update`` Mosaic call on the donated parameter itself, and six
+    convolution tails, every tensor aliased input to output with temporaries
+    smaller than ONE state tensor. At the top rung, the whole worker's
+    memory: weights + pools + the temporaries and outputs of the 2,048 and
+    8,192 prefills and of the cache length 9,216 the runtime adds stay under
+    15 GB, each prefill with its ``prompt_attention`` by Mosaic."""
+    import importlib
+    from ai4e_tpu.ops import state_pool
+    runtime, spec = ling3_cell
+    assert runtime.step_bounds == (6912, 9216)
+    shape = (1, 96, 9216, 640)
+    assert runtime.cache_spec() == ((shape, jnp.bfloat16),)
+    state = runtime.state_spec()
+    assert len(state) == 12
+    assert state[0] == ("kda0", (32, 128, 128), jnp.float32)
+    assert state[1] == ("conv0", (3, 12288), jnp.bfloat16)
+    one_state = 96 * 32 * 128 * 128 * 4
+    bound = runtime.step_bounds[rung]
+    compiled = _compile_step(runtime, v5e_sharding, bound)
+    assert len(_mosaic_calls(compiled, "latent_attention")) == 1
+    _assert_state_steps_in_place(
+        compiled, _hlo_type((96, 32, 128, 128), jnp.float32), 6)
+    memory = compiled.memory_analysis()
+    pools = runtime.cache_nbytes()
+    assert pools == 2 * 96 * 9216 * 640 + state_pool.nbytes(state, 96)
+    assert memory.alias_size_in_bytes >= pools
+    assert memory.temp_size_in_bytes < one_state, memory.temp_size_in_bytes
+    if bound < runtime.max_len:
+        return
+
+    resident = memory.argument_size_in_bytes   # weights + pools (+ ints)
+    assert 12.7e9 < resident < 13.0e9, resident
+    flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
+    for top in (2048, 8192, runtime.max_len):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(flash, "resolve_interpret",
+                          lambda kernel, interpret: False)
+            prefill = runtime._programs["prefill"].lower(
+                _on(v5e_sharding, runtime.servable.params),
+                _on(v5e_sharding, ((1, top), jnp.int32)),
+                _on(v5e_sharding, ((1,), jnp.int32))).compile()
+        assert len(_mosaic_calls(prefill, "prompt_attention")) == 1
+        prefill = prefill.memory_analysis()
+        peak = resident + max(memory.temp_size_in_bytes,
+                              prefill.temp_size_in_bytes
+                              + prefill.output_size_in_bytes)
+        print(f"ling3 cell: resident {resident}, step temporaries "
+              f"{memory.temp_size_in_bytes}, prefill {top}: temporaries "
+              f"{prefill.temp_size_in_bytes} + outputs "
+              f"{prefill.output_size_in_bytes}, peak {peak}")
+        assert peak < 15.0e9, (top, peak, prefill.temp_size_in_bytes)
