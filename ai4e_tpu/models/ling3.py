@@ -50,9 +50,9 @@ layers (padded to whole lane tiles), and per KDA layer its state ``kda<j>``
 advances at the live slots only, in place (``kda_update``:
 ``state_pool.update_live`` with ``kda_block`` as the slot's math), the
 latent read is the absorbed form (``kv_pool.latent_decode_attention``).
-``prefill`` runs the recurrence in chunks (``kda_chunked``) and the latent
-layers in the published form (``kv_pool.prompt_attention``), one prompt a
-call. The experts' product is ``routed`` in a prefill and ``dense`` over the
+``prefill`` runs a KDA layer's convolution and recurrence in chunks, one
+kernel (``ops/pallas/kda_chunk.py``), and the latent layers in the published
+form (``kv_pool.prompt_attention``), one prompt a call. The experts' product is ``routed`` in a prefill and ``dense`` over the
 held experts in a step.
 
 Weights, activations and the latent rows are ``dtype`` (bfloat16 as served);
@@ -61,7 +61,6 @@ the state, the gates, routing and accumulation float32.
 
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import flax.linen as nn
@@ -70,22 +69,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import kv_pool, state_pool
+from ..ops.pallas.kda_chunk import CHUNK, SUB_BLOCK, kda_chunk
 from . import experts as expert_layer
 from .dots3 import padded
 from .olmoe import norm_scale, rms_norm, rope, seeded
-from .qwen3_next import l2_norm
-
-CHUNK = 64       # tokens a chunk of the prefill's recurrence
-SUB_BLOCK = 16   # tokens whose decays are factored about one of them
-CHUNK_GROUP = 16  # chunks whose state-free algebra is worked out at once
+from .qwen3_next import L2_EPS, l2_norm
 
 # The seeded init's gains (``create_ling3_lm`` says why these).
 INIT_GAINS = {"w_a": 0.5, "dt_bias": -5.5, "dt_spread": 1.0, "kda_out": 0.5,
               "w_q": 1.5, "w_g": 1.5, "w_o": 1.0, "router": 2.0,
               "router_bias": 0.2, "w_down": 0.2, "shared_down": 0.15,
               "mlp_down": 0.15}
-
-HIGHEST = jax.lax.Precision.HIGHEST
 
 # The ``jax.named_scope``s of this family's programs, for a trace's reader.
 TRACE_SCOPES = ("embedding", "kda_proj", "conv", "kda_gate", "kda_chunk",
@@ -99,21 +93,14 @@ def _dot(eq, a, b):
     return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
 
 
-def _dot32(eq, a, b):
-    """A float32 product at full precision (the MXU's default would round
-    float32 operands to bfloat16: the state is kept in float32 for a
-    reason)."""
-    return jnp.einsum(eq, a, b, precision=HIGHEST,
-                      preferred_element_type=jnp.float32)
-
-
 def _lane_pad(x, width: int):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
 
 
 def kda_step(state, q, k, v, g, beta):
     """One token of the recurrence for every (slot, head), in ``jax.numpy``
-    — the equation ``kda_block`` and ``kda_chunked`` are held to. state:
+    — the equation ``kda_block`` and the prefill's ``kda_chunk`` kernel are
+    held to. state:
     (..., dk, dv) float32; q, k: (..., dk) — normalised, q scaled; v: (...,
     dv); g: (..., dk), a channel's log-decay; beta: (...). Returns ``(o
     (..., dv), new state)``. Both readings of the decayed state (``Sᵀk``,
@@ -163,103 +150,6 @@ def kda_update(state, q, k, v, g, beta, position, interpret=None):
         state, (jnp.stack([q, k, g], axis=1).swapaxes(-1, -2), v,
                 jnp.stack([beta, (k * q).sum(axis=-1)], axis=1)),
         position, kda_block, (v.shape[1:], jnp.float32), interpret)
-
-
-def kda_chunked(qkv, g, beta):
-    """The same recurrence over a whole sequence from a zero state, ``CHUNK``
-    tokens at a time. g: (B, T, H, d); beta: (B, T, H); float32.
-    ``qkv(first, count)`` gives the recurrence's q, k and v — (B, count, H,
-    d) each, float32 — of the ``count`` tokens from ``first`` (traced), for
-    any stretch inside ``T`` rounded up to whole chunks: the caller makes
-    them of whatever it holds (the convolution's input), ``CHUNK_GROUP``
-    chunks at a time, so that no float32 copy of the whole sequence's is
-    made. A position with ``g = 0`` and ``beta = 0`` leaves the state as it
-    was (padding). Returns ``(o (B, T, H, d), state (B, H, d, d))`` after
-    the last position.
-
-    Within a chunk, with ``G_t`` the running sum of ``g`` (a vector over the
-    key channels): the tokens' corrections solve ``(I + L) Δ = β V − (β K ⊙
-    e^{G}) S_0`` with ``L_tj = β_t Σ_c k_tc k_jc e^{G_tc − G_jc}`` (``j <
-    t``) — the unit triangular system of ``qwen3_next.delta_rule_chunked``,
-    inverted the same way —, and ``o_t = S_0ᵀ(e^{G_t} ⊙ q_t) + Σ_{j≤t} (Σ_c
-    q_tc k_jc e^{G_tc − G_jc}) δ_j``. A decay a channel does not factor out
-    of those sums as a scalar's does, and ``(x ⊙ e^{G})(k ⊙ e^{−G})ᵀ``
-    overflows; but ``g`` is bounded below (−5 a token), so about the first
-    row ``r`` of the ``SUB_BLOCK`` tokens a query lies in, ``e^{G_t − G_r} ≤
-    1`` and ``e^{G_r − G_j}`` is at most ``e^{5 (SUB_BLOCK − 1)}`` for a key
-    of the same tokens and at most 1 for an earlier one: each block of
-    ``SUB_BLOCK`` queries is ONE product against the chunk's keys so far.
-    All float32 at full precision. What does not depend on the state — the
-    scores, the solve — is worked out for ``CHUNK_GROUP`` chunks at once
-    (products batched over them and the heads), then their states in turn:
-    the temporaries are a group's, whatever the length."""
-    chunk, sub = CHUNK, SUB_BLOCK
-    b, t, h, dk = g.shape
-    pad = -t % chunk
-    if pad:
-        g, beta = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-                   for a in (g, beta))
-    n, blocks = (t + pad) // chunk, chunk // sub
-    group = math.gcd(n, CHUNK_GROUP)
-
-    def groups(a):   # (B, T, ...) -> (N / group, B, group x C, ...)
-        return jnp.moveaxis(a.reshape(b, n // group, group * chunk,
-                                      *a.shape[2:]), 1, 0)
-
-    def chunks(a):   # a group's (B, group x C, H, ...) -> (group, B, H, C, ...)
-        a = a.reshape(b, group, chunk, *a.shape[2:])
-        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
-
-    seen = (np.arange(chunk) // sub)[None, :] <= np.arange(blocks)[:, None]
-    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-
-    def one_chunk(state, xs):
-        u_i, w_i, within_i, q_i, k_i, last_i = xs
-        v_new = u_i - _dot32("...ck,...kd->...cd", w_i, state)
-        o_i = (_dot32("...ck,...kd->...cd", q_i, state)
-               + _dot32("...ij,...jd->...id", within_i, v_new))
-        return state * last_i + _dot32("...ck,...cd->...kd", k_i, v_new), o_i
-
-    def one_group(state, xs):
-        i, g, beta = xs
-        q, k, v, g, beta = map(chunks, (                      # (group,B,H,C,.)
-            *qkv(i * group * chunk, group * chunk), g, beta))
-        g_sum = jnp.cumsum(g, axis=-2)
-        lead = g_sum.shape[:-2]
-        first = g_sum[..., ::sub, :]                          # (..,blocks,dk)
-        # a query's factor about its block's first row: <= 1
-        late = jnp.exp(g_sum.reshape(*lead, blocks, sub, dk)
-                       - first[..., :, None, :])
-        # a key's factor about each block's first row, for the keys of that
-        # block and of the earlier ones; a later block's keys are no query's
-        k_early = k[..., None, :, :] * jnp.exp(jnp.where(
-            seen[..., None], first[..., :, None, :] - g_sum[..., None, :, :],
-            -jnp.inf))                                        # (..,blocks,C,dk)
-
-        def scores(x):   # sum_c x_tc k_jc e^{G_tc - G_jc}, (.., C, C): j <= t
-            x = x.reshape(*lead, blocks, sub, dk) * late
-            return _dot32("...aik,...ajk->...aij", x, k_early).reshape(
-                *lead, chunk, chunk)
-
-        x = -jnp.where(jnp.tril(lower, -1), scores(k) * beta[..., None], 0.0)
-        solve = jnp.eye(chunk, dtype=jnp.float32) + x
-        for _ in range(int(np.ceil(np.log2(chunk))) - 1):
-            x = _dot32("...ij,...jk->...ik", x, x)
-            solve = solve + _dot32("...ij,...jk->...ik", solve, x)
-        decay = jnp.exp(g_sum)
-        u = _dot32("...ij,...jd->...id", solve, v * beta[..., None])
-        w = _dot32("...ij,...jd->...id", solve, k * beta[..., None] * decay)
-        return jax.lax.scan(one_chunk, state, (
-            u, w, jnp.where(lower, scores(q), 0.0), q * decay,
-            k * jnp.exp(g_sum[..., -1:, :] - g_sum),
-            jnp.exp(g_sum[..., -1, :])[..., None]))
-
-    state, o = jax.lax.scan(
-        one_group, jnp.zeros((b, h, dk, dk), jnp.float32),
-        (jnp.arange(n // group), groups(g), groups(beta)))
-    # (N / group, group, B, H, C, dv) -> (B, T, H, dv)
-    o = jnp.moveaxis(o.reshape(n, b, h, chunk, -1), (0, 3), (1, 2))
-    return o.reshape(b, n * chunk, h, -1)[:, :t], state
 
 
 class _Layer(nn.Module):
@@ -418,10 +308,9 @@ class _Layer(nn.Module):
                             self.out_proj).astype(self.dtype)
 
     def _kda_prompt(self, x, mask, length):
-        """The mixer over padded prompts ``x (B, P, D)`` → ``x + KDA`` and
-        ``(state (B, H, d, d), tail (B, conv − 1, 3 H d))`` after ``length``
+        """The mixer over ONE padded prompt ``x (1, P, D)`` → ``x + KDA`` and
+        ``(state (1, H, d, d), tail (1, conv − 1, 3 H d))`` after ``length``
         tokens."""
-        p = x.shape[1]
         mixed, z, beta, g = self._project(x)
         keep = self.conv - 1
         with jax.named_scope("conv"):
@@ -430,24 +319,14 @@ class _Layer(nn.Module):
             at = length[:, None] - keep + jnp.arange(keep)[None, :]
             tail = jnp.where((at >= 0)[..., None], jnp.take_along_axis(
                 mixed, jnp.maximum(at, 0)[..., None], axis=1), 0)
-            # zeros before the sequence's start, and up to whole chunks
-            shifted = jnp.pad(mixed, ((0, 0), (keep, -p % CHUNK), (0, 0)))
-            w = self.conv_w.astype(jnp.float32)
-
-        def qkv(first, count):
-            with jax.named_scope("conv"):
-                rows = jax.lax.dynamic_slice_in_dim(shifted, first,
-                                                    count + keep, axis=1)
-                out = jax.nn.silu(sum(
-                    rows[:, j:j + count].astype(jnp.float32) * w[j]
-                    for j in range(self.conv)))
-            return self._heads(out)
-
         with jax.named_scope("kda_chunk"):
-            o, state = kda_chunked(
-                qkv, jnp.where(mask[..., None, None], g, 0.0),
-                jnp.where(mask[..., None], beta, 0.0))
-        return self._kda_out(x, o, z), (state, tail)
+            # the convolution, q's and k's norms and the recurrence, one
+            # kernel; a padded position leaves the state as it was
+            o, state = kda_chunk(
+                mixed[0], self.conv_w.astype(jnp.float32),
+                jnp.where(mask[0, :, None, None], g[0], 0.0),
+                jnp.where(mask[0, :, None], beta[0], 0.0), eps=L2_EPS)
+        return self._kda_out(x, o[None], z), (state[None], tail)
 
     def _kda_token(self, x, state, tail, position):
         """The mixer of one token a slot: ``x (S, D)`` → ``x + KDA`` and the

@@ -2,8 +2,8 @@
 
 The serving kernels (``flash_attention``, ``segmentation_argmax``,
 ``normalize_image``, ``decode_attention``, ``latent_attention``,
-``prompt_attention``, ``index_scores``, ``state_update``) default to
-interpret mode off-TPU, so CPU CI never
+``prompt_attention``, ``index_scores``, ``state_update``, ``kda_chunk``)
+default to interpret mode off-TPU, so CPU CI never
 proves they compile to Mosaic and fit VMEM on real hardware. This module is
 that proof: ``validate_kernels()`` runs each kernel with ``interpret=False``
 (on TPU) against a pure-XLA oracle and asserts its working set fits the
@@ -59,6 +59,23 @@ def state_update_vmem_bytes(block_bytes: int, small_bytes: int) -> int:
     number is the call's ``vmem_limit_bytes``)."""
     from .state_update import vmem_bytes
     return vmem_bytes(block_bytes, small_bytes)
+
+
+def kda_chunk_vmem_bytes(head_block: int, d: int, dtype_bytes: int = 2,
+                         taps: int = 4) -> int:
+    """A chunk's blocks ``(CHUNK, head_block · d)`` — the convolution's
+    input three times (q's, k's and v's lanes) with the ``HALO`` rows before
+    each, in its dtype; g and o in float32 —, the taps' three blocks (a
+    sublane tile each) and the head block's states ``(head_block, d, d)``,
+    double-buffered all, β's block (a lane tile a row); and for the values
+    of the heads in work — their q, k, v, the CHUNK-square matrices, the
+    products — four times one chunk's float32 block."""
+    from .kda_chunk import CHUNK, HALO
+    lanes = head_block * d
+    blocks = (3 * (CHUNK + HALO) * lanes * dtype_bytes
+              + 3 * max(taps, 8) * lanes * 4 + 2 * CHUNK * lanes * 4
+              + head_block * d * d * 4 + CHUNK * 128 * 4)
+    return 2 * blocks + 4 * CHUNK * lanes * 4
 
 
 # What the chip has of VMEM, which a call may ask for beyond the scoped
@@ -422,6 +439,60 @@ def validate_kernels(interpret: bool = False) -> dict:
         results[f"state_update_{name}"] = {
             "ok": bool(err < 1e-4 and untouched), "max_err": round(err, 7),
             "vmem_bytes": vmem}
+
+    # the prefill's kernel — the convolution, q's and k's norms and the
+    # chunked Kimi-Delta recurrence — vs ``jax.numpy`` and ``kda_step`` token
+    # by token, at the ``ling3`` cell's shapes: 32 heads of 128 over a prompt
+    # of 2,048 from a bfloat16 input (8 heads over 256 under the
+    # interpreter), with the gate AT its bound on every channel — the
+    # sub-block factors reach e^75 — and drawn from the bound to nearly 0
+    # inside a head. Timed where compiled.
+    from .kda_chunk import CHUNK, HEAD_BLOCK, kda_chunk
+    hv, t = (8, 256) if interpret else (32, 2048)
+    eps = qwen3_next.L2_EPS
+
+    def recurrence(mixed, taps, g, beta):
+        shifted = jax.numpy.pad(mixed.astype("float32"),
+                                ((len(taps) - 1, 0), (0, 0)))
+        out = jax.nn.silu(sum(shifted[j:j + t] * taps[j]
+                              for j in range(len(taps))))
+        q, k, v = (out[:, i * hv * dk:(i + 1) * hv * dk].reshape(t, hv, dk)
+                   for i in range(3))
+
+        def token(state, xs):
+            out, state = ling3.kda_step(state, *xs)
+            return state, out
+        state, out = jax.lax.scan(
+            token, jax.numpy.zeros((hv, dk, dk), jax.numpy.float32),
+            (qwen3_next.l2_norm(q) * dk ** -0.5, qwen3_next.l2_norm(k), v, g,
+             beta))
+        return out, state
+
+    run = jax.jit(lambda *a: kda_chunk(*a, eps=eps, interpret=interpret))
+    vmem = kda_chunk_vmem_bytes(HEAD_BLOCK, dk)
+    assert vmem <= VMEM_BUDGET_BYTES, f"kda chunk VMEM {vmem}"
+    for name, g in (
+            ("bound", jax.numpy.full((t, hv, dk), -5.0)),
+            ("drawn", -5.0 * jax.nn.sigmoid(normal(t, hv, dk, scale=3.0)
+                                            - 3.0))):
+        args = (normal(t, 3 * hv * dk).astype("bfloat16"),
+                normal(4, 3 * hv * dk, scale=0.35), g,
+                jax.nn.sigmoid(normal(t, hv)))
+        got = [np.asarray(r) for r in run(*args)]
+        want = [np.asarray(r) for r in jax.jit(recurrence)(*args)]
+        err = max(float(np.abs(a - b).max()) for a, b in zip(got, want))
+        entry = {"ok": bool(err < 1e-4 and all(
+            np.isfinite(a).all() for a in got)), "max_err": round(err, 9),
+            "vmem_bytes": vmem}
+        if not interpret:
+            t0 = time.perf_counter()
+            for _ in range(5):
+                out = run(*args)
+            jax.block_until_ready(out)
+            seconds = (time.perf_counter() - t0) / 5
+            entry.update(ms=round(seconds * 1e3, 3), us_a_chunk_a_head=round(
+                seconds * 1e6 / (t // CHUNK * hv), 3))
+        results[f"kda_chunk_{name}"] = entry
 
     results["all_ok"] = all(r["ok"] for r in results.values()
                             if isinstance(r, dict))

@@ -171,38 +171,55 @@ def _recurrence_inputs(seed, t, heads=3, d=16, gate="drawn"):
     return q, k, v, g, beta
 
 
-def _token_by_token(q, k, v, g, beta):
+def _recurrence(q, k, v, g, beta):
+    """``kda_step`` token by token over one sequence from a zero state: q,
+    k, v, g (t, H, d), beta (t, H) → ``(o (t, H, d), state (H, d, d))``."""
     def token(state, xs):
         o, state = ling3.kda_step(state, *xs)
         return state, o
 
-    b, _, heads, d = q.shape
-    state, o = jax.lax.scan(
-        token, jnp.zeros((b, heads, d, v.shape[-1]), jnp.float32),
-        tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta)))
-    return jnp.moveaxis(o, 0, 1), state
+    _, heads, d = q.shape
+    state, o = jax.lax.scan(token, jnp.zeros((heads, d, d), jnp.float32),
+                            (q, k, v, g, beta))
+    return o, state
 
 
-def _chunked(q, k, v, g, beta):
-    """``kda_chunked`` handed q, k and v as arrays: a stretch is a slice of
-    them, padded up to whole chunks."""
-    whole = [jnp.pad(a, ((0, 0), (0, -a.shape[1] % ling3.CHUNK), (0, 0),
-                         (0, 0))) for a in (q, k, v)]
-    return ling3.kda_chunked(
-        lambda first, count: tuple(jax.lax.dynamic_slice_in_dim(
-            a, first, count, axis=1) for a in whole), g, beta)
+def _prompt_inputs(seed, t, heads=3, d=16, gate="drawn", dtype="float32"):
+    """What the prefill's kernel takes of one sequence — the convolution's
+    input ``[q | k | v] (t, 3 H d)`` in ``dtype``, its taps, g and β — and
+    the q, k, v the recurrence sees of them, in ``jax.numpy``: SiLU of the
+    causal depthwise convolution as a sliding product, q and k normalised a
+    head, q scaled."""
+    rng = np.random.default_rng(1000 + seed)
+    mixed = jnp.asarray(rng.standard_normal((t, 3 * heads * d)), dtype)
+    taps = jnp.asarray(0.35 * rng.standard_normal((4, 3 * heads * d)),
+                       jnp.float32)
+    g, beta = (a[0] for a in _recurrence_inputs(seed, t, heads, d, gate)[3:])
+    shifted = jnp.pad(mixed.astype(jnp.float32), ((len(taps) - 1, 0), (0, 0)))
+    out = jax.nn.silu(sum(shifted[j:j + t] * taps[j]
+                          for j in range(len(taps))))
+    q, k, v = (out[:, i * heads * d:(i + 1) * heads * d].reshape(t, heads, d)
+               for i in range(3))
+    return (mixed, taps, g, beta), (ling3.l2_norm(q) * d ** -0.5,
+                                    ling3.l2_norm(k), v)
 
 
-# both sides of a sub-block's edge, of a chunk's, several chunks, and more
-# chunks than the algebra takes at once
+def _chunked(mixed, taps, g, beta):
+    """The prefill's kernel (``ops/pallas/kda_chunk.py``; the interpreter
+    here) on the one sequence."""
+    return ling3.kda_chunk(mixed, taps, g, beta, eps=ling3.L2_EPS)
+
+
+# both sides of a sub-block's edge, of a chunk's (64: exactly one), several
+# chunks, and a good many
 @pytest.mark.parametrize("t", [15, 16, 17, 63, 64, 65, 150, 1100])
 @pytest.mark.parametrize("gate", ["bound", "near_0", "drawn"])
 def test_kda_chunked_is_the_recurrence_token_by_token(gate, t):
     """With ``g = −5`` on every channel of every token the factors about a
     sub-block's first row reach ``e^75``: nothing overflows, nothing is NaN,
     and the outputs are the recurrence's."""
-    inputs = _recurrence_inputs(t, t, gate=gate)
-    want_o, want_state = _token_by_token(*inputs)
+    inputs, qkv = _prompt_inputs(t, t, gate=gate)
+    want_o, want_state = _recurrence(*qkv, *inputs[2:])
     got_o, got_state = jax.jit(_chunked)(*inputs)
     assert bool(jnp.isfinite(got_o).all() & jnp.isfinite(got_state).all())
     assert np.abs(np.asarray(got_o - want_o)).max() < 2e-6
@@ -210,13 +227,28 @@ def test_kda_chunked_is_the_recurrence_token_by_token(gate, t):
 
 
 def test_kda_chunked_leaves_the_state_alone_at_padded_positions():
-    q, k, v, g, beta = _recurrence_inputs(9, 100)
+    (mixed, taps, g, beta), (q, k, v) = _prompt_inputs(9, 100)
     real = 37
-    mask = (jnp.arange(100) < real)[None, :, None]
-    _, padded = _chunked(q, k, v, jnp.where(mask[..., None], g, 0.0),
+    mask = (jnp.arange(100) < real)[:, None]
+    _, padded = _chunked(mixed, taps, jnp.where(mask[..., None], g, 0.0),
                          jnp.where(mask, beta, 0.0))
-    _, want = _token_by_token(*(a[:, :real] for a in (q, k, v, g, beta)))
+    _, want = _recurrence(*(a[:real] for a in (q, k, v, g, beta)))
     assert np.abs(np.asarray(padded - want)).max() < 5e-6
+
+
+# a head block (2 heads of 64 are a lane tile) and a half of one; an odd
+# count, one block whatever its lanes, over exactly one chunk; one head; the
+# published head width, the convolution's input in bfloat16
+@pytest.mark.parametrize("heads,d,t,dtype", [
+    (3, 64, 100, "float32"), (5, 16, 64, "float32"), (1, 16, 70, "float32"),
+    (2, 128, 130, "bfloat16")])
+def test_kda_chunk_takes_any_count_of_heads(heads, d, t, dtype):
+    inputs, qkv = _prompt_inputs(heads, t, heads=heads, d=d, dtype=dtype)
+    want_o, want_state = _recurrence(*qkv, *inputs[2:])
+    got_o, got_state = jax.jit(_chunked)(*inputs)
+    assert got_o.shape == want_o.shape and got_state.shape == want_state.shape
+    assert np.abs(np.asarray(got_o - want_o)).max() < 2e-6
+    assert np.abs(np.asarray(got_state - want_state)).max() < 5e-6
 
 
 @pytest.mark.parametrize("gate", ["bound", "drawn"])

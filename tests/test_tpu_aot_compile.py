@@ -826,9 +826,11 @@ def test_ling3_step_at_the_benchmark_cell_moves_no_pool(
     smaller than ONE state tensor. At the top rung, the whole worker's
     memory: weights + pools + the temporaries and outputs of the 2,048 and
     8,192 prefills and of the cache length 9,216 the runtime adds stay under
-    15 GB, each prefill with its ``prompt_attention`` by Mosaic."""
+    15 GB, each prefill with its ``prompt_attention`` and its six
+    ``kda_chunk`` calls by Mosaic."""
     import importlib
     from ai4e_tpu.ops import state_pool
+    from ai4e_tpu.ops.pallas import kda_chunk
     runtime, spec = ling3_cell
     assert runtime.step_bounds == (6912, 9216)
     shape = (1, 96, 9216, 640)
@@ -856,13 +858,15 @@ def test_ling3_step_at_the_benchmark_cell_moves_no_pool(
     flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
     for top in (2048, 8192, runtime.max_len):
         with pytest.MonkeyPatch.context() as patch:
-            patch.setattr(flash, "resolve_interpret",
-                          lambda kernel, interpret: False)
+            for kernel in (flash, kda_chunk):
+                patch.setattr(kernel, "resolve_interpret",
+                              lambda kernel, interpret: False)
             prefill = runtime._programs["prefill"].lower(
                 _on(v5e_sharding, runtime.servable.params),
                 _on(v5e_sharding, ((1, top), jnp.int32)),
                 _on(v5e_sharding, ((1,), jnp.int32))).compile()
         assert len(_mosaic_calls(prefill, "prompt_attention")) == 1
+        assert len(_mosaic_calls(prefill, "kda_chunk")) == 6
         prefill = prefill.memory_analysis()
         peak = resident + max(memory.temp_size_in_bytes,
                               prefill.temp_size_in_bytes
