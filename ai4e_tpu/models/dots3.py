@@ -312,8 +312,9 @@ class _Layer(nn.Module):
                     at)
                 with jax.named_scope("select"):
                     return kv_pool.select_top(
-                        scores, k_pos[None, :] <= q_pos[:, None],
-                        self.index_topk).astype(jnp.int8)
+                        scores,
+                        (k_pos[None, :] <= q_pos[:, None]).astype(jnp.int8),
+                        self.index_topk)
 
             # once for every head, a byte a pair: 0.15 GB at 12,288
             allowed = kv_pool.query_blocks(select, p)

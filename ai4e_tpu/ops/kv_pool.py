@@ -55,6 +55,7 @@ from .pallas.decode_attention import latent_attention, pooled_attention
 from .pallas.flash_attention import (
     index_scores as flash_index_scores,
     prompt_attention as flash_prompt_attention)
+from .pallas.select_top import select_top as pallas_select_top
 
 # What one grid step of the decode read fetches of each tensor: 256
 # positions of a 4 KB row, 1,024 of a 1 KB one. Smaller, and the grid's
@@ -239,15 +240,31 @@ def prefill_attention(q, k, v, mask, scale: float | None = None):
 QUERY_BLOCK = 256
 
 
+# The scores of a ``select_top`` call from which the selection is the kernel's:
+# under it XLA keeps the ``jax.numpy`` form's key image on chip (a step's
+# 16 slots x 12,545 positions are 0.8 MB).
+SELECT_KERNEL_BYTES = 1 << 20
+
+
 def select_top(scores, valid, k: int):
     """The exact top-``k`` of ``scores (..., N)`` (float32) among the
-    positions ``valid (..., N)`` marks, as a mask ``(..., N)``: the ``k``
+    positions ``valid (..., N)`` marks, as a mask ``(..., N)`` of
+    ``valid``'s dtype (a caller that wants bytes hands bytes in): the ``k``
     largest, a tie to the lower index; every valid position where fewer than
     ``k`` are. No sort and no gather: the ``k``-th largest value is found
     bit by bit on the scores' order-preserving integer image (32 counts),
-    then the ties at it are ranked by a running count."""
-    if k >= scores.shape[-1]:
+    then the ties at it are ranked by a running count — a prompt's block of
+    queries in ``pallas.select_top``, which holds the image in VMEM, a
+    step's few rows as ``jax.numpy``."""
+    n = scores.shape[-1]
+    if k >= n:
         return valid
+    if scores.size * 4 >= SELECT_KERNEL_BYTES:
+        return pallas_select_top(
+            scores.astype(jnp.float32).reshape(-1, n),
+            jnp.broadcast_to(valid, scores.shape).reshape(-1, n).astype(
+                jnp.int8), k).reshape(scores.shape).astype(valid.dtype)
+    kind, valid = valid.dtype, valid.astype(bool)
     bits = jax.lax.bitcast_convert_type(scores.astype(jnp.float32),
                                         jnp.uint32)
     sign = jnp.uint32(1 << 31)
@@ -265,7 +282,8 @@ def select_top(scores, valid, k: int):
     above = key > kth
     ties = (key == kth) & valid
     room = k - above.sum(axis=-1, keepdims=True)
-    return valid & (above | (ties & (jnp.cumsum(ties, axis=-1) <= room)))
+    return (valid & (above | (ties & (jnp.cumsum(ties, axis=-1) <= room)))
+            ).astype(kind)
 
 
 def query_blocks(fn, p: int, block: int = QUERY_BLOCK):

@@ -2,8 +2,8 @@
 
 The serving kernels (``flash_attention``, ``segmentation_argmax``,
 ``normalize_image``, ``decode_attention``, ``latent_attention``,
-``prompt_attention``, ``index_scores``, ``state_update``, ``kda_chunk``)
-default to interpret mode off-TPU, so CPU CI never
+``prompt_attention``, ``index_scores``, ``select_top``, ``state_update``,
+``kda_chunk``) default to interpret mode off-TPU, so CPU CI never
 proves they compile to Mosaic and fit VMEM on real hardware. This module is
 that proof: ``validate_kernels()`` runs each kernel with ``interpret=False``
 (on TPU) against a pure-XLA oracle and asserts its working set fits the
@@ -76,6 +76,15 @@ def kda_chunk_vmem_bytes(head_block: int, d: int, dtype_bytes: int = 2,
               + 3 * max(taps, 8) * lanes * 4 + 2 * CHUNK * lanes * 4
               + head_block * d * d * 4 + CHUNK * 128 * 4)
     return 2 * blocks + 4 * CHUNK * lanes * 4
+
+
+def select_top_vmem_bytes(n: int) -> int:
+    """A tile of rows at ``n`` columns: its float32 scores, its one-byte mask
+    and its one-byte result double-buffered, the int32 key image once, and
+    the headroom the call asks Mosaic for beside them (``select_top
+    .vmem_bytes``: the same number is the call's ``vmem_limit_bytes``)."""
+    from .select_top import vmem_bytes
+    return vmem_bytes(n)
 
 
 # What the chip has of VMEM, which a call may ask for beyond the scoped
@@ -369,6 +378,36 @@ def validate_kernels(interpret: bool = False) -> dict:
         "max_err": round(err, 6),
         "vmem_bytes": 2 * (j_heads * queries * d + 512 * d) * 2
         + 2 * queries * 512 * 4 + queries * j_heads * 4}
+
+    # the selection of a block of a prompt's queries vs a stable sort: the
+    # last 256 queries of the cache's own length against every key, 2,048
+    # kept a row (under the interpreter 64 queries against 1,536 keys, 200
+    # kept), once over scores as the indexer makes them — a sum of
+    # rectified products, many exact zeros — and once rounded so that some
+    # hundred keys tie at the 2,048th value. ``max_err`` counts the
+    # positions that differ: none.
+    from .select_top import select_top
+    rows, keys, kept = (64, 1536, 200) if interpret else (256, 12544, 2048)
+    select = jax.jit(lambda scores, valid: select_top(
+        scores, valid, kept, interpret=interpret))
+    valid = (np.arange(keys)[None, :]
+             <= (keys - rows + np.arange(rows))[:, None])
+    vmem = select_top_vmem_bytes(keys)
+    assert vmem <= VMEM_PHYSICAL_BYTES // 2, f"select_top VMEM {vmem}"
+    for name, step in (("scores", 0.0), ("ties", 0.125)):
+        scores = (np.maximum(rng.standard_normal((rows, keys)), 0)
+                  * rng.standard_normal((rows, keys))).astype(np.float32)
+        if step:
+            scores = np.round(scores / step) * step
+        args = (jax.numpy.asarray(scores), jax.numpy.asarray(valid, "int8"))
+        got = np.asarray(select(*args))
+        order = np.argsort(-np.where(valid, scores, -np.inf), axis=-1,
+                           kind="stable")[:, :kept]
+        want = np.zeros(scores.shape, bool)
+        np.put_along_axis(want, order, True, axis=-1)
+        wrong = int((got != (want & valid)).sum())
+        results[f"select_top_{name}"] = {
+            "ok": wrong == 0, "max_err": wrong, "vmem_bytes": vmem}
 
     # the state update at the live slots of the pool vs the families' own
     # jax.numpy recurrences — the two cells' blocks (Mamba-2: 64 heads of 64

@@ -164,31 +164,84 @@ def _top_by_sort(scores, valid, k):
     return out & valid
 
 
+def _causal(at, rows, n):
+    """A block of ``rows`` queries from position ``at`` against ``n`` keys."""
+    return np.arange(n)[None, :] <= (at + np.arange(rows))[:, None]
+
+
+def _step_shaped(rows, n):
+    """What a step hands over: a slot's cached positions, then its own new
+    token in the last column."""
+    valid = np.arange(n)[None, :] < np.linspace(1, n - 1, rows).astype(
+        int)[:, None]
+    valid[:, -1] = True
+    return valid
+
+
+# the shape of a call -> (valid, k): the few rows a step selects over and
+# the first test's (``jax.numpy``), a block of a prompt's queries from its
+# middle and a width that is no whole lane tile nor a whole row tile (the
+# kernel, interpreted)
+SELECT_SHAPES = {
+    "rows": (np.tril(np.ones((50, 50), bool))[[0, 3, 7, 8, 30, 49]], 8),
+    "block": (_causal(768, 256, 1536), 200),
+    "step": (_step_shaped(16, 513), 64),
+    "ragged": (_causal(1000, 72, 3700), 1500),
+}
+
+
+@pytest.mark.parametrize("shape", list(SELECT_SHAPES))
 @pytest.mark.parametrize("case", ["random", "ties", "zeros", "few", "all",
                                   "negative", "huge"])
-def test_select_top_is_the_exact_top_k_with_ties_to_the_lower_index(case):
+def test_select_top_is_the_exact_top_k_with_ties_to_the_lower_index(case,
+                                                                    shape):
     rng = np.random.default_rng(len(case))
-    n, k = 50, 8
-    scores = rng.standard_normal((6, n)).astype(np.float32)
-    valid = np.tril(np.ones((n, n), bool))[[0, 3, 7, 8, 30, 49]]
+    valid, k = SELECT_SHAPES[shape]
+    rows, n = valid.shape
+    in_kernel = rows * n * 4 >= kv_pool.SELECT_KERNEL_BYTES
+    assert in_kernel == (shape in ("block", "ragged"))
+    scores = rng.standard_normal((rows, n)).astype(np.float32)
     if case == "ties":
         scores = np.round(scores)            # many equal values at the edge
     elif case == "zeros":
-        scores = np.where(rng.random((6, n)) < 0.7, 0.0, scores)
-        scores = scores * np.where(rng.random((6, n)) < 0.5, -1.0, 1.0)  # ±0
+        scores = np.where(rng.random((rows, n)) < 0.7, 0.0, scores)
+        scores = scores * np.where(rng.random((rows, n)) < 0.5, -1.0, 1.0)
     elif case == "few":
-        valid = valid & (rng.random((6, n)) < 0.1)
+        valid = valid & (rng.random((rows, n)) < 0.1)
     elif case == "all":
-        valid = np.ones((6, n), bool)
+        valid = np.ones((rows, n), bool)
     elif case == "negative":
         scores = -np.abs(scores) - 1.0
     elif case == "huge":
         scores = scores * 1e30
+    scores = scores.astype(np.float32)
     got = np.asarray(jax.jit(kv_pool.select_top, static_argnums=2)(
         jnp.asarray(scores), jnp.asarray(valid), k))
+    assert got.dtype == bool
     assert np.array_equal(got, _top_by_sort(scores, valid, k))
     assert np.array_equal(np.asarray(kv_pool.select_top(
         jnp.asarray(scores), jnp.asarray(valid), n)), valid)
+
+
+def test_select_top_ranks_ties_across_the_kernels_tile_edges():
+    """The ties at the ``k``-th value lie over three lane tiles and two of
+    the kernel's column chunks, and the last one kept falls in a different
+    place row by row — before, on and after the edges at 384 and 512; the
+    mask a prompt asks for comes back in bytes."""
+    rows, n, k = 256, 1536, 300
+    rng = np.random.default_rng(7)
+    scores = -rng.random((rows, n)).astype(np.float32)
+    scores[:, 300:900] = 1.0                               # the ties
+    for r in range(rows):                                  # above them
+        scores[r, rng.choice(np.arange(900, 1200), r % 250, replace=False)] = 2.0
+    valid = _causal(1200, rows, n)
+    got = jax.jit(kv_pool.select_top, static_argnums=2)(
+        jnp.asarray(scores), jnp.asarray(valid, jnp.int8), k)
+    assert got.dtype == jnp.int8
+    want = _top_by_sort(scores, valid, k)
+    assert np.array_equal(np.asarray(got), want.astype(np.int8))
+    last_kept = (want[:, 300:900].sum(axis=1) + 299).tolist()
+    assert min(last_kept) < 384 < 512 < max(last_kept)
 
 
 # -- the latent kernel ---------------------------------------------------------

@@ -608,9 +608,10 @@ def test_prompt_kernels_compile(v5e_sharding, p, heads, dqk, window, masked):
     or over the window's band, at the shortest and the longest buckets
     (blocks of 512) and the cache's own length (blocks of 256), 4 heads a
     grid step; ``xing4``'s form of the same call, 32 heads with
-    neither mask nor window; and the indexer's 64 heads of 128 for a block
-    of 256 queries. A call's blocks fit the VMEM it asks Mosaic for, by the
-    kernel's own count and by the compile."""
+    neither mask nor window; the indexer's 64 heads of 128 for a block
+    of 256 queries, and the selection of 2,048 of that block's scores. A
+    call's blocks fit the VMEM it asks Mosaic for, by the kernel's own count
+    and by the compile."""
     import importlib
     from ai4e_tpu.ops import kv_pool
     from ai4e_tpu.ops.pallas.validate import VMEM_PHYSICAL_BYTES
@@ -641,6 +642,14 @@ def test_prompt_kernels_compile(v5e_sharding, p, heads, dqk, window, masked):
         _on(v5e_sharding, ((256, 64), jnp.float32)),
         _on(v5e_sharding, ((), jnp.int32)))
     assert "tpu_custom_call" in scores.as_text()
+    from ai4e_tpu.ops.pallas import select_top
+    selection = _compile(
+        lambda scores, valid: select_top.select_top(scores, valid, 2048,
+                                                    interpret=False),
+        _on(v5e_sharding, ((256, p), jnp.float32)),
+        _on(v5e_sharding, ((256, p), jnp.int8)))
+    assert "tpu_custom_call" in selection.as_text()
+    assert select_top.vmem_bytes(p) <= VMEM_PHYSICAL_BYTES // 2
 
 
 @pytest.fixture(scope="module")
