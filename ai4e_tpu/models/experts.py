@@ -12,6 +12,12 @@ A MoE layer, for a row ``h`` (after its norm)::
     p <- p / sum of the K                   where the family renormalises
     y = sum over the row's K experts e of p_e * W_down,e(silu(W_gate,e h) * W_up,e h)
 
+Where the family's configuration clamps its SwiGLU (``limit`` > 0: the
+published ``swiglu_limit``), the gate is cut from above and the up
+projection on both sides before the product, ``silu(min(g, limit)) * clip(u,
+-limit, limit)`` (``swiglu``); 0, every family's default, is no clamp and the
+program it always was.
+
 and this process holds the experts ``first_held .. first_held + held - 1``
 (``held`` = the leading axis of the weights it is given): it routes over all
 ``total`` — the router keeps its published width — and sums the terms of the
@@ -64,6 +70,14 @@ COMBINE_COLUMNS = 1024
 
 def _dot(eq, a, b):
     return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
+
+
+def swiglu(g, u, limit: float = 0.0):
+    """``silu(g) * u`` in float32 — with ``limit`` > 0 the clamped form:
+    ``silu(min(g, limit)) * clip(u, -limit, limit)``."""
+    if limit:
+        g, u = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
+    return jax.nn.silu(g) * u
 
 
 def kept_groups(choice, groups: tuple):
@@ -125,15 +139,15 @@ def gate_matrix(top_e, top_p, held: int, first_held: int = 0):
         return (chosen * top_p[..., None]).sum(axis=-2)
 
 
-def dense(h, gate, w_gate, w_up, w_down):
+def dense(h, gate, w_gate, w_up, w_down, limit: float = 0.0):
     """``(silu(h W_gate) * h W_up * P) W_down`` over the held experts:
     ``h (..., D)``, ``gate`` = ``gate_matrix``'s ``P (..., held)``, weights
-    ``(held, D, F)`` and ``(held, F, D)``. Returns ``(..., D)`` in ``h``'s
-    dtype."""
+    ``(held, D, F)`` and ``(held, F, D)``; ``limit``: ``swiglu``'s clamp.
+    Returns ``(..., D)`` in ``h``'s dtype."""
     with jax.named_scope("experts"):
         g = _dot("...d,edf->...ef", h, w_gate)
         u = _dot("...d,edf->...ef", h, w_up)
-        a = (jax.nn.silu(g) * u * gate[..., None]).astype(h.dtype)
+        a = (swiglu(g, u, limit) * gate[..., None]).astype(h.dtype)
         return _dot("...ef,efd->...d", a, w_down).astype(h.dtype)
 
 
@@ -178,7 +192,7 @@ def pass_report(passes):
 
 
 def routed(h, top_e, top_p, w_gate, w_up, w_down, total: int,
-           first_held: int = 0):
+           first_held: int = 0, limit: float = 0.0):
     """The same sum with each held expert multiplying only the rows that
     chose it. ``h (T, D)``, ``top_e``, ``top_p (T, K)``, ``total`` the
     router's width. The ``T x K`` (row, pick) pairs are ranked by held
@@ -190,8 +204,8 @@ def routed(h, top_e, top_p, w_gate, w_up, w_down, total: int,
     What ``ragged_dot`` leaves in the rows past the last group is not
     defined on every backend, so they are zeroed by hand. Each pair's
     output is weighted and added into its row of a ``(T, D)`` float32
-    result, ``COMBINE_COLUMNS`` columns a scatter. Returns ``(T, D)`` in
-    ``h``'s dtype."""
+    result, ``COMBINE_COLUMNS`` columns a scatter. ``limit``: ``swiglu``'s
+    clamp. Returns ``(T, D)`` in ``h``'s dtype."""
     held = w_gate.shape[0]
     t, k = top_e.shape
     window = window_rows(t, k, held, total)
@@ -218,7 +232,7 @@ def routed(h, top_e, top_p, w_gate, w_up, w_down, total: int,
                                    preferred_element_type=jnp.float32)
             u = jax.lax.ragged_dot(x, w_up, cut,
                                    preferred_element_type=jnp.float32)
-            a = jnp.where(grouped, jax.nn.silu(g) * u
+            a = jnp.where(grouped, swiglu(g, u, limit)
                           * weight[pairs][:, None], 0.0).astype(h.dtype)
             y = jnp.where(grouped, jax.lax.ragged_dot(
                 a, w_down, cut, preferred_element_type=jnp.float32), 0.0)
@@ -233,14 +247,14 @@ def routed(h, top_e, top_p, w_gate, w_up, w_down, total: int,
         return jnp.concatenate(out, axis=1).astype(h.dtype)
 
 
-def shared(h, gate_w, w_gate, w_up, w_down):
+def shared(h, gate_w, w_gate, w_up, w_down, limit: float = 0.0):
     """A shared expert every row passes through: ``W_down(silu(W_gate h) *
     W_up h)``, weights ``(D, F)`` and ``(F, D)`` — behind a sigmoid gate of
     its own, ``sigmoid(h w_s)``, where the family has one (``gate_w (D,
-    1)``; None: ungated)."""
+    1)``; None: ungated). ``limit``: ``swiglu``'s clamp."""
     with jax.named_scope("shared_expert"):
-        a = (jax.nn.silu(_dot("...d,df->...f", h, w_gate))
-             * _dot("...d,df->...f", h, w_up)).astype(h.dtype)
+        a = swiglu(_dot("...d,df->...f", h, w_gate),
+                   _dot("...d,df->...f", h, w_up), limit).astype(h.dtype)
         y = _dot("...f,fd->...d", a, w_down)
         if gate_w is None:
             return y.astype(h.dtype)

@@ -152,6 +152,122 @@ def kda_update(state, q, k, v, g, beta, position, interpret=None):
         position, kda_block, (v.shape[1:], jnp.float32), interpret)
 
 
+def kda_params(p, dim: int, heads: int, head_dim: int, conv: int,
+               lora: int = 0) -> dict:
+    """Declare a KDA mixer's parameters through ``p(name, init, *shape,
+    dtype=None)`` (a layer's ``self.param`` at its dtype) and return them by
+    name. ``lora`` = 0: the decay's ``W_a`` and the output gate's ``W_z``
+    are full rank, ``(D, H d)`` (``no_kda_lora``); else both go through that
+    rank — ``a = (u W_a1) W_a2``, ``z = (u W_z1) W_z2 + b_z``, Kimi Linear's
+    published form."""
+    g, wide = INIT_GAINS, heads * head_dim
+    out = {"in_qkv": p("in_qkv", seeded(1.0), dim, 3 * wide),
+           "conv_w": p("conv_w", seeded(1.0, fan_in_axis=0), conv, 3 * wide)}
+    if lora:
+        out["w_a1"] = p("w_a1", seeded(1.0), dim, lora)
+        out["w_a2"] = p("w_a2", seeded(g["w_a"]), lora, wide)
+        out["w_z1"] = p("w_z1", seeded(1.0), dim, lora)
+        out["w_z2"] = p("w_z2", seeded(1.0), lora, wide)
+        out["b_z"] = p("b_z", norm_scale(0.0), wide)
+    else:
+        out["w_a"] = p("w_a", seeded(g["w_a"]), dim, wide)
+        out["w_z"] = p("w_z", seeded(1.0), dim, wide)
+    out["w_beta"] = p("w_beta", seeded(1.0), dim, heads)
+    out["a_log"] = p("a_log", norm_scale(0.0), heads, dtype=jnp.float32)
+    # around ``dt_bias``, SPREAD over a head's channels
+    out["dt_bias"] = p("dt_bias", seeded(
+        g["dt_spread"], g["dt_bias"], fan_in_axis=None), heads, head_dim,
+        dtype=jnp.float32)
+    out["norm_o"] = p("norm_o", norm_scale(1.0), head_dim)
+    out["out_proj"] = p("out_proj", seeded(g["kda_out"]), wide, dim)
+    return out
+
+
+def kda_project(u, w: dict, gate_bound: float):
+    """The normed input ``u (..., D)`` → the convolution's input ``[q|k|v]
+    (..., 3 H d)``, the output gate's ``z (..., H, d)``, ``β (..., H)`` and
+    the log-decay ``g (..., H, d)`` (float32) under ``w`` (``kda_params``)."""
+    heads, head_dim = w["dt_bias"].shape
+    dtype = w["in_qkv"].dtype
+    split = (*u.shape[:-1], heads, head_dim)
+    with jax.named_scope("kda_proj"):
+        mixed = _dot("...d,de->...e", u, w["in_qkv"]).astype(dtype)
+        if "w_a" in w:
+            z = _dot("...d,de->...e", u, w["w_z"]).astype(dtype)
+            a = _dot("...d,de->...e", u, w["w_a"])
+        else:
+            z = (_dot("...r,re->...e", _dot("...d,dr->...r", u, w[
+                "w_z1"]).astype(dtype), w["w_z2"])
+                + w["b_z"].astype(jnp.float32)).astype(dtype)
+            a = _dot("...r,re->...e", _dot("...d,dr->...r", u, w[
+                "w_a1"]).astype(dtype), w["w_a2"])
+        beta = jax.nn.sigmoid(_dot("...d,dh->...h", u, w["w_beta"]))
+    with jax.named_scope("kda_gate"):
+        g = gate_bound * jax.nn.sigmoid(
+            jnp.exp(w["a_log"])[:, None] * (a.reshape(split) + w["dt_bias"]))
+    return mixed, z.reshape(split), beta, g
+
+
+def kda_heads(mixed, heads: int, head_dim: int):
+    """The convolution's output ``(..., 3 H d)`` (after SiLU, float32) → q, k
+    normalised a head, q scaled; v: ``(..., H, d)`` each."""
+    q, k, v = jnp.moveaxis(mixed.reshape(
+        *mixed.shape[:-1], 3, heads, head_dim), -3, 0)
+    return l2_norm(q) * head_dim ** -0.5, l2_norm(k), v
+
+
+def kda_out(o, z, w: dict, eps: float):
+    """The recurrence's read-out ``o (..., H, d)`` normed a head, gated by
+    ``σ(z)`` and projected: the mixer's output ``(..., D)``."""
+    dtype = w["out_proj"].dtype
+    with jax.named_scope("gated_norm"):
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+        o = (o * w["norm_o"].astype(jnp.float32)).astype(dtype)
+        o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+            z.astype(jnp.float32))).astype(dtype)
+    with jax.named_scope("out_proj"):
+        return _dot("...e,ed->...d", o.reshape(*o.shape[:-2], -1),
+                    w["out_proj"]).astype(dtype)
+
+
+def kda_prompt(u, w: dict, mask, length, gate_bound: float, eps: float):
+    """The mixer over ONE padded prompt, ``u (1, P, D)`` after its norm →
+    its output ``(1, P, D)`` and ``(state (1, H, d, d), tail (1, conv − 1,
+    3 H d))`` after ``length`` tokens."""
+    mixed, z, beta, g = kda_project(u, w, gate_bound)
+    keep = w["conv_w"].shape[0] - 1
+    with jax.named_scope("conv"):
+        # the last ``keep`` inputs before ``length``; zero before the
+        # sequence's start
+        at = length[:, None] - keep + jnp.arange(keep)[None, :]
+        tail = jnp.where((at >= 0)[..., None], jnp.take_along_axis(
+            mixed, jnp.maximum(at, 0)[..., None], axis=1), 0)
+    with jax.named_scope("kda_chunk"):
+        # the convolution, q's and k's norms and the recurrence, one
+        # kernel; a padded position leaves the state as it was
+        o, state = kda_chunk(
+            mixed[0], w["conv_w"].astype(jnp.float32),
+            jnp.where(mask[0, :, None, None], g[0], 0.0),
+            jnp.where(mask[0, :, None], beta[0], 0.0), eps=L2_EPS)
+    return kda_out(o[None], z, w, eps), (state[None], tail)
+
+
+def kda_token(u, w: dict, state, tail, position, gate_bound: float,
+              eps: float):
+    """The mixer of one token a slot, ``u (S, D)`` after its norm → its
+    output ``(S, D)``, the successors of ``(state, tail)`` and the step's
+    log-decay ``g (S, H, d)``."""
+    mixed, z, beta, g = kda_project(u, w, gate_bound)
+    with jax.named_scope("conv"):
+        window = jnp.concatenate([tail, mixed[:, None]], axis=1)
+        out = jax.nn.silu((window.astype(jnp.float32)
+                           * w["conv_w"].astype(jnp.float32)).sum(axis=1))
+    q, k, v = kda_heads(out, *w["dt_bias"].shape)
+    with jax.named_scope("state_update"):
+        o, state = kda_update(state, q, k, v, g, beta, position)
+    return kda_out(o, z, w, eps), (state, window[:, 1:]), g
+
+
 class _Layer(nn.Module):
     """One block: a mixer (``latent``: latent attention, else KDA) and its
     FFN (``dense``: a SwiGLU; else experts)."""
@@ -201,20 +317,7 @@ class _Layer(nn.Module):
             self.w_g = p("w_g", seeded(g["w_g"]), d, h)
             self.w_o = p("w_o", seeded(g["w_o"]), h * self.v_dim, d)
         else:
-            wide = h * self.head_dim
-            self.in_qkv = p("in_qkv", seeded(1.0), d, 3 * wide)
-            self.conv_w = p("conv_w", seeded(1.0, fan_in_axis=0), self.conv,
-                            3 * wide)
-            self.w_a = p("w_a", seeded(g["w_a"]), d, wide)
-            self.w_z = p("w_z", seeded(1.0), d, wide)
-            self.w_beta = p("w_beta", seeded(1.0), d, h)
-            self.a_log = p("a_log", norm_scale(0.0), h, dtype=jnp.float32)
-            # around ``dt_bias``, SPREAD over a head's channels
-            self.dt_bias = p("dt_bias", seeded(
-                g["dt_spread"], g["dt_bias"], fan_in_axis=None), h,
-                self.head_dim, dtype=jnp.float32)
-            self.norm_o = p("norm_o", norm_scale(1.0), self.head_dim)
-            self.out_proj = p("out_proj", seeded(g["kda_out"]), wide, d)
+            self.kda = kda_params(p, d, h, self.head_dim, self.conv)
         if self.dense:
             f = self.mlp_dim
             self.m_gate = p("m_gate", seeded(1.0), d, f)
@@ -272,74 +375,21 @@ class _Layer(nn.Module):
 
     # -- Kimi Delta Attention --------------------------------------------------
 
-    def _project(self, x):
-        """``x (..., D)`` → the convolution's input ``[q|k|v] (..., 3 H d)``,
-        the output gate's ``z (..., H, d)``, ``β (..., H)`` and the log-decay
-        ``g (..., H, d)`` (float32), from the layer's normed input."""
-        u = rms_norm(x, self.norm_in, self.eps)
-        split = (*x.shape[:-1], self.heads, self.head_dim)
-        with jax.named_scope("kda_proj"):
-            mixed = _dot("...d,de->...e", u, self.in_qkv).astype(self.dtype)
-            z = _dot("...d,de->...e", u, self.w_z).astype(self.dtype)
-            a = _dot("...d,de->...e", u, self.w_a)
-            beta = jax.nn.sigmoid(_dot("...d,dh->...h", u, self.w_beta))
-        with jax.named_scope("kda_gate"):
-            g = self.gate_bound * jax.nn.sigmoid(
-                jnp.exp(self.a_log)[:, None] * (a.reshape(split)
-                                                + self.dt_bias))
-        return mixed, z.reshape(split), beta, g
-
-    def _heads(self, mixed):
-        """The convolution's output ``(..., 3 H d)`` (after SiLU, float32) →
-        q, k normalised a head, q scaled; v: ``(..., H, d)`` each."""
-        q, k, v = jnp.moveaxis(mixed.reshape(
-            *mixed.shape[:-1], 3, self.heads, self.head_dim), -3, 0)
-        return l2_norm(q) * self.head_dim ** -0.5, l2_norm(k), v
-
-    def _kda_out(self, x, o, z):
-        with jax.named_scope("gated_norm"):
-            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
-                                  + self.eps)
-            o = (o * self.norm_o.astype(jnp.float32)).astype(self.dtype)
-            o = (o.astype(jnp.float32) * jax.nn.sigmoid(
-                z.astype(jnp.float32))).astype(self.dtype)
-        with jax.named_scope("out_proj"):
-            return x + _dot("...e,ed->...d", o.reshape(*x.shape[:-1], -1),
-                            self.out_proj).astype(self.dtype)
-
     def _kda_prompt(self, x, mask, length):
         """The mixer over ONE padded prompt ``x (1, P, D)`` → ``x + KDA`` and
         ``(state (1, H, d, d), tail (1, conv − 1, 3 H d))`` after ``length``
         tokens."""
-        mixed, z, beta, g = self._project(x)
-        keep = self.conv - 1
-        with jax.named_scope("conv"):
-            # the last ``keep`` inputs before ``length``; zero before the
-            # sequence's start
-            at = length[:, None] - keep + jnp.arange(keep)[None, :]
-            tail = jnp.where((at >= 0)[..., None], jnp.take_along_axis(
-                mixed, jnp.maximum(at, 0)[..., None], axis=1), 0)
-        with jax.named_scope("kda_chunk"):
-            # the convolution, q's and k's norms and the recurrence, one
-            # kernel; a padded position leaves the state as it was
-            o, state = kda_chunk(
-                mixed[0], self.conv_w.astype(jnp.float32),
-                jnp.where(mask[0, :, None, None], g[0], 0.0),
-                jnp.where(mask[0, :, None], beta[0], 0.0), eps=L2_EPS)
-        return self._kda_out(x, o[None], z), (state[None], tail)
+        y, cache = kda_prompt(rms_norm(x, self.norm_in, self.eps), self.kda,
+                              mask, length, self.gate_bound, self.eps)
+        return x + y, cache
 
     def _kda_token(self, x, state, tail, position):
         """The mixer of one token a slot: ``x (S, D)`` → ``x + KDA`` and the
         successors of ``(state, tail)``."""
-        mixed, z, beta, g = self._project(x)
-        with jax.named_scope("conv"):
-            window = jnp.concatenate([tail, mixed[:, None]], axis=1)
-            out = jax.nn.silu((window.astype(jnp.float32)
-                               * self.conv_w.astype(jnp.float32)).sum(axis=1))
-        q, k, v = self._heads(out)
-        with jax.named_scope("state_update"):
-            o, state = kda_update(state, q, k, v, g, beta, position)
-        return self._kda_out(x, o, z), (state, window[:, 1:]), g
+        y, cache, g = kda_token(rms_norm(x, self.norm_in, self.eps), self.kda,
+                                state, tail, position, self.gate_bound,
+                                self.eps)
+        return x + y, cache, g
 
     # -- latent attention ------------------------------------------------------
 

@@ -94,6 +94,19 @@ def _hc_bias(streams: int):
     return init
 
 
+def hyper_params(p, name: str, streams: int, dim: int) -> dict:
+    """Declare one sublayer's hyper-connection parameters (``ops/mhc.py``)
+    through ``p(name, init, *shape, dtype=None)`` — a layer's ``self.param``
+    at its dtype — as ``<name>_phi``, ``<name>_alpha``, ``<name>_bias``."""
+    n = streams
+    return {"phi": p(f"{name}_phi", seeded(INIT_GAINS["hc_phi"]), n * dim,
+                     2 * n + n * n),
+            "alpha": p(f"{name}_alpha", norm_scale(1.0), 3,
+                       dtype=jnp.float32),
+            "bias": p(f"{name}_bias", _hc_bias(n), 2 * n + n * n,
+                      dtype=jnp.float32)}
+
+
 class _Layer(nn.Module):
     """One block: latent attention and its FFN (``dense``: a SwiGLU; else
     experts), each between the two halves of its hyper-connection."""
@@ -132,15 +145,8 @@ class _Layer(nn.Module):
         def p(name, init, *shape, dtype=None):
             return self.param(name, init, shape, dtype or self.dtype)
 
-        def hyper(name):
-            return {"phi": p(f"{name}_phi", seeded(g["hc_phi"]), n * d,
-                             2 * n + n * n),
-                    "alpha": p(f"{name}_alpha", norm_scale(1.0), 3,
-                               dtype=jnp.float32),
-                    "bias": p(f"{name}_bias", _hc_bias(n), 2 * n + n * n,
-                              dtype=jnp.float32)}
-
-        self.hc_attn, self.hc_ffn = hyper("hc_attn"), hyper("hc_ffn")
+        self.hc_attn = hyper_params(p, "hc_attn", n, d)
+        self.hc_ffn = hyper_params(p, "hc_ffn", n, d)
         self.norm_in = p("norm_in", norm_scale(1.0), d)
         self.norm_post = p("norm_post", norm_scale(1.0), d)
         self.w_dq = p("w_dq", seeded(1.0), d, self.q_rank)

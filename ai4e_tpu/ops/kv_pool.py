@@ -74,7 +74,14 @@ class Rows(NamedTuple):
     ``select``: the family's read keeps at most that many of the positions
     it scores (a learned selection); None: it keeps all it reads. ``whole``:
     the step reads every slot's first ``bound`` positions in ``jax.numpy``,
-    not the blocks a slot has written through the kernel."""
+    not the blocks a slot has written through the kernel. ``every``: the
+    tensor keeps one row every that many positions — a block of ``every``
+    positions' pooled row, position ``p``'s block at row ``p // every``: a
+    slot holds ``ceil(length / every)`` rows, a prefill's block is a row a
+    block of the prompt, a step writes row ``p // every`` (what it writes
+    before the block closes at ``p % every == every - 1`` is overwritten by
+    the next step and read by none) and reads the rows of the blocks closed
+    under ``bound``."""
 
     name: str
     layers: int
@@ -84,6 +91,7 @@ class Rows(NamedTuple):
     kind: str = "kv"
     select: int | None = None
     whole: bool = False
+    every: int = 1
 
 
 class SlotSpec(NamedTuple):
@@ -115,7 +123,8 @@ def kv_slot(layers: int, kv_heads: int, head_dim: int, dtype,
 
 def pool_shape(rows: Rows, slots: int, max_len: int) -> tuple:
     """Shape of the pool tensor of one ``Rows`` declaration."""
-    return rows.layers, slots, rows.length or max_len, rows.width
+    return (rows.layers, slots, -(-(rows.length or max_len) // rows.every),
+            rows.width)
 
 
 def rows_nbytes(spec: tuple, slots: int, max_len: int) -> int:
@@ -161,23 +170,31 @@ def step_reads(spec: tuple, slots: int, max_len: int, position, active,
     ``attended``: the positions its attention reads of the first tensor, a
     layer (``positions_read``). Bytes: of each tensor the rows read — the
     blocks the kernel fetches (``positions_read``), or every slot's first
-    ``bound`` where it is read ``whole`` — and the one row a live slot
-    writes, every layer, summed under the tensor's ``kind``. ``selected``:
-    the positions the softmax kept, a layer, where a tensor declares a
-    selection (a live slot at ``p`` keeps ``min(p + 1, select)``); else
-    None."""
+    ``bound`` where it is read ``whole`` (of a tensor that keeps a row
+    ``every`` positions, the rows of the blocks under ``bound``) — and the
+    one row a live slot writes, every layer, summed under the tensor's
+    ``kind``. ``selected``: the positions the softmax kept, a layer, where a
+    tensor declares a selection; else None. A selection over keys pooled
+    ``every`` positions at a time (another tensor of the spec) keeps whole
+    blocks and, always, the query's own block up to the query, which takes
+    one of the places: a live slot at ``p`` keeps ``min(p − p % every,
+    select − every) + p % every + 1`` — ``min(p + 1, select)`` where no
+    tensor is pooled."""
+    block = max(rows.every for rows in spec)
     live = sum(map(bool, active))
     attended, selected, nbytes = None, None, {}
     for rows in spec:
         shape = pool_shape(rows, slots, max_len)
-        read = (slots * min(bound, shape[2]) + live if rows.whole
+        read = (slots * min(-(-bound // rows.every), shape[2]) + live
+                if rows.whole
                 else positions_read(shape, rows.dtype, position, active,
                                     bound))
         attended = read if attended is None else attended
         row = rows.layers * rows.width * np.dtype(rows.dtype).itemsize
         nbytes[rows.kind] = nbytes.get(rows.kind, 0) + row * (read + live)
         if rows.select:
-            selected = sum(min(p + 1, rows.select)
+            selected = sum(min(p - p % block, rows.select - block)
+                           + p % block + 1
                            for p, on in zip(position, active) if on)
     return attended, nbytes, selected
 
@@ -187,7 +204,9 @@ def prefill_pairs(spec: tuple, n: int) -> dict:
     over, a layer, by kind: causal pairs ``n (n + 1) / 2`` under a tensor's
     ``kind``; a ring of ``length`` keeps the ``length + 1`` last keys of a
     query (the query's own among them), and a selection at most ``select``
-    of them, counted as ``selected`` (what it scored is another tensor's)."""
+    of them, counted as ``selected`` (what it scored is another tensor's);
+    against a tensor that keeps a row ``every`` positions a query at ``t``
+    meets the ``t // every`` blocks closed before it."""
     def pairs(cap):
         full = min(n, cap)
         return full * (full + 1) // 2 + (n - full) * cap
@@ -196,6 +215,10 @@ def prefill_pairs(spec: tuple, n: int) -> dict:
     for rows in spec:
         if rows.select:
             out["selected"] = pairs(rows.select)
+        elif rows.every > 1:
+            blocks, rest = divmod(n, rows.every)
+            out[rows.kind] = (rows.every * blocks * (blocks - 1) // 2
+                              + rest * blocks)
         else:
             out[rows.kind] = pairs(rows.length + 1 if rows.length else n)
     return out
@@ -369,7 +392,9 @@ def insert_block(pools: tuple, blocks: tuple, slot) -> tuple:
     the declaration each, at the start of ``slot``'s rows — ``slot`` may be
     traced: one program a block length, any slot. Blocks are rank-matched to
     the pool, so one dynamic_update_slice a tensor lands the whole prompt. A
-    ring's block is the whole ring, each position at its row already."""
+    ring's block is the whole ring, each position at its row already; the
+    block of a tensor that keeps a row ``every`` positions holds a row a
+    block of the prompt, which is where they lie."""
     zero = (0, slot, 0, 0)
     with jax.named_scope("cache_insert"):
         return tuple(jax.lax.dynamic_update_slice(pool, block, zero)
@@ -414,11 +439,13 @@ def decode_attention(q, k_new, v_new, k_pool, v_pool, layer: int, position,
             interpret=interpret).reshape(q.shape).astype(q.dtype)
 
 
-def write_rows(pools: tuple, rows: tuple, position) -> tuple:
+def write_rows(pools: tuple, rows: tuple, position,
+               every: tuple | None = None) -> tuple:
     """Store one decode step's new rows: ``rows[i]`` is the per-layer list
     of (S, ...) the step made for the tensor ``pools[i]``, ``position``
     (S,) — the row of each slot the new ones land at (a ring's caller
-    hands ``position % length``).
+    hands ``position % length``). ``every[i]``, where given: the tensor's
+    ``Rows.every`` — its row of a slot is ``position // every[i]``.
 
     One row per slot, all layers at once, written where the pool already
     lives: ``layers`` contiguous rows of whole lane tiles a slot and a
@@ -433,9 +460,10 @@ def write_rows(pools: tuple, rows: tuple, position) -> tuple:
         rows = [jnp.stack(new).reshape(len(new), position.shape[0], 1,
                                        pool.shape[-1])
                 for pool, new in zip(pools, rows)]
+        at = [position if step == 1 else position // step
+              for step in every or (1,) * len(pools)]
         for slot in range(position.shape[0]):
             for i, (pool, new) in enumerate(zip(pools, rows)):
                 pools[i] = jax.lax.dynamic_update_slice(
-                    pool, new[:, slot:slot + 1],
-                    (0, slot, position[slot], 0))
+                    pool, new[:, slot:slot + 1], (0, slot, at[i][slot], 0))
     return tuple(pools)

@@ -219,14 +219,18 @@ def validate_kernels(interpret: bool = False) -> dict:
     # heads on a 640-lane row (576 published + padding) of which 512 are the
     # value, a cache of 12,544 in blocks of 768 — the last one cut —, with
     # and without a selection's mask (whole blocks left out in one slot, the
-    # new token's own term in another); and a window's ring, 64 heads on
-    # 1,152 lanes, 512 rows. Timed where compiled: the bytes are the rows of
-    # the blocks fetched.
+    # new token's own term in another); a window's ring, 64 heads on 1,152
+    # lanes, 512 rows; and the ``glm5`` cell's row of 512 lanes that is its
+    # own value. Timed where compiled: the bytes are the rows of the blocks
+    # fetched.
     import time
     for name, heads, row, value, length, masked in (
             ("latent", 128, 640, 512, 12544, False),
             ("latent_masked", 128, 640, 512, 12544, True),
-            ("window", 64, 1152, 1024, 512, False)):
+            ("window", 64, 1152, 1024, 512, False),
+            # ``glm5``: a row that is all value (no rotary lanes, nothing
+            # padded), 64 heads, blocks of 1,024 of a cache of 17,408
+            ("latent_all_value", 64, 512, 512, 17408, True)):
         dtype = "bfloat16"
         shape = (2, 8, length, row)
         edge = kv_pool.read_block(shape, dtype)
@@ -316,7 +320,9 @@ def validate_kernels(interpret: bool = False) -> dict:
     selected |= s_pos == t_pos         # every query keeps a key: its own
     for name, dqk, dv, mask, window in (
             ("selected", 192, 128, selected, None),
-            ("window", 256, 128, None, 513)):
+            ("window", 256, 128, None, 513),
+            # ``glm5``: keys and values both 256 wide (no rotary part)
+            ("selected_256", 256, 256, selected, None)):
         q, k = (jax.numpy.asarray(rng.standard_normal((heads, p, dqk)),
                                   "bfloat16") for _ in range(2))
         v = jax.numpy.asarray(rng.standard_normal((heads, p, dv)),
@@ -387,14 +393,20 @@ def validate_kernels(interpret: bool = False) -> dict:
     # hundred keys tie at the 2,048th value. ``max_err`` counts the
     # positions that differ: none.
     from .select_top import select_top
-    rows, keys, kept = (64, 1536, 200) if interpret else (256, 12544, 2048)
-    select = jax.jit(lambda scores, valid: select_top(
-        scores, valid, kept, interpret=interpret))
-    valid = (np.arange(keys)[None, :]
-             <= (keys - rows + np.arange(rows))[:, None])
-    vmem = select_top_vmem_bytes(keys)
-    assert vmem <= VMEM_PHYSICAL_BYTES // 2, f"select_top VMEM {vmem}"
-    for name, step in (("scores", 0.0), ("ties", 0.125)):
+    # ``pooled``: the ``glm5`` cell's selection of 511 of the 4,352 pooled
+    # blocks of its cache (not a multiple of the kernel's 1,024-column chunk).
+    sizes = {True: {"scores": (64, 1536, 200), "ties": (64, 1536, 200),
+                    "pooled": (64, 384, 40)},
+             False: {"scores": (256, 12544, 2048), "ties": (256, 12544, 2048),
+                     "pooled": (256, 4352, 511)}}[bool(interpret)]
+    for name, step in (("scores", 0.0), ("ties", 0.125), ("pooled", 0.125)):
+        rows, keys, kept = sizes[name]
+        select = jax.jit(lambda scores, valid: select_top(
+            scores, valid, kept, interpret=interpret))
+        valid = (np.arange(keys)[None, :]
+                 <= (keys - rows + np.arange(rows))[:, None])
+        vmem = select_top_vmem_bytes(keys)
+        assert vmem <= VMEM_PHYSICAL_BYTES // 2, f"select_top VMEM {vmem}"
         scores = (np.maximum(rng.standard_normal((rows, keys)), 0)
                   * rng.standard_normal((rows, keys))).astype(np.float32)
         if step:

@@ -728,6 +728,31 @@ def build_ling3_lm(name: str = "lm", vocab_size: int = 512,
                       vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
 
 
+def build_glm5_lm(name: str = "lm", vocab_size: int = 512,
+                  max_len: int = 256, eos_id: int | None = None,
+                  rng=None, dtype: str = "bfloat16", **dims):
+    """The hyper-connected KDA / sparse-latent hybrid (``models/glm5.py``
+    ``Glm5LM``): ``streams`` residual streams a token around every sublayer
+    (``ops/mhc.py``); a layer mixes by Kimi Delta Attention (``layer_types``
+    ``kda``: ``models/ling3.py``'s recurrence, the decay and the output gate
+    through a rank of ``kda_lora``) or by latent attention without positions
+    over a learned selection (``sparse``: a row a position that is all value;
+    the indexer's keys pooled ``index_pool`` at a time, one cached row a
+    block, ``index_topk`` positions kept as whole blocks, the query's own
+    always); ``mlp_types`` says which FFNs are a dense SwiGLU and which
+    sigmoid-routed experts, of which this process holds ``experts_held``
+    from ``first_expert``, with an ungated shared expert; every SwiGLU
+    clamped at ``swiglu_limit``; untied head, bfloat16 weights and cache.
+    ``dims``: the model's fields; a key the family does not know is an
+    error, not a default."""
+    from ..models.glm5 import create_glm5_lm
+    from .kvcache import LMServable
+    model, params = create_glm5_lm(rng=rng, vocab_size=vocab_size,
+                                   dtype=dtype, **dims)
+    return LMServable(name=name, model=model, params=params,
+                      vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
+
+
 # LM families ride the decode engine (``runtime/decode.py``), never the
 # MicroBatcher: ``cli`` tells them from the batch families by this table.
 LM_FAMILIES = {
@@ -738,6 +763,7 @@ LM_FAMILIES = {
     "dots3": build_dots3_lm,
     "xing4": build_xing4_lm,
     "ling3": build_ling3_lm,
+    "glm5": build_glm5_lm,
 }
 
 

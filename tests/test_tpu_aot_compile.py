@@ -885,3 +885,178 @@ def test_ling3_step_at_the_benchmark_cell_moves_no_pool(
               f"{prefill.temp_size_in_bytes} + outputs "
               f"{prefill.output_size_in_bytes}, peak {peak}")
         assert peak < 15.0e9, (top, peak, prefill.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("bound", [13056, 17408])
+def test_latent_kernel_compiles_on_a_row_that_is_all_value(v5e_sharding,
+                                                           bound):
+    """The latent read at the ``glm53.longctx`` cell's shapes alone, by
+    Mosaic: 64 heads on one 512-lane row that is its own value (``value ==
+    row``: no rotary lanes and nothing padded), blocks of 1,024 positions,
+    under the selection's mask."""
+    from ai4e_tpu.ops import kv_pool
+    pool = (1, 64, 17408, 512)
+    rows = _on(v5e_sharding, (pool, jnp.bfloat16))
+    q = _on(v5e_sharding, ((64, 64, 512), jnp.bfloat16))
+    new = _on(v5e_sharding, ((64, 512), jnp.bfloat16))
+    ints = _on(v5e_sharding, ((64,), jnp.int32))
+    keep = _on(v5e_sharding, ((64, bound), jnp.bool_))
+    assert kv_pool.read_block(pool, jnp.bfloat16) == 1024
+
+    def read(q, new, rows, position, keep):
+        return kv_pool.latent_decode_attention(
+            q, new, rows, 0, position, value=512, bound=bound, scale=1 / 16,
+            keep=keep, interpret=False)
+
+    compiled = _compile(read, q, new, rows, ints, keep)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("p", [4096, 16384, 17408])
+def test_prompt_kernels_compile_at_keys_and_values_256_wide(v5e_sharding, p):
+    """The sparse layer's prefill kernels at the ``glm53.longctx`` cell's
+    shapes alone, by Mosaic: a group of 16 heads with keys and values both
+    256 wide under the selection's one-byte mask, at the shortest and the
+    longest buckets and the cache's own length; the indexer's 32 heads of 128
+    for a block of 256 queries against ``p / 4`` pooled keys; and the
+    selection of 511 of that block's scores. A call's blocks fit the VMEM it
+    asks Mosaic for, by the kernel's own count and by the compile."""
+    import importlib
+    from ai4e_tpu.ops import kv_pool
+    from ai4e_tpu.ops.pallas import select_top
+    from ai4e_tpu.ops.pallas.validate import VMEM_PHYSICAL_BYTES
+    flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
+    q, k, v = (_on(v5e_sharding, ((p, 16, 256), jnp.bfloat16))
+               for _ in range(3))
+    mask = _on(v5e_sharding, ((p, p), jnp.int8))
+
+    def attend(q, k, v, mask):
+        return kv_pool.prompt_attention(q, k, v, 1 / 16, mask=mask,
+                                        interpret=False)
+
+    assert "tpu_custom_call" in _compile(attend, q, k, v, mask).as_text()
+    block = flash._prompt_block(p)
+    group = flash._head_group(16, block, 256, 256, 2, True)
+    assert (flash.prompt_vmem_bytes(group, block, 256, 256, 2, True)
+            <= flash.PROMPT_VMEM_BYTES <= VMEM_PHYSICAL_BYTES // 2)
+    scores = _compile(
+        lambda iq, ik, w, first: flash.index_scores(iq, ik, w, first,
+                                                    interpret=False),
+        _on(v5e_sharding, ((32, 256, 128), jnp.bfloat16)),
+        _on(v5e_sharding, ((p // 4, 128), jnp.bfloat16)),
+        _on(v5e_sharding, ((256, 32), jnp.float32)),
+        _on(v5e_sharding, ((), jnp.int32)))
+    assert "tpu_custom_call" in scores.as_text()
+    selection = _compile(
+        lambda scores, valid: select_top.select_top(scores, valid, 511,
+                                                    interpret=False),
+        _on(v5e_sharding, ((256, p // 4), jnp.float32)),
+        _on(v5e_sharding, ((256, p // 4), jnp.int8)))
+    assert "tpu_custom_call" in selection.as_text()
+    assert select_top.vmem_bytes(p // 4) <= VMEM_PHYSICAL_BYTES // 2
+
+
+@pytest.fixture(scope="module")
+def glm53_cell():
+    from ai4e_tpu.models.glm5 import Glm5LM, create_glm5_lm
+    from benchmark.references.glm5 import NOT_MODEL_KEYS
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "glm-5.3-flash.json")) as f:
+        spec = json.load(f)["models"]["models"][0]
+
+    def model(**dims):   # the spec's JSON lists as the module's tuples
+        return Glm5LM(**dict(dims, layer_types=tuple(dims["layer_types"]),
+                             mlp_types=tuple(dims["mlp_types"])))
+
+    return _benchmark_cell(
+        "glm-5.3-flash.json", create_glm5_lm, model,
+        [key for key in spec if key not in NOT_MODEL_KEYS])
+
+
+@pytest.mark.parametrize("rung", [0, 1])
+def test_glm53_step_at_the_benchmark_cell_moves_no_pool(
+        v5e_sharding, glm53_cell, rung):
+    """The ``glm53.longctx`` cell (``benchmark/configs/glm-5.3-flash.json``):
+    a tensor of latent rows ``(1, 64, 17408, 512)`` — a row that is all
+    value — and one of pooled index keys ``(1, 64, 4352, 128)``, a row every
+    four positions, each made only by row writes on its donated parameter;
+    ONE ``latent_attention`` Mosaic call; four ``f32[64,64,128,128]`` KDA
+    states, each advanced by one ``state_update`` Mosaic call on the donated
+    parameter itself; four convolution tails and the open block's sum; every
+    tensor aliased input to output with temporaries smaller than ONE state
+    tensor. At the top rung, the whole worker's memory: weights + pools (at
+    least 11.5 GB) + the temporaries and outputs of the 8,192 and 16,384
+    prefills and of the cache length 17,408 the runtime adds stay within the
+    chip's 16.9 GB (4.07 GB of temporaries at 16,384 by the compiler's count:
+    15.9 GB; the chip's own allocator read a peak of 12.1 GB over a whole
+    run, ``PERF.md`` section 4), each prefill with its sixteen-head
+    ``prompt_attention`` calls and its four ``kda_chunk`` calls by Mosaic."""
+    import importlib
+    from ai4e_tpu.ops import state_pool
+    from ai4e_tpu.ops.pallas import kda_chunk, select_top
+    runtime, spec = glm53_cell
+    assert runtime.step_bounds == (13056, 17408)
+    shapes = ((1, 64, 17408, 512), (1, 64, 4352, 128))
+    assert runtime.cache_spec() == tuple((s, jnp.bfloat16) for s in shapes)
+    state = runtime.state_spec()
+    assert len(state) == 9
+    assert state[0] == ("kda0", (64, 128, 128), jnp.float32)
+    assert state[1] == ("conv0", (3, 24576), jnp.bfloat16)
+    assert state[8] == ("isum0", (128,), jnp.float32)
+    one_state = 64 * 64 * 128 * 128 * 4
+    bound = runtime.step_bounds[rung]
+    with pytest.MonkeyPatch.context() as patch:
+        # the step's selection of 511 of 4,352 blocks a slot is the kernel's
+        # at the top rung (``kv_pool.SELECT_KERNEL_BYTES``)
+        patch.setattr(select_top, "resolve_interpret",
+                      lambda kernel, interpret: False)
+        compiled = _compile_step(runtime, v5e_sharding, bound)
+    assert len(_mosaic_calls(compiled, "latent_attention")) == 1
+    assert len(_mosaic_calls(compiled, "select_top")) == rung
+    _assert_state_steps_in_place(
+        compiled, _hlo_type((64, 64, 128, 128), jnp.float32), 4)
+    results = _entry_results(compiled)
+    for shape in shapes:
+        pool_type = _hlo_type(shape, jnp.bfloat16)
+        makers = [op for kind, op in results if kind.startswith(pool_type)]
+        # The pooled keys are scored WHOLE (71 MB of a step's 10 GB): at the
+        # top rung, where the scores read the tensor's every row, XLA hands
+        # them one prefetched copy of it (at the lower rung a slice, of
+        # another type); the latent rows, 17 times that, are never copied.
+        allowed = {"dynamic-update-slice", "parameter"} | (
+            {"copy-done"} if shape[3] == 128 and rung else set())
+        assert set(makers) <= allowed, (shape, set(makers))
+        assert makers.count("dynamic-update-slice") == 64
+        assert makers.count("copy-done") <= 1
+    memory = compiled.memory_analysis()
+    pools = runtime.cache_nbytes()
+    assert pools == (2 * 64 * (17408 * 512 + 4352 * 128)
+                     + state_pool.nbytes(state, 64))
+    assert memory.alias_size_in_bytes >= pools
+    assert memory.temp_size_in_bytes < one_state, memory.temp_size_in_bytes
+    if bound < runtime.max_len:
+        return
+
+    resident = memory.argument_size_in_bytes   # weights + pools (+ ints)
+    assert 11.5e9 < resident < 12.0e9, resident
+    flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
+    for top in (8192, 16384, runtime.max_len):
+        with pytest.MonkeyPatch.context() as patch:
+            for kernel in (flash, kda_chunk, select_top):
+                patch.setattr(kernel, "resolve_interpret",
+                              lambda kernel, interpret: False)
+            prefill = runtime._programs["prefill"].lower(
+                _on(v5e_sharding, runtime.servable.params),
+                _on(v5e_sharding, ((1, top), jnp.int32)),
+                _on(v5e_sharding, ((1,), jnp.int32))).compile()
+        assert len(_mosaic_calls(prefill, "prompt_attention")) == 4
+        assert len(_mosaic_calls(prefill, "kda_chunk")) == 4
+        prefill = prefill.memory_analysis()
+        peak = resident + max(memory.temp_size_in_bytes,
+                              prefill.temp_size_in_bytes
+                              + prefill.output_size_in_bytes)
+        print(f"glm53 cell: resident {resident}, step temporaries "
+              f"{memory.temp_size_in_bytes}, prefill {top}: temporaries "
+              f"{prefill.temp_size_in_bytes} + outputs "
+              f"{prefill.output_size_in_bytes}, peak {peak}")
+        assert peak < 16.3e9, (top, peak, prefill.temp_size_in_bytes)
