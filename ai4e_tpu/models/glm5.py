@@ -15,7 +15,9 @@ imported:
   every sublayer ``F`` — a mixer or an FFN, each with its own input norm —
   runs as ``u = H_pre X; X ← H_res X + H_postᵀ F(n(u))``; after the last layer
   the final norm of the streams' sum, the untied head, greedy argmax on the
-  device.
+  device. A step holds its slots' streams as ``(S, n, D)``, a prefill its
+  prompt's as rows ``(P, n·D)`` from the embedding to the head
+  (``mhc.pre_rows``).
 - **KDA** (``layer_types[i] == "kda"``): ``models/ling3.py``'s mixer
   (``kda_prompt`` / ``kda_token``: the chunked kernel in a prefill, the live
   slots' states advanced in place in a step) with the decay's and the output
@@ -87,7 +89,9 @@ INIT_GAINS = {"w_uq": 1.5, "w_o": 3.0, "index": 2.0, "w_down": 0.2,
               "shared_down": 0.15, "mlp_down": 0.15, "router": 2.0,
               "router_bias": 0.2}
 
-# The ``jax.named_scope``s of this family's programs, for a trace's reader.
+# The ``jax.named_scope``s of this family's programs, for a trace's reader
+# (``sinkhorn``: a step's only — a prompt's iterations run inside the
+# ``mhc_pre`` kernel and are booked there).
 TRACE_SCOPES = ("embedding", "mhc_pre", "sinkhorn", "mhc_post", "kda_proj",
                 "conv", "kda_gate", "kda_chunk", "state_update", "gated_norm",
                 "latent_q", "latent_kv", "indexer", "index_pool", "select",
@@ -199,9 +203,9 @@ class _Layer(nn.Module):
         ``index_topk / index_pool`` places."""
         return self.index_topk // self.index_pool - 1
 
-    def _hyper(self, x, params):
-        return mhc.pre(x, params, iters=self.sinkhorn_iters, eps=self.hc_eps,
-                       clamp=self.hc_clamp, norm_eps=self.eps)
+    def _hyper(self, x, params, pre=mhc.pre):
+        return pre(x, params, iters=self.sinkhorn_iters, eps=self.hc_eps,
+                   clamp=self.hc_clamp, norm_eps=self.eps)
 
     # -- the FFN ---------------------------------------------------------------
 
@@ -363,12 +367,13 @@ class _Layer(nn.Module):
     # -- the block -------------------------------------------------------------
 
     def prefill(self, x, mask, length):
-        """``x (P, n, D)``, one prompt of ``length (1,)`` tokens padded to its
-        bucket; mask: (1, P). Returns the block's output, what the mixer
-        caches — a sparse layer ``((latent rows, pooled keys), open sum)``, a
-        KDA layer ``(state, tail)`` — and the passes its expert product took
-        (``experts.window_passes``; None from a dense layer)."""
-        u, h_post, h_res = self._hyper(x, self.hc_attn)
+        """``x (P, n·D)``, the rows of one prompt of ``length (1,)`` tokens
+        padded to its bucket (``mhc.pre_rows``); mask: (1, P). Returns the
+        block's output, what the mixer caches — a sparse layer ``((latent
+        rows, pooled keys), open sum)``, a KDA layer ``(state, tail)`` — and
+        the passes its expert product took (``experts.window_passes``; None
+        from a dense layer)."""
+        u, coef = self._hyper(x, self.hc_attn, mhc.pre_rows)
         if self.sparse:
             y, *cache = self._sparse_prompt(u, length[0])
         else:
@@ -376,10 +381,10 @@ class _Layer(nn.Module):
                 rms_norm(u, self.norm_in, self.eps)[None], self.kda, mask,
                 length, self.gate_bound, self.eps)
             y = y[0]
-        x = mhc.post(x, y, h_post, h_res)
-        u, h_post, h_res = self._hyper(x, self.hc_ffn)
+        x = mhc.post_rows(x, y, coef)
+        u, coef = self._hyper(x, self.hc_ffn, mhc.pre_rows)
         y, top_e = self._ffn(u, routed=True)
-        return (mhc.post(x, y, h_post, h_res), tuple(cache),
+        return (mhc.post_rows(x, y, coef), tuple(cache),
                 None if top_e is None else expert_layer.window_passes(
                     top_e, self.experts_held, self.experts,
                     self.first_expert))
@@ -501,10 +506,13 @@ class Glm5LM(nn.Module):
                           kind="index", whole=True, every=self.index_pool)),
             tuple(state), tuple(f"kda{j}" for j in range(linear)))
 
-    def _streams(self, tokens):
-        """``X_0``: the embedding on every stream, ``(..., n, D)``."""
+    def _streams(self, tokens, rows: bool = False):
+        """``X_0``: the embedding on every stream, ``(..., n, D)`` — a
+        prompt's as ``rows (P, n·D)``, stream ``i`` the lanes from ``i·D``."""
         with jax.named_scope("embedding"):
             e = self.embed[tokens]
+            if rows:
+                return jnp.concatenate([e] * self.streams, axis=-1)
             return jnp.broadcast_to(e[..., None, :],
                                     (*e.shape[:-1], self.streams, self.dim))
 
@@ -517,8 +525,9 @@ class Glm5LM(nn.Module):
                         rms_norm(h, self.norm_f, self.rms_eps), self.lm_head)
 
     def _prefill(self, tokens, length):
-        """One prompt: ``tokens (1, P)``, ``length (1,)``."""
-        x = self._streams(tokens[0])
+        """One prompt: ``tokens (1, P)``, ``length (1,)``; its streams come
+        back as rows ``(P, n·D)``."""
+        x = self._streams(tokens[0], rows=True)
         mask = jnp.arange(tokens.shape[1])[None, :] < length[:, None]
         latent, index, state, passes, kda = [], [], {}, [], 0
         for layer in self.layers:
@@ -533,9 +542,8 @@ class Glm5LM(nn.Module):
                 kda += 1
             if taken is not None:
                 passes.append(taken)
-        return (x[None], (jnp.stack(latent)[:, None],
-                          jnp.stack(index)[:, None]), state,
-                expert_layer.pass_report(passes))
+        return (x, (jnp.stack(latent)[:, None], jnp.stack(index)[:, None]),
+                state, expert_layer.pass_report(passes))
 
     def _step(self, tokens, latent, index, state, position, bound):
         x = self._streams(tokens)
@@ -571,9 +579,8 @@ class Glm5LM(nn.Module):
 
     def prefill(self, tokens, length):
         x, blocks, state, passes = self._prefill(tokens, length)
-        last = jnp.take_along_axis(
-            x, (length - 1)[:, None, None, None].astype(jnp.int32),
-            axis=1)[:, 0]
+        last = jax.lax.dynamic_slice_in_dim(x, length[0] - 1, 1).reshape(
+            1, self.streams, self.dim)
         ids = jnp.argmax(self._logits(last), axis=-1).astype(jnp.int32)
         return jnp.concatenate([ids, passes]), *blocks, state
 
@@ -594,7 +601,8 @@ class Glm5LM(nn.Module):
 
     def prefill_logits(self, tokens, length):
         x, blocks, state, _ = self._prefill(tokens, length)
-        return self._logits(x), *blocks, state
+        return (self._logits(x.reshape(1, -1, self.streams, self.dim)),
+                *blocks, state)
 
     def decode_logits(self, tokens, latent, index, state, position,
                       bound=None):

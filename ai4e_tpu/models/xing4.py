@@ -14,7 +14,9 @@ in float32, no biases.
   H_postᵀ y`` with ``H_pre = σ(·)``, ``H_post = 2σ(·)`` and ``H_res`` made
   doubly stochastic by ``sinkhorn_iters`` Sinkhorn iterations, a token its
   own, all from the flat-normed streams. After the last layer ``h = Σ_i
-  X_i``, the final norm, the untied head, greedy argmax on the device.
+  X_i``, the final norm, the untied head, greedy argmax on the device. A
+  step holds its slots' streams as ``(S, n, D)``, a prefill its prompt's as
+  rows ``(P, n·D)`` from the embedding to the head (``mhc.pre_rows``).
 - **Latent attention** (``H`` heads, ranks ``r_q`` / ``r_kv``, head widths
   ``nope`` / ``rope`` / ``v``): ``c_q = n_q(u W_dq)``; ``[q_nope | q_rope]_h
   = c_q W_uq``; ``[c_kv | k_r] = u W_dkv``, ``c_kv ← n_kv(c_kv)``; ``q_rope``
@@ -66,7 +68,9 @@ INIT_GAINS = {"w_uq": 0.75, "w_o": 1.0, "w_down": 0.4, "shared_down": 0.3,
               "mlp_down": 0.4, "router": 2.0, "router_bias": 0.2,
               "hc_phi": 0.5, "hc_bias": 0.5, "hc_diagonal": 2.0}
 
-# The ``jax.named_scope``s of this family's programs, for a trace's reader.
+# The ``jax.named_scope``s of this family's programs, for a trace's reader
+# (``sinkhorn``: a step's only — a prompt's iterations run inside the
+# ``mhc_pre`` kernel and are booked there).
 TRACE_SCOPES = ("embedding", "mhc_pre", "sinkhorn", "mhc_post", "latent_q",
                 "latent_kv", "attention", "out_proj", "router", "experts",
                 "shared_expert", "mlp", "cache_update", "cache_insert",
@@ -201,9 +205,9 @@ class _Layer(nn.Module):
         return x if factor == 1.0 else (x.astype(jnp.float32)
                                         * factor).astype(x.dtype)
 
-    def _hyper(self, x, params):
-        return mhc.pre(x, params, iters=self.sinkhorn_iters, eps=self.hc_eps,
-                       clamp=self.hc_clamp, norm_eps=self.eps)
+    def _hyper(self, x, params, pre=mhc.pre):
+        return pre(x, params, iters=self.sinkhorn_iters, eps=self.hc_eps,
+                   clamp=self.hc_clamp, norm_eps=self.eps)
 
     # -- the FFN ---------------------------------------------------------------
 
@@ -294,15 +298,16 @@ class _Layer(nn.Module):
     # -- the block -------------------------------------------------------------
 
     def prefill(self, x):
-        """``x (P, n, D)``, one prompt padded to its bucket → the block's
-        output, the rows it caches ``(P, row)`` and the passes its expert
-        product took (``experts.window_passes``; None from a dense layer)."""
-        u, h_post, h_res = self._hyper(x, self.hc_attn)
+        """``x (P, n·D)``, the rows of one prompt padded to its bucket
+        (``mhc.pre_rows``) → the block's output, the rows it caches ``(P,
+        row)`` and the passes its expert product took
+        (``experts.window_passes``; None from a dense layer)."""
+        u, coef = self._hyper(x, self.hc_attn, mhc.pre_rows)
         y, row = self._attend_prompt(u)
-        x = mhc.post(x, y, h_post, h_res)
-        u, h_post, h_res = self._hyper(x, self.hc_ffn)
+        x = mhc.post_rows(x, y, coef)
+        u, coef = self._hyper(x, self.hc_ffn, mhc.pre_rows)
         y, top_e = self._ffn(u, routed=True)
-        return (mhc.post(x, y, h_post, h_res), row,
+        return (mhc.post_rows(x, y, coef), row,
                 None if top_e is None else expert_layer.window_passes(
                     top_e, self.experts, self.experts))
 
@@ -383,10 +388,13 @@ class Xing4LM(nn.Module):
             "latent", self.depth, padded(self.kv_rank + self.rope_dim),
             self.dtype, kind="latent"),))
 
-    def _streams(self, tokens):
-        """``X_0``: the embedding on every stream, ``(..., n, D)``."""
+    def _streams(self, tokens, rows: bool = False):
+        """``X_0``: the embedding on every stream, ``(..., n, D)`` — a
+        prompt's as ``rows (P, n·D)``, stream ``i`` the lanes from ``i·D``."""
         with jax.named_scope("embedding"):
             e = self.embed[tokens]
+            if rows:
+                return jnp.concatenate([e] * self.streams, axis=-1)
             return jnp.broadcast_to(e[..., None, :],
                                     (*e.shape[:-1], self.streams, self.dim))
 
@@ -399,16 +407,16 @@ class Xing4LM(nn.Module):
                         rms_norm(h, self.norm_f, self.rms_eps), self.lm_head)
 
     def _prefill(self, tokens):
-        """One prompt: ``tokens (1, P)``."""
-        x = self._streams(tokens[0])
+        """One prompt: ``tokens (1, P)``; its streams come back as rows
+        ``(P, n·D)``."""
+        x = self._streams(tokens[0], rows=True)
         rows, passes = [], []
         for layer in self.layers:
             x, row, taken = layer.prefill(x)
             rows.append(row)
             if taken is not None:
                 passes.append(taken)
-        return (x[None], jnp.stack(rows)[:, None],
-                expert_layer.pass_report(passes))
+        return x, jnp.stack(rows)[:, None], expert_layer.pass_report(passes)
 
     def _step(self, tokens, latent, position, bound):
         x = self._streams(tokens)
@@ -425,9 +433,8 @@ class Xing4LM(nn.Module):
 
     def prefill(self, tokens, length):
         x, block, passes = self._prefill(tokens)
-        last = jnp.take_along_axis(
-            x, (length - 1)[:, None, None, None].astype(jnp.int32),
-            axis=1)[:, 0]
+        last = jax.lax.dynamic_slice_in_dim(x, length[0] - 1, 1).reshape(
+            1, self.streams, self.dim)
         ids = jnp.argmax(self._logits(last), axis=-1).astype(jnp.int32)
         return jnp.concatenate([ids, passes]), block, {}
 
@@ -444,7 +451,8 @@ class Xing4LM(nn.Module):
 
     def prefill_logits(self, tokens, length):
         x, block, _ = self._prefill(tokens)
-        return self._logits(x), block, {}
+        return (self._logits(x.reshape(1, -1, self.streams, self.dim)), block,
+                {})
 
     def decode_logits(self, tokens, latent, state, position, bound=None):
         x, latent, _, _ = self._step(tokens, latent, position, bound)

@@ -21,13 +21,35 @@ toward a doubly stochastic matrix, a token its own. The clamp holds ``exp``
 finite in float32 (``e^30`` ≈ 1e13).
 
 ``pre`` is what comes before a sublayer and ``post`` what comes after it,
-each one operation: plain ``jax.numpy`` in float32 whatever ``X``'s dtype
-(``X`` itself is held in the family's dtype between sublayers), the
-iterations unrolled over a ``(tokens, n, n)`` array so that XLA makes one
-fusion of them, the mixes written as sums over streams (elementwise: a
-``dot_general`` of 4 x 4 a token is not what the chip's matrix unit is for).
-Scopes ``mhc_pre`` (norm, projection, the read mix), ``sinkhorn`` (inside
-it) and ``mhc_post`` name the device side for a trace's reader.
+float32 whatever ``X``'s dtype (``X`` itself is held in the family's dtype
+between sublayers), the mixes written as sums over streams (elementwise: a
+``dot_general`` of 4 x 4 a token is not what the chip's matrix unit is for;
+the projection ``x' φ`` is, and takes it). One algorithm in two forms, by
+the size of ``X``:
+
+* **A step's streams** ``X (S, n, D)`` — a token a slot, a megabyte or two —
+  ``pre`` / ``post``: plain ``jax.numpy``, the iterations unrolled over a
+  ``(tokens, n, n)`` array. At that size every array stays on chip between
+  XLA's fusions and the whole costs 0.18 ms of a 14 ms step.
+* **A prompt's rows** ``X (P, n·D)`` — stream ``i`` the lanes ``i·D … (i+1)·D``
+  of a token's row — ``pre_rows`` / ``post_rows``, which the families'
+  ``prefill`` carry from the embedding to the head. The stream axis is
+  never a tiled dimension of its own there (as the second-minor of ``(P, n,
+  D)`` it is padded from 4 to 16 rows or re-laid streams-major, and the
+  projection's ``(P, n·D)`` view is then a transposing copy), and from
+  ``ROWS_KERNEL_BYTES`` on each half is ONE Pallas call
+  (``pallas/mhc_rows.py``) that reads a block of rows from HBM once: the
+  ``jax.numpy`` form at 8,192 tokens moves 4.3 times the bytes the halves
+  need, a float32 copy of ``X`` among them (``PERF.md`` section 6, PR 52).
+  The coefficients pass from ``pre_rows`` to ``post_rows`` as one float32
+  lane tile a token (``mhc_rows.coefficient_lanes``), never as ``(P, n,
+  n)``. Rows under the threshold, or whose ``D`` is not whole lane tiles,
+  take the first form through a reshape.
+
+Scopes ``mhc_pre`` (norm, projection, the coefficients, the read mix) and
+``mhc_post`` name the device side for a trace's reader in both forms;
+``sinkhorn`` is a scope of its own inside ``mhc_pre`` in the first form only
+— in a kernel the iterations are part of the one call.
 
 A sublayer's parameters (``params``): ``phi (nD, 2n + n²)`` — the columns of
 ``φ_pre``, ``φ_post``, ``φ_res`` side by side —, ``alpha (3,)`` and ``bias
@@ -39,6 +61,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .pallas import mhc_rows
+
+# The bytes of a prompt's rows ``X`` from which its halves are the kernels':
+# under it (a step's slots are 2 MB) the ``jax.numpy`` form.
+ROWS_KERNEL_BYTES = 4 << 20
 
 
 def sinkhorn(logits, iters: int, eps: float):
@@ -94,3 +122,41 @@ def post(x, y, h_post, h_res):
         mixed = (h_res[:, :, :, None] * h[:, None, :, :]).sum(axis=2)
         return (mixed + h_post[:, :, None]
                 * y.astype(jnp.float32)[:, None, :]).astype(x.dtype)
+
+
+def _kernel_rows(x, d: int) -> bool:
+    return (x.size * x.dtype.itemsize >= ROWS_KERNEL_BYTES
+            and d % mhc_rows.LANES == 0)
+
+
+def pre_rows(x, params, *, iters: int = 20, eps: float = 1e-6,
+             clamp: float = 30.0, norm_eps: float = 1e-6):
+    """``pre`` of a prompt's rows ``x (T, n·D)`` → ``u (T, D)`` and the
+    coefficients its ``post_rows`` needs, one float32 lane tile a token
+    (``mhc_rows.coefficient_lanes``)."""
+    t, width = x.shape
+    n = mhc_rows.streams(params["phi"].shape[1])
+    with jax.named_scope("mhc_pre"):
+        if _kernel_rows(x, width // n):
+            return mhc_rows.pre(x, params["phi"], params["alpha"],
+                                params["bias"], iters=iters, eps=eps,
+                                clamp=clamp, norm_eps=norm_eps)
+    u, h_post, h_res = pre(x.reshape(t, n, width // n), params, iters=iters,
+                           eps=eps, clamp=clamp, norm_eps=norm_eps)
+    lanes = mhc_rows.coefficient_lanes(n)[n:]
+    return u, jnp.zeros((t, mhc_rows.LANES), jnp.float32).at[:, lanes].set(
+        jnp.concatenate([h_post, h_res.reshape(t, n * n)], axis=1))
+
+
+def post_rows(x, y, coef):
+    """``post`` of a prompt's rows: ``x (T, n·D)``, ``y (T, D)`` and
+    ``pre_rows``' coefficients → ``(T, n·D)``."""
+    t, width = x.shape
+    d = y.shape[1]
+    n = width // d
+    with jax.named_scope("mhc_post"):
+        if _kernel_rows(x, d):
+            return mhc_rows.post(x, y, coef)
+    h = coef[:, mhc_rows.coefficient_lanes(n)[n:]]
+    return post(x.reshape(t, n, d), y, h[:, :n],
+                h[:, n:].reshape(t, n, n)).reshape(t, width)

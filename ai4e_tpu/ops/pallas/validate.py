@@ -3,7 +3,7 @@
 The serving kernels (``flash_attention``, ``segmentation_argmax``,
 ``normalize_image``, ``decode_attention``, ``latent_attention``,
 ``prompt_attention``, ``index_scores``, ``select_top``, ``state_update``,
-``kda_chunk``) default to interpret mode off-TPU, so CPU CI never
+``kda_chunk``, ``mhc_pre`` / ``mhc_post``) default to interpret mode off-TPU, so CPU CI never
 proves they compile to Mosaic and fit VMEM on real hardware. This module is
 that proof: ``validate_kernels()`` runs each kernel with ``interpret=False``
 (on TPU) against a pure-XLA oracle and asserts its working set fits the
@@ -85,6 +85,18 @@ def select_top_vmem_bytes(n: int) -> int:
     .vmem_bytes``: the same number is the call's ``vmem_limit_bytes``)."""
     from .select_top import vmem_bytes
     return vmem_bytes(n)
+
+
+def mhc_rows_vmem_bytes(n: int, d: int, dtype_bytes: int = 2) -> int:
+    """The larger of a prompt's two hyper-connection kernels at ``n``
+    streams of ``d`` lanes: a block of rows double-buffered — in and out in
+    ``mhc_post``, beside ``φ`` on a lane tile in ``mhc_pre`` — with the
+    sublayer's row, the coefficients' lane tile and the headroom
+    (``mhc_rows.pre_vmem_bytes`` / ``post_vmem_bytes``: the calls'
+    ``vmem_limit_bytes``)."""
+    from .mhc_rows import post_vmem_bytes, pre_vmem_bytes
+    return max(pre_vmem_bytes(n, d, dtype_bytes),
+               post_vmem_bytes(n, d, dtype_bytes))
 
 
 # What the chip has of VMEM, which a call may ask for beyond the scoped
@@ -544,6 +556,65 @@ def validate_kernels(interpret: bool = False) -> dict:
             entry.update(ms=round(seconds * 1e3, 3), us_a_chunk_a_head=round(
                 seconds * 1e6 / (t // CHUNK * hv), 3))
         results[f"kda_chunk_{name}"] = entry
+
+    # a prompt's hyper-connection halves vs ``ops/mhc.py``'s ``jax.numpy``
+    # form at the two cells' widths — ``glm53.longctx``'s 4 x 4,096 over a
+    # 4,096 bucket, ``xing4.reason``'s 4 x 3,584 over 2,048 tokens (4 x 256
+    # over 200 tokens, no multiple of the block, under the interpreter) —
+    # from bfloat16 rows: the coefficients to 2e-6, ``u`` and ``X'`` to one
+    # bfloat16 unit in the last place (``max_err`` is the worst of the two
+    # in such units: under 1). Timed where compiled.
+    from .. import mhc
+    from . import mhc_rows
+    knobs = dict(iters=20, eps=1e-6, clamp=30.0, norm_eps=1e-6)
+    for name, (t, n, d) in (
+            {"glm53": (200, 4, 256)} if interpret else
+            {"glm53": (4096, 4, 4096), "xing4": (2048, 4, 3584)}).items():
+        columns = 2 * n + n * n
+        hyper = {"phi": normal(n * d, columns,
+                               scale=(n * d) ** -0.5).astype("bfloat16"),
+                 "alpha": jax.numpy.asarray([1.0, 0.7, 2.0]),
+                 "bias": normal(columns)}
+        x = normal(t, n, d).astype("bfloat16")
+        y = normal(t, d).astype("bfloat16")
+        pre = jax.jit(lambda x: mhc_rows.pre(
+            x, hyper["phi"], hyper["alpha"], hyper["bias"], **knobs,
+            interpret=interpret))
+        post = jax.jit(lambda x, y, coef: mhc_rows.post(
+            x, y, coef, interpret=interpret))
+        u, coef = pre(x.reshape(t, n * d))
+        mixed = post(x.reshape(t, n * d), y, coef)
+        want_u, h_post, h_res = jax.jit(
+            lambda x: mhc.pre(x, hyper, **knobs))(x)
+        want = jax.jit(mhc.post)(x, y, h_post, h_res)
+        held = np.asarray(coef)[:, mhc_rows.coefficient_lanes(n)[n:]]
+        coef_err = float(np.abs(held - np.concatenate(
+            [h_post, h_res.reshape(t, n * n)], axis=1)).max())
+
+        def ulps(got, want):
+            got, want = (np.asarray(a, np.float32) for a in (got, want))
+            # 2e-5: the float32 sums' own units, all there is where the
+            # four or five terms cancel
+            return float((np.abs(got - want) / (2.0 ** -7 * np.maximum(
+                np.abs(want), np.abs(got)) + 2e-5)).max())
+
+        err = max(ulps(u, want_u), ulps(mixed.reshape(t, n, d), want))
+        vmem = mhc_rows_vmem_bytes(n, d)
+        assert vmem <= VMEM_PHYSICAL_BYTES // 2, f"mhc rows VMEM {vmem}"
+        entry = {"ok": bool(err < 1.0 and coef_err < 2e-6),
+                 "max_err": round(err, 6), "coef_err": coef_err,
+                 "vmem_bytes": vmem}
+        if not interpret:
+            for half, run, args in (("pre", pre, (x.reshape(t, n * d),)),
+                                    ("post", post,
+                                     (x.reshape(t, n * d), y, coef))):
+                t0 = time.perf_counter()
+                for _ in range(5):
+                    out = run(*args)
+                jax.block_until_ready(out)
+                entry[f"{half}_ms"] = round(
+                    (time.perf_counter() - t0) / 5 * 1e3, 3)
+        results[f"mhc_rows_{name}"] = entry
 
     results["all_ok"] = all(r["ok"] for r in results.values()
                             if isinstance(r, dict))

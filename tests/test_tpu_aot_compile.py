@@ -761,6 +761,7 @@ def test_xing4_step_at_the_benchmark_cell_moves_no_pool(
     temporaries and outputs stay under 15 GB."""
     import importlib
     import re
+    from ai4e_tpu.ops.pallas import mhc_rows, validate
     runtime, spec = xing4_cell
     assert runtime.step_bounds == (3072, 4096)
     shape = (7, 32, 4096, 640)
@@ -777,6 +778,8 @@ def test_xing4_step_at_the_benchmark_cell_moves_no_pool(
     (pool,) = re.findall(r"(%\S+) = " + re.escape(pool_type)
                          + r"\S* parameter\(", entry)
     assert sum(pool in ops for _, ops, _ in kernels) == 7
+    # no other Mosaic call: the step's 32 slots of four streams (0.9 MB)
+    # keep the ``jax.numpy`` hyper-connections (``mhc.ROWS_KERNEL_BYTES``)
     assert len(kernels) == compiled.as_text().count(
         'custom_call_target="tpu_custom_call"')
     memory = compiled.memory_analysis()
@@ -789,15 +792,21 @@ def test_xing4_step_at_the_benchmark_cell_moves_no_pool(
     resident = memory.argument_size_in_bytes   # weights + pool (+ ints)
     assert 12.2e9 < resident < 12.3e9, resident
     flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
+    assert (validate.mhc_rows_vmem_bytes(4, 3584)
+            <= validate.VMEM_PHYSICAL_BYTES // 2)
     for top in (2048, runtime.max_len):
         with pytest.MonkeyPatch.context() as patch:
-            patch.setattr(flash, "resolve_interpret",
-                          lambda kernel, interpret: False)
+            for kernel in (flash, mhc_rows):
+                patch.setattr(kernel, "resolve_interpret",
+                              lambda kernel, interpret: False)
             prefill = runtime._programs["prefill"].lower(
                 _on(v5e_sharding, runtime.servable.params),
                 _on(v5e_sharding, ((1, top), jnp.int32)),
                 _on(v5e_sharding, ((1,), jnp.int32))).compile()
         assert len(_mosaic_calls(prefill, "prompt_attention")) == 7
+        # a prompt's fourteen sublayers: each half one read of its rows
+        assert len(_mosaic_calls(prefill, "mhc_pre")) == 14
+        assert len(_mosaic_calls(prefill, "mhc_post")) == 14
         prefill = prefill.memory_analysis()
         peak = resident + max(memory.temp_size_in_bytes,
                               prefill.temp_size_in_bytes
@@ -993,7 +1002,7 @@ def test_glm53_step_at_the_benchmark_cell_moves_no_pool(
     ``prompt_attention`` calls and its four ``kda_chunk`` calls by Mosaic."""
     import importlib
     from ai4e_tpu.ops import state_pool
-    from ai4e_tpu.ops.pallas import kda_chunk, select_top
+    from ai4e_tpu.ops.pallas import kda_chunk, mhc_rows, select_top, validate
     runtime, spec = glm53_cell
     assert runtime.step_bounds == (13056, 17408)
     shapes = ((1, 64, 17408, 512), (1, 64, 4352, 128))
@@ -1013,6 +1022,9 @@ def test_glm53_step_at_the_benchmark_cell_moves_no_pool(
         compiled = _compile_step(runtime, v5e_sharding, bound)
     assert len(_mosaic_calls(compiled, "latent_attention")) == 1
     assert len(_mosaic_calls(compiled, "select_top")) == rung
+    # the step's 64 slots of four streams (2 MB) keep the ``jax.numpy``
+    # hyper-connections (``mhc.ROWS_KERNEL_BYTES``)
+    assert not _mosaic_calls(compiled, "mhc_")
     _assert_state_steps_in_place(
         compiled, _hlo_type((64, 64, 128, 128), jnp.float32), 4)
     results = _entry_results(compiled)
@@ -1040,9 +1052,11 @@ def test_glm53_step_at_the_benchmark_cell_moves_no_pool(
     resident = memory.argument_size_in_bytes   # weights + pools (+ ints)
     assert 11.5e9 < resident < 12.0e9, resident
     flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
+    assert (validate.mhc_rows_vmem_bytes(4, 4096)
+            <= validate.VMEM_PHYSICAL_BYTES // 2)
     for top in (8192, 16384, runtime.max_len):
         with pytest.MonkeyPatch.context() as patch:
-            for kernel in (flash, kda_chunk, select_top):
+            for kernel in (flash, kda_chunk, select_top, mhc_rows):
                 patch.setattr(kernel, "resolve_interpret",
                               lambda kernel, interpret: False)
             prefill = runtime._programs["prefill"].lower(
@@ -1051,6 +1065,15 @@ def test_glm53_step_at_the_benchmark_cell_moves_no_pool(
                 _on(v5e_sharding, ((1,), jnp.int32))).compile()
         assert len(_mosaic_calls(prefill, "prompt_attention")) == 4
         assert len(_mosaic_calls(prefill, "kda_chunk")) == 4
+        # a prompt's ten sublayers: each half one read of its rows, and no
+        # float32 or re-laid copy of the rows between them
+        assert len(_mosaic_calls(prefill, "mhc_pre")) == 10
+        assert len(_mosaic_calls(prefill, "mhc_post")) == 10
+        rows = [op for kind, op in _entry_results(prefill)
+                if kind.startswith((f"f32[{top},16384]",
+                                    f"bf16[{top},4,4096]",
+                                    f"f32[{top},4,4096]"))]
+        assert not rows, rows
         prefill = prefill.memory_analysis()
         peak = resident + max(memory.temp_size_in_bytes,
                               prefill.temp_size_in_bytes
