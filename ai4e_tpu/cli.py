@@ -252,8 +252,24 @@ def _restore_checkpoint(servable, checkpoint: str,
     log.info("restored %s params from %s", servable.name, checkpoint)
 
 
+def _param_bytes(params) -> int:
+    """Bytes of a servable's parameters, from their shapes: nothing is
+    waited for. A seeded initialisation is dispatched, not done, when its
+    call returns, and the device finishes it under the host's next work
+    (the pools, the first prefill's trace) — a wait here would take that
+    overlap out of every start."""
+    import jax
+    return sum(leaf.nbytes for leaf in jax.tree.leaves(params))
+
+
 def build_worker(config: FrameworkConfig, models: dict):
-    """Assemble a worker process; returns (worker, batcher, task_manager)."""
+    """Assemble a worker process; returns (worker, batcher, task_manager).
+
+    Under ``run_worker`` every second of it is booked to a phase of the
+    boot ledger (``observability/boot.py``): ``enter`` closes the phase
+    that is open and opens the next."""
+    from .observability import boot
+    boot.enter("build")   # the runtime's imports, the mesh, the task client
     from .runtime import (
         LM_FAMILIES,
         InferenceWorker,
@@ -356,6 +372,7 @@ def build_worker(config: FrameworkConfig, models: dict):
         # Families that build mesh-aware compute (seqformer's sp attention)
         # receive the serving mesh; the rest ignore it via their **_ sink.
         spec.setdefault("mesh", runtime.mesh)
+        boot.enter("build", model=spec.get("name", family))
         servable = build_servable(family, **spec)
         if checkpoint:
             # Restore real weights at pod start (SURVEY.md §5: the slot the
@@ -363,9 +380,11 @@ def build_worker(config: FrameworkConfig, models: dict):
             # ai4e_tpu.train.make_checkpoints produces them).
             _restore_checkpoint(servable, checkpoint, rt.checkpoint_dir)
         runtime.register(servable)
+        boot.note(param_bytes=_param_bytes(servable.params))
         to_serve.append((servable, sync_path, async_path, cap,
                          pipeline_spec, batch))
 
+    boot.enter("serve")   # the ladder, the batcher, the shell's routes
     ladders = None
     import jax
     if rt.ladder_derive and jax.process_count() > 1 and jax.process_index():
@@ -441,7 +460,8 @@ def build_worker(config: FrameworkConfig, models: dict):
         if batch:
             worker.serve_batch(servable,
                                **(batch if isinstance(batch, dict) else {}))
-    runtime.warmup()
+    boot.enter("batch_warmup")
+    boot.note(model_s=runtime.warmup())
 
     # Continuous-batching decode path (AI4E_RUNTIME_DECODE_ENABLE,
     # docs/streaming.md): one engine per LM-family spec, AOT-warmed
@@ -459,6 +479,7 @@ def build_worker(config: FrameworkConfig, models: dict):
                     "loop owns the device); not serving %d LM "
                     "servable(s)", len(lm_specs))
     elif lm_specs:
+        boot.enter("build")   # the decode engine's and the families' imports
         from .runtime.decode import DecodeEngine
         from .runtime.kvcache import PagedDecodeRuntime, build_lm_servable
         for spec in lm_specs:
@@ -466,13 +487,19 @@ def build_worker(config: FrameworkConfig, models: dict):
             cap = spec.pop("maximum_concurrent_requests", 64)
             checkpoint = spec.pop("checkpoint", None)
             spec.setdefault("max_len", rt.kv_max_len)
+            boot.enter("build", model=spec.get("name"))
             lm = build_lm_servable(**spec)
             if checkpoint:
                 _restore_checkpoint(lm, checkpoint, rt.checkpoint_dir)
+            boot.note(model=lm.name, param_bytes=_param_bytes(lm.params))
             backend = PagedDecodeRuntime(
                 lm, slots=rt.kv_slots,
                 prompt_buckets=rt.decode_prompt_buckets or None)
+            boot.enter("pools", model=lm.name, bytes=backend.cache_nbytes())
+            backend.reset_cache()   # the first pool: allocated here
+            boot.enter("warm", model=lm.name)
             backend.warm()
+            boot.enter("serve")
             engine = DecodeEngine(backend,
                                   max_pending=rt.decode_max_pending,
                                   metrics=worker.service.metrics)
@@ -483,6 +510,7 @@ def build_worker(config: FrameworkConfig, models: dict):
                      backend.max_len, backend.prompt_buckets,
                      backend.cache_nbytes() / 1e6)
 
+    boot.enter("serve")
     if jax.process_count() > 1:
         # Multi-host serving (SURVEY.md §7 hard part #3): the primary's
         # batcher broadcasts each batch so every process enters the same
@@ -609,6 +637,7 @@ def _claim_devices(rt) -> None:
     with JAX's own "Unable to initialize backend"."""
     import jax
 
+    from .observability import boot
     from .parallel import init_distributed
     from .runtime.registry import device_report
     if rt.platform:
@@ -619,6 +648,7 @@ def _claim_devices(rt) -> None:
     # a 5.7 ms decode tick: 116 -> 102 s, CHANGES.md PR 30).
     jax.config.update("jax_traceback_in_locations_limit", 0)
     # Before the first backend touch: jax.distributed cannot start after it.
+    boot.enter("backend")
     init_distributed()
     report = device_report()
     if report["platform"] == "cpu" and rt.platform != "cpu":
@@ -644,6 +674,9 @@ async def _close(resource) -> None:
 async def run_worker(config: FrameworkConfig, models: dict) -> None:
     from aiohttp import web
 
+    from .observability import boot
+    # Open since the process's own start, closed where the server accepts.
+    boot.begin(models.get("service_name", "tpu-worker"))
     _claim_devices(config.runtime)
     worker, batcher, task_manager = build_worker(config, models)
 
@@ -653,6 +686,7 @@ async def run_worker(config: FrameworkConfig, models: dict) -> None:
         # primary's batch executions until it shuts us down.
         log.info("follower %d/%d: entering mirror loop",
                  jax.process_index(), jax.process_count())
+        boot.serving(worker.service.metrics)   # as far as a follower boots
         await asyncio.to_thread(worker.runtime.follower_loop)
         return
 
@@ -663,6 +697,7 @@ async def run_worker(config: FrameworkConfig, models: dict) -> None:
     await runner.setup()
     site = web.TCPSite(runner, config.service.host, config.service.port)
     await site.start()
+    boot.serving(worker.service.metrics)
     vitals = None
     if config.observability.vitals:
         # Same sampler as the control plane, in the worker's service

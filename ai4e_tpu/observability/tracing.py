@@ -280,13 +280,20 @@ class Tracer:
         finally:
             _CURRENT.reset(token)
             span.duration = time.perf_counter() - t0
-            self._span_seconds().observe(span.duration, name=name,
-                                         service=self.service)
-            if sampled:
-                try:
-                    self._effective_exporter().export(span)
-                except Exception:  # noqa: BLE001 — telemetry must not break serving
-                    log.exception("span export failed")
+            self.record(span, sampled)
+
+    def record(self, span: Span, sampled: bool = True) -> None:
+        """A closed span: observed, and exported if its trace is sampled.
+        ``span()`` ends here; so does a span whose interval its maker
+        measured — one that began before any tracer could open it, as a
+        boot does with its process (``observability/boot.py``)."""
+        self._span_seconds().observe(span.duration, name=span.name,
+                                     service=span.service)
+        if sampled:
+            try:
+                self._effective_exporter().export(span)
+            except Exception:  # noqa: BLE001 — telemetry must not break serving
+                log.exception("span export failed")
 
     def current_trace_id(self) -> str | None:
         cur = _CURRENT.get()

@@ -80,22 +80,44 @@ def read_rss_mb(pid: int | None = None,
     return -1.0 if rss < 0 else round(rss / (1024.0 * 1024.0), 1)
 
 
+def _stat_fields(pid: int | None, proc_root: str) -> list[str]:
+    """``/proc/<pid>/stat`` from its third field (state) on. The comm
+    field may contain spaces and parentheses — parse from the LAST ')'
+    like every correct /proc/stat reader."""
+    who = "self" if pid is None else str(pid)
+    with open(f"{proc_root}/{who}/stat", encoding="ascii") as fh:
+        raw = fh.read()
+    return raw[raw.rindex(")") + 2:].split()
+
+
 def read_cpu_seconds(pid: int | None = None,
                      proc_root: str = PROC_ROOT) -> float:
     """utime+stime of the process in seconds (``/proc/<pid>/stat``
-    fields 14/15), -1.0 on failure. The comm field may contain spaces
-    and parentheses — parse from the LAST ')' like every correct
-    /proc/stat reader."""
-    who = "self" if pid is None else str(pid)
+    fields 14/15), -1.0 on failure."""
     try:
-        with open(f"{proc_root}/{who}/stat", encoding="ascii") as fh:
-            raw = fh.read()
-        fields = raw[raw.rindex(")") + 2:].split()
+        fields = _stat_fields(pid, proc_root)
         # fields[0] is state (field 3); utime/stime are fields 14/15.
         ticks = int(fields[11]) + int(fields[12])
         return ticks / float(os.sysconf("SC_CLK_TCK"))
     except (OSError, IndexError, ValueError, TypeError):
         return -1.0
+
+
+def read_start_epoch(pid: int | None = None,
+                     proc_root: str = PROC_ROOT) -> float | None:
+    """Epoch seconds at which the process started, or None when ``/proc``
+    cannot say: ``starttime`` (``/proc/<pid>/stat`` field 22, ticks since
+    the machine's boot) against ``/proc/uptime`` now. Good to a tick or two
+    — ``btime`` in ``/proc/stat`` would give the same instant to a whole
+    second only."""
+    try:
+        started = (int(_stat_fields(pid, proc_root)[19])
+                   / float(os.sysconf("SC_CLK_TCK")))
+        with open(f"{proc_root}/uptime", encoding="ascii") as fh:
+            uptime = float(fh.read().split()[0])
+        return time.time() - (uptime - started)
+    except (OSError, IndexError, ValueError, TypeError):
+        return None
 
 
 def read_fd_count(pid: int | None = None,

@@ -59,7 +59,6 @@ also while the step runs (no copy to write into, no rewritten pool:
 
 from __future__ import annotations
 
-import logging
 import time
 from collections import deque
 from dataclasses import dataclass
@@ -67,11 +66,10 @@ from typing import Any
 
 import numpy as np
 
+from ..observability import boot
 from ..observability.tracing import device_trace
 from ..ops import kv_pool, state_pool
 from .decode import LaunchedStep
-
-log = logging.getLogger("ai4e_tpu.kvcache")
 
 # What the step program's compiler is told, by backend. XLA:TPU cuts each
 # weight's prefetch into slices — 736 of the 2,484 entry operations of a step
@@ -603,27 +601,28 @@ class PagedDecodeRuntime:
 
     # -- warmup ------------------------------------------------------------
 
-    def warm(self) -> float:
+    def warm(self) -> None:
         """Execute every program once — ``len(prompt_buckets)`` prefill +
         insert pairs and the step program of every rung of ``step_bounds``
         — so nothing compiles on the serving path, then reset the cache to
         a clean pool. The programs do not depend on the weights: after
-        ``reload_params`` the same ones serve. Returns wall seconds
-        (exported by the worker boot like batch warmup)."""
+        ``reload_params`` the same ones serve. Under a worker's boot
+        (``observability/boot.py``; ``cli.build_worker`` marks the phase)
+        every call below is one ``boot.warm.program`` span holding what JAX
+        reported of its trace, lowering, compile and cache; anywhere else
+        nothing is recorded."""
         self._ensure()
-        t0 = time.perf_counter()
         for bucket in self.prompt_buckets:
             n = min(bucket, self.max_len - 1)
-            self.prefill_into(0, [1] * n)
+            # The bucket's insert program is built inside the same call.
+            with boot.program("prefill", bucket=bucket):
+                self.prefill_into(0, [1] * n)
         for bound in self.step_bounds:
             # Once fed from the host and once from the step before: the
             # same program, and neither form of call is new when serving.
-            for fresh in ([0] * self.slots, [None] * self.slots):
-                self.fetch(self.launch(fresh, [bound] * self.slots,
-                                       [True] * self.slots))
+            for feed, fresh in (("host", [0] * self.slots),
+                                ("device", [None] * self.slots)):
+                with boot.program("step", bound=bound, feed=feed):
+                    self.fetch(self.launch(fresh, [bound] * self.slots,
+                                           [True] * self.slots))
         self.reset_cache()
-        seconds = time.perf_counter() - t0
-        log.info("decode warmup %s: %d prompt buckets + %d step bounds in "
-                 "%.1fs", self.name, len(self.prompt_buckets),
-                 len(self.step_bounds), seconds)
-        return seconds

@@ -164,7 +164,10 @@ class ModelRuntime:
     def warmup(self, names: list[str] | None = None,
                parallel: bool = True) -> dict[str, float]:
         """Precompile every (model, bucket) program. Returns compile seconds
-        per model — exported as a metric so pod-start latency is visible.
+        per model: a worker's boot carries them as attributes of its
+        ``boot.batch_warmup`` span, whose seconds are
+        ``ai4e_boot_seconds{phase="batch_warmup"}`` (``observability/
+        boot.py``).
 
         ``parallel`` (default): all (model, bucket) programs are AOT
         lowered+compiled concurrently first — XLA releases the GIL during
@@ -190,8 +193,6 @@ class ModelRuntime:
         if parallel and jax.process_count() == 1:
             jobs = [(s, b) for _, s in todo for b in s.batch_buckets]
             compile_s = self._aot_compile(jobs)
-            log.info("warmup: %d programs compiled concurrently in %.1fs",
-                     len(jobs), compile_s)
 
         # The concurrent compile phase serves every model at once, so its
         # wall time is amortised evenly across the per-model figures — the
@@ -207,8 +208,6 @@ class ModelRuntime:
                 self.run_batch(name, dummy)
             times[name] = (time.perf_counter() - t0
                            + compile_s / max(1, len(todo)))
-            log.info("warmup %s: %d buckets in %.1fs", name,
-                     len(servable.batch_buckets), times[name])
         return times
 
     def _aot_compile(self, jobs) -> float:

@@ -401,15 +401,27 @@ def main() -> None:
         if device["platform"] != platform:
             raise SystemExit(f"worker runs on {device['platform']!r}, "
                              f"not {platform!r}")
-        wlog = procs.log_text("worker")
-        for label, pattern in (
-                ("aot_compile_s", r"programs compiled concurrently in ([\d.]+)s"),
-                ("batch_warmup_s", r"warmup landcover: \d+ buckets in ([\d.]+)s"),
-                ("decode_warmup_s", r"decode warmup lm: .* in ([\d.]+)s")):
-            m = re.search(pattern, wlog)
-            if not m:
-                raise SystemExit(f"worker log has no {label} line")
-            timings[label] = float(m.group(1))
+        # The worker's own account of its start (observability/boot.py).
+        scrape = get_text(f"{wk_base}/metrics")
+        boot = {labels.get("phase"): v for labels, v in metric_samples(
+            scrape, "ai4e_boot_seconds")}
+        for label, phase in (("boot_s", "total"),
+                             ("batch_warmup_s", "batch_warmup"),
+                             ("decode_warmup_s", "warm")):
+            if phase not in boot:
+                raise SystemExit(f"worker /metrics has no ai4e_boot_seconds"
+                                 f"{{phase=\"{phase}\"}}")
+            timings[label] = boot[phase]
+        # What the backend compiled (a cold cache) or retrieved (a warm one).
+        compiled = [v for labels, v in metric_samples(
+            scrape, "ai4e_jax_compile_seconds_total")
+            if labels.get("when") == "boot"
+            and labels.get("stage") in ("backend", "retrieve")]
+        if len(compiled) != 2:
+            raise SystemExit("worker /metrics has no ai4e_jax_compile_"
+                             "seconds_total{stage=\"backend\"|\"retrieve\","
+                             "when=\"boot\"}")
+        timings["aot_compile_s"] = sum(compiled)
         landcover = next(m for m in listing["models"]
                          if m["name"] == "landcover")
 
