@@ -17,7 +17,8 @@ in float32, no biases.
   X_i``, the final norm, the untied head, greedy argmax on the device. A
   step holds its slots' streams as ``(S, n, D)``, a prefill its prompt's as
   rows ``(P, n·D)`` from the embedding to the head (``mhc.pre_rows``).
-- **Latent attention** (``H`` heads, ranks ``r_q`` / ``r_kv``, head widths
+- **Latent attention** (``models/latent.py``, the mixer ``axk1`` shares;
+  ``H`` heads, ranks ``r_q`` / ``r_kv``, head widths
   ``nope`` / ``rope`` / ``v``): ``c_q = n_q(u W_dq)``; ``[q_nope | q_rope]_h
   = c_q W_uq``; ``[c_kv | k_r] = u W_dkv``, ``c_kv ← n_kv(c_kv)``; ``q_rope``
   and ``k_r`` rotated, ``k_r`` shared by every head; ``k_nope,h = c_kv
@@ -59,9 +60,8 @@ import numpy as np
 
 from ..ops import kv_pool, mhc
 from . import experts as expert_layer
-from .dots3 import padded
-from .olmoe import (norm_scale, rms_norm, rope, seeded, yarn_inv_freq,
-                    yarn_mscale)
+from .latent import Latent, row_lanes
+from .olmoe import norm_scale, rms_norm, seeded
 
 # The seeded init's gains (``create_xing4_lm`` says why these).
 INIT_GAINS = {"w_uq": 0.75, "w_o": 1.0, "w_down": 0.4, "shared_down": 0.3,
@@ -79,10 +79,6 @@ TRACE_SCOPES = ("embedding", "mhc_pre", "sinkhorn", "mhc_post", "latent_q",
 
 def _dot(eq, a, b):
     return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32)
-
-
-def _lane_pad(x, width: int):
-    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
 
 
 def _hc_bias(streams: int):
@@ -144,7 +140,7 @@ class _Layer(nn.Module):
     dtype: jnp.dtype
 
     def setup(self):
-        d, g, h, n = self.dim, INIT_GAINS, self.heads, self.streams
+        d, g, n = self.dim, INIT_GAINS, self.streams
 
         def p(name, init, *shape, dtype=None):
             return self.param(name, init, shape, dtype or self.dtype)
@@ -153,17 +149,8 @@ class _Layer(nn.Module):
         self.hc_ffn = hyper_params(p, "hc_ffn", n, d)
         self.norm_in = p("norm_in", norm_scale(1.0), d)
         self.norm_post = p("norm_post", norm_scale(1.0), d)
-        self.w_dq = p("w_dq", seeded(1.0), d, self.q_rank)
-        self.norm_q = p("norm_q", norm_scale(1.0), self.q_rank)
-        self.w_uq = p("w_uq", seeded(g["w_uq"]), self.q_rank,
-                      h * (self.nope + self.rope_dim))
-        self.w_dkv = p("w_dkv", seeded(1.0), d, self.kv_rank + self.rope_dim)
-        self.norm_kv = p("norm_kv", norm_scale(1.0), self.kv_rank)
-        self.w_uk = p("w_uk", seeded(1.0, fan_in_axis=0), self.kv_rank, h,
-                      self.nope)
-        self.w_uv = p("w_uv", seeded(1.0, fan_in_axis=0), self.kv_rank, h,
-                      self.v_dim)
-        self.w_o = p("w_o", seeded(g["w_o"]), h * self.v_dim, d)
+        self.mixer = Latent.of(self)
+        self.latent = self.mixer.declare(p, g)
         if self.dense:
             f = self.mlp_dim
             self.m_gate = p("m_gate", seeded(1.0), d, f)
@@ -181,29 +168,9 @@ class _Layer(nn.Module):
             self.s_up = p("s_up", seeded(1.0), d, s)
             self.s_down = p("s_down", seeded(g["shared_down"]), s, d)
 
-    # -- sizes and positions ---------------------------------------------------
-
-    @property
-    def row(self) -> int:
-        """Lanes of the cached row, padded to whole tiles."""
-        return padded(self.kv_rank + self.rope_dim)
-
     @property
     def scale(self) -> float:
-        """What multiplies the scores: YaRN's ``m(mscale_all_dim)²`` on the
-        published ``(nope + rope)^(−1/2)``."""
-        return float((self.nope + self.rope_dim) ** -0.5 * yarn_mscale(
-            self.rope_factor, self.mscale_all_dim) ** 2)
-
-    def _rotate(self, x, position):
-        """``x (..., heads, rope)`` rotated under YaRN."""
-        x = rope(x, position, None, inv_freq=yarn_inv_freq(
-            self.rope_dim, self.theta, self.rope_factor, self.rope_original,
-            self.beta_fast, self.beta_slow))
-        factor = (yarn_mscale(self.rope_factor, self.mscale)
-                  / yarn_mscale(self.rope_factor, self.mscale_all_dim))
-        return x if factor == 1.0 else (x.astype(jnp.float32)
-                                        * factor).astype(x.dtype)
+        return self.mixer.scale
 
     def _hyper(self, x, params, pre=mhc.pre):
         return pre(x, params, iters=self.sinkhorn_iters, eps=self.hc_eps,
@@ -234,67 +201,6 @@ class _Layer(nn.Module):
         return y + expert_layer.shared(h, None, self.s_gate, self.s_up,
                                        self.s_down), top_e
 
-    # -- latent attention ------------------------------------------------------
-
-    def _down(self, x, position):
-        """``x (..., D)`` after ``n_in`` at ``position (...)`` → the query's
-        latent ``c_q (..., r_q)`` and the row a position caches, ``[c_kv |
-        k_r]`` ``(..., r_kv + rope)``: normed, ``k_r`` rotated."""
-        with jax.named_scope("latent_q"):
-            c_q = rms_norm(_dot("...d,dr->...r", x, self.w_dq).astype(
-                self.dtype), self.norm_q, self.eps)
-        with jax.named_scope("latent_kv"):
-            kv = _dot("...d,dr->...r", x, self.w_dkv).astype(self.dtype)
-            c_kv = rms_norm(kv[..., :self.kv_rank], self.norm_kv, self.eps)
-            k_r = self._rotate(kv[..., None, self.kv_rank:],
-                               position)[..., 0, :]
-            return c_q, jnp.concatenate([c_kv, k_r], axis=-1)
-
-    def _queries(self, c_q, position):
-        """``q_nope (..., H, nope)`` and ``q_rope (..., H, rope)``, rotated."""
-        with jax.named_scope("latent_q"):
-            q = _dot("...r,rhe->...he", c_q, self.w_uq.reshape(
-                self.q_rank, self.heads, -1)).astype(self.dtype)
-            return (q[..., :self.nope],
-                    self._rotate(q[..., self.nope:], position))
-
-    def _out(self, o):
-        with jax.named_scope("out_proj"):
-            return _dot("...e,ed->...d", o.reshape(*o.shape[:-2], -1),
-                        self.w_o).astype(self.dtype)
-
-    def _attend_prompt(self, u):
-        """The mixer over one padded prompt ``u (P, D)`` → its output ``(P,
-        D)`` and the rows it caches ``(P, row)``."""
-        position = jnp.arange(u.shape[0])
-        c_q, row = self._down(rms_norm(u, self.norm_in, self.eps), position)
-        c_kv, k_r = row[:, :self.kv_rank], row[:, self.kv_rank:]
-        with jax.named_scope("latent_kv"):
-            k_nope = _dot("pr,rhn->phn", c_kv, self.w_uk).astype(self.dtype)
-            v = _dot("pr,rhv->phv", c_kv, self.w_uv).astype(self.dtype)
-            k = jnp.concatenate([k_nope, jnp.broadcast_to(
-                k_r[:, None], (*k_nope.shape[:2], self.rope_dim))], axis=-1)
-        q = jnp.concatenate(self._queries(c_q, position), axis=-1)
-        o = kv_pool.prompt_attention(q, k, v, self.scale)
-        return self._out(o), _lane_pad(row, self.row)
-
-    def _attend_step(self, u, pool, layer: int, position, bound: int):
-        """The mixer of one token a slot, absorbed: ``u (S, D)`` against
-        ``pool``'s ``layer`` → its output ``(S, D)`` and the new rows."""
-        c_q, row = self._down(rms_norm(u, self.norm_in, self.eps), position)
-        q_nope, q_rope = self._queries(c_q, position)
-        with jax.named_scope("latent_q"):
-            q = jnp.concatenate(
-                [_dot("shn,rhn->shr", q_nope, self.w_uk).astype(self.dtype),
-                 q_rope], axis=-1)
-        q, row = _lane_pad(q, self.row), _lane_pad(row, self.row)
-        o = kv_pool.latent_decode_attention(
-            q, row, pool, layer, position, value=self.kv_rank,
-            bound=min(bound, pool.shape[2]), scale=self.scale)
-        with jax.named_scope("latent_kv"):
-            o = _dot("shr,rhv->shv", o, self.w_uv).astype(self.dtype)
-        return self._out(o), row
-
     # -- the block -------------------------------------------------------------
 
     def prefill(self, x):
@@ -303,7 +209,8 @@ class _Layer(nn.Module):
         row)`` and the passes its expert product took
         (``experts.window_passes``; None from a dense layer)."""
         u, coef = self._hyper(x, self.hc_attn, mhc.pre_rows)
-        y, row = self._attend_prompt(u)
+        y, row = self.mixer.attend_prompt(
+            self.latent, rms_norm(u, self.norm_in, self.eps))
         x = mhc.post_rows(x, y, coef)
         u, coef = self._hyper(x, self.hc_ffn, mhc.pre_rows)
         y, top_e = self._ffn(u, routed=True)
@@ -318,7 +225,9 @@ class _Layer(nn.Module):
         slot's two ``H_res`` are from doubly stochastic ``(S,)``."""
         u, h_post, h_res = self._hyper(x, self.hc_attn)
         error = mhc.balance_error(h_res)
-        y, row = self._attend_step(u, pool, layer, position, bound)
+        y, row = self.mixer.attend_step(
+            self.latent, rms_norm(u, self.norm_in, self.eps), pool, layer,
+            position, bound)
         x = mhc.post(x, y, h_post, h_res)
         u, h_post, h_res = self._hyper(x, self.hc_ffn)
         error = jnp.maximum(error, mhc.balance_error(h_res))
@@ -385,7 +294,7 @@ class Xing4LM(nn.Module):
         """What a slot holds (``kv_pool.SlotSpec``): every layer's latent row
         a position, whose value is its own first lanes — one tensor."""
         return kv_pool.SlotSpec((kv_pool.Rows(
-            "latent", self.depth, padded(self.kv_rank + self.rope_dim),
+            "latent", self.depth, row_lanes(self.kv_rank, self.rope_dim),
             self.dtype, kind="latent"),))
 
     def _streams(self, tokens, rows: bool = False):

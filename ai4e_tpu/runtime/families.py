@@ -753,6 +753,26 @@ def build_glm5_lm(name: str = "lm", vocab_size: int = 512,
                       vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
 
 
+def build_axk1_lm(name: str = "lm", vocab_size: int = 512,
+                  max_len: int = 256, eos_id: int | None = None,
+                  rng=None, dtype: str = "bfloat16", **dims):
+    """The dense latent-attention decoder (``models/axk1.py`` ``Axk1LM``): a
+    plain pre-norm residual around ``models/latent.py``'s mixer — the one
+    ``xing4`` runs inside its hyper-connections — under YaRN, one latent row
+    a position on every layer; ``dense_layers`` leading dense MLPs, then
+    sigmoid-routed experts chosen without a bias (inside ``route_groups``
+    where given), of which this process holds ``experts_held`` from
+    ``first_expert``, with an ungated shared expert; untied head, bfloat16
+    weights and cache. ``dims``: the model's fields; a key the family does
+    not know is an error, not a default."""
+    from ..models.axk1 import create_axk1_lm
+    from .kvcache import LMServable
+    model, params = create_axk1_lm(rng=rng, vocab_size=vocab_size,
+                                   dtype=dtype, **dims)
+    return LMServable(name=name, model=model, params=params,
+                      vocab_size=vocab_size, max_len=max_len, eos_id=eos_id)
+
+
 # LM families ride the decode engine (``runtime/decode.py``), never the
 # MicroBatcher: ``cli`` tells them from the batch families by this table.
 LM_FAMILIES = {
@@ -764,6 +784,7 @@ LM_FAMILIES = {
     "xing4": build_xing4_lm,
     "ling3": build_ling3_lm,
     "glm5": build_glm5_lm,
+    "axk1": build_axk1_lm,
 }
 
 

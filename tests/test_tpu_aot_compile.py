@@ -1083,3 +1083,128 @@ def test_glm53_step_at_the_benchmark_cell_moves_no_pool(
               f"{prefill.temp_size_in_bytes} + outputs "
               f"{prefill.output_size_in_bytes}, peak {peak}")
         assert peak < 16.3e9, (top, peak, prefill.temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("bound", [12288, 16384])
+def test_latent_kernel_compiles_at_64_heads_on_a_640_lane_row(v5e_sharding,
+                                                              bound):
+    """The latent read at the ``axk1.history`` cell's shapes alone, by
+    Mosaic: 64 heads (``xing4``'s 32 doubled) on one 640-lane row whose first
+    512 lanes are the value, every block under a slot's position, no mask,
+    at both rungs of a 16,384-position cache."""
+    from ai4e_tpu.ops import kv_pool
+    pool = (7, 16, 16384, 640)
+    rows = _on(v5e_sharding, (pool, jnp.bfloat16))
+    q = _on(v5e_sharding, ((16, 64, 640), jnp.bfloat16))
+    new = _on(v5e_sharding, ((16, 640), jnp.bfloat16))
+    ints = _on(v5e_sharding, ((16,), jnp.int32))
+
+    def read(q, new, rows, position):
+        return kv_pool.latent_decode_attention(
+            q, new, rows, 3, position, value=512, bound=bound,
+            scale=192 ** -0.5 * 1.8133, interpret=False)
+
+    compiled = _compile(read, q, new, rows, ints)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("p", [1024, 6144, 14336, 16384])
+def test_prompt_kernel_compiles_at_64_heads(v5e_sharding, p):
+    """The prefill's attention at the ``axk1.history`` cell's shapes alone,
+    by Mosaic: 64 heads with keys 192 wide against values of 128, neither
+    mask nor window, at its shortest bucket, at 6,144, at the traffic's cap
+    14,336 (28 blocks of 512) and at the cache's own length; 4 heads a grid
+    step, and the call's blocks fit the VMEM it asks Mosaic for."""
+    import importlib
+    from ai4e_tpu.ops import kv_pool
+    from ai4e_tpu.ops.pallas.validate import VMEM_PHYSICAL_BYTES
+    flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
+    q, k = (_on(v5e_sharding, ((p, 64, 192), jnp.bfloat16)) for _ in range(2))
+    v = _on(v5e_sharding, ((p, 64, 128), jnp.bfloat16))
+
+    def attend(q, k, v):
+        return kv_pool.prompt_attention(q, k, v, 192 ** -0.5 * 1.8133,
+                                        interpret=False)
+
+    assert "tpu_custom_call" in _compile(attend, q, k, v).as_text()
+    block = flash._prompt_block(p)
+    assert block == 512
+    group = flash._head_group(64, block, 192, 128, 2, False)
+    assert group == 4
+    assert (flash.prompt_vmem_bytes(group, block, 192, 128, 2, False)
+            <= flash.PROMPT_VMEM_BYTES <= VMEM_PHYSICAL_BYTES // 2)
+
+
+@pytest.fixture(scope="module")
+def axk1_cell():
+    from ai4e_tpu.models.axk1 import Axk1LM, create_axk1_lm
+    from benchmark.references.axk1 import NOT_MODEL_KEYS
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "a.x-k1.json")) as f:
+        spec = json.load(f)["models"]["models"][0]
+    return _benchmark_cell(
+        "a.x-k1.json", create_axk1_lm, Axk1LM,
+        [key for key in spec if key not in NOT_MODEL_KEYS])
+
+
+@pytest.mark.parametrize("rung", [0, 1])
+def test_axk1_step_at_the_benchmark_cell_moves_no_pool(
+        v5e_sharding, axk1_cell, rung):
+    """The ``axk1.history`` cell (``benchmark/configs/a.x-k1.json``): one
+    tensor of latent rows ``(7, 16, 16384, 640)`` made only by row writes on
+    its donated parameter, one ``latent_attention`` Mosaic call a layer — 64
+    heads on the row — whose pool operand is the parameter itself, aliased
+    input to output, and no other Mosaic call. At the top rung, the whole
+    worker's memory: weights + pool (at least 11 GB) + the temporaries and
+    outputs of the 8,192 and 14,336 prefills and of the cache length 16,384
+    the runtime adds stay within the chip's 16.9 GB, each prefill with one
+    ``prompt_attention`` Mosaic call a layer."""
+    import importlib
+    import re
+    runtime, spec = axk1_cell
+    assert runtime.step_bounds == (12288, 16384)
+    shape = (7, 16, 16384, 640)
+    assert runtime.cache_spec() == ((shape, jnp.bfloat16),)
+    assert runtime.state_spec() == ()
+    bound = runtime.step_bounds[rung]
+    compiled = _compile_step(runtime, v5e_sharding, bound)
+    results, entry = _entry_results(compiled), _entry(compiled)
+    kernels = _mosaic_calls(compiled, "latent_attention")
+    assert len(kernels) == 7, len(kernels)
+    pool_type = _hlo_type(shape, jnp.bfloat16)
+    makers = [op for kind, op in results if kind.startswith(pool_type)]
+    assert sorted(set(makers)) == ["dynamic-update-slice", "parameter"]
+    assert makers.count("dynamic-update-slice") == 16
+    (pool,) = re.findall(r"(%\S+) = " + re.escape(pool_type)
+                         + r"\S* parameter\(", entry)
+    assert sum(pool in ops for _, ops, _ in kernels) == 7
+    assert len(kernels) == compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"')
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= runtime.cache_nbytes()
+    assert runtime.cache_nbytes() == 2 * 7 * 16 * 16384 * 640
+    assert memory.temp_size_in_bytes < 0.5e9, memory.temp_size_in_bytes
+    if bound < runtime.max_len:
+        return
+
+    resident = memory.argument_size_in_bytes   # weights + pool (+ ints)
+    assert 11.9e9 < resident < 12.2e9, resident
+    flash = importlib.import_module("ai4e_tpu.ops.pallas.flash_attention")
+    for top in (8192, 14336, runtime.max_len):
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(flash, "resolve_interpret",
+                          lambda kernel, interpret: False)
+            prefill = runtime._programs["prefill"].lower(
+                _on(v5e_sharding, runtime.servable.params),
+                _on(v5e_sharding, ((1, top), jnp.int32)),
+                _on(v5e_sharding, ((1,), jnp.int32))).compile()
+        assert len(_mosaic_calls(prefill, "prompt_attention")) == 7
+        prefill = prefill.memory_analysis()
+        peak = resident + max(memory.temp_size_in_bytes,
+                              prefill.temp_size_in_bytes
+                              + prefill.output_size_in_bytes)
+        print(f"axk1 cell: resident {resident}, step temporaries "
+              f"{memory.temp_size_in_bytes}, prefill {top}: temporaries "
+              f"{prefill.temp_size_in_bytes} + outputs "
+              f"{prefill.output_size_in_bytes}, peak {peak}")
+        assert peak < 16.3e9, (top, peak, prefill.temp_size_in_bytes)
