@@ -47,6 +47,7 @@ import asyncio
 import inspect
 import json
 import logging
+import os
 import signal
 
 from .config import ConfigError, FrameworkConfig
@@ -242,7 +243,6 @@ def _restore_checkpoint(servable, checkpoint: str,
     working directory — orbax requires absolute paths. The path is
     recorded for the hot-reload endpoint (POST
     {prefix}/models/{name}/reload re-reads it)."""
-    import os
     from .checkpoint import load_params
     if not os.path.isabs(checkpoint):
         checkpoint = os.path.abspath(os.path.join(
@@ -403,7 +403,6 @@ def build_worker(config: FrameworkConfig, models: dict):
         # device_path.md): restore any persisted derived ladder now —
         # BEFORE warmup — so the restarted worker compiles the tuned
         # ladder and its first serving call stamps execute, not compile.
-        import os
         from .runtime.ladder import LadderManager
         ladders = LadderManager(
             runtime, window_s=rt.ladder_window_s,
@@ -481,7 +480,11 @@ def build_worker(config: FrameworkConfig, models: dict):
     elif lm_specs:
         boot.enter("build")   # the decode engine's and the families' imports
         from .runtime.decode import DecodeEngine
+        from .runtime.executables import ExecutableStore
         from .runtime.kvcache import PagedDecodeRuntime, build_lm_servable
+        # Beside the compile cache: every process of a checkout resolves the
+        # same path, and this start loads what the last one built.
+        store = ExecutableStore(os.path.join(cache_dir, "executables"))
         for spec in lm_specs:
             async_path = spec.pop("async_path", None)
             cap = spec.pop("maximum_concurrent_requests", 64)
@@ -494,7 +497,7 @@ def build_worker(config: FrameworkConfig, models: dict):
             boot.note(model=lm.name, param_bytes=_param_bytes(lm.params))
             backend = PagedDecodeRuntime(
                 lm, slots=rt.kv_slots,
-                prompt_buckets=rt.decode_prompt_buckets or None)
+                prompt_buckets=rt.decode_prompt_buckets or None, store=store)
             boot.enter("pools", model=lm.name, bytes=backend.cache_nbytes())
             backend.reset_cache()   # the first pool: allocated here
             boot.enter("warm", model=lm.name)
@@ -506,9 +509,10 @@ def build_worker(config: FrameworkConfig, models: dict):
             worker.serve_stream(engine, async_path=async_path,
                                 maximum_concurrent_requests=cap)
             log.info("decode engine %s: %d slots, max_len %d, prompt "
-                     "buckets %s, cache %.1f MB", lm.name, backend.slots,
-                     backend.max_len, backend.prompt_buckets,
-                     backend.cache_nbytes() / 1e6)
+                     "buckets %s, cache %.1f MB, stored executables %.1f MB "
+                     "under %s", lm.name, backend.slots, backend.max_len,
+                     backend.prompt_buckets, backend.cache_nbytes() / 1e6,
+                     store.nbytes() / 1e6, store.directory)
 
     boot.enter("serve")
     if jax.process_count() > 1:

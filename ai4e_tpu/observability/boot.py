@@ -14,7 +14,11 @@ This module books it through the tracer that is there (``tracing.Span``,
 - under a ``boot.warm``, one ``boot.warm.program`` a program call of
   ``PagedDecodeRuntime.warm()``, which also opens ``device_trace(
   "ai4e.boot.warm")`` so a profiler session laid over a boot has the host's
-  Python and the device's first execution on one clock.
+  Python and the device's first execution on one clock. How the call came by
+  each program it ran (``obtained``) is on the span too: ``loaded`` from the
+  store of executables (``runtime/executables.py``) with the ``load_s`` that
+  took, or ``built`` — traced, lowered and compiled — and the span's
+  ``outcome`` is ``loaded`` where nothing was built.
 
 Every span carries wall seconds and the process's CPU seconds (``cpu_s``:
 wall far above it is a worker that waited — for cores, for the device — and
@@ -31,7 +35,8 @@ Spans are opened and closed by the ledger itself — none is ever the
 context's current span, so no request inherits the boot's trace — kept in
 memory, and handed to the process's tracer when the worker starts serving
 (``serving()``), with the gauges ``ai4e_boot_seconds{phase}`` /
-``ai4e_boot_cpu_seconds{phase}`` and one summary in the log. The listener
+``ai4e_boot_cpu_seconds{phase}``, ``ai4e_boot_programs_total{outcome}``,
+``ai4e_boot_program_load_seconds_total`` and one summary in the log. The listener
 stays registered: it fires only when something compiles, so a warm worker
 pays nothing, and a compile while serving is counted under ``when="serving"``
 with the function's name in a WARNING line.
@@ -106,6 +111,10 @@ class BootLedger:
         # last, ``(start, seconds)`` — what a later event that encloses them
         # has to leave out.
         self._local = threading.local()
+        # The warmed programs by how the boot came by them, and the seconds
+        # the loaded ones took to load.
+        self.programs = {"loaded": 0, "built": 0}
+        self.load_s = 0.0
         # Both began with the process: their CPU seconds count from 0.
         self.root = self._open_span("boot", None, start_epoch, cpu0=0.0)
         self._phase = self._open_span("boot.import", self.root, start_epoch,
@@ -169,8 +178,25 @@ class BootLedger:
             self._local.program = None
             self._close_span(span, time.time())
             span.attrs["run_s"] = round(
-                span.duration - sum(span.attrs.get(p, 0.0) for p in _PARTS),
-                4)
+                span.duration - sum(span.attrs.get(p, 0.0) for p in _PARTS)
+                - span.attrs.get("load_s", 0.0), 4)
+            if span.attrs.get("loaded") or span.attrs.get("built"):
+                span.attrs["outcome"] = ("built" if span.attrs.get("built")
+                                         else "loaded")
+
+    def obtained(self, outcome: str, load_s: float = 0.0) -> None:
+        """A warm-up call came by one of its programs: ``loaded`` from the
+        store of executables in ``load_s`` seconds, or ``built``. Counted
+        for the boot, and on the program span this thread has open."""
+        with self._lock:
+            self.programs[outcome] += 1
+            self.load_s += load_s
+            span = getattr(self._local, "program", None)
+            if span is not None:
+                span.attrs[outcome] = span.attrs.get(outcome, 0) + 1
+                if load_s:
+                    span.attrs["load_s"] = round(
+                        span.attrs.get("load_s", 0.0) + load_s, 4)
 
     # -- JAX's events ----------------------------------------------------------
 
@@ -259,6 +285,15 @@ class BootLedger:
             "cache": metrics.counter(
                 "ai4e_jax_compile_cache_total", "Persistent compile cache "
                 "hits and misses as JAX reported them")}
+        programs = metrics.counter(
+            "ai4e_boot_programs_total", "Programs this worker's start ran "
+            "before serving, by how it came by them: loaded from the store "
+            "of executables, or built (traced, lowered, compiled)")
+        for outcome, count in self.programs.items():
+            programs.inc(count, outcome=outcome)
+        metrics.counter(
+            "ai4e_boot_program_load_seconds_total", "Seconds this worker's "
+            "start spent loading stored executables").inc(self.load_s)
         phases = self.phase_seconds()
         for phase, (wall_s, cpu_s) in phases.items():
             wall.set(wall_s, phase=phase)
@@ -299,7 +334,9 @@ class BootLedger:
                     jax_s[phase][i] += span.attrs.get(part, 0.0)
         parts = "trace %.2f lower %.2f compile %.2f retrieve %.2f"
         lines = ["boot: %.1fs from process start to serving (cpu %.1fs), "
-                 "trace %s" % (*phases["total"], self.root.trace_id)]
+                 "%d programs loaded in %.2fs, %d built, trace %s" % (
+                     *phases["total"], self.programs["loaded"], self.load_s,
+                     self.programs["built"], self.root.trace_id)]
         for phase in PHASES:
             lines.append(("  %-13s %7.2fs  cpu %7.2fs  " + parts) % (
                 phase, *phases[phase], *jax_s[phase]) + "".join(
@@ -312,11 +349,12 @@ class BootLedger:
                             if k in a)
             lines.append(
                 ("  warm %-8s %-24s %6.2fs  cpu %6.2fs  " + parts
-                 + " run %.2f  hit %d miss %d") % (
+                 + " load %.2f run %.2f  hit %d miss %d  %s") % (
                     a.get("program", "?"), what, span.duration,
                     a.get("cpu_s", 0.0), *(a.get(p, 0.0) for p in _PARTS),
-                    a.get("run_s", 0.0), a.get("cache_hits", 0),
-                    a.get("cache_misses", 0)))
+                    a.get("load_s", 0.0), a.get("run_s", 0.0),
+                    a.get("cache_hits", 0), a.get("cache_misses", 0),
+                    a.get("outcome", "ran")))
         return "\n".join(lines)
 
 
@@ -400,6 +438,12 @@ _NO_SPAN = contextlib.nullcontext()
 def program(program: str, **attrs):
     ledger = active()
     return _NO_SPAN if ledger is None else ledger.program(program, **attrs)
+
+
+def obtained(outcome: str, load_s: float = 0.0) -> None:
+    ledger = active()
+    if ledger is not None:
+        ledger.obtained(outcome, load_s)
 
 
 def serving(metrics) -> None:

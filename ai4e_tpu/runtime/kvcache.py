@@ -19,7 +19,10 @@ per request.
 
 Three kinds of compiled program serve the whole path, none of which may
 compile on the serving path (``warm()`` executes every one — the AOT-warm
-discipline ``ModelRuntime.warmup`` applies to batch buckets):
+discipline ``ModelRuntime.warmup`` applies to batch buckets). Each is built
+once a size — ``jit(...).lower(...).compile()`` — and called as that
+executable from then on; a runtime given a store (``runtime/executables.py``)
+loads the executable its last start built instead, and traces nothing:
 
 - **prefill** — full causal attention over ONE padded prompt, per
   prompt bucket (``ladder.DECODE_PROMPT_BUCKETS``: prompts pad to the
@@ -69,6 +72,7 @@ import numpy as np
 from ..observability import boot
 from ..observability.tracing import device_trace
 from ..ops import kv_pool, state_pool
+from . import executables
 from .decode import LaunchedStep
 
 # What the step program's compiler is told, by backend. XLA:TPU cuts each
@@ -134,7 +138,8 @@ class PagedDecodeRuntime:
     has answered but ``launch`` and ``join``, which only dispatch."""
 
     def __init__(self, servable: LMServable, slots: int = 8,
-                 prompt_buckets=None, donate: bool | None = None):
+                 prompt_buckets=None, donate: bool | None = None,
+                 store: executables.ExecutableStore | None = None):
         from .ladder import DECODE_PROMPT_BUCKETS
         if slots < 1:
             raise ValueError(f"slots must be >= 1, got {slots}")
@@ -187,7 +192,14 @@ class PagedDecodeRuntime:
         # The last launched step: unread for as long as it has its ``out``.
         self._newest = None
         self._donate = donate
+        # The jitted functions by name and what each was jitted with; the
+        # executables built or loaded from them, by ``(name, size)`` — a
+        # prefill's and an insert's bucket, a step's rung; and where the
+        # built ones are kept between starts (None: nowhere).
         self._programs = None
+        self._jitted_with = None
+        self._executables = {}
+        self._store = store
         # ``hook(phase, seconds)``, installed by the DecodeEngine: told the
         # seconds a fetch spent blocked on the device (``device_wait``) and
         # of those its read-back alone (``readback``), the seconds a join
@@ -196,8 +208,8 @@ class PagedDecodeRuntime:
         # before it and, in ``prefill_into``, its own), the instant before a
         # prefill or a step is enqueued (``enqueue``, 0 seconds: the device
         # thread's ledger closes there) and the seconds of any call that had
-        # to build its program (``compile``). None (and during ``warm()``):
-        # nothing is reported.
+        # to build or load its program first (``compile``). None (and during
+        # ``warm()``): nothing is reported.
         self.phase_hook = None
 
     # -- cache lifecycle ---------------------------------------------------
@@ -258,8 +270,10 @@ class PagedDecodeRuntime:
         written its slot."""
         if self._ids is None:
             import jax.numpy as jnp
-            self._ids = jnp.zeros(
-                ((1 + len(self.report_kinds)) * self.slots,), jnp.int32)
+            # Copied from the host, not computed: a start on loaded
+            # executables compiles nothing, not even a ``zeros``.
+            self._ids = jnp.asarray(np.zeros(
+                ((1 + len(self.report_kinds)) * self.slots,), np.int32))
 
     def _build_programs(self) -> None:
         import jax
@@ -308,30 +322,74 @@ class PagedDecodeRuntime:
                     ids.at[slot + slots * jnp.arange(token.shape[0])].set(
                         token))
 
-        self._programs = {
-            "prefill": jax.jit(prefill),
-            # ``bound`` is static: one entry of this jit's cache per rung,
-            # so ``_run`` sees a rung that was not warmed as a compile.
-            "step": jax.jit(step, donate_argnums=donate_step,
-                            static_argnums=(5,),
-                            compiler_options=STEP_COMPILER_OPTIONS.get(
-                                jax.default_backend())),
-            "insert": jax.jit(insert, donate_argnums=donate_insert),
+        self._jitted_with = {
+            "prefill": {},
+            # ``bound`` is static: one executable a rung.
+            "step": {"donate_argnums": donate_step, "static_argnums": (5,),
+                     "compiler_options": STEP_COMPILER_OPTIONS.get(
+                         jax.default_backend())},
+            "insert": {"donate_argnums": donate_insert},
         }
+        self._programs = {
+            name: jax.jit(fn, **self._jitted_with[name])
+            for name, fn in (("prefill", prefill), ("step", step),
+                             ("insert", insert))}
 
-    def _run(self, program: str, *args):
-        """Call one of the programs. A call that grew the jit's
-        dispatch cache traced and compiled (or loaded from the persistent
-        cache) instead of dispatching what ``warm()`` had built: its
-        seconds go to the hook as ``compile`` — read off the cache itself,
-        as ``registry._execute_blocked`` does for the batch path."""
-        fn = self._programs[program]
-        before = fn._cache_size()
+    def _run(self, program: str, size: int, *args):
+        """Call the executable of one of the programs at ``size`` (a
+        prefill's or an insert's bucket, a step's rung). A call that finds
+        none — a size ``warm()`` did not run — gets one first, and its
+        seconds go to the hook as ``compile``: read off the runtime's own
+        table of executables, as ``registry._execute_blocked`` reads the
+        batch path's off the jit's cache."""
+        call = self._executables.get((program, size))
+        if call is not None:
+            return call(*args)
         t0 = time.perf_counter()
-        out = fn(*args)
-        if fn._cache_size() > before:
-            self._tell("compile", time.perf_counter() - t0)
+        call = self._executables[program, size] = self._obtain(
+            program, size, args)
+        out = call(*args)
+        self._tell("compile", time.perf_counter() - t0)
         return out
+
+    def _key(self, program: str, size: int, arguments) -> str:
+        """The store's key of one program: beside the store's own part (the
+        source, the installation, the device) it holds the model, the
+        cache's geometry, how the program was jitted — donation, the static
+        argument, the compiler's options — and ``arguments``, the call's
+        ``executables.signature``."""
+        return self._store.key(
+            model=repr(self.servable.model), slots=self.slots,
+            max_len=self.max_len, prompt_buckets=self.prompt_buckets,
+            step_bounds=self.step_bounds, rows=self.cache_spec(),
+            state=self.state_spec(), program=program, size=size,
+            jitted_with=self._jitted_with[program], arguments=arguments)
+
+    def _obtain(self, program: str, size: int, args):
+        """The executable of ``program`` for ``args``: the one the store
+        holds under this call's key, else built as the jitted function
+        compiles it (through JAX's persistent compile cache) and stored.
+        What decides is whether the store holds the key. No store, or
+        arguments that span devices: built, and kept nowhere."""
+        described = (executables.signature(args) if self._store is not None
+                     else None)
+        key = None
+        if described is not None:
+            arguments, device = described
+            key = self._key(program, size, arguments)
+            t0 = time.perf_counter()
+            call = self._store.load(key, device)
+            if call is not None:
+                boot.obtained("loaded", time.perf_counter() - t0)
+                return call
+        # The step's static argument is its size; the others have none.
+        static = ((size,) if "static_argnums" in self._jitted_with[program]
+                  else ())
+        lowered = self._programs[program].lower(*args, *static)
+        call = (lowered.compile() if key is None
+                else self._store.build(key, lowered))
+        boot.obtained("built")
+        return call
 
     def _tell(self, phase: str, seconds: float = 0.0) -> None:
         if self.phase_hook is not None:
@@ -407,12 +465,12 @@ class PagedDecodeRuntime:
         self._tell("enqueue")
         with device_trace("ai4e.decode.prefill", bucket=bucket, slot=slot):
             token, *blocks, state_block = self._run(
-                "prefill", self.servable.params, padded,
+                "prefill", bucket, self.servable.params, padded,
                 np.asarray([n], np.int32))
         with device_trace("ai4e.decode.insert", slot=slot):
             self._rows, self._state, self._ids = self._run(
-                "insert", self._rows, self._state, self._ids, tuple(blocks),
-                state_block, token, np.int32(slot))
+                "insert", bucket, self._rows, self._state, self._ids,
+                tuple(blocks), state_block, token, np.int32(slot))
         self._joined.append(token)
         self._reports[slot] = None
         return token, waited
@@ -526,8 +584,8 @@ class PagedDecodeRuntime:
             with device_trace("ai4e.decode.dispatch", bound=bound,
                               starved=int(starved)):
                 out, self._ids, self._rows, self._state = self._run(
-                    "step", self.servable.params, host, self._ids,
-                    self._rows, self._state, bound)
+                    "step", bound, self.servable.params, host, self._ids,
+                    self._rows, self._state)
         except Exception:
             self._ids = None   # nothing launched: the next launch feeds all
             raise
@@ -606,11 +664,15 @@ class PagedDecodeRuntime:
         insert pairs and the step program of every rung of ``step_bounds``
         — so nothing compiles on the serving path, then reset the cache to
         a clean pool. The programs do not depend on the weights: after
-        ``reload_params`` the same ones serve. Under a worker's boot
-        (``observability/boot.py``; ``cli.build_worker`` marks the phase)
-        every call below is one ``boot.warm.program`` span holding what JAX
-        reported of its trace, lowering, compile and cache; anywhere else
-        nothing is recorded."""
+        ``reload_params`` the same ones serve. Each is loaded from the store
+        where it holds the program's key, else built and stored
+        (``_obtain``): the serving path calls the very executables this
+        ran. Under a worker's boot (``observability/boot.py``;
+        ``cli.build_worker`` marks the phase) every call below is one
+        ``boot.warm.program`` span holding what JAX reported of its trace,
+        lowering, compile and cache, how many programs it loaded and built
+        and the seconds the loads took; anywhere else nothing is
+        recorded."""
         self._ensure()
         for bucket in self.prompt_buckets:
             n = min(bucket, self.max_len - 1)

@@ -739,22 +739,15 @@ class FakeArray:
 
 
 class FakePrograms(dict):
-    """``prefill`` / ``insert`` of a runtime over a scripted device: at
-    every prefill's dispatch, how many earlier joins nobody has waited
-    for."""
+    """The ``prefill`` / ``insert`` executables of a runtime's one bucket
+    over a scripted device: at every prefill's dispatch, how many earlier
+    joins nobody has waited for."""
 
-    def __init__(self):
+    def __init__(self, bucket):
         self.waited, self.tokens, self.unwaited = set(), [], []
-        self["prefill"] = self._program(self._prefill)
-        self["insert"] = self._program(
-            lambda *args: tuple(FakeArray(self.waited) for _ in range(3)))
-
-    @staticmethod
-    def _program(fn):
-        def program(*args):
-            return fn(*args)
-        program._cache_size = lambda: 1
-        return program
+        self["prefill", bucket] = self._prefill
+        self["insert", bucket] = lambda *args: tuple(
+            FakeArray(self.waited) for _ in range(3))
 
     def _prefill(self, params, padded, length):
         self.unwaited.append(sum(id(t) not in self.waited
@@ -774,7 +767,8 @@ def test_the_runtime_keeps_at_most_two_joins_in_flight(joins):
         **FAMILIES["seqformer-lm"]), slots=4, prompt_buckets=(8,))
     runtime._rows = runtime._state = object()
     runtime._ids = object()
-    programs = runtime._programs = FakePrograms()
+    runtime._programs = {}   # built: nothing below may reach for them
+    programs = runtime._executables = FakePrograms(bucket=8)
     for i in range(joins):
         assert runtime.join(i % 4, [1, 2, 3]) is None
     assert programs.unwaited == [0, 1, 1, 1, 1, 1, 1, 1, 1][:joins]

@@ -187,7 +187,8 @@ class TestEveryRungIsWarmed:
     @pytest.mark.parametrize("bound", RUNGS)
     def test_a_step_at_a_warmed_rung_compiles_nothing(self, warmed, bound):
         assert warmed.step_bounds == RUNGS
-        assert warmed._programs["step"]._cache_size() == len(RUNGS)
+        assert sorted(size for name, size in warmed._executables
+                      if name == "step") == list(RUNGS)
         phases = []
         warmed.phase_hook = _compiles_and_waits(phases)
         step = warmed.fetch(warmed.launch([1] * SLOTS, [bound - 1, 0, 0],
@@ -207,7 +208,9 @@ class TestEveryRungIsWarmed:
         assert phases == ["device_wait"] * len(RUNGS)
 
     def test_a_rung_that_was_not_warmed_is_a_compile(self, warmed):
-        warmed._programs["step"].clear_cache()
+        warmed._executables = {
+            key: call for key, call in warmed._executables.items()
+            if key[0] != "step"}
         phases = []
         warmed.phase_hook = _compiles_and_waits(phases)
         for position in (5, 6, 400):

@@ -276,7 +276,9 @@ class TestDecodeProgramCompiles:
         if state != "cold":
             runtime.warm()
         if state == "step program dropped":
-            runtime._programs["step"].clear_cache()
+            runtime._executables = {
+                key: call for key, call in runtime._executables.items()
+                if key[0] != "step"}
         ledger = HopLedger()
         reg, out = serve(runtime, [([1, 2, 3], 4, ledger)])
         assert len(out[0]) == 4
